@@ -212,6 +212,65 @@ def _git_sha() -> str:
         return "unknown"
 
 
+_DEVICE_KEYS = ("platform", "device_kind", "device_count")
+
+
+def _name_device(record, source=None):
+    """Every record names the device it came from — ``platform``,
+    ``device_kind``, ``device_count`` as JAX reports them — so a CPU number
+    can never pass for a chip number.  A parent that only aggregated
+    measurement children (and stayed off JAX so they could have the chip)
+    passes the child record as ``source``."""
+    if source is not None:
+        record.update({k: source.get(k) for k in _DEVICE_KEYS})
+    elif "platform" not in record:
+        from lightgbm_tpu.runtime import device_record
+        record.update(device_record())
+    return record
+
+
+def _emit(record, source=None) -> None:
+    """Print one result line, device named."""
+    print(json.dumps(_name_device(record, source)), flush=True)
+
+
+def _emit_head(record) -> None:
+    """_emit for the service gates whose full record goes to a BENCH_*.json
+    artifact: the printed line keeps the headline keys only."""
+    _name_device(record)
+    print(json.dumps({k: record[k] for k in
+                      ("metric", "value", "unit", "vs_baseline",
+                       *_DEVICE_KEYS, "replica_platform")
+                      if k in record}), flush=True)
+
+
+def _arm_devices(n_dev: int, what: str):
+    """Where a multi-device arm's children run.  The probe is a throw-away
+    process (this parent never initialises JAX: a chip belongs to one
+    process and the children need it).  With ``n_dev`` chips the children
+    are told ``tpu``; with fewer they are told ``cpu`` with virtual devices
+    — said out loud here, and the caller prefixes its metric ``cpusim_`` so
+    CPU time-slicing is never written under a device metric's name.
+    Returns (forced_cpu, probe record)."""
+    from lightgbm_tpu.runtime import probe_devices
+    have = probe_devices()
+    forced_cpu = have["platform"] != "tpu" or have["device_count"] < n_dev
+    if forced_cpu:
+        print(f"{what}: needs {n_dev} chips, found {have['platform']} x "
+              f"{have['device_count']} — running on VIRTUAL CPU DEVICES: "
+              "counts (bytes, launches, syncs) carry over, times do not; "
+              "metrics are prefixed cpusim_", file=sys.stderr, flush=True)
+    return forced_cpu, have
+
+
+def _arm_env(env, forced_cpu: bool, have, n_dev: int):
+    """A measurement child's environment with its platform stated."""
+    from lightgbm_tpu.runtime import child_env
+    if forced_cpu:
+        return child_env("cpu", n_cpu_devices=n_dev, base=env)
+    return child_env(have["platform"], base=env)
+
+
 def _append_history(record, ok: bool = True) -> None:
     """One line per bench result into the unified BENCH_HISTORY.jsonl —
     the in-repo measurement archive scripts/perf_sentinel.py compares
@@ -231,6 +290,7 @@ def _append_history(record, ok: bool = True) -> None:
     from lightgbm_tpu.telemetry import (costmodel, host_sync_count,
                                         launch_count)
     flops, hbm = costmodel.dispatch_totals()
+    record = _name_device(dict(record))
     row = {
         "date": datetime.datetime.now(datetime.timezone.utc)
         .isoformat(timespec="seconds"),
@@ -240,6 +300,7 @@ def _append_history(record, ok: bool = True) -> None:
         "value": record.get("value"),
         "unit": record.get("unit"),
         "vs_baseline": record.get("vs_baseline"),
+        **{k: record[k] for k in _DEVICE_KEYS},
         # cumulative process counters at append time: launch/sync budget
         # drift shows up here even when wall-clock noise hides it
         "launches": launch_count(),
@@ -356,7 +417,7 @@ def run_ranking():
         **_memory_fields(rss0),
         **_telemetry_fields(bst),
     }
-    print(json.dumps(record), flush=True)
+    _emit(record)
     _append_history(record)
     return ok
 
@@ -452,7 +513,7 @@ def run_multiclass():
         **_memory_fields(rss0),
         **_telemetry_fields(bst),
     }
-    print(json.dumps(record), flush=True)
+    _emit(record)
     _append_history(record)
     return ok
 
@@ -566,8 +627,10 @@ def _wide_child():
         eng = bst.engine
         cm = eng._comms_model() or {}
         gp = eng._grow_params
+        from lightgbm_tpu.runtime import device_record
         out = {
-            "wide_child": 1, "task": task, "learner": learner,
+            "wide_child": 1, **device_record(),
+            "task": task, "learner": learner,
             "features": f, "rows": rows,
             "devices": cm.get("devices", 1),
             "s_per_tree": round(s_per_tree, 4),
@@ -624,15 +687,7 @@ def run_wide():
                 ("rank", 1024, int(os.environ.get("BENCH_WIDE_RANK_ROWS",
                                                   20000)))]
     max_dev = max(sweep)
-
-    probe = subprocess.run(
-        [sys.executable, "-c", "import jax; print(len(jax.devices()))"],
-        capture_output=True, text=True)
-    try:
-        visible = int(probe.stdout.strip().splitlines()[-1])
-    except (ValueError, IndexError):
-        visible = 0
-    forced_cpu = visible < max_dev
+    forced_cpu, have = _arm_devices(max_dev, "BENCH_TASK=wide")
 
     top_k = int(os.environ.get("BW_TOPK", "20"))
 
@@ -648,12 +703,7 @@ def run_wide():
         env["LGBTPU_HIST_COMMS"] = "psum"
         env.pop("LGBTPU_FUSE_ITER", None)
         env.pop("LGBTPU_COMPACT", None)
-        if forced_cpu:
-            env["JAX_PLATFORMS"] = "cpu"
-            flags = [x for x in env.get("XLA_FLAGS", "").split() if not
-                     x.startswith("--xla_force_host_platform_device_count")]
-            env["XLA_FLAGS"] = " ".join(
-                flags + [f"--xla_force_host_platform_device_count={n_dev}"])
+        env = _arm_env(env, forced_cpu, have, n_dev)
         r = subprocess.run([sys.executable, os.path.abspath(__file__)],
                            env=env, capture_output=True, text=True,
                            cwd=os.path.dirname(os.path.abspath(__file__)))
@@ -751,7 +801,8 @@ def run_wide():
         next(iter(results.values()))
     plat = "forced-CPU virtual devices" if forced_cpu else "accelerators"
     record = {
-        "metric": f"wide_feature_parallel_s_per_tree_{max_dev}dev",
+        "metric": (("cpusim_" if forced_cpu else "")
+                   + f"wide_feature_parallel_s_per_tree_{max_dev}dev"),
         "value": head["feature"]["s_per_tree"],
         "unit": (f"s/tree, tree_learner=feature at {max_dev} devices "
                  f"({plat}), {head['feature']['features']} features "
@@ -773,7 +824,7 @@ def run_wide():
                   "failures": failures},
         "arms": results,
     }
-    print(json.dumps(record), flush=True)
+    _emit(record, source=head["feature"])
     if failures:
         for msg in failures:
             print(f"BENCH_WIDE gate FAIL: {msg}", flush=True)
@@ -858,7 +909,6 @@ def run_goss():
     auc = auc_score(y_te, bst.predict(X_te, raw_score=True))
     scale = HIGGS_ROWS / N_ROWS
     ok = auc >= AUC_GATE and speedup >= speed_gate and compact > 0
-    import jax
     record = {
         "metric": "higgs_like_goss_s_per_tree",
         "value": round(goss_s * scale, 4),
@@ -878,11 +928,10 @@ def run_goss():
                               "goss": round(goss_lpi, 3)},
         "auc": round(float(auc), 5),
         "rows": N_ROWS,
-        "platform": jax.default_backend(),
         **_memory_fields(rss0),
         **_telemetry_fields(bst),
     }
-    print(json.dumps(record), flush=True)
+    _emit(record)
     _append_history(record)
     if ok:
         # the committed artifact holds the last PASSING measurement; a
@@ -962,8 +1011,9 @@ def _histfloor_child():
         eng = bst.engine
         cm = eng._comms_model() or {}
         sampled = eng._last_sampled_rows or 0
+        from lightgbm_tpu.runtime import device_record
         out = {
-            "hf_child": 1, "arm": arm,
+            "hf_child": 1, **device_record(), "arm": arm,
             "backend": eng._grow_params.hist_backend,
             "s_per_tree": round(s_per_tree, 4),
             "auc": round(auc, 5),
@@ -1043,15 +1093,7 @@ def run_histfloor():
                                     "0.78" if smoke else str(AUC_GATE)))
     proj_gate = float(os.environ.get("BENCH_HISTFLOOR_PROJ_GATE", "0.10"))
     mesh_d = 4
-
-    probe = subprocess.run(
-        [sys.executable, "-c", "import jax; print(len(jax.devices()))"],
-        capture_output=True, text=True)
-    try:
-        visible = int(probe.stdout.strip().splitlines()[-1])
-    except (ValueError, IndexError):
-        visible = 0
-    forced_cpu = visible < mesh_d
+    forced_cpu, have = _arm_devices(mesh_d, "BENCH_TASK=histfloor mesh arms")
 
     def child(arm, n_dev=0):
         env = dict(os.environ)
@@ -1064,12 +1106,9 @@ def run_histfloor():
                   "LGBTPU_ROUTE_FUSION", "LGBTPU_HIST_COMMS",
                   "LGBTPU_FUSE_ITER", "LGBTPU_COMPACT"):
             env.pop(k, None)
-        if n_dev > 0 and forced_cpu:
-            env["JAX_PLATFORMS"] = "cpu"
-            flags = [x for x in env.get("XLA_FLAGS", "").split() if not
-                     x.startswith("--xla_force_host_platform_device_count")]
-            env["XLA_FLAGS"] = " ".join(
-                flags + [f"--xla_force_host_platform_device_count={n_dev}"])
+        # single-device arms run on whatever the host has; mesh arms on
+        # chips when there are mesh_d of them, else virtual CPU devices
+        env = _arm_env(env, n_dev > 0 and forced_cpu, have, n_dev)
         r = subprocess.run([sys.executable, os.path.abspath(__file__)],
                            env=env, capture_output=True, text=True,
                            cwd=os.path.dirname(os.path.abspath(__file__)))
@@ -1172,7 +1211,7 @@ def run_histfloor():
                   "failures": failures},
         "arms": arms,
     }
-    print(json.dumps(record), flush=True)
+    _emit(record, source=arms["stream"])
     if failures:
         for msg in failures:
             print(f"BENCH_HISTFLOOR gate FAIL: {msg}", flush=True)
@@ -1273,17 +1312,17 @@ def main():
                      f"({'OK' if resume_ok else 'FAIL'}: gate < 2%)"),
             "vs_baseline": None,
         }
-        print(json.dumps(ck_record), flush=True)
+        _emit(ck_record)
         _append_history(ck_record, ok=resume_ok)
 
     if auc < AUC_GATE:
-        print(json.dumps({
+        _emit({
             "metric": "higgs_like_train_s_per_tree_10p5M_rows",
             "value": round(s_per_tree_full, 4),
             "unit": f"s/tree INVALID: AUC {auc:.4f} < gate {AUC_GATE}",
             "vs_baseline": 0.0,
             **_memory_fields(rss0),
-        }), flush=True)
+        })
         return False
     record = {
         "metric": "higgs_like_train_s_per_tree_10p5M_rows",
@@ -1294,7 +1333,7 @@ def main():
         **_memory_fields(rss0),
         **_telemetry_fields(bst),
     }
-    print(json.dumps(record), flush=True)
+    _emit(record)
     _append_history(record)
     return resume_ok
 
@@ -1376,8 +1415,10 @@ def _multichip_child() -> bool:
         rounds = max(rounds, int(math.ceil(math.log2(gp.num_leaves))))
     auc = auc_score(y_te, bst.predict(X_te, raw_score=True))
     snap = global_registry.snapshot()
+    from lightgbm_tpu.runtime import device_record
     print(json.dumps({
-        "mc_child": True, "devices": n_dev, "mode": mode,
+        "mc_child": True, **device_record(),
+        "devices": n_dev, "mode": mode,
         "fused": bool(bst.engine._fused_last),
         "s_per_tree": round(s_per_tree, 6), "auc": round(float(auc), 5),
         "launches_per_iter": round(launches_iter, 3),
@@ -1418,21 +1459,12 @@ def run_multichip_bench() -> bool:
     iters = int(os.environ.get("BENCH_MULTICHIP_ITERS", N_ITERS))
     max_dev = max(sweep)
 
-    # probe the device count in a THROWAWAY subprocess: initializing jax in
-    # this parent would take the accelerator lock (libtpu is exclusive) and
-    # every measuring child below would then fall back to CPU
-    probe = subprocess.run(
-        [sys.executable, "-c", "import jax; print(len(jax.devices()))"],
-        capture_output=True, text=True)
-    try:
-        visible = int(probe.stdout.strip().splitlines()[-1])
-    except (ValueError, IndexError):
-        visible = 0
     # only the HEADLINE device count decides the platform: a host with D
     # real accelerators must keep measuring on them (sweep entries past
     # the real device count are dropped with a note, never silently
     # demoting the headline run to CPU simulation)
-    forced_cpu = visible < D
+    forced_cpu, have = _arm_devices(D, "BENCH_MULTICHIP=1")
+    visible = have["device_count"]
     if not forced_cpu:
         dropped = [d for d in sweep if d > visible]
         if dropped:
@@ -1455,13 +1487,7 @@ def run_multichip_bench() -> bool:
             env["LGBTPU_FUSE_ITER"] = fuse
         else:
             env.pop("LGBTPU_FUSE_ITER", None)
-        if forced_cpu:
-            env["JAX_PLATFORMS"] = "cpu"
-            flags = [f for f in env.get("XLA_FLAGS", "").split() if not
-                     f.startswith("--xla_force_host_platform_device_count")]
-            env["XLA_FLAGS"] = " ".join(
-                flags + ["--xla_force_host_platform_device_count="
-                         f"{max(max_dev, n_dev)}"])
+        env = _arm_env(env, forced_cpu, have, max(max_dev, n_dev))
         r = subprocess.run([sys.executable, os.path.abspath(__file__)],
                            env=env, capture_output=True, text=True,
                            cwd=os.path.dirname(os.path.abspath(__file__)))
@@ -1532,7 +1558,8 @@ def run_multichip_bench() -> bool:
     ok = auc >= AUC_GATE
     plat = "forced-CPU virtual devices" if rr["forced_cpu"] else "accelerators"
     record = {
-        "metric": f"multichip_data_parallel_s_per_tree_{D}dev_{rows}rows",
+        "metric": (("cpusim_" if forced_cpu else "")
+                   + f"multichip_data_parallel_s_per_tree_{D}dev_{rows}rows"),
         "value": round(rr["s_per_tree"], 4),
         "unit": (f"s/tree at {D} devices ({plat}), "
                  f"hist_comms=reduce_scatter, fused iteration (lower is "
@@ -1568,7 +1595,7 @@ def run_multichip_bench() -> bool:
     }
     if mesh2d:
         record["mesh2d"] = mesh2d
-    print(json.dumps(record), flush=True)
+    _emit(record, source=rr)
     _append_history(record)
     if ok:
         # BENCH_MULTICHIP.json holds the last PASSING run only (a failed
@@ -1837,9 +1864,9 @@ def run_serve_bench():
         "unit": f"p50 ms client-side HTTP (p99 {p99:.3f} ms)",
         "vs_baseline": None,
     }
-    print(json.dumps(bin_record), flush=True)
-    print(json.dumps(qps_record), flush=True)
-    print(json.dumps(lat_record), flush=True)
+    _emit(bin_record)
+    _emit(qps_record)
+    _emit(lat_record)
     _append_history(bin_record, ok=ok)
     _append_history(qps_record, ok=ok)
     _append_history(lat_record, ok=ok)
@@ -2079,7 +2106,7 @@ def run_drift_bench():
         "audit_mismatches": audit["mismatches"],
         "gates": {"failures": failures},
     }
-    print(json.dumps(record), flush=True)
+    _emit(record)
     for msg in failures:
         print(f"BENCH_DRIFT gate FAIL: {msg}", flush=True)
     if not smoke:
@@ -2186,7 +2213,11 @@ def run_fleet_bench():
         restart_backoff_s=0.2, hang_timeout_s=3.0,
         fleet_dir=os.path.join(td, "fleet"),
         slo_p99_ms=slo_p99_ms, slo_window_s=1.0, slo_burn=slo_burn,
-        binary_port=0)
+        binary_port=0,
+        # this parent trained the model and holds the chip (a chip belongs
+        # to one process), and the gate measures no device quantity: the
+        # replicas are told to serve on the CPU
+        platform="cpu")
     bodies = {m: {"rows": X[:m].tolist(), "raw_score": True,
                   "deadline_ms": deadline_ms} for m in sizes}
     lat_ms: list = []
@@ -2455,11 +2486,10 @@ def run_fleet_bench():
         "metrics_endpoints": prom_report,
         "trace": trace_report,
     }
-    print(json.dumps({k: record[k] for k in
-                      ("metric", "value", "unit", "vs_baseline")}),
-          flush=True)
+    record["replica_platform"] = fleet.platform
+    _emit_head(record)
     _append_history(record, ok=ok)
-    print(json.dumps({
+    _emit({
         "metric": "fleet_chaos_latency_ms",
         "value": record["p50_ms"],
         "unit": (f"p50 ms client-side (p99 {record['p99_ms']} ms, "
@@ -2468,7 +2498,7 @@ def run_fleet_bench():
                  f"{record['breaker_trips']} breaker trips, "
                  f"{restarts} restarts)"),
         "vs_baseline": None,
-    }), flush=True)
+    })
     if ok:
         # a failing chaos run must not clobber the last PASSING artifact
         # (the BENCH_GOSS.json lesson from the round-12 review)
@@ -2587,7 +2617,10 @@ def run_pipeline_bench():
         # must fire within the observation window (run_drift_bench
         # settings, minus the wire-overhead arm)
         quality_sample=1.0, quality_audit_sample=0.25,
-        drift_window_s=4.0, quality_min_rows=120)
+        drift_window_s=4.0, quality_min_rows=120,
+        # the parent holds the chip and this gate measures no device
+        # quantity: replicas (and the refit subprocess arm) run on the CPU
+        platform="cpu")
 
     sizes = [1, 4, 16]
     outcomes = {"ok": 0, "s503": 0, "errors": 0, "mis_versioned": 0}
@@ -2738,7 +2771,8 @@ def run_pipeline_bench():
 
         # ---- ARM4: SIGKILL between gate-pass and pointer write -------
         m4 = os.path.join(td, "m4.marker")
-        env4 = dict(os.environ)
+        from lightgbm_tpu.runtime import child_env
+        env4 = child_env("cpu")
         env4["LGBTPU_CHAOS"] = f"kill_refit:once={m4}"
         proc = subprocess.run(
             [sys.executable, "-m", "lightgbm_tpu"]
@@ -2886,9 +2920,8 @@ def run_pipeline_bench():
                       "refit/walk_fallback_passes")},
         "gates": {"failures": failures},
     }
-    print(json.dumps({k: record[k] for k in
-                      ("metric", "value", "unit", "vs_baseline")}),
-          flush=True)
+    record["replica_platform"] = fleet.platform
+    _emit_head(record)
     for msg in failures:
         print(f"BENCH_PIPELINE gate FAIL: {msg}", flush=True)
     if not smoke:
@@ -3116,7 +3149,9 @@ def run_multimodel_bench():
                                      "b": roster[mids[1]]},
                          replicas=1, max_batch=32, max_delay_ms=1.0,
                          fleet_dir=fd, warmup=False,
-                         startup_timeout_s=240.0)
+                         startup_timeout_s=240.0,
+                         # the parent holds the chip: replicas on the CPU
+                         platform="cpu")
     try:
         fleet.start()
 
@@ -3213,9 +3248,7 @@ def run_multimodel_bench():
         "pipeline": pipe,
         "gates": {"failures": failures},
     }
-    print(json.dumps({k: record[k] for k in
-                      ("metric", "value", "unit", "vs_baseline")}),
-          flush=True)
+    _emit_head(record)
     for msg in failures:
         print(f"BENCH_MULTIMODEL gate FAIL: {msg}", flush=True)
     if not smoke:
@@ -3259,6 +3292,7 @@ def _ingest_child() -> bool:
     process gives the RSS gate a clean ru_maxrss baseline (the parent's
     own allocations never leak into the measurement)."""
     import lightgbm_tpu as lgb
+    from lightgbm_tpu.runtime import device_record
     path = os.environ["_BENCH_INGEST_PATH"]
     params = json.loads(os.environ["_BENCH_INGEST_PARAMS"])
     rounds = int(os.environ.get("BENCH_INGEST_TRAIN_ROUNDS", 2))
@@ -3284,6 +3318,7 @@ def _ingest_child() -> bool:
                     "bytes_per_s", "bytes", "peak_rss_bytes",
                     "cache_hit", "sketch_exact", "mode")},
         "trees": trees,
+        **device_record(),
     }
     print("INGEST_CHILD " + json.dumps(out), flush=True)
     return bool(stats) and trees == rounds
@@ -3321,6 +3356,40 @@ def _run_ingest_gate(td):
     import lightgbm_tpu as lgb
 
     ok = True
+    # ---- (b) scale gate: its child trains too, so it runs FIRST — before
+    # this parent initialises JAX and takes the chip — and is told its
+    # platform ------------------------------------------------------------
+    n_big = int(os.environ.get("BENCH_INGEST_ROWS", 2_000_000))
+    f_big = int(os.environ.get("BENCH_INGEST_FEATURES", 28))
+    raw_bytes = n_big * (f_big + 1) * 8
+    budget = float(os.environ.get("BENCH_INGEST_RSS_BUDGET_GB", 0)) * 1e9 \
+        or raw_bytes / 2
+    min_rows_s = float(os.environ.get("BENCH_INGEST_MIN_ROWS_S", 50_000))
+    big_csv = os.path.join(td, "big.csv")
+    t0 = time.time()
+    csv_bytes = _write_synth_csv(big_csv, n_big, f_big, seed=11)
+    gen_s = time.time() - t0
+    child_params = {
+        "objective": "binary", "num_leaves": 31, "max_bin": 63,
+        "verbosity": -1, "ingest_mode": "stream",
+        "ingest_chunk_rows": int(os.environ.get("BENCH_INGEST_CHUNK",
+                                                262_144)),
+    }
+    from lightgbm_tpu.runtime import child_env, child_platform
+    env = child_env(child_platform())
+    env.update(_BENCH_INGEST_CHILD="1", _BENCH_INGEST_PATH=big_csv,
+               _BENCH_INGEST_PARAMS=json.dumps(child_params))
+    try:
+        r = subprocess.run([sys.executable, os.path.abspath(__file__)],
+                           capture_output=True, text=True, timeout=3600,
+                           env=env)
+        rc, out, err = r.returncode, r.stdout or "", r.stderr or ""
+    except subprocess.TimeoutExpired as exc:
+        rc = -1
+        out = exc.stdout if isinstance(exc.stdout, str) else ""
+        err = (exc.stderr if isinstance(exc.stderr, str) else "") \
+            + "\nBENCH_INGEST: child timed out after 3600s"
+
     # ---- (a) identity gate ---------------------------------------------
     n_id = int(os.environ.get("BENCH_INGEST_ID_ROWS", 120_000))
     f_id = int(os.environ.get("BENCH_INGEST_FEATURES", 16))
@@ -3364,37 +3433,6 @@ def _run_ingest_gate(td):
               flush=True)
         ok = False
 
-    # ---- (b) scale gate -------------------------------------------------
-    n_big = int(os.environ.get("BENCH_INGEST_ROWS", 2_000_000))
-    f_big = int(os.environ.get("BENCH_INGEST_FEATURES", 28))
-    raw_bytes = n_big * (f_big + 1) * 8
-    budget = float(os.environ.get("BENCH_INGEST_RSS_BUDGET_GB", 0)) * 1e9 \
-        or raw_bytes / 2
-    min_rows_s = float(os.environ.get("BENCH_INGEST_MIN_ROWS_S", 50_000))
-    big_csv = os.path.join(td, "big.csv")
-    t0 = time.time()
-    csv_bytes = _write_synth_csv(big_csv, n_big, f_big, seed=11)
-    gen_s = time.time() - t0
-    child_params = {
-        "objective": "binary", "num_leaves": 31, "max_bin": 63,
-        "verbosity": -1, "ingest_mode": "stream",
-        "ingest_chunk_rows": int(os.environ.get("BENCH_INGEST_CHUNK",
-                                                262_144)),
-    }
-    env = dict(os.environ, _BENCH_INGEST_CHILD="1",
-               _BENCH_INGEST_PATH=big_csv,
-               _BENCH_INGEST_PARAMS=json.dumps(child_params),
-               JAX_PLATFORMS=os.environ.get("JAX_PLATFORMS", ""))
-    try:
-        r = subprocess.run([sys.executable, os.path.abspath(__file__)],
-                           capture_output=True, text=True, timeout=3600,
-                           env=env)
-        rc, out, err = r.returncode, r.stdout or "", r.stderr or ""
-    except subprocess.TimeoutExpired as exc:
-        rc = -1
-        out = exc.stdout if isinstance(exc.stdout, str) else ""
-        err = (exc.stderr if isinstance(exc.stderr, str) else "") \
-            + "\nBENCH_INGEST: child timed out after 3600s"
     child = None
     for ln in out.splitlines():
         if ln.startswith("INGEST_CHILD "):
@@ -3422,7 +3460,6 @@ def _run_ingest_gate(td):
               f"{min_rows_s:.0f}", flush=True)
         ok = False
 
-    import jax
     record = {
         "metric": "ingest_stream_rows_per_s",
         "value": round(rows_per_s, 1),
@@ -3449,9 +3486,8 @@ def _run_ingest_gate(td):
         "csv_gen_s": round(gen_s, 1),
         "identity_rows": n_id,
         "bit_identical": identical,
-        "platform": jax.default_backend(),
     }
-    print(json.dumps(record), flush=True)
+    _emit(record, source=child)
     _append_history(record, ok=ok)
     if ok and os.environ.get("BENCH_INGEST_SMOKE", "") != "1":
         # the committed artifact holds the last PASSING full-size
@@ -3467,6 +3503,8 @@ def _run_ingest_gate(td):
 
 
 if __name__ == "__main__":
+    from lightgbm_tpu.runtime import configure_compile_cache
+    configure_compile_cache()     # parents and measurement children alike
     if os.environ.get("_BENCH_MC_CHILD", "") == "1":
         sys.exit(0 if _multichip_child() else 1)
     if os.environ.get("_BENCH_INGEST_CHILD", "") == "1":

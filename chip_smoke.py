@@ -1,0 +1,422 @@
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process drives the main path once, through the entry points a user
+calls, at the full width of the HIGGS-like configuration `python bench.py`
+times (28 features, 255 leaves, 63 bins, quantized gradients; rows cut to
+CHIP_SMOKE_ROWS): `lgb.train` -> `Booster.predict` on a held-out batch big
+enough for the device walk -> a few requests to an in-process
+`serving.ServingApp`.  It checks that the answers are right by the repo's
+own references and that no CPU/interpret/host fallback was taken on the
+way, then prints as its last line
+
+    {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+
+and exits 0.  Any failed check, or no TPU, exits non-zero with the reason and
+prints no result.  With >= 4 devices it adds the data-parallel mesh leg.
+
+It reports no s/tree or rows/s: the wall and compile seconds it prints exist
+so a cold and a warm run can be compared, not to be read as a benchmark.
+
+    python chip_smoke.py                      # on the chip (through chiprun)
+    CHIP_SMOKE_ROWS=10500000 python chip_smoke.py
+"""
+import contextlib
+import gc
+import json
+import os
+import sys
+import time
+import traceback
+
+import numpy as np
+
+ROWS = int(os.environ.get("CHIP_SMOKE_ROWS", 2_097_152))
+HOLDOUT = 100_000
+ITERS = 6                      # one warm-up iteration plus five more
+FAILED = []
+_T0 = time.time()
+
+
+def say(msg):
+    print(f"[{time.time() - _T0:7.1f}s] {msg}", flush=True)
+
+
+def check(name, ok, detail=""):
+    ok = bool(ok)
+    say(f"{'ok  ' if ok else 'FAIL'} {name}" + (f" — {detail}" if detail else ""))
+    if not ok:
+        FAILED.append(name)
+    return ok
+
+
+@contextlib.contextmanager
+def phase(name):
+    """A failed phase is recorded and the run goes on to the phases that do
+    not depend on it — one chip call should say everything that is wrong."""
+    say(f"== {name}")
+    t0 = time.time()
+    try:
+        yield
+    except Exception:  # noqa: BLE001 — reported; the run exits non-zero
+        traceback.print_exc(file=sys.stdout)
+        FAILED.append(f"{name} (raised)")
+    say(f"== {name}: {time.time() - t0:.1f} s")
+
+
+class CompileClock:
+    """Seconds JAX itself reports spending in compilation and in the
+    persistent cache, summed per event name."""
+
+    def __init__(self):
+        import jax.monitoring
+        self.secs = {}
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, event, duration, **_):
+        if "compil" in event or "cache" in event:
+            self.secs[event] = self.secs.get(event, 0.0) + duration
+
+    def report(self):
+        for k in sorted(self.secs):
+            say(f"   {k}: {self.secs[k]:.2f} s")
+        backend = sum(v for k, v in self.secs.items()
+                      if k.endswith("backend_compile_duration"))
+        say(f"compile seconds (backend_compile_duration): {backend:.2f}")
+        return backend
+
+
+def leaf_counts(node, out):
+    if "leaf_count" in node:
+        out.append(node["leaf_count"])
+    else:
+        leaf_counts(node["left_child"], out)
+        leaf_counts(node["right_child"], out)
+    return out
+
+
+def check_trees(bst, n_rows, num_leaves, tag):
+    dumped = bst.dump_model()["tree_info"]
+    leaves = [t["num_leaves"] for t in dumped]
+    sums = [sum(leaf_counts(t["tree_structure"], [])) for t in dumped]
+    check(f"{tag}: every tree reaches {num_leaves} leaves",
+          leaves and all(v == num_leaves for v in leaves), f"{leaves}")
+    check(f"{tag}: leaf counts sum to N={n_rows} in every tree",
+          all(s == n_rows for s in sums), f"{sums}")
+
+
+def kernel_exactness(dd, params):
+    """tests/test_stream_kernel.py::test_int8_hist_exact on the chip, at the
+    cell's block shape, plus a 64-slot pass (the round shape) — the compiled
+    Mosaic kernel against the segment-sum reference, exactly."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.histogram import _hist_segsum
+    from lightgbm_tpu.pallas.stream_kernel import (build_route_tables,
+                                                   pack_bins_T,
+                                                   route_and_hist,
+                                                   stream_block_rows)
+    G, Bmax, L = dd.num_groups, dd.max_bins, params["num_leaves"]
+    T = stream_block_rows(Bmax, G, True)
+    check("block shape is the cell's", (G, Bmax, L, T) == (28, 63, 255, 4096),
+          f"G={G} Bmax={Bmax} L={L} T={T}")
+    N = 8 * T                                   # eight grid blocks
+    bins = dd.bins[:N]
+    rs = np.random.RandomState(0)
+    gi = jnp.asarray(rs.randint(-32, 33, N).astype(np.float32))
+    hi = jnp.asarray(rs.randint(0, 33, N).astype(np.float32))
+    cnt = jnp.ones(N, jnp.float32)
+    slay = pack_bins_T(bins, T, max_bins=Bmax)
+    check("bins are in the u8 layout the trainer uses",
+          slay.bins_T.dtype == jnp.int8, str(slay.bins_T.dtype))
+    w_T = (jnp.zeros((8, N), jnp.float32).at[0].set(gi).at[1].set(hi)
+           .at[2].set(cnt))
+    zL = jnp.zeros(L, jnp.int32)
+    bits = jnp.zeros((-(-Bmax // 8) * 8, L), jnp.bfloat16)
+    kw = dict(block_rows=T, has_cat=False, int_weights=True)
+
+    # root pass: every row in leaf 0 -> slot 0
+    tabs = build_route_tables(zL, zL, zL, zL, zL, zL, zL, zL.at[0].set(1),
+                              dd.routing, L)
+    leaf = jnp.zeros((1, N), jnp.int32)
+    lowered = route_and_hist.lower(slay.bins_T, leaf, w_T, tabs, bits, 1,
+                                   Bmax, G, L, **kw).as_text()
+    check("route_and_hist lowers to a Mosaic tpu_custom_call",
+          "tpu_custom_call" in lowered)
+    _, hist, scnt = route_and_hist(slay.bins_T, leaf, w_T, tabs, bits, 1,
+                                   Bmax, G, L, **kw)
+    ref = _hist_segsum(bins, jnp.zeros(N, jnp.int32), gi, hi, cnt, 1, Bmax)
+    check("root pass: int32 histogram == _hist_segsum exactly",
+          hist.dtype == jnp.int32 and np.array_equal(
+              np.asarray(hist, np.float64),
+              np.asarray(ref[..., :2], np.float64)))
+    check("root pass: slot count == N", float(scnt[0]) == float(N))
+
+    # round shape: rows spread over 64 leaves, leaf l kept in slot l
+    S = 64
+    lid = jnp.asarray(rs.randint(0, S, N).astype(np.int32))
+    keep = jnp.where(jnp.arange(L) < S, jnp.arange(L) + 1, 0).astype(jnp.int32)
+    tabs = build_route_tables(zL, zL, zL, zL, zL, zL, zL, keep, dd.routing, L)
+    new_leaf, hist, scnt = route_and_hist(slay.bins_T, lid.reshape(1, -1),
+                                          w_T, tabs, bits, S, Bmax, G, L,
+                                          **kw)
+    ref = _hist_segsum(bins, lid, gi, hi, cnt, S, Bmax)
+    check("64-slot pass: int32 histogram == _hist_segsum exactly",
+          np.array_equal(np.asarray(hist, np.float64),
+                         np.asarray(ref[..., :2], np.float64)))
+    check("64-slot pass: rows keep their leaf, slot counts exact",
+          np.array_equal(np.asarray(new_leaf[0]), np.asarray(lid))
+          and np.array_equal(np.asarray(scnt),
+                             np.bincount(np.asarray(lid), minlength=S)))
+
+
+def main():
+    try:
+        import jax
+    except ImportError as e:
+        print(f"chip_smoke: cannot import jax: {e}", file=sys.stderr)
+        return 2
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: needs a TPU; JAX found platform "
+              f"{device['platform']!r} ({device['kind']} x {device['count']})",
+              file=sys.stderr)
+        return 2
+    return run(device, devs)
+
+
+def run(device, devs):
+    import jax
+    clock = CompileClock()
+
+    import jaxlib
+    from importlib import metadata
+
+    import bench
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import native, runtime, telemetry
+    from lightgbm_tpu.telemetry import costmodel
+
+    cache_dir = runtime.configure_compile_cache()
+    say(f"device: {device}")
+    say(f"versions: python {sys.version.split()[0]} jax {jax.__version__} "
+        f"jaxlib {jaxlib.__version__} libtpu {metadata.version('libtpu')} "
+        f"numpy {np.__version__}")
+    n_cached = len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+    say(f"compile cache: {cache_dir} "
+        f"({'from ' + runtime.CACHE_ENV if os.environ.get(runtime.CACHE_ENV) else 'checkout default'}; "
+        f"{n_cached} entries at start)")
+    check("native binner loaded (no silent NumPy fallback)",
+          native.get_lib() is not None)
+    bal = costmodel.machine_balance()
+    check("device_kind has published peaks incl. int8",
+          bal["peak_int8_ops_per_s"], f"{bal}")
+    check("runtime: on_tpu and Pallas compiled, not interpreted",
+          runtime.on_tpu() and not runtime.pallas_interpret())
+
+    # ---------------------------------------------------------------- data
+    params = {
+        "objective": "binary", "num_leaves": bench.NUM_LEAVES,
+        "learning_rate": 0.1, "max_bin": 63, "verbosity": -1,
+        "use_quantized_grad": True, "num_grad_quant_bins": 64,
+    }
+    with phase(f"data: HIGGS-like {ROWS}+{HOLDOUT} x {bench.N_FEATURES}"):
+        X, y = bench.make_higgs_like(ROWS + HOLDOUT, bench.N_FEATURES)
+        X_tr, y_tr, X_te, y_te = X[:ROWS], y[:ROWS], X[ROWS:], y[ROWS:]
+        ds = lgb.Dataset(X_tr, label=y_tr)
+
+    # --------------------------------------------------------------- train
+    bst = None
+    with phase(f"train: lgb.train, {ITERS} iterations"):
+        after_warmup = {}
+        stamps = []
+
+        def watch(env):
+            stamps.append(time.time())
+            if env.iteration == 0:       # the warm-up iteration just ended
+                after_warmup.update(telemetry.recompile_counts())
+
+        t0 = time.time()
+        trained = lgb.train(params, ds, num_boost_round=ITERS,
+                            callbacks=[watch])
+        trained.engine.score.block_until_ready()
+        say(f"lgb.train wall {time.time() - t0:.1f} s; iteration ends at "
+            + " ".join(f"{s - t0:.1f}" for s in stamps))
+        eng = trained.engine
+        gp = eng._grow_params
+        check("engine resolved hist_backend=stream",
+              gp.hist_backend == "stream", gp.hist_backend)
+        check("engine resolved int8 histograms (int_hist)", gp.int_hist)
+        check("iterations ran fused (one launch, no host sync)",
+              eng._can_fuse_iteration() and eng._fused_last)
+        check("max_splits_per_round resolved to 64",
+              gp.max_splits_per_round == 64, str(gp.max_splits_per_round))
+        now = telemetry.recompile_counts()
+        grew = {k: (after_warmup.get(k, 0), v) for k, v in now.items()
+                if v != after_warmup.get(k, 0)}
+        check("zero recompiles after the warm-up iteration", not grew,
+              f"{grew}" if grew else f"{sum(now.values())} traces, all in "
+              "warm-up")
+        check_trees(trained, ROWS, bench.NUM_LEAVES, "train")
+        bst = trained
+
+    if bst is not None:
+        with phase("kernel: route_and_hist vs _hist_segsum at the cell's "
+                   "block shape"):
+            kernel_exactness(bst.engine.dd, params)
+
+        # ----------------------------------------------------------- predict
+        ref = None
+        with phase(f"predict: Booster.predict on {HOLDOUT} held-out rows"):
+            raw = bst.predict(X_te, raw_score=True)
+            check("predict took the device path (predict_stream)",
+                  bst.last_predict_path == "device", bst.last_predict_path)
+            ref = lgb.Booster(model_str=bst.model_to_string())
+            host = ref.predict(X_te, raw_score=True)
+            check("host walk of the reloaded model is the host path",
+                  ref.last_predict_path.startswith("host"),
+                  ref.last_predict_path)
+            check("device predict == host walk (rtol 1e-4, atol 1e-5)",
+                  raw.shape == host.shape and np.all(np.isfinite(raw))
+                  and np.allclose(raw, host, rtol=1e-4, atol=1e-5),
+                  f"max abs diff {np.max(np.abs(raw - host)):.3g}")
+            auc_last = bench.auc_score(y_te, raw)
+            auc_first = bench.auc_score(
+                y_te, bst.predict(X_te, raw_score=True, num_iteration=1))
+            check("holdout AUC after the last iteration above the first",
+                  auc_last > auc_first > 0.5,
+                  f"first {auc_first:.4f} -> last {auc_last:.4f}")
+
+        # ----------------------------------------------------------- serving
+        with phase("serve: in-process ServingApp, binary wire"):
+            serve_requests(bst, ref, X_te)
+
+    # ---------------------------------------------------------------- mesh
+    if device["count"] >= 4 and bst is not None:
+        del bst, trained
+        gc.collect()
+        with phase(f"mesh: tree_learner=data over {device['count']} devices"):
+            mesh_leg(params, X_tr, y_tr, X_te, y_te, devs)
+    else:
+        say(f"mesh leg skipped: {device['count']} device(s) visible, "
+            "needs >= 4")
+
+    # -------------------------------------------------------------- memory
+    stats = devs[0].memory_stats() or {}
+    check("memory_stats reports peak_bytes_in_use",
+          stats.get("peak_bytes_in_use"),
+          f"peak_bytes_in_use={stats.get('peak_bytes_in_use')} "
+          f"bytes_in_use={stats.get('bytes_in_use')} "
+          f"bytes_limit={stats.get('bytes_limit')}")
+    clock.report()
+    say(f"total wall {time.time() - _T0:.1f} s")
+
+    if FAILED:
+        print("chip_smoke FAILED: " + "; ".join(FAILED), flush=True)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+def serve_requests(bst, ref, X_te):
+    import tempfile
+
+    from lightgbm_tpu import telemetry
+    from lightgbm_tpu.serving import BinaryClient, ServingApp
+    from lightgbm_tpu.serving.compiled import device_accumulation_supported
+
+    say(f"device_accum (f64 probe on this backend): "
+        f"{device_accumulation_supported()}")
+    with tempfile.TemporaryDirectory(prefix="chip_smoke_") as td:
+        path = os.path.join(td, "model.txt")
+        bst.save_model(path)
+        app = ServingApp(path, port=0, max_batch=256, max_delay_ms=2.0,
+                         queue_size=4096, binary_port=0).start()
+        try:
+            model = app.registry.current()
+            ladder = model.describe()["buckets"]
+            compiled = model._compiled
+            if check("serving model compiled for the device (no host "
+                     "predictor fallback)", compiled is not None):
+                say(f"serving predictor: device_accum="
+                    f"{compiled.device_accum} buckets={ladder}")
+            exact = True
+            with BinaryClient(app.host, app.binary_port) as c:
+                for sweep in range(2):
+                    if sweep == 1:
+                        warm = telemetry.recompile_counts()
+                    for m in ladder:
+                        resp = c.request(X_te[:m], raw_score=True)
+                        got = np.asarray(resp["predictions"])
+                        want = ref.predict(X_te[:m], raw_score=True)
+                        ok = resp["status"] == 0 and np.array_equal(got, want)
+                        if not ok:
+                            say(f"   bucket {m}: status {resp['status']}, "
+                                f"max abs diff "
+                                f"{np.max(np.abs(got - want)):.3g}")
+                        exact &= ok
+            check("ServingApp responses bitwise == Booster.predict(raw_score)"
+                  f" on buckets {ladder}", exact)
+            now = telemetry.recompile_counts()
+            grew = {k: v for k, v in now.items() if v != warm.get(k, 0)}
+            check("serving: zero recompiles on the second sweep", not grew,
+                  f"{grew}")
+        finally:
+            app.shutdown()
+
+
+def mesh_leg(params, X_tr, y_tr, X_te, y_te, devs):
+    import jax
+
+    import bench
+    import lightgbm_tpu as lgb
+
+    def in_use():
+        return [(d.memory_stats() or {}).get("bytes_in_use", 0) for d in devs]
+
+    def strip(model_str):
+        return model_str.split("\nparameters:")[0]
+
+    base = in_use()
+    models = {}
+    for mode in ("psum", "reduce_scatter"):
+        p = dict(params, tree_learner="data", hist_comms=mode)
+        bst = lgb.train(p, lgb.Dataset(X_tr, label=y_tr), num_boost_round=3)
+        jax.block_until_ready(bst.engine.score)
+        eng = bst.engine
+        if mode == "psum":
+            check("mesh: stream kernel under shard_map (_mesh_stream)",
+                  eng._mesh_stream and eng._grow_params.hist_backend == "stream")
+            check("mesh: takes every device",
+                  eng.mesh is not None and eng.mesh.devices.size == len(devs),
+                  f"{None if eng.mesh is None else eng.mesh.shape}")
+            span = {d.id for d in eng.dd.bins.sharding.device_set}
+            check("mesh: bins' sharding spans all devices",
+                  span == {d.id for d in devs}
+                  and not eng.dd.bins.sharding.is_fully_replicated,
+                  f"{eng.dd.bins.sharding}")
+            delta = [b - a for a, b in zip(base, in_use())]
+            # a whole unsharded bin matrix on one device doubles its share;
+            # the objective's unsharded label (4 B/row) is within the bound
+            check("mesh: per-device bytes_in_use balanced (none piled on "
+                  "device 0)", min(delta) > 0
+                  and max(delta) <= 1.5 * min(delta),
+                  f"delta MiB {[round(d / 2 ** 20, 1) for d in delta]}")
+            check_trees(bst, len(y_tr), params["num_leaves"], "mesh")
+            auc = bench.auc_score(y_te, bst.predict(X_te, raw_score=True))
+            check("mesh: holdout AUC sane", auc > 0.7, f"{auc:.4f}")
+        models[mode] = strip(bst.model_to_string())
+        del bst, eng
+        gc.collect()
+    check("mesh: psum and reduce_scatter grow byte-identical models "
+          "(tests/test_distributed_fast.py)",
+          models["psum"] == models["reduce_scatter"])
+    serial = lgb.train(params, lgb.Dataset(X_tr, label=y_tr),
+                       num_boost_round=3)
+    say("mesh: serial vs data-parallel models byte-identical: "
+        f"{strip(serial.model_to_string()) == models['psum']} "
+        "(reported, not required)")
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -35,7 +35,8 @@ REPO_MARKERS = ("pytest.ini", "ROADMAP.md")
 # directories under the repo root that the gate walks by default; tests/
 # is deliberately excluded — test files exercise tripping patterns (rule
 # fixtures, chaos writes) that are violations by design
-DEFAULT_SCAN = ("lightgbm_tpu", "scripts", "bench.py", "__graft_entry__.py")
+DEFAULT_SCAN = ("lightgbm_tpu", "scripts", "bench.py", "chip_smoke.py",
+                "__graft_entry__.py")
 
 
 @dataclasses.dataclass(frozen=True)

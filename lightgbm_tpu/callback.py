@@ -96,7 +96,7 @@ def log_telemetry(period: int = 10) -> Callable:
         for k, v in sorted(phases.items(), key=lambda kv: -kv[1]):
             parts.append(f"{k[:-2]} {v / len(window) * 1e3:.1f} ms")
         last = window[-1]
-        hbm = last.get("peak_hbm_gb") or last.get("device_hbm_gb")
+        hbm = last.get("peak_hbm_gb")
         if hbm:
             parts.append(f"hbm {hbm:.3f} GB")
         compiles = sum(s["compiles"]

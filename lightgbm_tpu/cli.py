@@ -294,6 +294,8 @@ def main(argv=None) -> int:
     if not argv:
         print(__doc__)
         return 1
+    from .runtime import configure_compile_cache
+    configure_compile_cache()
     params = _coerce(resolve_aliases(parse_args(list(argv))))
     task = str(params.get("task", "train"))
     if task == "train":

@@ -121,7 +121,8 @@ def _ship_supported() -> bool:
     env = os.environ.get("LGBTPU_INGEST_SHIP", "")
     if env in ("0", "1"):
         return env == "1"
-    return jax.default_backend() not in ("cpu",)
+    from .runtime import platform_name
+    return platform_name() != "cpu"
 
 
 _ship_jit = None

@@ -15,6 +15,7 @@ from .basic import Booster, Dataset
 from .callback import CallbackEnv, EarlyStopException
 from .config import Config, resolve_aliases
 from .robustness import chaos as _chaos
+from .runtime import configure_compile_cache
 from .utils.log import LightGBMError, log_info, log_warning
 
 
@@ -36,6 +37,7 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
     a run that was never interrupted (docs/ROBUSTNESS.md).  Callback
     state is NOT checkpointed: an early-stopping window restarts at the
     resume point, so runs that stop early may stop differently."""
+    configure_compile_cache()
     params = resolve_aliases(dict(params or {}))
     # popped so the resumed booster's params (and saved params block) match
     # the uninterrupted run's exactly
@@ -303,6 +305,7 @@ def cv(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100,
        eval_train_metric: bool = False,
        return_cvbooster: bool = False) -> Dict[str, List[float]]:
     """Cross-validation (reference: engine.py:626)."""
+    configure_compile_cache()
     params = resolve_aliases(dict(params or {}))
     if "num_iterations" in params:
         num_boost_round = int(params["num_iterations"])

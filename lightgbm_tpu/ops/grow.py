@@ -811,7 +811,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         leaf_parent=jnp.full(L, -1, i32),
         out_lo=jnp.full(L if use_output else 1, -BIG, f32),
         out_hi=jnp.full(L if use_output else 1, BIG, f32),
-        leaf_out=(jnp.zeros(L, f32).at[0].set(root_out)
+        leaf_out=(jnp.zeros(L, f32).at[0].set(root_out.astype(f32))
                   if use_output else jnp.zeros(1, f32)),
         anc_left=jnp.zeros((L, L) if use_imono else (1, 1), bool),
         anc_right=jnp.zeros((L, L) if use_imono else (1, 1), bool),
@@ -1313,18 +1313,20 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                         avmx = avmx.at[nw].set(avmx[o_c], mode="drop")
                         up_hi_o = g_num & (m_split > 0)
                         up_lo_o = g_num & (m_split < 0)
+                        # (outputs are f64 under hist_precision=double;
+                        # the f32 slabs take them by an explicit cast)
                         avmx = avmx.at[o].set(
                             jnp.where(up_hi_o, jnp.minimum(avmx[o_c], or_i),
-                                      avmx[o_c]), mode="drop")
+                                      avmx[o_c]).astype(f32), mode="drop")
                         avmn = avmn.at[o].set(
                             jnp.where(up_lo_o, jnp.maximum(avmn[o_c], or_i),
-                                      avmn[o_c]), mode="drop")
+                                      avmn[o_c]).astype(f32), mode="drop")
                         avmn = avmn.at[nw].set(
                             jnp.where(up_hi_o, jnp.maximum(avmn[nw], ol_i),
-                                      avmn[nw]), mode="drop")
+                                      avmn[nw]).astype(f32), mode="drop")
                         avmx = avmx.at[nw].set(
                             jnp.where(up_lo_o, jnp.minimum(avmx[nw], ol_i),
-                                      avmx[nw]), mode="drop")
+                                      avmx[nw]).astype(f32), mode="drop")
                     return (lo_v, hi_v, lov, anc_l, anc_r, nmono, ndepth,
                             rlo, rhi, inmono, bchg_min, bchg_max, avmn, avmx)
 

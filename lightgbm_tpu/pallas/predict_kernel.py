@@ -35,10 +35,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# TPUCompilerParams was renamed CompilerParams across JAX releases
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
+from ..runtime import pallas_interpret
 from ..telemetry.watchdog import watched_jit
 
 ROWS_PER_TREE = 24
@@ -50,8 +47,6 @@ ROWS_PER_TREE = 24
 # digit rows per tree in the categorical side table: one 32-bit bitset
 # word = five 7-bit digits (each exact in bf16, reassembled with shifts)
 CAT_DIGITS = 5
-
-_INTERPRET = False
 
 
 def _predict_kernel(bins_ref, tabs_ref, cat_ref, out_ref, *, T, L, GW, CW,
@@ -189,9 +184,9 @@ def predict_stream(bins_T: jax.Array, tabs: jax.Array, cat_tab: jax.Array,
         ],
         out_specs=pl.BlockSpec((1, T), lambda b: (0, b)),
         out_shape=jax.ShapeDtypeStruct((1, n_pad), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=_INTERPRET,
+        interpret=pallas_interpret(),
     )(bins_T, tabs, cat_tab)
     return out[0]
 

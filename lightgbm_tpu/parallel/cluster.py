@@ -59,24 +59,13 @@ _WORKER = r"""
 import json, os, sys
 spec = json.load(open(sys.argv[1]))
 rank = int(sys.argv[2])
-os.environ.pop("XLA_FLAGS", None)
-os.environ["JAX_PLATFORMS"] = spec["platform"]
+# platform and compile cache arrive in the environment the supervisor built
+# (runtime.child_env): JAX_PLATFORMS is always set here, never inherited
 import jax
-jax.config.update("jax_platforms", spec["platform"])
-if spec["platform"] == "cpu":
-    try:  # cross-process CPU collectives (older jax: option absent)
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass
-try:
-    from jax.extend.backend import clear_backends; clear_backends()
-except Exception:
-    pass
+if os.environ["JAX_PLATFORMS"] == "cpu":
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
 jax.distributed.initialize(spec["coordinator"], num_processes=spec["nproc"],
                            process_id=rank)
-if spec.get("cache_dir"):
-    jax.config.update("jax_compilation_cache_dir", spec["cache_dir"])
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 import lightgbm_tpu as lgb
 from lightgbm_tpu.robustness.heartbeat import heartbeat_callback
 ds = lgb.Dataset(spec["data"])
@@ -236,19 +225,16 @@ def train_distributed(params: Dict[str, Any], data_path: str,
         # retry without snapshots would replay the whole run — checkpoint
         # often enough that a relaunch loses at most ~10% of the work
         params.setdefault("snapshot_freq", max(1, num_boost_round // 10))
+    from ..runtime import child_env, require_chips
+    require_chips(num_processes, platform,
+                  f"train_distributed(num_processes={num_processes})")
     td = tempfile.mkdtemp(prefix="lgb_tpu_cluster_")
     params.setdefault("output_model", os.path.join(td, "ckpt.txt"))
     output_model = str(params["output_model"])
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = platform
+    env = child_env(platform)
     env["PYTHONUNBUFFERED"] = "1"
-    repo = str(Path(__file__).resolve().parents[2])
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
     spec = {
         "nproc": num_processes,
-        "platform": platform,
-        "cache_dir": "/tmp/lgb_tpu_jax_cache",
         "params": dict(params),
         "data": str(data_path),
         "valid": [str(p) for p in (valid_paths or [])],

@@ -58,14 +58,11 @@ def init_distributed(coordinator_address: Optional[str] = None,
     # ("Multiprocess computations aren't implemented on the CPU
     # backend"); the gloo collectives implementation is what makes
     # localhost-simulated multi-host runs work (parallel/cluster.py's
-    # workers set the same; older jax: option absent, TPU: irrelevant)
+    # workers set the same; TPU: irrelevant)
     platforms = str(getattr(jax.config, "jax_platforms", None)
                     or os.environ.get("JAX_PLATFORMS", ""))
     if "cpu" in platforms:
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:  # pragma: no cover - option absent in old jax
-            pass
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
     kwargs = {}
     if coordinator_address is not None:
         kwargs["coordinator_address"] = coordinator_address

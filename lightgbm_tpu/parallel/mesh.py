@@ -183,18 +183,6 @@ def shard_map_rows(fn, mesh: Mesh, in_specs, out_specs):
     check OFF: pallas_call cannot annotate varying-mesh-axes on its outputs,
     so callers psum whatever must come back replicated (the reference's
     per-worker histogram construction + ReduceScatter,
-    data_parallel_tree_learner.cpp:285-299). Handles the old/new shard_map
-    API spellings (check_vma in current jax, check_rep in the older
-    experimental shard_map)."""
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    specs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    try:
-        return _sm(fn, check_vma=False, **specs)
-    except TypeError:   # older signature spells it check_rep
-        try:
-            return _sm(fn, check_rep=False, **specs)
-        except TypeError:   # oldest: no replication-check kwarg at all
-            return _sm(fn, **specs)
+    data_parallel_tree_learner.cpp:285-299)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

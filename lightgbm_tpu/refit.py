@@ -39,10 +39,7 @@ from .utils.log import LightGBMError, log_debug, log_info
 
 def _x64():
     """Scoped float64 (the repo never enables x64 globally)."""
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:
-        from jax.experimental import enable_x64 as ctx
-    return ctx()
+    return jax.enable_x64()
 
 
 # ---------------------------------------------------------------------------

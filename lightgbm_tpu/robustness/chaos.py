@@ -8,10 +8,10 @@ tests and manual experiments share.
 
 Grammar (directives separated by ``;``, options by ``,``)::
 
-    LGBTPU_CHAOS="kill:iter=5,rank=1,once=/tmp/m"   # os._exit after iter 5
+    LGBTPU_CHAOS="kill:iter=5,rank=1,once=/run/m"   # os._exit after iter 5
     LGBTPU_CHAOS="nan_grad:iter=3,count=8"          # NaN one gradient batch
     LGBTPU_CHAOS="truncate_snapshot"                # corrupt snapshot files
-    LGBTPU_CHAOS="hang:iter=3,rank=1,once=/tmp/m"   # stop heartbeating
+    LGBTPU_CHAOS="hang:iter=3,rank=1,once=/run/m"   # stop heartbeating
     LGBTPU_CHAOS="heartbeat_delay:seconds=2"        # slow every heartbeat
 
 Closed-loop pipeline faults (docs/ROBUSTNESS.md "Closed-loop
@@ -19,8 +19,8 @@ freshness"; ``iter`` for ``poison_refit`` is the 1-based tree index of
 the refit loop)::
 
     LGBTPU_CHAOS="poison_refit:iter=1,count=4"      # NaN refit leaf values
-    LGBTPU_CHAOS="kill_refit:once=/tmp/m"           # die between gate and pointer
-    LGBTPU_CHAOS="torn_pointer:once=/tmp/m"         # truncated promote.json write
+    LGBTPU_CHAOS="kill_refit:once=/run/m"           # die between gate and pointer
+    LGBTPU_CHAOS="torn_pointer:once=/run/m"         # truncated promote.json write
 
 Serving-fleet faults (docs/SERVING.md fleet architecture; ``rank`` here
 is the REPLICA rank — the supervisor exports ``LGBTPU_REPLICA_RANK`` to
@@ -28,8 +28,8 @@ every replica process and rank matching prefers it over
 ``jax.process_index``; ``iter`` is the replica's heartbeat-loop beat
 number, one beat every ~0.25 s)::
 
-    LGBTPU_CHAOS="kill_replica:iter=8,rank=0,once=/tmp/m"  # SIGKILL-like exit
-    LGBTPU_CHAOS="hang_replica:iter=12,rank=1,once=/tmp/m" # wedge the replica
+    LGBTPU_CHAOS="kill_replica:iter=8,rank=0,once=/run/m"  # SIGKILL-like exit
+    LGBTPU_CHAOS="hang_replica:iter=12,rank=1,once=/run/m" # wedge the replica
     LGBTPU_CHAOS="slow_replica:seconds=0.5"                # delay every request
     LGBTPU_CHAOS="drop_conn:count=3"                       # reset 3 connections
 

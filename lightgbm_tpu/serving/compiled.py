@@ -19,7 +19,7 @@ threshold`` becomes an exact lexicographic integer compare.
 
 Score accumulation ALSO runs on device: the per-tree leaf-value table
 rides into the program as a float64 argument (under a scoped
-``jax.experimental.enable_x64``), and one sequential ``fori_loop``
+``jax.enable_x64``), and one sequential ``fori_loop``
 replays the host batch loop's exact tree order — per row, the same
 IEEE-754 float64 adds in the same order — so the returned scores are
 bitwise equal to ``Booster.predict`` without the host ever touching a
@@ -43,7 +43,7 @@ from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..utils.log import LightGBMError, log_info
+from ..utils.log import LightGBMError, log_warning
 
 # monotone keys of +/-1e-35 — the reference's kZeroThreshold band used by
 # zero-as-missing routing (tree.py predict_raw: np.abs(v) < 1e-35)
@@ -86,10 +86,7 @@ def _x64_scope():
     programs inside the scope so the f64 leaf table and accumulator are
     real IEEE doubles on capable backends."""
     import jax
-    ctx = getattr(jax, "enable_x64", None)
-    if ctx is None:   # moved under jax.experimental in recent releases
-        from jax.experimental import enable_x64 as ctx
-    return ctx()
+    return jax.enable_x64()
 
 
 _DEVICE_F64: Optional[bool] = None
@@ -97,8 +94,11 @@ _DEVICE_F64: Optional[bool] = None
 
 def device_accumulation_supported() -> bool:
     """Can this backend hold float64 arrays and add them with IEEE-754
-    semantics?  Probed ONCE: a pair whose low word vanishes under any
-    f32 emulation (1.0 + 1e-16 == 1.0 in f32) must survive bitwise.
+    semantics?  Probed ONCE: a sequential sum of sixteen full-mantissa
+    doubles must come back bitwise equal to NumPy's.  (The earlier probe,
+    1.0 + 1e-16, is 1.0 in IEEE double too and so passed on the TPU v5e,
+    whose emulated float64 keeps ~48 mantissa bits: responses there were
+    off by a few 1e-15 — chip_smoke.py, PR 22.)
     ``LGBTPU_SERVE_ACCUM=host`` forces the host-accumulation fallback;
     ``=device`` raises if the probe fails (no silent downgrade)."""
     global _DEVICE_F64
@@ -109,24 +109,32 @@ def device_accumulation_supported() -> bool:
     if mode == "host":
         return False
     if _DEVICE_F64 is None:
-        try:
-            import jax.numpy as jnp
-            want = np.float64(1.0) + np.float64(1e-16)
-            with _x64_scope():
-                a = jnp.asarray(np.asarray([1.0, 1e-16], np.float64))
-                ok = a.dtype == jnp.float64
-                if ok:
-                    # eager device add (no bare jit): any f32 emulation
-                    # loses the 1e-16 and fails the bit compare
-                    got = np.asarray(a[0] + a[1])
-                    ok = (got.dtype == np.float64
-                          and got.view(np.uint64) ==
-                          np.float64(want).view(np.uint64))
-            _DEVICE_F64 = bool(ok)
-        except Exception as e:  # noqa: BLE001 — probe must never kill serving
-            log_info(f"serving: device float64 probe failed ({e}); "
-                     "leaf accumulation stays on the host")
-            _DEVICE_F64 = False
+        # a backend that cannot hold or add f64 answers False here; one
+        # that RAISES has a fault serving would hit again on its first
+        # request, so the exception is the caller's to see
+        import jax.numpy as jnp
+        vals = np.random.RandomState(0).standard_normal(16)
+        want = np.float64(0.0)
+        for v in vals:
+            want = want + v
+        with _x64_scope():
+            a = jnp.asarray(vals)
+            ok = a.dtype == jnp.float64
+            if ok:
+                # eager device adds (no bare jit), in the host loop's
+                # order: an emulation that drops mantissa bits on the
+                # transfer or in an add fails the bit compare
+                s = jnp.zeros((), jnp.float64)
+                for i in range(len(vals)):
+                    s = s + a[i]
+                got = np.asarray(s)
+                ok = (got.dtype == np.float64
+                      and got.view(np.uint64) == want.view(np.uint64))
+        _DEVICE_F64 = bool(ok)
+        if not _DEVICE_F64:
+            log_warning("serving: this backend's float64 is not IEEE-754 "
+                        "(a 16-term sum differs bitwise from NumPy's); "
+                        "leaf accumulation runs on the host")
     if mode == "device" and not _DEVICE_F64:
         raise LightGBMError(
             "LGBTPU_SERVE_ACCUM=device but this backend has no IEEE "

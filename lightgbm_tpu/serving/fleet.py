@@ -311,12 +311,10 @@ def _replica_main(spec_path: str, rank: int) -> int:
     # every replica; per-request span emission still follows the
     # propagated head-sampling decision (serve_trace_sample)
     telemetry.configure(enabled=True)
-    if spec.get("cache_dir"):
-        # shared persistent compile cache: replica warmups after the
-        # first pay file reads, not XLA compiles
-        import jax
-        jax.config.update("jax_compilation_cache_dir", spec["cache_dir"])
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    # shared persistent compile cache: replica warmups after the first pay
+    # file reads, not XLA compiles (placed by the supervisor's child_env)
+    from ..runtime import configure_compile_cache
+    configure_compile_cache()
     fleet_dir = spec["fleet_dir"]
     hb_path = os.path.join(fleet_dir, f"hb_{rank}")
     stop = threading.Event()
@@ -614,7 +612,8 @@ class ServingFleet:
                  explain_max_batch: int = 16,
                  explain_queue_size: int = 64,
                  explain_max_delay_ms: float = 2.0,
-                 python: str = sys.executable):
+                 python: str = sys.executable, platform: str = ""):
+        from ..runtime import child_platform
         from .server import reuseport_available
 
         if replicas < 1:
@@ -629,6 +628,10 @@ class ServingFleet:
             mode = "front"
         self.mode = mode
         self.replicas = int(replicas)
+        # every replica is TOLD its JAX platform; a chip belongs to one
+        # process, so platform "tpu" needs a chip per replica and a
+        # supervisor that never initialised JAX (checked in start())
+        self.platform = str(platform or child_platform())
         self.host = str(host)
         self.port = int(port)
         if self.mode == "reuseport" and self.port == 0:
@@ -708,7 +711,7 @@ class ServingFleet:
             "max_delay_ms": float(max_delay_ms),
             "queue_size": int(queue_size), "buckets": str(buckets_spec),
             "warmup": bool(warmup), "deadline_ms": self.deadline_ms,
-            "poll_s": _BEAT_S, "cache_dir": "/tmp/lgb_tpu_jax_cache",
+            "poll_s": _BEAT_S,
             "trace_sample": self.trace_sample,
             "trace_tail": int(trace_tail),
             "access_log_dir": self.access_dir,
@@ -794,12 +797,10 @@ class ServingFleet:
                       os.path.join(self.dir, f"hb_{rank}")):
             if os.path.exists(stale):
                 os.unlink(stale)
-        env = dict(os.environ)
+        from ..runtime import child_env
+        env = child_env(self.platform)
         env["LGBTPU_REPLICA_RANK"] = str(rank)
         env["PYTHONUNBUFFERED"] = "1"
-        repo = os.path.dirname(os.path.dirname(
-            os.path.dirname(os.path.abspath(__file__))))
-        env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
         log_path = os.path.join(self.dir, f"replica_{rank}.log")
         with open(log_path, "ab") as logf:
             proc = subprocess.Popen(
@@ -887,6 +888,9 @@ class ServingFleet:
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> "ServingFleet":
+        from ..runtime import require_chips
+        require_chips(self.replicas, self.platform,
+                      f"ServingFleet(replicas={self.replicas})")
         for r in range(self.replicas):
             self._spawn(r)
         deadline = time.monotonic() + self.startup_timeout_s

@@ -855,7 +855,9 @@ def run_server(params: Dict[str, Any]) -> int:
     server."""
     from .. import telemetry
     from ..config import Config
+    from ..runtime import configure_compile_cache
 
+    configure_compile_cache()
     if Config.from_params(params).serve_replicas > 1:
         from .fleet import run_fleet
         return run_fleet(params)

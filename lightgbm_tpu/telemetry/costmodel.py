@@ -51,23 +51,28 @@ _flops_total = 0.0          # dispatch-weighted running totals
 _bytes_total = 0.0
 _balance: Optional[Dict[str, Any]] = None    # cached machine balance
 
-# Published peak dense-f32-equivalent flops and HBM bandwidth per device
-# kind (roofline ridge = peak_flops / peak_bw).  Matched by prefix on
-# jax's ``device_kind``; LGBTPU_PEAK_FLOPS / LGBTPU_PEAK_BW override for
-# unlisted parts.  TPU numbers are the public per-chip specs.
+# Published per-chip peaks by device kind: (bf16 flop/s, int8 op/s or None
+# where this table has no sourced figure, HBM bytes/s).  The roofline ridge
+# is bf16 peak / bandwidth; the int8 peak is what the quantized stream
+# kernel's one-hot contraction runs against.  Matched by prefix on jax's
+# ``device_kind`` (a v5e reports "TPU v5 lite").  Source for v5e: Google
+# Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 393 TOP/s int8, 819
+# GB/s HBM; the other rows are the same pages' per-chip bf16/HBM figures.
+# A TPU kind that is not listed is an error, not a default
+# (LGBTPU_PEAK_FLOPS + LGBTPU_PEAK_BW together stand in for a new part).
 _DEVICE_PEAKS = {
-    "TPU v2": (45e12, 700e9),
-    "TPU v3": (123e12, 900e9),
-    "TPU v4": (275e12, 1228e9),
-    "TPU v5 lite": (197e12, 819e9),
-    "TPU v5e": (197e12, 819e9),
-    "TPU v5p": (459e12, 2765e9),
-    "TPU v6": (918e12, 1640e9),
+    "TPU v2": (45e12, None, 700e9),
+    "TPU v3": (123e12, None, 900e9),
+    "TPU v4": (275e12, None, 1228e9),
+    "TPU v5 lite": (197e12, 393e12, 819e9),
+    "TPU v5e": (197e12, 393e12, 819e9),
+    "TPU v5p": (459e12, None, 2765e9),
+    "TPU v6": (918e12, None, 1640e9),
 }
 # conservative single-socket CPU estimate (AVX fma) — the exact numbers
 # matter less than a stable ridge so CPU verdicts are deterministic
-_CPU_DEFAULT = (5e11, 5e10)
-_GENERIC_DEFAULT = (1e13, 1e12)
+_CPU_DEFAULT = (5e11, None, 5e10)
+_GENERIC_DEFAULT = (1e13, None, 1e12)   # non-TPU accelerators only
 
 
 # -- control ----------------------------------------------------------------
@@ -123,31 +128,34 @@ def machine_balance() -> Dict[str, Any]:
     global _balance
     if _balance is not None:
         return dict(_balance)
-    kind = platform = "unknown"
-    try:
-        import jax
-        dev = jax.local_devices()[0]
-        kind = str(getattr(dev, "device_kind", "") or "unknown")
-        platform = str(getattr(dev, "platform", "") or "unknown")
-    except Exception:
-        pass
+    import jax
+    dev = jax.local_devices()[0]
+    kind, platform = str(dev.device_kind), str(dev.platform)
+    env_flops = os.environ.get("LGBTPU_PEAK_FLOPS")
+    env_bw = os.environ.get("LGBTPU_PEAK_BW")
     peaks = None
-    for prefix, pair in _DEVICE_PEAKS.items():
+    for prefix, row in _DEVICE_PEAKS.items():
         if kind.lower().startswith(prefix.lower()):
-            peaks = pair
+            peaks = row
             break
     if peaks is None:
+        if platform == "tpu" and not (env_flops and env_bw):
+            raise ValueError(
+                f"no published peaks for TPU device_kind {kind!r} in "
+                "telemetry/costmodel._DEVICE_PEAKS — add the row with its "
+                "source (or set LGBTPU_PEAK_FLOPS and LGBTPU_PEAK_BW)")
         peaks = _CPU_DEFAULT if platform == "cpu" else _GENERIC_DEFAULT
-    peak_flops, peak_bw = peaks
+    peak_flops, peak_int8, peak_bw = peaks
     try:
-        peak_flops = float(os.environ.get("LGBTPU_PEAK_FLOPS", peak_flops))
-        peak_bw = float(os.environ.get("LGBTPU_PEAK_BW", peak_bw))
+        peak_flops = float(env_flops or peak_flops)
+        peak_bw = float(env_bw or peak_bw)
     except ValueError:
         pass
     _balance = {
         "device_kind": kind,
         "platform": platform,
         "peak_flops_per_s": peak_flops,
+        "peak_int8_ops_per_s": peak_int8,
         "peak_hbm_bytes_per_s": peak_bw,
         "ridge_intensity": round(peak_flops / max(peak_bw, 1.0), 3),
     }

@@ -14,6 +14,7 @@ import glob
 import gzip
 import json
 import os
+import shutil
 import sys
 import time
 from collections import defaultdict
@@ -27,6 +28,12 @@ import numpy as np
 def main():
     import jax
     import lightgbm_tpu as lgb
+    from lightgbm_tpu.runtime import (CHECKOUT, configure_compile_cache,
+                                      require_tpu)
+    configure_compile_cache()
+    # device op durations from a CPU trace would be CPU times
+    dev = require_tpu("profile_grow (device trace)")
+    print(f"profiling on {dev['device_kind']} x{dev['device_count']}")
 
     ranking = os.environ.get("PROFILE_TASK", "") == "ranking"
     default_rows = 2_270_000 if ranking else 10_500_000
@@ -56,8 +63,9 @@ def main():
         bst.update()
     bst.engine.score.block_until_ready()
 
-    tdir = "/tmp/lgb_trace"
-    os.system(f"rm -rf {tdir}")
+    # under chiprun_out/ so a run through the chip tool brings it back
+    tdir = str(CHECKOUT / "chiprun_out" / "profile_grow")
+    shutil.rmtree(tdir, ignore_errors=True)
     with jax.profiler.trace(tdir):
         t0 = time.time()
         for _ in range(3):

@@ -405,3 +405,57 @@ def test_profile_session_merges_host_and_device_trace(telemetry_cost,
     device_events = [s["events"] for s in shard_info
                      if "device" in s["path"]][0]
     assert device_events > 0
+
+
+# ---------------------------------------------------------------------------
+# the peak table (PR 22): int8 peak for the quantized kernel, unknown TPU kinds
+# ---------------------------------------------------------------------------
+
+class _FakeDevice:
+    def __init__(self, platform, device_kind):
+        self.platform, self.device_kind = platform, device_kind
+
+
+def _balance_for(monkeypatch, platform, kind):
+    import jax
+    monkeypatch.setattr(costmodel, "_balance", None)
+    monkeypatch.delenv("LGBTPU_PEAK_FLOPS", raising=False)
+    monkeypatch.delenv("LGBTPU_PEAK_BW", raising=False)
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [_FakeDevice(platform, kind)])
+    try:
+        return costmodel.machine_balance()
+    finally:
+        costmodel._balance = None
+
+
+def test_v5e_peaks_include_the_int8_peak(monkeypatch):
+    """A v5e reports device_kind "TPU v5 lite"; the quantized stream
+    kernel's one-hot contraction runs against the int8 peak, not bf16."""
+    bal = _balance_for(monkeypatch, "tpu", "TPU v5 lite")
+    assert bal["peak_flops_per_s"] == 197e12
+    assert bal["peak_int8_ops_per_s"] == 393e12
+    assert bal["peak_hbm_bytes_per_s"] == 819e9
+    assert bal["platform"] == "tpu" and bal["device_kind"] == "TPU v5 lite"
+
+
+def test_unknown_tpu_kind_is_an_error_not_a_default(monkeypatch):
+    with pytest.raises(ValueError, match="no published peaks.*TPU v9 mega"):
+        _balance_for(monkeypatch, "tpu", "TPU v9 mega")
+    # both overrides together stand in for a new part
+    monkeypatch.setenv("LGBTPU_PEAK_FLOPS", "1e15")
+    monkeypatch.setenv("LGBTPU_PEAK_BW", "1e12")
+    monkeypatch.setattr(costmodel, "_balance", None)
+    import jax
+    monkeypatch.setattr(jax, "local_devices",
+                        lambda: [_FakeDevice("tpu", "TPU v9 mega")])
+    try:
+        assert costmodel.machine_balance()["ridge_intensity"] == 1000.0
+    finally:
+        costmodel._balance = None
+
+
+def test_cpu_keeps_its_default_and_has_no_int8_peak(monkeypatch):
+    bal = _balance_for(monkeypatch, "cpu", "cpu")
+    assert bal["peak_int8_ops_per_s"] is None
+    assert bal["ridge_intensity"] == 10.0

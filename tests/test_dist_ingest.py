@@ -8,7 +8,6 @@ binned shards assemble into one global row-sharded array, and the trained
 model must match single-process training on the full file.
 """
 import os
-import pathlib
 import socket
 import subprocess
 import sys
@@ -18,8 +17,7 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.dataset_io import load_data_file
-
-REPO = pathlib.Path(__file__).resolve().parent.parent
+from lightgbm_tpu.runtime import child_env
 
 
 def _write_csv(path, n=4000, f=6, seed=0):
@@ -47,22 +45,11 @@ def test_shard_loading_concat_equals_full(tmp_path):
 
 _CHILD = r"""
 import os, sys
-os.environ.pop("XLA_FLAGS", None)
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:  # cross-process CPU collectives (older jax: option absent)
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
-try:
-    from jax.extend.backend import clear_backends; clear_backends()
-except Exception:
-    pass
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 port, rank, data, out = sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4]
 jax.distributed.initialize(f"localhost:{port}", num_processes=2,
                            process_id=rank)
-jax.config.update("jax_compilation_cache_dir", "/tmp/lgb_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 import lightgbm_tpu as lgb
 ds = lgb.Dataset(data)
 bst = lgb.train({"objective": "binary", "num_leaves": 15, "verbosity": -1,
@@ -102,10 +89,8 @@ def test_two_process_distributed_training(tmp_path, require_two_process_collecti
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
 
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = f"{REPO}:" + env.get("PYTHONPATH", "")
+    # platform, device count and compile cache stated for the child
+    env = child_env("cpu")
     procs = [subprocess.Popen(
         [sys.executable, "-c", _CHILD, str(port), str(r), data, out],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
@@ -125,22 +110,12 @@ def test_two_process_distributed_training(tmp_path, require_two_process_collecti
 
 _CHILD_VALID = r"""
 import os, sys, json
-os.environ.pop("XLA_FLAGS", None)
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:  # cross-process CPU collectives (older jax: option absent)
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
-try:
-    from jax.extend.backend import clear_backends; clear_backends()
-except Exception:
-    pass
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 port, rank, data, vdata, out = (sys.argv[1], int(sys.argv[2]), sys.argv[3],
                                 sys.argv[4], sys.argv[5])
 jax.distributed.initialize(f"localhost:{port}", num_processes=2,
                            process_id=rank)
-jax.config.update("jax_compilation_cache_dir", "/tmp/lgb_tpu_jax_cache")
 import lightgbm_tpu as lgb
 ds = lgb.Dataset(data)
 vs = lgb.Dataset(vdata, reference=ds)
@@ -172,10 +147,8 @@ def test_two_process_valid_early_stopping_matches_single(
     with socket.socket() as s:
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = f"{REPO}:" + env.get("PYTHONPATH", "")
+    # platform, device count and compile cache stated for the child
+    env = child_env("cpu")
     procs = [subprocess.Popen(
         [sys.executable, "-c", _CHILD_VALID, str(port), str(r), data, vdata,
          out], env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
@@ -204,21 +177,11 @@ def test_two_process_valid_early_stopping_matches_single(
 
 _CHILD_RANK = r"""
 import os, sys
-os.environ.pop("XLA_FLAGS", None)
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:  # cross-process CPU collectives (older jax: option absent)
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
-try:
-    from jax.extend.backend import clear_backends; clear_backends()
-except Exception:
-    pass
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 port, rank, data, out = sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4]
 jax.distributed.initialize(f"localhost:{port}", num_processes=2,
                            process_id=rank)
-jax.config.update("jax_compilation_cache_dir", "/tmp/lgb_tpu_jax_cache")
 import lightgbm_tpu as lgb
 ds = lgb.Dataset(data)
 bst = lgb.train({"objective": "lambdarank", "num_leaves": 15,
@@ -260,10 +223,8 @@ def test_two_process_lambdarank_matches_single(
     with socket.socket() as s:
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = f"{REPO}:" + env.get("PYTHONPATH", "")
+    # platform, device count and compile cache stated for the child
+    env = child_env("cpu")
     procs = [subprocess.Popen(
         [sys.executable, "-c", _CHILD_RANK, str(port), str(r), data, out],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)
@@ -335,22 +296,12 @@ def test_query_aligned_byte_range_empty_rank(tmp_path):
 
 _CHILD_RANK_STREAM = r"""
 import os, sys, json
-os.environ.pop("XLA_FLAGS", None)
 import numpy as np
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:  # cross-process CPU collectives (older jax: option absent)
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
-try:
-    from jax.extend.backend import clear_backends; clear_backends()
-except Exception:
-    pass
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 port, rank, data, out = sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4]
 jax.distributed.initialize(f"localhost:{port}", num_processes=2,
                            process_id=rank)
-jax.config.update("jax_compilation_cache_dir", "/tmp/lgb_tpu_jax_cache")
 import lightgbm_tpu as lgb
 ds = lgb.Dataset(data, params={"ingest_mode": "stream",
                                "ingest_chunk_rows": 256})
@@ -378,10 +329,8 @@ def test_two_process_lambdarank_streamed_matches_inmem(
     with socket.socket() as s:
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = f"{REPO}:" + env.get("PYTHONPATH", "")
+    # platform, device count and compile cache stated for the child
+    env = child_env("cpu")
     procs = [subprocess.Popen(
         [sys.executable, "-c", _CHILD_RANK_STREAM, str(port), str(r), data,
          out], env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)

@@ -17,6 +17,7 @@ from lightgbm_tpu.binning import BinMapper, construct_binned
 from lightgbm_tpu.ingest import (BottomKSample, FeatureSketch,
                                  _merge_rank_blobs, _pack_rank_blob,
                                  resolve_ingest_mode)
+from lightgbm_tpu.runtime import child_env
 from lightgbm_tpu.utils.log import LightGBMError
 
 PARAMS = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
@@ -541,22 +542,11 @@ def test_construct_binned_matches_bin_rows_into_chunks():
 
 _DIST_CHILD = r"""
 import os, sys
-os.environ.pop("XLA_FLAGS", None)
 import jax
-jax.config.update("jax_platforms", "cpu")
-try:  # cross-process CPU collectives (older jax: option absent)
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-except Exception:
-    pass
-try:
-    from jax.extend.backend import clear_backends; clear_backends()
-except Exception:
-    pass
+jax.config.update("jax_cpu_collectives_implementation", "gloo")
 port, rank, data, out = sys.argv[1], int(sys.argv[2]), sys.argv[3], sys.argv[4]
 jax.distributed.initialize(f"localhost:{port}", num_processes=2,
                            process_id=rank)
-jax.config.update("jax_compilation_cache_dir", "/tmp/lgb_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 import lightgbm_tpu as lgb
 os.environ["LGBTPU_INGEST"] = "stream"
 os.environ["LGBTPU_INGEST_CHUNK"] = "700"
@@ -581,11 +571,9 @@ def test_two_process_stream_ingest(tmp_path,
     """Each rank streams only its byte shard; the ONE-collective sketch
     sync must yield the same mappers — and structurally the same model —
     as a single-process streamed run over the whole file."""
-    import pathlib
     import socket
     import subprocess
     import sys as _sys
-    repo = pathlib.Path(__file__).resolve().parent.parent
     rng = np.random.RandomState(0)
     Xd = rng.randn(4000, 6)
     yd = (Xd[:, 0] + np.sin(Xd[:, 1]) + 0.1 * rng.randn(4000) > 0)
@@ -596,10 +584,8 @@ def test_two_process_stream_ingest(tmp_path,
     with socket.socket() as s:
         s.bind(("localhost", 0))
         port = s.getsockname()[1]
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = f"{repo}:" + env.get("PYTHONPATH", "")
+    # platform, device count and compile cache stated for the child
+    env = child_env("cpu")
     procs = [subprocess.Popen(
         [_sys.executable, "-c", _DIST_CHILD, str(port), str(r), data, out],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.STDOUT)

@@ -8,7 +8,6 @@ local_listen_port; each locates its rank in the machine list, connects via
 jax.distributed, ingests its row shard, and trains the same SPMD program.
 The resulting model must match single-process CLI training on the full
 file."""
-import pathlib
 import socket
 import subprocess
 import sys
@@ -16,19 +15,11 @@ import sys
 import numpy as np
 import pytest
 
-REPO = pathlib.Path(__file__).resolve().parent.parent
+from lightgbm_tpu.runtime import child_env
+
 
 _CHILD = r"""
 import os, sys
-os.environ.pop("XLA_FLAGS", None)
-import jax
-jax.config.update("jax_platforms", "cpu")
-try:
-    from jax.extend.backend import clear_backends; clear_backends()
-except Exception:
-    pass
-jax.config.update("jax_compilation_cache_dir", "/tmp/lgb_tpu_jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
 workdir, port = sys.argv[1], sys.argv[2]
 os.chdir(workdir)
 from lightgbm_tpu import cli
@@ -70,12 +61,7 @@ def test_two_machine_cli_matches_single(tmp_path,
     (single / "train.csv").symlink_to(data)
     (single / "train.conf").write_text(conf_body.replace(
         "num_machines = 2\nmachine_list_file = mlist.txt\n", ""))
-    env = {"PYTHONPATH": str(REPO)}
-    import os
-    env.update({k: v for k, v in os.environ.items()
-                if k not in ("XLA_FLAGS", "JAX_PLATFORMS")})
-    env["PYTHONPATH"] = str(REPO) + os.pathsep + os.environ.get(
-        "PYTHONPATH", "")
+    env = child_env("cpu")
     out = subprocess.run([sys.executable, "-c", _CHILD, str(single), "12400"],
                          env=env, capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stdout + out.stderr

@@ -110,7 +110,7 @@ def _get(host, port, path, timeout=15):
 # ---------------------------------------------------------------------------
 
 def test_parse_model_roster():
-    r = parse_model_roster("a=/tmp/a.txt, b=/tmp/b.txt")
+    r = parse_model_roster("a=/models/a.txt, b=/models/b.txt")
     assert list(r) == ["a", "b"]
     assert parse_model_roster({"x": "p"}) == {"x": "p"}
     for bad in ("justapath", "a=", "=p", "a=p,a=q", "bad id=p", ""):

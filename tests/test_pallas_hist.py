@@ -12,14 +12,6 @@ from lightgbm_tpu.ops.histogram import _hist_segsum, build_histograms
 from lightgbm_tpu.pallas import hist_kernel as hk
 
 
-@pytest.fixture(autouse=True)
-def _interpret_mode():
-    old = hk._INTERPRET
-    hk._INTERPRET = True
-    yield
-    hk._INTERPRET = old
-
-
 def _mk(n, g, s, b, seed=0, frac_invalid=0.3):
     rs = np.random.RandomState(seed)
     bins = jnp.asarray(rs.randint(0, b, size=(n, g)), jnp.uint8)

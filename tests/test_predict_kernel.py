@@ -7,12 +7,13 @@ import pytest
 
 import lightgbm_tpu as lgb
 from lightgbm_tpu.basic import Booster
-from lightgbm_tpu.pallas import predict_kernel
 
 
 @pytest.fixture(autouse=True)
 def _interpret(monkeypatch):
-    monkeypatch.setattr(predict_kernel, "_INTERPRET", True)
+    # the kernel runs interpreted off the chip (runtime.pallas_interpret);
+    # opt in to the device path there and lower its batch threshold
+    monkeypatch.setattr(Booster, "_DEVICE_PREDICT_OFF_CHIP", True)
     monkeypatch.setattr(Booster, "_DEVICE_PREDICT_MIN_ROWS", 100)
     yield
 

@@ -260,10 +260,10 @@ def test_chaos_noop_when_env_unset(monkeypatch):
 
 def test_chaos_parse_and_cli(monkeypatch):
     monkeypatch.setenv(chaos.ENV_VAR,
-                       "kill:iter=5,rank=1,once=/tmp/m; nan_grad:iter=3,count=4")
+                       "kill:iter=5,rank=1,once=/run/m; nan_grad:iter=3,count=4")
     ds = chaos.directives()
     assert [d.name for d in ds] == ["kill", "nan_grad"]
-    assert ds[0].iteration == 5 and ds[0].rank == 1 and ds[0].once == "/tmp/m"
+    assert ds[0].iteration == 5 and ds[0].rank == 1 and ds[0].once == "/run/m"
     assert ds[1].count == 4
     assert chaos.main() == 0
     monkeypatch.setenv(chaos.ENV_VAR, "kill:bogus_key=1")
@@ -277,12 +277,12 @@ def test_chaos_closed_loop_directives(monkeypatch, tmp_path):
     and all three parse with the standard option grammar."""
     monkeypatch.setenv(
         chaos.ENV_VAR,
-        "poison_refit:iter=1,count=3; kill_refit:once=/tmp/m; "
-        "torn_pointer:once=/tmp/m2")
+        "poison_refit:iter=1,count=3; kill_refit:once=/run/m; "
+        "torn_pointer:once=/run/m2")
     ds = chaos.directives()
     assert [d.name for d in ds] == ["poison_refit", "kill_refit",
                                     "torn_pointer"]
-    assert ds[0].count == 3 and ds[1].once == "/tmp/m"
+    assert ds[0].count == 3 and ds[1].once == "/run/m"
     vals = np.linspace(-1.0, 1.0, 8)
     poisoned = chaos.inject_nan_refit(vals, tree_index=1)
     assert np.isnan(poisoned[:3]).all() and np.isfinite(poisoned[3:]).all()

@@ -12,17 +12,8 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu.ops.grow import feature_local_bin
 from lightgbm_tpu.ops.histogram import _hist_segsum
-from lightgbm_tpu.pallas import stream_kernel
 from lightgbm_tpu.pallas.stream_kernel import (build_route_tables, pack_bins_T,
                                                route_and_hist)
-
-
-@pytest.fixture(autouse=True)
-def _interpret_mode():
-    old = stream_kernel._INTERPRET
-    stream_kernel._INTERPRET = True
-    yield
-    stream_kernel._INTERPRET = old
 
 
 def _dataset(n=2000, seed=11):

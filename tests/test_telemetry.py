@@ -228,7 +228,9 @@ def test_train_emits_iteration_records(telemetry, tmp_path):
         assert r["wall_s"] > 0
         assert 2 <= r["num_leaves"] <= 7
         assert r["phases"]  # boosting/grow splits present
-        assert "peak_hbm_gb" in r or "device_hbm_gb" in r
+        # XLA:CPU keeps no allocator stats: no peak, and no estimate
+        # under another name in its place
+        assert "peak_hbm_gb" not in r and "device_hbm_gb" not in r
         assert "host_rss_gb" in r
     assert any("boosting_s" in r["phases"] for r in iters)
     assert any("grow_s" in r["phases"] for r in iters)

@@ -1,0 +1,226 @@
+"""Kernels that compile — kept true from the CPU box.
+
+The installed libtpu can describe a v5e topology with no chip attached
+(``jax.experimental.topologies``), so the programs the chip will run are
+AOT-compiled here, by the real Mosaic/XLA:TPU compiler: the interpret seam is
+off (``runtime.lowering_for("tpu")``) and the engine resolves its
+configuration exactly as it does on the chip (stream backend, int8
+histograms, 64 splits a round, fused iteration, the TPU block-size tiers).
+Nothing compiled here is executed; whether the compiled kernels give the
+right ANSWERS is chip_smoke.py's job, on the chip.
+
+A topology that cannot be built is an error, not a skip: without it this
+file proves nothing and must not pass quietly.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.experimental import topologies
+from jax.sharding import SingleDeviceSharding
+
+import lightgbm_tpu as lgb
+from lightgbm_tpu import runtime
+from lightgbm_tpu.models import gbdt as gbdt_mod
+from lightgbm_tpu.pallas import stream_kernel
+from lightgbm_tpu.utils.log import LightGBMError
+
+
+@pytest.fixture(scope="module")
+def tpu():
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    dev = topo.devices[0]
+    assert dev.platform == "tpu" and "v5" in dev.device_kind
+    with runtime.lowering_for("tpu"):
+        yield SingleDeviceSharding(dev)
+
+
+def _abstract(tree, sharding):
+    """Every array leaf -> a ShapeDtypeStruct placed on the topology's
+    device, which is what makes ``.lower()`` target the TPU."""
+    def one(a):
+        if isinstance(a, (jax.Array, np.ndarray)):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+        return a
+    return jax.tree.map(one, tree)
+
+
+class _Lowered(Exception):
+    pass
+
+
+def _rows(n, f, seed):
+    rs = np.random.RandomState(seed)
+    return rs.randn(n, f).astype(np.float32), rs
+
+
+def _higgs_like():
+    """bench.py main(): 28 features, 255 leaves, 63 bins, quantized."""
+    X, rs = _rows(8192, 28, 0)
+    return ({"objective": "binary", "num_leaves": 255, "max_bin": 63,
+             "use_quantized_grad": True, "num_grad_quant_bins": 64},
+            X, (rs.rand(len(X)) < 0.5).astype(np.float64), {})
+
+
+def _mslr_like():
+    """bench.py run_ranking(): 136 features, lambdarank, quantized."""
+    X, rs = _rows(4096, 136, 1)
+    return ({"objective": "lambdarank", "num_leaves": 255, "max_bin": 63,
+             "use_quantized_grad": True, "num_grad_quant_bins": 64,
+             "ndcg_eval_at": [10]},
+            X, rs.randint(0, 5, len(X)).astype(np.float64),
+            {"group": np.full(32, 128)})
+
+
+def _multiclass_k10():
+    """bench.py run_multiclass(): K=10 batched growth, bf16 two-pass."""
+    X, rs = _rows(4096, 28, 2)
+    return ({"objective": "multiclass", "num_class": 10, "num_leaves": 255,
+             "max_bin": 63},
+            X, rs.randint(0, 10, len(X)).astype(np.float64), {})
+
+
+def _lower_iteration(sharding, params, X, y, ds_kw):
+    """Build the Booster as on the chip and lower — for the topology's TPU —
+    the program its first ``update()`` would launch (the fused iteration),
+    instead of running it."""
+    real = gbdt_mod.watched_jit
+    got = []
+
+    def capturing(fn=None, *, name=None, **kw):
+        jitted = real(fn, name=name, **kw)
+        if name != "fused_iter":
+            return jitted
+
+        def call(*args, **kwargs):
+            a, k = _abstract((args, kwargs), sharding)
+            got.append(jitted.lower(*a, **k))
+            raise _Lowered()
+        return call
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(gbdt_mod, "watched_jit", capturing)
+        bst = lgb.Booster(dict(params, verbosity=-1),
+                          lgb.Dataset(X, label=y, **ds_kw))
+        eng = bst.engine
+        gp = eng._grow_params
+        assert gp.hist_backend == "stream" and gp.max_splits_per_round == 64
+        assert eng._can_fuse_iteration() and eng._use_leaf_gather_kernel
+        with pytest.raises(_Lowered):
+            bst.update()
+    return eng, got[0]
+
+
+@pytest.fixture(scope="module")
+def iterations(tpu):
+    """The three iteration programs ``python bench.py`` runs, at their full
+    widths and small N: lowered one after another (tracing holds the GIL),
+    compiled side by side (XLA does not)."""
+    from concurrent.futures import ThreadPoolExecutor
+    lowered = {name: _lower_iteration(tpu, *make())
+               for name, make in (("higgs", _higgs_like),
+                                  ("mslr", _mslr_like),
+                                  ("multiclass", _multiclass_k10))}
+    with ThreadPoolExecutor(len(lowered)) as pool:
+        texts = {name: pool.submit(lambda lo=lo: lo.compile().as_text())
+                 for name, (_, lo) in lowered.items()}
+        return {name: (lowered[name][0], fut.result())
+                for name, fut in texts.items()}
+
+
+def test_higgs_like_iteration_compiles(iterations):
+    eng, text = iterations["higgs"]
+    assert "tpu_custom_call" in text                 # Mosaic, not interpret
+    assert eng._grow_params.int_hist and eng._pack_block == 4096
+    assert (eng.dd.num_groups, eng.dd.max_bins) == (28, 63)
+
+
+def test_mslr_like_lambdarank_iteration_compiles(iterations):
+    eng, text = iterations["mslr"]
+    assert "tpu_custom_call" in text
+    assert eng._grow_params.int_hist and eng._pack_block == 1024
+    assert eng.dd.num_groups == 136
+
+
+def test_multiclass_k10_iteration_compiles(iterations):
+    eng, text = iterations["multiclass"]
+    assert "tpu_custom_call" in text
+    assert not eng._grow_params.int_hist
+    assert eng._use_batched_multiclass()
+
+
+def test_predict_stream_compiles(tpu):
+    """Booster.predict's device walk (basic._try_device_predict) at the
+    trained-model shape: 255 leaves, packed i32 bins, 8 trees."""
+    from lightgbm_tpu.pallas.predict_kernel import (CAT_DIGITS,
+                                                    ROWS_PER_TREE,
+                                                    predict_stream)
+    n_trees, L = 8, 255
+    args = _abstract((np.zeros((8, 20480), np.int32),
+                      np.zeros((n_trees * ROWS_PER_TREE, L), np.float32),
+                      np.zeros((CAT_DIGITS, 128), np.float32)), tpu)
+    text = predict_stream.lower(*args, L, n_trees, 12).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def test_serving_programs_compile(tpu, tmp_path):
+    """serve_leaves (what a TPU serves with: its float64 is not IEEE, so
+    accumulation stays on the host) and serve_predict (the f64 program a
+    backend with real doubles runs)."""
+    from lightgbm_tpu.serving import compiled as sc
+    rs = np.random.RandomState(3)
+    X = rs.randn(600, 28)
+    with runtime.lowering_for("cpu"):           # train for real, on the CPU
+        bst = lgb.train({"objective": "binary", "num_leaves": 31,
+                         "verbosity": -1}, lgb.Dataset(
+                             X, label=(X[:, 0] > 0).astype(float)),
+                        num_boost_round=4)
+    pred = sc.CompiledPredictor(bst._all_trees(), 1, 28, max_batch=64)
+    rows = _abstract(pred._encode(X[:64]), tpu)
+    pack = _abstract(pred._pack, tpu)
+    sc._get_walk().lower(pack, *rows, max_depth=pred.max_depth).compile()
+    with jax.enable_x64():
+        lv = jax.ShapeDtypeStruct(pred._lv_dev.shape, jnp.float64,
+                                  sharding=tpu)
+        sc._get_score().lower(pack, lv, *rows, max_depth=pred.max_depth,
+                              num_class=1).compile()
+
+
+def test_scatter_backend_is_refused_on_a_tpu(tpu):
+    """Mosaic has no scatter-add lowering: refuse at parameter resolution
+    with a LightGBMError, not a NotImplementedError from inside a jit."""
+    X, rs = _rows(1024, 8, 4)
+    with pytest.raises(LightGBMError, match="scatter.*cannot run on a TPU"):
+        lgb.Booster({"objective": "binary", "hist_backend": "scatter",
+                     "verbosity": -1},
+                    lgb.Dataset(X, label=(rs.rand(len(X)) < 0.5) * 1.0))
+
+
+def test_over_limit_block_rows_rejected_before_the_compiler(tpu, monkeypatch):
+    """A bf16 one-hot at G=136 sits on the 16 MiB scoped-VMEM limit: the
+    compiler's own words are "Scoped allocation with size 17.36M and limit
+    16.00M" for T=512 with a 128-slot histogram block, and 21.84M for
+    T=1024 at the default 64 slots (12.80M, T=512, 64 slots still fits).
+    The block-rows check says so first, and the tiers it picks stay under."""
+    est = stream_kernel.stream_vmem_estimate
+    limit = stream_kernel.SCOPED_VMEM_LIMIT
+    m = 136 * 64
+    assert stream_kernel.stream_block_rows(63, 136, False) == 256
+    assert stream_kernel.stream_block_rows(63, 136, True) == 1024
+    assert stream_kernel.stream_block_rows(63, 28, True) == 4096
+    assert stream_kernel.stream_block_rows(63, 28, False) == 2048
+    assert est(m, 512, False) <= limit < est(m, 512, False, hist_channels=256)
+    assert est(m, 512, False) <= limit < est(m, 1024, False)
+    assert est(28 * 64, 2048, False) <= limit < est(28 * 64, 4096, False)
+    monkeypatch.setenv("LGBTPU_BLOCK_ROWS", "512")
+    with pytest.raises(LightGBMError, match="scoped VMEM.*limit is 16 MiB"):
+        stream_kernel.stream_block_rows(63, 136, False, hist_channels=256)
+    assert stream_kernel.stream_block_rows(63, 136, False) == 512
+    monkeypatch.setenv("LGBTPU_BLOCK_ROWS", "1024")
+    with pytest.raises(LightGBMError, match="scoped VMEM.*limit is 16 MiB"):
+        stream_kernel.stream_block_rows(63, 136, False)
+    assert stream_kernel.stream_block_rows(63, 136, True) == 1024  # int8 fits
+    monkeypatch.setenv("LGBTPU_BLOCK_ROWS", "1000")
+    with pytest.raises(LightGBMError, match="multiple of 128"):
+        stream_kernel.stream_block_rows(63, 28, True)
