@@ -21,6 +21,7 @@ from .config import Config, resolve_aliases
 from .device_data import DeviceData, to_device
 from .metrics import create_metrics
 from .objectives import create_objective
+from .telemetry import boundary as _boundary
 from .utils.log import LightGBMError, log_info, log_warning, set_verbosity
 
 _LABEL_FIELDS = ("label", "weight", "group", "init_score", "position")
@@ -653,12 +654,14 @@ class Dataset:
             ref = self.reference.construct()
             mappers = ref.binned.bin_mappers
             groups = ref.binned.group_features
-            if sparse:
-                from .binning import construct_binned_sparse
-                self.binned = construct_binned_sparse(self.raw_sparse,
-                                                      mappers, groups)
-            else:
-                self.binned = construct_binned(self.raw_data, mappers, groups)
+            with _boundary("Dataset::Bin", rows=self.num_data_):
+                if sparse:
+                    from .binning import construct_binned_sparse
+                    self.binned = construct_binned_sparse(self.raw_sparse,
+                                                          mappers, groups)
+                else:
+                    self.binned = construct_binned(self.raw_data, mappers,
+                                                   groups)
         else:
             cats = self._resolve_categorical()
             from .binning import load_forced_bins
@@ -671,47 +674,59 @@ class Dataset:
                 max_bin_by_feature=cfg.max_bin_by_feature,
                 forced_bins=load_forced_bins(cfg.forcedbins_filename,
                                              self.num_feature_, cats))
+            # find-bins = bin mappers + EFB groups from the row sample;
+            # bin = the full (N, G) fill
             if sparse:
                 from .binning import (construct_binned_sparse,
                                       find_bin_mappers_sparse,
                                       sample_sparse_csc, sparse_nz_masks)
-                mappers = find_bin_mappers_sparse(self.raw_sparse, **mapper_kw)
-                groups = None
-                if cfg.enable_bundle:
-                    # SAME sample rows as the dense path (same seed/draw), so
-                    # bundling — and therefore the model — is identical to
-                    # Dataset(X.todense()); transient cost is the F boolean
-                    # masks, ~F * min(N, sample_cnt) bytes
-                    Xc, n_sample = sample_sparse_csc(
-                        self.raw_sparse, cfg.bin_construct_sample_cnt,
-                        cfg.data_random_seed)
-                    masks = sparse_nz_masks(Xc, n_sample, mappers)
-                    del Xc
-                    groups = find_feature_groups(None, mappers,
-                                                 enable_bundle=True,
-                                                 nz_masks=masks)
-                    del masks
-                self.binned = construct_binned_sparse(self.raw_sparse,
-                                                      mappers, groups)
+                with _boundary("Dataset::FindBins", rows=self.num_data_):
+                    mappers = find_bin_mappers_sparse(self.raw_sparse,
+                                                      **mapper_kw)
+                    groups = None
+                    if cfg.enable_bundle:
+                        # SAME sample rows as the dense path (same
+                        # seed/draw), so bundling — and therefore the model
+                        # — is identical to Dataset(X.todense()); transient
+                        # cost is the F boolean masks,
+                        # ~F * min(N, sample_cnt) bytes
+                        Xc, n_sample = sample_sparse_csc(
+                            self.raw_sparse, cfg.bin_construct_sample_cnt,
+                            cfg.data_random_seed)
+                        masks = sparse_nz_masks(Xc, n_sample, mappers)
+                        del Xc
+                        groups = find_feature_groups(None, mappers,
+                                                     enable_bundle=True,
+                                                     nz_masks=masks)
+                        del masks
+                with _boundary("Dataset::Bin", rows=self.num_data_):
+                    self.binned = construct_binned_sparse(self.raw_sparse,
+                                                          mappers, groups)
             else:
-                mappers = find_bin_mappers(self.raw_data, **mapper_kw)
-                groups = None
-                if cfg.enable_bundle:
-                    sample_n = min(self.num_data_, cfg.bin_construct_sample_cnt)
-                    rng = np.random.RandomState(cfg.data_random_seed)
-                    idx = (np.arange(self.num_data_)
-                           if self.num_data_ <= sample_n else
-                           np.sort(rng.choice(self.num_data_, sample_n,
-                                              replace=False)))
-                    sample_bins = [mappers[f].transform(self.raw_data[idx, f])
-                                   for f in range(self.num_feature_)]
-                    groups = find_feature_groups(sample_bins, mappers,
-                                                 enable_bundle=True)
-                    # the sampled per-feature bin pool is dead the moment
-                    # groups exist — free it BEFORE the full bin fill
-                    # allocates the (N, G) matrix (peak-memory moment)
-                    del sample_bins
-                self.binned = construct_binned(self.raw_data, mappers, groups)
+                with _boundary("Dataset::FindBins", rows=self.num_data_):
+                    mappers = find_bin_mappers(self.raw_data, **mapper_kw)
+                    groups = None
+                    if cfg.enable_bundle:
+                        sample_n = min(self.num_data_,
+                                       cfg.bin_construct_sample_cnt)
+                        rng = np.random.RandomState(cfg.data_random_seed)
+                        idx = (np.arange(self.num_data_)
+                               if self.num_data_ <= sample_n else
+                               np.sort(rng.choice(self.num_data_, sample_n,
+                                                  replace=False)))
+                        sample_bins = [
+                            mappers[f].transform(self.raw_data[idx, f])
+                            for f in range(self.num_feature_)]
+                        groups = find_feature_groups(sample_bins, mappers,
+                                                     enable_bundle=True)
+                        # the sampled per-feature bin pool is dead the
+                        # moment groups exist — free it BEFORE the full bin
+                        # fill allocates the (N, G) matrix (peak-memory
+                        # moment)
+                        del sample_bins
+                with _boundary("Dataset::Bin", rows=self.num_data_):
+                    self.binned = construct_binned(self.raw_data, mappers,
+                                                   groups)
         if self._should_free_raw():
             self.raw_data = None
             self.raw_sparse = None
@@ -1446,6 +1461,21 @@ class Booster:
             score = raw[:1] if k == 1 else raw.reshape(1, k)
             self.last_predict_path = "host (single-row native walk)"
         if score is None:
+            score = self._predict_batch(X, use, k, early_stop, es_freq,
+                                        es_margin)
+        if self._average_output() and len(use):
+            score = score / max(len(use) // max(k, 1), 1)
+        if raw_score:
+            return score
+        conv = self._convert_output_fn()
+        return np.asarray(conv(score))
+
+    def _predict_batch(self, X, use, k, early_stop, es_freq, es_margin):
+        """Raw scores of a batch under the ``Predict`` boundary span: the
+        device walk where it applies (its steps are the span's children),
+        else the host walk; ``path`` and ``reason`` on the record say
+        which way the batch went (``last_predict_path``, split)."""
+        with _boundary("Predict", rows=X.shape[0], trees=len(use)) as sp:
             # pred_early_stop composes with the device batch walk (k == 1):
             # the kernel freezes cleared rows every es_freq trees, exactly
             # the host loop's bookkeeping (the reference's early stop is a
@@ -1453,47 +1483,53 @@ class Booster:
             # wide batches)
             es = (es_freq, es_margin) if early_stop else None
             score = self._try_device_predict(X, use, k, es=es)
-        if score is None:
-            if k == 1:
-                score = np.zeros(n, np.float64)
-                active = np.ones(n, bool)
-                all_active = True
-                for i, t in enumerate(use):
-                    if early_stop and not all_active:
-                        score[active] += t.predict_raw(X[active])
-                    else:
-                        score += t.predict_raw(X)
-                    if early_stop and (i + 1) % es_freq == 0:
-                        # reference: prediction_early_stop.cpp CreateBinary —
-                        # rows whose margin 2|score| clears the threshold stop
-                        # accumulating further trees
-                        active &= ~(2.0 * np.abs(score) > es_margin)
-                        all_active = bool(active.all())
-                        if not active.any():
-                            break
-            else:
-                score = np.zeros((n, k), np.float64)
-                active = np.ones(n, bool)
-                all_active = True
-                for i, t in enumerate(use):
-                    if early_stop and not all_active:
-                        score[active, i % k] += t.predict_raw(X[active])
-                    else:
-                        score[:, i % k] += t.predict_raw(X)
-                    if early_stop and (i + 1) % (es_freq * k) == 0:
-                        # CreateMulticlass: top-1 minus top-2 margin
-                        part = np.partition(score, -2, axis=1)
-                        margin = part[:, -1] - part[:, -2]
-                        active &= ~(margin > es_margin)
-                        all_active = bool(active.all())
-                        if not active.any():
-                            break
-        if self._average_output() and len(use):
-            score = score / max(len(use) // max(k, 1), 1)
-        if raw_score:
-            return score
-        conv = self._convert_output_fn()
-        return np.asarray(conv(score))
+            if score is None:
+                with _boundary("Predict::HostWalk"):
+                    score = self._host_walk(X, use, k, early_stop, es_freq,
+                                            es_margin)
+            path, _, reason = self.last_predict_path.partition(" (")
+            sp.set(path=path, reason=reason[:-1])
+        return score
+
+    @staticmethod
+    def _host_walk(X, use, k, early_stop, es_freq, es_margin):
+        """Tree-by-tree NumPy walk of the real-valued thresholds."""
+        n = X.shape[0]
+        if k == 1:
+            score = np.zeros(n, np.float64)
+            active = np.ones(n, bool)
+            all_active = True
+            for i, t in enumerate(use):
+                if early_stop and not all_active:
+                    score[active] += t.predict_raw(X[active])
+                else:
+                    score += t.predict_raw(X)
+                if early_stop and (i + 1) % es_freq == 0:
+                    # reference: prediction_early_stop.cpp CreateBinary —
+                    # rows whose margin 2|score| clears the threshold stop
+                    # accumulating further trees
+                    active &= ~(2.0 * np.abs(score) > es_margin)
+                    all_active = bool(active.all())
+                    if not active.any():
+                        break
+        else:
+            score = np.zeros((n, k), np.float64)
+            active = np.ones(n, bool)
+            all_active = True
+            for i, t in enumerate(use):
+                if early_stop and not all_active:
+                    score[active, i % k] += t.predict_raw(X[active])
+                else:
+                    score[:, i % k] += t.predict_raw(X)
+                if early_stop and (i + 1) % (es_freq * k) == 0:
+                    # CreateMulticlass: top-1 minus top-2 margin
+                    part = np.partition(score, -2, axis=1)
+                    margin = part[:, -1] - part[:, -2]
+                    active &= ~(margin > es_margin)
+                    all_active = bool(active.all())
+                    if not active.any():
+                        break
+        return score
 
     def _resolve_tree_slice(self, start_iteration: int,
                             num_iteration: Optional[int]):
@@ -1624,10 +1660,11 @@ class Booster:
         eng = self.engine
         tb = eng.train_data.binned
         r = eng.dd.routing
-        routing_np = {name: np.asarray(getattr(r, name))
-                      for name in ("feat_group", "span_start", "default_bin",
-                                   "bundled", "nan_bin", "num_bins",
-                                   "mzero_bin")}
+        with _boundary("Predict::RoutingTables"):
+            routing_np = {name: np.asarray(getattr(r, name))
+                          for name in ("feat_group", "span_start",
+                                       "default_bin", "bundled", "nan_bin",
+                                       "num_bins", "mzero_bin")}
         for f in sorted(cat_feats):
             # the NaN/unseen sentinel re-bin below needs the cat feature
             # alone in its group, and the sentinel bin num_bins must fit
@@ -1635,33 +1672,36 @@ class Booster:
             if routing_np["bundled"][f] or tb.bin_mappers[f].num_bins >= 255:
                 return host(f"categorical feature {f} is EFB-bundled or "
                             "fills the uint8 bin ladder")
-        binned = construct_binned(np.asarray(X, np.float64), tb.bin_mappers,
-                                  tb.group_features)
-        bins = np.asarray(binned.bins)
-        if cat_feats:
-            # the host walk routes NaN / unseen / negative categories
-            # RIGHT (bit absent from the bitset); the mapper bins them to
-            # bin 0 (the most frequent category) — re-bin those rows to
-            # the sentinel bin one past the span, whose bitset bit is
-            # always zero by construction (build_predict_tables)
-            Xf = np.asarray(X, np.float64)
-            for f in sorted(cat_feats):
-                m = tb.bin_mappers[f]
-                v = Xf[:, f]
-                ivc = np.where(np.isnan(v), -1.0, v)
-                ivc = np.clip(ivc, -1.0, float(2 ** 62)).astype(np.int64)
-                ok = (ivc >= 0) & np.isin(ivc,
-                                          m.categories.astype(np.int64))
-                bins[~ok, int(routing_np["feat_group"][f])] = m.num_bins
-        slay = pack_bins_T(jnp.asarray(bins))
+        with _boundary("Predict::Rebin"):
+            binned = construct_binned(np.asarray(X, np.float64),
+                                      tb.bin_mappers, tb.group_features)
+            bins = np.asarray(binned.bins)
+            if cat_feats:
+                # the host walk routes NaN / unseen / negative categories
+                # RIGHT (bit absent from the bitset); the mapper bins them
+                # to bin 0 (the most frequent category) — re-bin those rows
+                # to the sentinel bin one past the span, whose bitset bit
+                # is always zero by construction (build_predict_tables)
+                Xf = np.asarray(X, np.float64)
+                for f in sorted(cat_feats):
+                    m = tb.bin_mappers[f]
+                    v = Xf[:, f]
+                    ivc = np.where(np.isnan(v), -1.0, v)
+                    ivc = np.clip(ivc, -1.0, float(2 ** 62)).astype(np.int64)
+                    ok = (ivc >= 0) & np.isin(ivc,
+                                              m.categories.astype(np.int64))
+                    bins[~ok, int(routing_np["feat_group"][f])] = m.num_bins
+        with _boundary("Predict::PackShip"):
+            slay = pack_bins_T(jnp.asarray(bins))
         maxd = max(max(tree_max_depth(t) for t in use), 1)
         n = X.shape[0]
         es_freq, es_margin = (int(es[0]), float(es[1])) if es else (0, 0.0)
         outs = []
         for c in range(k):
             trees_c = [t for i, t in enumerate(use) if i % k == c]
-            tabs, cat_tab = build_predict_tables(trees_c, routing_np, L,
-                                                 bin_mappers=tb.bin_mappers)
+            with _boundary("Predict::NodeTables", trees=len(trees_c)):
+                tabs, cat_tab = build_predict_tables(
+                    trees_c, routing_np, L, bin_mappers=tb.bin_mappers)
             if cat_tab.shape[1] > 2048:
                 return host("categorical bitset side table wider than "
                             "2048 words")
@@ -1669,12 +1709,14 @@ class Booster:
                 # numeric-only: a minimal dummy keeps the unread cat
                 # input out of VMEM (the kernel never touches it)
                 cat_tab = cat_tab[:predict_kernel_CAT_DIGITS]
-            s = predict_stream(slay.bins_T, jnp.asarray(tabs),
-                               jnp.asarray(cat_tab), L, len(trees_c), maxd,
-                               has_cat=bool(cat_feats), es_freq=es_freq,
-                               es_margin=es_margin)
+            with _boundary("Predict::Walk"):
+                s = predict_stream(slay.bins_T, jnp.asarray(tabs),
+                                   jnp.asarray(cat_tab), L, len(trees_c),
+                                   maxd, has_cat=bool(cat_feats),
+                                   es_freq=es_freq, es_margin=es_margin)
             outs.append(s)
-        got = jax.device_get(outs)
+        with _boundary("Predict::Readback"):
+            got = jax.device_get(outs)
         self.last_predict_path = "device"
         if k == 1:
             return np.asarray(got[0][:n], np.float64)

@@ -159,26 +159,31 @@ def ship_binned_chunks(bins: np.ndarray, n_pad: int,
 
 def to_device(binned: BinnedData, pad_rows_to: int = 256,
               sharding=None, ship_chunk_rows=None) -> DeviceData:
+    from .telemetry import boundary
     layout, routing, Bmax = build_layouts(binned)
     bins = binned.bins
     n = bins.shape[0]
     n_pad = -(-n // pad_rows_to) * pad_rows_to
-    if ship_chunk_rows and _ship_supported():
-        arr = ship_binned_chunks(bins, n_pad, int(ship_chunk_rows))
-    elif isinstance(bins, np.memmap):
-        # out-of-core bins: transfer straight from the mapping (pages
-        # stream in, file-backed and reclaimable) and pad ON DEVICE —
-        # never materialize a padded full-size host copy
-        arr = jnp.asarray(bins)
-        if n_pad != n:
-            arr = jnp.pad(arr, ((0, n_pad - n), (0, 0)))
-    else:
-        bins = np.ascontiguousarray(bins)
-        if n_pad != n:
-            bins = np.pad(bins, ((0, n_pad - n), (0, 0)))
-        arr = jnp.asarray(bins)
-    if sharding is not None:
-        arr = jax.device_put(arr, sharding)
+    # the span ends with the bins ON the device: the transfer is
+    # asynchronous, and everything built next reads them anyway
+    with boundary("Dataset::Ship", rows=n, groups=bins.shape[1]):
+        if ship_chunk_rows and _ship_supported():
+            arr = ship_binned_chunks(bins, n_pad, int(ship_chunk_rows))
+        elif isinstance(bins, np.memmap):
+            # out-of-core bins: transfer straight from the mapping (pages
+            # stream in, file-backed and reclaimable) and pad ON DEVICE —
+            # never materialize a padded full-size host copy
+            arr = jnp.asarray(bins)
+            if n_pad != n:
+                arr = jnp.pad(arr, ((0, n_pad - n), (0, 0)))
+        else:
+            bins = np.ascontiguousarray(bins)
+            if n_pad != n:
+                bins = np.pad(bins, ((0, n_pad - n), (0, 0)))
+            arr = jnp.asarray(bins)
+        if sharding is not None:
+            arr = jax.device_put(arr, sharding)
+        arr.block_until_ready()
     return DeviceData(bins=arr, layout=layout, routing=routing,
                       num_data=n, num_features=binned.num_features,
                       num_groups=binned.num_groups, max_bins=Bmax)

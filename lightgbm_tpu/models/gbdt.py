@@ -36,7 +36,6 @@ from ..telemetry import (costmodel as _tel_cost,
                          watched_jit)
 from ..tree import Tree, TreeArrays, finalize_tree
 from ..utils.log import LightGBMError, log_info, log_warning
-from ..utils.timer import global_timer
 from .sample_strategy import create_sample_strategy
 
 # accepted hist_backend values (docs/PERF.md "histogram-formulation floor"):
@@ -380,11 +379,14 @@ class GBDT:
 
             def _vote_fn(bins, g, h, mask, colm, key=None, packed=None,
                          cegb_used=None, cegb_lazy=None, gh_scales=None,
-                         compact_rows=0):
-                return grow_tree_voting(bins, g, h, mask, colm,
-                                        sp_root, sp, gp, routing,
-                                        mesh=vote_mesh, row_axis=vote_axis,
-                                        compact_rows=compact_rows)
+                         compact_rows=0, with_passes=False):
+                out = grow_tree_voting(bins, g, h, mask, colm,
+                                       sp_root, sp, gp, routing,
+                                       mesh=vote_mesh, row_axis=vote_axis,
+                                       compact_rows=compact_rows)
+                # the voting grower keeps no round histogram pass to count
+                return (out + (jnp.zeros((), jnp.int32),) if with_passes
+                        else out)
 
             # the voting fn replaces grow_tree as THE grow partial, so the
             # fused-iteration and per-class-scan paths thread it unchanged
@@ -401,6 +403,7 @@ class GBDT:
         self._fused_last = False
         self._compact_overflow = False
         self._overflow_seen = 0
+        self._hist_passes_seen = 0
         # batched device-flag fetch cadence: eval_fetch_freq, or auto —
         # 16 wherever the fused one-launch path is the default (TPU, any
         # row-sharded stream mesh: each blocking flag read costs a full
@@ -466,8 +469,7 @@ class GBDT:
             return
         pending = self._lazy_trees
         self._lazy_trees = []
-        with global_timer.scope("GBDT::FinalizeTrees"), \
-                _tel_tracer.span("GBDT::FinalizeTrees", trees=len(pending)):
+        with _tel_tracer.boundary("GBDT::FinalizeTrees", trees=len(pending)):
             got = jax.device_get([e["arrays"] for e in pending])
         from ..telemetry import note_host_sync
         note_host_sync()
@@ -555,8 +557,7 @@ class GBDT:
                 and self._sample_count_cache[0] == ck:
             counts = self._sample_count_cache[1]
         else:
-            with global_timer.scope("GBDT::SampleCount"), \
-                    _tel_tracer.span("GBDT::SampleCount"):
+            with _tel_tracer.boundary("GBDT::SampleCount"):
                 counts = np.asarray(jax.device_get(
                     (mask > 0).reshape(D, local).sum(axis=1)))
             from ..telemetry import note_host_sync
@@ -1666,9 +1667,11 @@ class GBDT:
             sampled=jnp.asarray(0, jnp.int32),
             overflow=jnp.asarray(0, jnp.int32),
             finished=jnp.asarray(False),
-            ok=jnp.asarray(True))
+            ok=jnp.asarray(True),
+            hist_passes=jnp.asarray(0, jnp.int32))
         self._train_state = st
         self._overflow_seen = 0
+        self._hist_passes_seen = 0
         return st
 
     def _fused_compact_rows(self, sample_mode: str, mask_arg=None) -> int:
@@ -1779,7 +1782,6 @@ class GBDT:
             if self._use_leaf_gather_kernel:
                 from ..pallas.stream_kernel import leaf_gather
                 gather = leaf_gather
-
             def _fn(state, bound, pad_mask, mask_arg, qkey, skey, gkey,
                     bins, colm, packed, rate, compact_rows=0,
                     sample_mode="none"):
@@ -1827,10 +1829,10 @@ class GBDT:
                 # ---- growth + score update ----
                 rate32 = jnp.float32(rate)
                 if k == 1:
-                    arrays, leaf_id = grow(
+                    arrays, leaf_id, grown = grow(
                         bins, gq, hq, mask, colm, key=gkey, packed=packed,
                         cegb_used=None, gh_scales=sc,
-                        compact_rows=compact_rows)
+                        compact_rows=compact_rows, with_passes=True)
                     lv = arrays.leaf_value * rate32
                     delta = (gather(leaf_id, lv) if gather is not None
                              else lv[leaf_id])
@@ -1840,12 +1842,12 @@ class GBDT:
                     from ..ops.grow import grow_tree_k
                     scales = (jnp.transpose(sc) if sc is not None
                               else jnp.zeros((k, 2), jnp.float32))
-                    arrays, leaf_id = grow_tree_k(
+                    arrays, leaf_id, grown = grow_tree_k(
                         bins, gq.T, hq.T, mask, colm, layout=dd.layout,
                         routing=dd.routing, params=gp, packed=packed,
                         gh_scales=scales, mesh=mesh, row_axis=row_axis,
                         feature_axis=feature_axis,
-                        compact_rows=compact_rows)
+                        compact_rows=compact_rows, with_passes=True)
                     # stacked score add — same arithmetic as score_add_k
                     Lk = arrays.leaf_value.shape[1]
                     flat = arrays.leaf_value.reshape(-1) * rate32
@@ -1859,7 +1861,8 @@ class GBDT:
                 new_state = ShardedTrainState(
                     score=new_score, grad=g, hess=h, leaf_id=leaf_id,
                     mask=mask, key=qkey, sampled=nc, overflow=over,
-                    finished=fin, ok=ok)
+                    finished=fin, ok=ok,
+                    hist_passes=state.hist_passes + grown)
                 return new_state, arrays, new_obj
 
             out_sh = None
@@ -1915,14 +1918,24 @@ class GBDT:
         pending = self._nan_guard.take_pending()
         fetch = [self._finished_dev] + [ok for _, ok in pending]
         if st is not None:
-            fetch += [st.sampled, st.overflow]
-        got = jax.device_get(fetch)
-        from ..telemetry import note_host_sync
+            fetch += [st.sampled, st.overflow, st.hist_passes]
+        from ..telemetry import (hist_pass_count, note_hist_passes,
+                                 note_host_sync)
+        with _tel_tracer.boundary("GBDT::FlagPoll",
+                                  iteration=self.iter_) as poll:
+            got = jax.device_get(fetch)
+            if st is not None:
+                # the device's count of histogram passes rides the fetch:
+                # publish what it grew since the last poll
+                passes = int(got[-1])
+                note_hist_passes(passes - self._hist_passes_seen, self.iter_)
+                self._hist_passes_seen = passes
+                poll.set(hist_passes=hist_pass_count())
         note_host_sync()
         self._nan_guard.resolve(pending, got[1:1 + len(pending)])
         if st is not None:
-            self._last_sampled_rows = int(got[-2])
-            overflow = int(got[-1])
+            self._last_sampled_rows = int(got[-3])
+            overflow = int(got[-2])
             if overflow > getattr(self, "_overflow_seen", 0):
                 self._overflow_seen = overflow
                 if not getattr(self, "_compact_overflow", False):
@@ -1941,20 +1954,22 @@ class GBDT:
         """One boosting iteration (reference: GBDT::TrainOneIter, gbdt.cpp:353).
         Returns True if no further training is possible (all-zero trees).
 
-        With telemetry enabled this wraps the core step in an iteration
-        span and emits one structured record (wall time, phase splits,
-        leaf count, memory) per iteration; disabled, the guard is a
-        single boolean check and the core runs untouched."""
+        The core step always runs inside the ``GBDT::Iteration`` boundary
+        span (a profiler step annotation + one ring record, about 2 us).
+        With telemetry enabled it also emits one structured record (wall
+        time, phase splits, leaf count, memory) per iteration."""
+        # 1-based, matching the record _emit_iter_record writes after the
+        # impl increments iter_ — span N and JSONL row N are the same step
+        step = _tel_tracer.boundary("GBDT::Iteration", step=True,
+                                    step_num=self.iter_ + 1,
+                                    booster=self.boosting_type)
         if not _tel_tracer.enabled:
-            return self._train_one_iter_impl(grad, hess)
+            with step:
+                return self._train_one_iter_impl(grad, hess)
         t0 = time.perf_counter()
         ph0 = _tel_tracer.phase_snapshot()
         cost0 = _tel_cost.dispatch_totals()
-        # 1-based, matching the record _emit_iter_record writes after the
-        # impl increments iter_ — span N and JSONL row N are the same step
-        it = self.iter_ + 1
-        with _tel_tracer.span("GBDT::Iteration", iteration=it,
-                              booster=self.boosting_type):
+        with step:
             finished = self._train_one_iter_impl(grad, hess)
         self._emit_iter_record(t0, ph0, cost0, finished)
         return finished
@@ -2106,8 +2121,7 @@ class GBDT:
                      and self._row_sharding is None)
         if grad is None and hess is None and self._can_fuse_iteration():
             k = self.num_tree_per_iteration
-            with global_timer.scope("GBDT::FusedIter"), \
-                    _tel_tracer.span("GBDT::FusedIter"):
+            with _tel_tracer.boundary("GBDT::FusedIter"):
                 state, arrays_k = self._iter_fused()
             self.score = state.score
             rate = self._shrinkage_rate()
@@ -2150,8 +2164,7 @@ class GBDT:
         if fast_path:
             # no bagging: the in-bag mask IS the pad mask, and the gradient
             # chain (incl. quantization) runs as one fused program
-            with global_timer.scope("GBDT::Boosting"), \
-                    _tel_tracer.span("GBDT::Boosting"):
+            with _tel_tracer.boundary("GBDT::Boosting"):
                 (graw, hraw, grad, hess, q_scales) = self._boost_padded()
             if _chaos.has("nan_grad"):
                 grad = _chaos.inject_nan_grad(grad, self.iter_ + 1)
@@ -2162,8 +2175,7 @@ class GBDT:
             quant_done = True
         else:
             if grad is None or hess is None:
-                with global_timer.scope("GBDT::Boosting"), \
-                        _tel_tracer.span("GBDT::Boosting"):
+                with _tel_tracer.boundary("GBDT::Boosting"):
                     grad, hess = self._boost()
             else:
                 grad = self._pad_gh(jnp.asarray(grad, jnp.float32))
@@ -2212,8 +2224,7 @@ class GBDT:
                 and self._cegb_used is None
                 and not (self.config.use_quantized_grad
                          and self.config.quant_train_renew_leaf)):
-            with global_timer.scope("GBDT::TrainTree"), \
-                    _tel_tracer.span("GBDT::TrainTree", k=k), \
+            with _tel_tracer.boundary("GBDT::TrainTree", k=k), \
                     self._grow_x64_ctx():
                 k_results = self._grow_classes(grad, hess, mask, col_mask,
                                                gh_scales, k, compact)
@@ -2259,8 +2270,7 @@ class GBDT:
             if k_results is not None:
                 arrays, leaf_id = k_results[kk]
             else:
-                with global_timer.scope("GBDT::TrainTree"), \
-                        _tel_tracer.span("GBDT::TrainTree"), \
+                with _tel_tracer.boundary("GBDT::TrainTree"), \
                         self._grow_x64_ctx():
                     out = self._grow_fn(
                         self.dd.bins, g, h, mask, col_mask, key=gkey,
@@ -2402,8 +2412,11 @@ class GBDT:
         if self.iter_ % self._finished_check_every == 0:
             from ..telemetry import note_host_sync
             note_host_sync()
-            self._nan_guard.poll()
-            if bool(self._finished_dev):
+            with _tel_tracer.boundary("GBDT::FlagPoll",
+                                      iteration=self.iter_):
+                self._nan_guard.poll()
+                finished = bool(self._finished_dev)
+            if finished:
                 self._trim_trailing_trivial()
                 return True
         return False
@@ -2638,7 +2651,7 @@ class GBDT:
     # ------------------------------------------------------------------
     def eval_train(self) -> List[Tuple[str, str, float, bool]]:
         out = []
-        with _tel_tracer.span("GBDT::Eval", dataset="training"):
+        with _tel_tracer.boundary("GBDT::Eval", dataset="training"):
             score = self._score_to_host(self.score, self.num_data)
             conv = (self.objective.convert_output
                     if self.objective is not None else (lambda x: x))
@@ -2651,7 +2664,7 @@ class GBDT:
         out = []
         conv = (self.objective.convert_output if self.objective is not None
                 else (lambda x: x))
-        with _tel_tracer.span("GBDT::Eval", dataset="valid"):
+        with _tel_tracer.boundary("GBDT::Eval", dataset="valid"):
             for vi, vset in enumerate(self.valid_sets):
                 n = vset.num_data()
                 score = self._score_to_host(self._valid_scores[vi], n)

@@ -180,6 +180,10 @@ class _GrowState(NamedTuple):
     cegb_lazy: jax.Array        # (N, F) bool — per-row feature acquisition
                                 # bitset (CEGB lazy costs; (1,1) dummy when off)
     round_idx: jax.Array        # () i32 — for PRNG folding (bynode / extra_trees)
+    hist_passes: jax.Array      # () i32 — passes over the rows that built a
+                                # histogram: the root pass + every round body
+                                # run with_hist (the route-only sprint round
+                                # is not one)
     best_gain: jax.Array
     best_feat: jax.Array
     best_thr: jax.Array
@@ -356,8 +360,12 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
               mesh=None, row_axis: Optional[str] = None,
               feature_axis: Optional[str] = None,
               compact_rows: int = 0,
+              with_passes: bool = False,
               ) -> Tuple[TreeArrays, jax.Array]:
-    """Grow one tree. Returns (TreeArrays, leaf_id[N]).
+    """Grow one tree. Returns (TreeArrays, leaf_id[N]); with_passes=True
+    appends the () i32 count of histogram-building passes over the rows
+    this tree took (root pass + rounds; the fused iteration sums it into
+    its state for telemetry.hist_pass_count()).
 
     grad/hess must already include any bagging mask; cnt_w is the mask itself.
     monotone: (F,) i32 in {-1,0,1} (reference: monotone_constraints.hpp, basic method).
@@ -828,6 +836,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         cegb_used=(cegb_used0 if use_cegb else jnp.zeros(1, bool)),
         cegb_lazy=(cegb_lazy if use_lazy else jnp.zeros((1, 1), bool)),
         round_idx=jnp.asarray(0, i32),
+        hist_passes=jnp.asarray(1, i32),
         best_gain=jnp.full(L, NEG_INF, hdt).at[0].set(root_split.gain[0]),
         best_feat=jnp.zeros(L, i32).at[0].set(root_split.feature[0]),
         best_thr=jnp.zeros(L, i32).at[0].set(root_split.threshold[0]),
@@ -1511,7 +1520,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 st2 = st2._replace(adv_split_ok=jnp.where(
                     valid2[:, None], res.feat_ok, st2.adv_split_ok))
             return st2._replace(num_leaves_cur=cur + k, progressed=k > 0,
-                                round_idx=st.round_idx + 1)
+                                round_idx=st.round_idx + 1,
+                                hist_passes=st.hist_passes + 1)
 
         return body
 
@@ -1611,9 +1621,12 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         leaf_parent=final.leaf_parent, num_leaves=final.num_leaves_cur,
         leaf_depth=final.depth,
     )
+    out = (tree, final.leaf_id[:N])
     if use_lazy:
-        return tree, final.leaf_id[:N], final.cegb_lazy
-    return tree, final.leaf_id[:N]
+        out += (final.cegb_lazy,)
+    if with_passes:
+        out += (final.hist_passes,)
+    return out
 
 
 class _GrowStateK(NamedTuple):
@@ -1647,6 +1660,8 @@ class _GrowStateK(NamedTuple):
     hist: jax.Array             # (K, L, G, Bmax, 2)
     num_leaves_cur: jax.Array   # (K,) i32
     progressed: jax.Array       # (K,) bool
+    hist_passes: jax.Array      # () i32 — as _GrowState.hist_passes: one
+                                # lockstep pass serves all K classes
 
 
 def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
@@ -1657,11 +1672,13 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 mesh=None, row_axis: Optional[str] = None,
                 feature_axis: Optional[str] = None,
                 compact_rows: int = 0,
+                with_passes: bool = False,
                 ) -> Tuple[TreeArrays, jax.Array]:
     """Grow K class trees in LOCKSTEP inside one widened XLA program
     (batched multiclass). Returns (TreeArrays with a leading K axis,
     leaf_id (K, N)) — the same stacked layout the per-class lax.scan path
-    produces.
+    produces; with_passes=True appends the () i32 count of
+    histogram-building passes, as grow_tree does.
 
     grad/hess: (K, N) class-major gradient channels (bagging mask applied).
     gh_scales: (K, 2) per-class (grad_scale, hess_scale) or None.
@@ -1950,6 +1967,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         hist=hist,
         num_leaves_cur=jnp.ones(K, i32),
         progressed=jnp.ones(K, bool),
+        hist_passes=jnp.asarray(1, i32),
     )
 
     def cond_k(st: _GrowStateK):
@@ -2259,7 +2277,8 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             )
             return st2._replace(
                 num_leaves_cur=cur + ksp,
-                progressed=jnp.where(active, ksp > 0, st.progressed))
+                progressed=jnp.where(active, ksp > 0, st.progressed),
+                hist_passes=st.hist_passes + 1)
         return body
 
     # streaming rounds: same specialized small-S prefix as grow_tree
@@ -2300,4 +2319,6 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         leaf_parent=final.leaf_parent, num_leaves=final.num_leaves_cur,
         leaf_depth=final.depth,
     )
+    if with_passes:
+        return tree, final.leaf_id[:, :N], final.hist_passes
     return tree, final.leaf_id[:, :N]

@@ -47,6 +47,10 @@ class ShardedTrainState(NamedTuple):
         disables compaction and warns when it moves)
       * ``finished`` — () bool, last tree grew no split
       * ``ok``       — () bool, nan_guard all-finite flag
+      * ``hist_passes`` — () i32, histogram-building passes over the rows
+        grown so far (ops/grow.py counts them per tree; the poll publishes
+        the sum as ``telemetry.hist_pass_count()``).  Checkpoints do not
+        hold this state — a resumed run rebuilds it and counts from 0
     """
     score: jax.Array
     grad: jax.Array
@@ -58,6 +62,7 @@ class ShardedTrainState(NamedTuple):
     overflow: jax.Array
     finished: jax.Array
     ok: jax.Array
+    hist_passes: jax.Array
 
 
 def state_shardings(mesh, row_axis: Optional[str], num_class: int,
@@ -97,4 +102,5 @@ def state_shardings(mesh, row_axis: Optional[str], num_class: int,
         leaf = NamedSharding(mesh, P(None, row_axis))
     return ShardedTrainState(
         score=score, grad=grad, hess=hess, leaf_id=leaf, mask=row,
-        key=rep, sampled=rep, overflow=rep, finished=rep, ok=rep)
+        key=rep, sampled=rep, overflow=rep, finished=rep, ok=rep,
+        hist_passes=rep)

@@ -34,22 +34,24 @@ from .metrics import (MetricsRegistry, device_memory_gb, global_registry,
 from .prometheus import registry_text, render_parts, render_prometheus
 from .quality import (QualityMonitor, QualityProfile, js_divergence,
                       psi, quality_sidecar_path)
-from .tracer import SpanTracer, global_tracer
-from .watchdog import (WatchEntry, get_recompile_threshold, host_sync_count,
-                       launch_count, note_host_sync, note_launch,
+from .tracer import SpanRecord, SpanTracer, global_tracer
+from .watchdog import (WatchEntry, get_recompile_threshold, hist_pass_count,
+                       hist_pass_iteration, host_sync_count, launch_count,
+                       note_hist_passes, note_host_sync, note_launch,
                        recompile_counts, reset_counters,
                        reset_watchdog, set_recompile_threshold,
                        watchdog_summary, watched_jit)
 
 __all__ = [
-    "SpanTracer", "MetricsRegistry", "WatchEntry",
+    "SpanTracer", "SpanRecord", "MetricsRegistry", "WatchEntry",
     "global_tracer", "global_registry",
     "configure", "enabled", "enabled_source", "enable", "disable", "reset",
-    "span", "instant", "counter_sample", "inc", "gauge", "observe",
+    "span", "boundary", "recent_spans", "instant", "counter_sample", "inc", "gauge", "observe",
     "quantiles", "record", "export_trace", "flush", "summary",
     "watched_jit", "recompile_counts", "watchdog_summary",
     "set_recompile_threshold", "get_recompile_threshold", "reset_watchdog",
     "launch_count", "host_sync_count", "note_host_sync", "note_launch",
+    "hist_pass_count", "hist_pass_iteration", "note_hist_passes",
     "reset_counters", "costmodel", "cost_summary", "machine_balance",
     "memory_snapshot", "device_memory_gb", "host_rss_gb",
     "TraceContext", "TailRing", "AccessLog", "TRACE_HEADER",
@@ -124,6 +126,8 @@ def reset() -> None:
 
 # -- thin instrument aliases (the hot-path entry points) --------------------
 span = global_tracer.span
+boundary = global_tracer.boundary
+recent_spans = global_tracer.recent_spans
 instant = global_tracer.instant
 counter_sample = global_tracer.counter
 inc = global_registry.inc
@@ -176,6 +180,15 @@ def summary() -> Dict[str, Any]:
         # events the bounded span buffer had to drop (the tracer warns
         # once when this first goes nonzero)
         "trace_dropped_events": global_tracer.dropped,
+        # the always-on boundary-span ring: its newest records (the whole
+        # ring is recent_spans()) and how many older ones it has overwritten
+        "recent_spans": [r._asdict()
+                         for r in global_tracer.recent_spans()[-128:]],
+        "recent_spans_overwritten": global_tracer.ring_overwritten,
+        # histogram passes the fused iterations grew, as of the flag poll
+        # at `iteration` (telemetry/watchdog.py)
+        "hist_passes": {"count": hist_pass_count(),
+                        "iteration": hist_pass_iteration()},
     }
     if global_registry.sink_path:
         out["telemetry_out"] = global_registry.sink_path

@@ -13,6 +13,16 @@ Design constraints:
     thread (trace-event "B"/"E" pairs nest per ``tid`` by construction);
   * bounded — the event buffer is capped; overflow increments a drop
     counter instead of growing without limit.
+
+Layer boundaries (:meth:`SpanTracer.boundary`) are the one exception to
+"off unless enabled": a boundary span is always live.  It enters a
+``jax.profiler.TraceAnnotation`` (``lgbtpu.<name>``), so inside ANY
+profiler session the span lands in the host plane of the same
+``.xplane.pb`` as the device's operations — one clock, nothing to align —
+and on exit it appends one :class:`SpanRecord` to a bounded in-process
+ring (:meth:`SpanTracer.recent_spans`, the flight recorder an operator
+reads after a stall).  With telemetry enabled it also emits the Chrome
+"B"/"E" pair exactly as :meth:`SpanTracer.span` does.
 """
 from __future__ import annotations
 
@@ -20,11 +30,20 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, Iterator, List, Optional
+from collections import deque
+from typing import Any, Dict, List, NamedTuple, Optional
+
+import jax
 
 # Chrome trace-event phases used here: B/E = nested begin/end duration
 # events, C = counter track, i = instant event, M = metadata.
 _MAX_EVENTS = int(os.environ.get("LIGHTGBM_TPU_TRACE_MAX_EVENTS", 2_000_000))
+# boundary-span ring: hours of training (3 records a tree) or thousands of
+# predict calls (8 records a call) at ~200 bytes a record
+_RING_SIZE = 16384
+# prefix of every boundary span in a profiler trace (the benchmark's own
+# spans are `bench.*`)
+ANNOTATION_PREFIX = "lgbtpu."
 
 
 class _NullSpan:
@@ -64,6 +83,67 @@ class _Span:
         return False
 
 
+class SpanRecord(NamedTuple):
+    """One finished boundary span in the ring.  ``start_unix_ns`` is
+    ``time.time_ns()`` at entry (the domain of any wall-clock stamp a
+    caller holds); ``duration_ns`` is a ``perf_counter_ns`` difference;
+    ``parent`` is the boundary span that was open on the same thread."""
+    seq: int
+    name: str
+    parent: Optional[str]
+    start_unix_ns: int
+    duration_ns: int
+    args: Optional[Dict[str, Any]]
+
+
+class _Boundary:
+    """One live boundary span (see :meth:`SpanTracer.boundary`)."""
+
+    __slots__ = ("_tracer", "_name", "_args", "_ann", "_parent", "_start",
+                 "_t0")
+
+    def __init__(self, tracer: "SpanTracer", name: str, args: dict,
+                 step: bool) -> None:
+        self._tracer = tracer
+        self._name = name
+        self._args = args
+        ann = (jax.profiler.StepTraceAnnotation if step
+               else jax.profiler.TraceAnnotation)
+        self._ann = ann(ANNOTATION_PREFIX + name, **args)
+
+    def set(self, **args: Any) -> None:
+        """Attributes known only at the end (which path a predict took,
+        what a poll read): they go to the ring record and the Chrome "E"
+        event, not to the profiler annotation, whose arguments are fixed
+        on entry."""
+        self._args = {**self._args, **args}
+
+    def __enter__(self):
+        tr = self._tracer
+        stack = tr._stack()
+        self._parent = stack[-1] if stack else None
+        stack.append(self._name)
+        self._ann.__enter__()
+        if tr.enabled:
+            tr._emit("B", self._name, time.perf_counter(),
+                     self._args or None)
+        self._start = time.time_ns()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        dur = time.perf_counter_ns() - self._t0
+        tr = self._tracer
+        if tr.enabled:
+            tr._emit("E", self._name, time.perf_counter(),
+                     self._args or None)
+        self._ann.__exit__(*exc)
+        tr._stack().pop()
+        tr._record(self._name, self._parent, self._start, dur,
+                   self._args or None)
+        return False
+
+
 class SpanTracer:
     """Nested, thread-safe span recorder (low-overhead when disabled)."""
 
@@ -83,6 +163,9 @@ class SpanTracer:
         self._phase_totals: Dict[str, float] = {}
         self._phase_counts: Dict[str, int] = {}
         self._local = threading.local()
+        # boundary-span ring (always on): the newest _RING_SIZE records
+        self._ring: "deque[SpanRecord]" = deque(maxlen=_RING_SIZE)
+        self._seq = 0
 
     # -- control -----------------------------------------------------------
     def enable(self) -> None:
@@ -101,6 +184,8 @@ class SpanTracer:
             self._epoch_unix = time.time()
             self._phase_totals = {}
             self._phase_counts = {}
+            self._ring.clear()
+            self._seq = 0
 
     # -- recording ---------------------------------------------------------
     def span(self, name: str, **args: Any):
@@ -111,6 +196,50 @@ class SpanTracer:
         if not self.enabled:
             return _NULL_SPAN
         return _Span(self, name, args or None)
+
+    def boundary(self, name: str, step: bool = False, **args: Any):
+        """Context manager for a LAYER BOUNDARY: always live, whatever
+        ``enabled`` says (module docstring).  ``step=True`` enters a
+        ``StepTraceAnnotation`` (pass ``step_num=``), which gives a
+        profiler trace its Steps line.  Costs about 2 us a span outside
+        a profiler session; it belongs round a dispatch, a blocking read
+        or a host phase — never inside a jitted function, a kernel or a
+        per-row loop."""
+        return _Boundary(self, name, args, step)
+
+    def _stack(self) -> List[str]:
+        try:
+            return self._local.stack
+        except AttributeError:
+            self._local.stack = []
+            return self._local.stack
+
+    def _record(self, name: str, parent: Optional[str], start_unix_ns: int,
+                duration_ns: int, args: Optional[dict]) -> None:
+        with self._lock:
+            self._ring.append(SpanRecord(self._seq, name, parent,
+                                         start_unix_ns, duration_ns, args))
+            self._seq += 1
+            self._account_locked(name, duration_ns / 1e9)
+
+    def recent_spans(self, name: Optional[str] = None,
+                     since_unix_ns: Optional[int] = None
+                     ) -> List[SpanRecord]:
+        """The ring's records, oldest first, optionally of one span name
+        and/or started at or after a ``time.time_ns()`` stamp."""
+        with self._lock:
+            records = list(self._ring)
+        return [r for r in records
+                if (name is None or r.name == name)
+                and (since_unix_ns is None
+                     or r.start_unix_ns >= since_unix_ns)]
+
+    @property
+    def ring_overwritten(self) -> int:
+        """Boundary records the bounded ring has overwritten (``seq`` of
+        the oldest record it still holds)."""
+        with self._lock:
+            return self._seq - len(self._ring)
 
     def instant(self, name: str, **args: Any) -> None:
         """Point-in-time marker (watchdog warnings, stop events, ...)."""
@@ -170,8 +299,11 @@ class SpanTracer:
 
     def _account(self, name: str, dt: float) -> None:
         with self._lock:
-            self._phase_totals[name] = self._phase_totals.get(name, 0.0) + dt
-            self._phase_counts[name] = self._phase_counts.get(name, 0) + 1
+            self._account_locked(name, dt)
+
+    def _account_locked(self, name: str, dt: float) -> None:
+        self._phase_totals[name] = self._phase_totals.get(name, 0.0) + dt
+        self._phase_counts[name] = self._phase_counts.get(name, 0) + 1
 
     # -- introspection -----------------------------------------------------
     @property

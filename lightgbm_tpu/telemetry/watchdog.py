@@ -49,6 +49,14 @@ _default_threshold = 2
 # a link-bound one.
 _launches = 0
 _host_syncs = 0
+# `hist_passes` is counted ON THE DEVICE: the fused iteration's state
+# carries an int32 the grower increments once per histogram-building pass
+# over the rows (root pass + every budget round; the route-only last round
+# is not one), and the engine publishes it here from the batched flag
+# fetch it already makes — so the count is as of `_hist_pass_iteration`,
+# up to eval_fetch_freq - 1 trees behind the device.
+_hist_passes = 0
+_hist_pass_iteration = 0
 
 
 def launch_count() -> int:
@@ -59,6 +67,26 @@ def launch_count() -> int:
 def host_sync_count() -> int:
     """Cumulative engine-noted device->host transfers."""
     return _host_syncs
+
+
+def hist_pass_count() -> int:
+    """Cumulative histogram passes grown by fused iterations in this
+    process, as of the last flag poll (:func:`hist_pass_iteration`)."""
+    return _hist_passes
+
+
+def hist_pass_iteration() -> int:
+    """The boosting iteration at which :func:`hist_pass_count` was last
+    read off the device."""
+    return _hist_pass_iteration
+
+
+def note_hist_passes(n: int, iteration: int) -> None:
+    """Add ``n`` passes read off the device at ``iteration`` (the
+    engine's flag poll calls this with the delta since its last poll)."""
+    global _hist_passes, _hist_pass_iteration
+    _hist_passes += n
+    _hist_pass_iteration = iteration
 
 
 def note_host_sync(n: int = 1) -> None:
@@ -84,9 +112,11 @@ def reset_counters() -> None:
     this is the A/B counterpart for the globals — bench arms call it at
     the start of each timed arm so launches/iter and host_syncs/iter are
     attributable to THAT arm, not contaminated by the previous one."""
-    global _launches, _host_syncs
+    global _launches, _host_syncs, _hist_passes, _hist_pass_iteration
     _launches = 0
     _host_syncs = 0
+    _hist_passes = 0
+    _hist_pass_iteration = 0
 
 
 class WatchEntry:

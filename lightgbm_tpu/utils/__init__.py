@@ -1,8 +1,8 @@
 from .log import (LightGBMError, log_debug, log_fatal, log_info, log_warning,
                   register_logger, set_verbosity)
-from .timer import Timer, named_scope
+from .timer import Timer
 
 __all__ = [
     "LightGBMError", "log_debug", "log_fatal", "log_info", "log_warning",
-    "register_logger", "set_verbosity", "Timer", "named_scope",
+    "register_logger", "set_verbosity", "Timer",
 ]

@@ -1,9 +1,10 @@
-"""Named accumulating timers + profiler scopes.
+"""Named accumulating timers and the at-exit phase report.
 
 Reference: include/LightGBM/utils/common.h:980 (Common::Timer / global_timer, RAII
-FunctionTimer, printed at exit under USE_TIMETAG). TPU equivalent additionally wraps
-jax.named_scope so regions show up in xprof traces, and feeds the telemetry span
-tracer (lightgbm_tpu.telemetry) so the same regions land in exported Chrome traces.
+FunctionTimer, printed at exit under USE_TIMETAG).  The engine's phases are the
+telemetry tracer's boundary spans (lightgbm_tpu.telemetry.tracer), accumulated
+there and nowhere else; ``LIGHTGBM_TPU_TIMETAG=1`` prints them at exit, hot spots
+first.  ``Timer`` stays for ad-hoc scopes of a caller's own.
 """
 from __future__ import annotations
 
@@ -12,11 +13,22 @@ import contextlib
 import os
 import time
 from collections import defaultdict
-from typing import Dict, Iterator, Optional
-
-import jax
+from typing import Dict, Iterator, Mapping, Optional
 
 from ..telemetry.tracer import global_tracer
+
+
+def format_report(totals: Mapping[str, float],
+                  counts: Mapping[str, int]) -> str:
+    """Hot spots first: sorted by total time descending, with per-call
+    mean (the alphabetical order of the original hid the hot paths)."""
+    lines = []
+    for name, total in sorted(totals.items(), key=lambda kv: -kv[1]):
+        n = counts.get(name, 0)
+        mean_ms = total / n * 1e3 if n else 0.0
+        lines.append(f"{name}: {total:.3f}s ({n} calls, "
+                     f"{mean_ms:.3f} ms/call)")
+    return "\n".join(lines)
 
 
 class Timer:
@@ -64,32 +76,23 @@ class Timer:
             self.counts[name] += 1
 
     def report(self) -> str:
-        """Hot spots first: sorted by total time descending, with per-call
-        mean (the alphabetical order of the original hid the hot paths)."""
-        lines = []
-        for name, total in sorted(self.totals.items(),
-                                  key=lambda kv: -kv[1]):
-            n = self.counts[name]
-            mean_ms = total / n * 1e3 if n else 0.0
-            lines.append(f"{name}: {total:.3f}s ({n} calls, "
-                         f"{mean_ms:.3f} ms/call)")
-        return "\n".join(lines)
+        return format_report(self.totals, self.counts)
 
 
+# the LIGHTGBM_TPU_TIMETAG switch (read lazily) for the at-exit report
 global_timer = Timer()
+
+
+def phase_report() -> str:
+    """The tracer's cumulative phases (every boundary span, plus ordinary
+    spans recorded while telemetry was enabled) in the timer's format."""
+    return format_report(global_tracer.phase_snapshot(),
+                         global_tracer.phase_counts())
 
 
 @atexit.register
 def _print_timers() -> None:
-    if global_timer.enabled and global_timer.totals:
-        print("[LightGBM-TPU] timers:\n" + global_timer.report())
-
-
-@contextlib.contextmanager
-def named_scope(name: str) -> Iterator[None]:
-    """Combined device trace annotation (JAX profiler) + host timer +
-    telemetry span (Chrome trace export) for one region."""
-    with jax.named_scope(name):
-        with global_timer.scope(name):
-            with global_tracer.span(name):
-                yield
+    if global_timer.enabled:
+        report = phase_report()
+        if report:
+            print("[LightGBM-TPU] timers:\n" + report)
