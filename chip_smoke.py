@@ -149,6 +149,12 @@ def kernel_exactness(dd, params):
               np.asarray(hist, np.float64),
               np.asarray(ref[..., :2], np.float64)))
     check("root pass: slot count == N", float(scnt[0]) == float(N))
+    # the form the trainer's root takes on this path: the factored contraction
+    _, fact, _ = route_and_hist(slay.bins_T, leaf, w_T, tabs, bits, 1, Bmax,
+                                G, L, root=True, **kw)
+    check("factored root pass: int32 histogram == the one-hot root's exactly",
+          fact.dtype == jnp.int32 and fact.shape == hist.shape
+          and np.array_equal(np.asarray(fact), np.asarray(hist)))
 
     # round shape: rows spread over 64 leaves, leaf l kept in slot l
     S = 64

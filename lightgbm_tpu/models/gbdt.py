@@ -314,6 +314,14 @@ class GBDT:
         # captured arrays are embedded in the HLO as constants, and a 10M-row
         # packed bin matrix (hundreds of MB) blows up compilation
         self._packed = packed
+        # which formulation the fused iteration's root histogram pass takes
+        # (static per compiled program; published at every flag poll)
+        self._root_pass = None
+        if self._grow_params.hist_backend == "stream":
+            from ..pallas.stream_kernel import root_pass_kind
+            self._root_pass = root_pass_kind(
+                packed.dtype, self._grow_params.int_hist,
+                self.num_tree_per_iteration)
         self._grow_partial = functools.partial(
             grow_tree, layout=dd.layout, routing=dd.routing,
             params=self._grow_params,
@@ -1930,7 +1938,9 @@ class GBDT:
                 passes = int(got[-1])
                 note_hist_passes(passes - self._hist_passes_seen, self.iter_)
                 self._hist_passes_seen = passes
-                poll.set(hist_passes=hist_pass_count())
+                poll.set(hist_passes=hist_pass_count(),
+                         **({"root_pass": self._root_pass}
+                            if self._root_pass else {}))
         note_host_sync()
         self._nan_guard.resolve(pending, got[1:1 + len(pending)])
         if st is not None:

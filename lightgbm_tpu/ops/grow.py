@@ -659,14 +659,15 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 packed_w = params.hist_packed_width
                 D_rows = mesh.shape[row_axis]
 
-            def _rh(bT, lid_row, wT, tb, bi, num_slots, with_hist=True):
+            def _rh(bT, lid_row, wT, tb, bi, num_slots, with_hist=True,
+                    root=False):
                 def _local(bT, lid_row, wT, tb, bi):
                     nl, h, c = route_and_hist(
                         bT, lid_row, wT, tb, bi, num_slots, Bmax, G, L,
                         block_rows=T_rows, has_cat=params.has_categorical,
                         two_pass=params.hist_two_pass, int_weights=use_int,
                         with_hist=with_hist,
-                        bin_buckets=params.bin_buckets)
+                        bin_buckets=params.bin_buckets, root=root)
                     if with_hist:
                         if use_packed:
                             pw, pscales = pack_gh_wire(h, row_axis, packed_w,
@@ -704,12 +705,14 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                     (P(None, row_axis), hspec, P(None)))
                 return wrapped(bT, lid_row, wT, tb, bi)
         else:
-            def _rh(bT, lid_row, wT, tb, bi, num_slots, with_hist=True):
+            def _rh(bT, lid_row, wT, tb, bi, num_slots, with_hist=True,
+                    root=False):
                 return route_and_hist(
                     bT, lid_row, wT, tb, bi, num_slots, Bmax, G, L,
                     block_rows=T_rows, has_cat=params.has_categorical,
                     two_pass=params.hist_two_pass, int_weights=use_int,
-                    with_hist=with_hist, bin_buckets=params.bin_buckets)
+                    with_hist=with_hist, bin_buckets=params.bin_buckets,
+                    root=root)
 
         zL = jnp.zeros(L, i32)
         tabs0 = build_route_tables(zL, zL, zL, zL, zL, zL, zL,
@@ -718,8 +721,10 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         leaf_id = jnp.zeros(n_pad, i32)
         leaf_id_c = jnp.zeros(n_pad_h if use_compact else 1, i32)
         lid0 = leaf_id_c if use_compact else leaf_id
+        # every row in leaf 0, tabs0 splits nothing: route_and_hist takes
+        # the factored root contraction where root_pass_kind() allows it
         _, root_hist, _ = _rh(bins_T_h, lid0.reshape(1, -1), w_T_h, tabs0,
-                              bits0, 1)
+                              bits0, 1, root=True)
         if use_int:
             root_hist = root_hist.astype(f32) * hscale
     else:

@@ -419,6 +419,129 @@ def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
         hist_ref[...] += dot(oh, A_hi)
 
 
+# The ROOT pass has one slot: every row is in leaf 0 and nothing is routed.
+# The one-hot formulation above would contract its (M, T) bin one-hot against
+# a 2-column operand that the MXU pads to a 128-column tile — a full pass's
+# M*128 MACs a row for 2/128 of a full pass's result.  The factored form
+# splits the bin, b = hi * 8 + lo, and for a group of 16 features contracts
+# over the rows t
+#
+#   LHS[(c, hi, f), t] = w_int[c, t] * 1[bin_f[t] >> 3 == hi]   2*H*16 rows
+#   RHS[(lo, f'),  t] = 1[bin_f'[t] & 7 == lo]                   128 rows
+#
+# on the int8 MXU into an int32 (2*H*16, 128) block that stays in VMEM over
+# the grid; the blocks on the diagonal f == f' are the root histogram, the
+# others are the price of a dense unit and are dropped outside the kernel.
+# That is G*B*32 MACs a row against G*B*128.
+#
+# Both operands are built four int8 rows at a time, in the 32-bit words the
+# u8 layout already stores them in (word r of a block holds the bins of
+# groups 4r..4r+3 of one row; pltpu.bitcast reads and writes that packing
+# for free): digit tests are byte-parallel arithmetic on words, there is no
+# int32 one-hot and no int32 -> int8 pack.  Measured on the v5e the kernel
+# then runs at the MXU's pace (PERF.md section 6, PR 28).
+# features a group: 4 words x 4 bytes, half of a word array's 8 sublanes (the
+# kernel's sublane arithmetic is written for it), and 16 x 8 low digits make
+# the RHS one 128-row MXU tile
+ROOT_GF = 16
+_BYTES = 0x01010101   # one per byte of a word
+
+
+def root_pass_kind(bins_dtype, int_weights: bool, num_class: int = 1) -> str:
+    """Which formulation the root histogram pass of such a program takes:
+    "factored" when the weights are integer-valued (exact int32 sums, so the
+    result is bit-identical whatever the formulation), the bins are in the
+    u8 layout and one tree grows at a time; "onehot" (the S = 1 call of the
+    64-slot kernel) otherwise."""
+    return ("factored" if int_weights and bins_dtype == jnp.int8
+            and num_class == 1 else "onehot")
+
+
+def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots):
+    i32 = jnp.int32
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        hist_ref[...] = jnp.zeros_like(hist_ref)
+
+    def is_zero(x):
+        """0x01 in every byte of x (bytes 0..15) that is 0, else 0x00."""
+        return jax.lax.shift_right_logical(0x10101010 - x, 4) & _BYTES
+
+    R = 2 * HP * ROOT_GF
+    top = jax.lax.broadcasted_iota(i32, (8, T), 0) < 4       # sublanes 0..3
+    # a word array (8, T) holds TWO digits of one group's 16 features: the
+    # even digit in sublanes 0..3, the odd one in 4..7; pair[p] is the digit
+    # pair (2p, 2p + 1) in every byte, to test such an array against
+    odd = jnp.where(top, 0, _BYTES)
+    pair = [odd + 2 * p * _BYTES for p in range(max(HP // 2, 4))]
+    # grad, hess as the int8 byte a word's matching lanes are multiplied by
+    wb = jnp.round(w_ref[0:2, :]).astype(i32) & 0xFF         # (2, T)
+    for a in range(-(-NG // 2)):  # static unroll: 32 features a word array
+        words = pltpu.bitcast(bins_ref[a * 32:(a + 1) * 32, :], i32)  # (8, T)
+        hi = jax.lax.shift_right_logical(words, 3) & 0x0F0F0F0F
+        lo = words & 0x07070707
+        hi_sw, lo_sw = pltpu.roll(hi, 4, 0), pltpu.roll(lo, 4, 0)
+        for half in range(min(2, NG - 2 * a)):
+            g = 2 * a + half
+            # this group's 4 words in both sublane halves
+            mine = top if half == 0 else ~top
+            hi_g = jnp.where(mine, hi, hi_sw)
+            lo_g = jnp.where(mine, lo, lo_sw)
+            hi_oh = [is_zero(hi_g ^ pair[p]) for p in range(HP // 2)]
+            lhs = jnp.concatenate([oh * wb[c:c + 1, :] for c in range(2)
+                                   for oh in hi_oh], axis=0)  # (R/4, T) words
+            rhs = jnp.concatenate([is_zero(lo_g ^ pair[q]) for q in range(4)],
+                                  axis=0)                    # (32, T) words
+            lhs8 = pltpu.bitcast(lhs, jnp.int8)              # (R, T)
+            rhs8 = pltpu.bitcast(rhs, jnp.int8)              # (128, T)
+            if f32_dots:
+                # CPU interpret: as the 64-slot kernel's int path — exact
+                d = jax.lax.dot_general(
+                    lhs8.astype(jnp.float32), rhs8.astype(jnp.float32),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32).astype(i32)
+            else:
+                d = jax.lax.dot_general(
+                    lhs8, rhs8, (((1,), (1,)), ((), ())),
+                    preferred_element_type=i32)
+            hist_ref[g * R:(g + 1) * R, :] += d
+
+
+def _root_hist_factored(bins_T, w_T, bmax: int, num_groups: int,
+                        block_rows: int):
+    """(1, G, bmax, 2) int32 root histogram of integer-valued grad/hess rows
+    over u8-layout bins: what route_and_hist(..., num_slots=1) returns for
+    rows that all sit in one leaf, by the factored contraction.  Rows
+    past the data carry zero weights and add nothing, as in every pass."""
+    GW, n_pad = bins_T.shape
+    T, G, GF = block_rows, num_groups, ROOT_GF
+    H = -(-bmax // 8)
+    HP = H + (H & 1)          # digits go two a word array
+    NG = -(-G // GF)
+    R = 2 * HP * GF
+    out = pl.pallas_call(
+        functools.partial(_root_hist_kernel, T=T, NG=NG, HP=HP,
+                          f32_dots=pallas_interpret()),
+        grid=(n_pad // T,),
+        in_specs=[
+            pl.BlockSpec((GW, T), lambda b: (0, b)),
+            pl.BlockSpec((w_T.shape[0], T), lambda b: (0, b)),
+        ],
+        out_specs=pl.BlockSpec((NG * R, 8 * GF), lambda b: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((NG * R, 8 * GF), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pallas_interpret(),
+    )(bins_T, w_T)
+    # rows (group, c, hi, f), columns (lo, f'): keep f == f'
+    same = jnp.eye(GF, dtype=bool)[:, None, :]
+    diag = jnp.sum(jnp.where(same, out.reshape(NG, 2, HP, GF, 8, GF), 0),
+                   axis=5)                                   # (NG, 2, HP, GF, 8)
+    hist = diag.transpose(0, 3, 2, 4, 1).reshape(NG * GF, HP * 8, 2)
+    return hist[None, :G, :bmax, :]
+
+
 # Mosaic's scoped-VMEM limit for one kernel on this compiler (jax 0.9.0,
 # libtpu 0.0.34, TPU v5e): kernel-internal temporaries past 16 MiB fail to
 # compile with "Scoped allocation with size ... and limit 16.00M".  No
@@ -555,13 +678,13 @@ def pack_bins_T(bins: jax.Array, block_rows: int = 1024,
                    static_argnames=("num_slots", "bmax", "num_groups",
                                     "num_leaves", "block_rows", "has_cat",
                                     "two_pass", "int_weights", "with_hist",
-                                    "bin_buckets", "num_class"))
+                                    "bin_buckets", "num_class", "root"))
 def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
                    tabs: jax.Array, bits: jax.Array, num_slots: int, bmax: int,
                    num_groups: int, num_leaves: int, block_rows: int = 1024,
                    has_cat: bool = True, two_pass: bool = True,
                    int_weights: bool = False, with_hist: bool = True,
-                   bin_buckets=None, num_class: int = 1):
+                   bin_buckets=None, num_class: int = 1, root: bool = False):
     """One fused streaming pass: route rows through this round's splits and
     build grad/hess histograms and exact data counts of the rows' NEW slots.
 
@@ -579,7 +702,17 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     and accumulate inside ONE widened program whose bin one-hot (the
     dominant construct) is built once per block and contracted against the
     stacked class x slot channel axis.
+
+    root=True is the caller's statement that every row sits in ONE leaf and
+    `tabs` splits nothing (the grower's root pass, num_slots == 1): leaf ids
+    come back as they went in, and where root_pass_kind() says so the
+    histogram is built by the factored contraction instead.
     """
+    if (root and num_slots == 1 and with_hist and root_pass_kind(
+            bins_T.dtype, int_weights, num_class) == "factored"):
+        hist = _root_hist_factored(bins_T, w_T, bmax, num_groups, block_rows)
+        # the slot's count is the caller's own row count; no grower reads it
+        return leaf_id, hist, jnp.sum(w_T[2]).reshape(1)
     GW, n_pad = bins_T.shape
     T = block_rows
     NB = n_pad // T

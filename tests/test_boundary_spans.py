@@ -255,6 +255,32 @@ def test_hist_pass_count_is_what_grow_tree_took(fused):
         polls = tel.recent_spans(name="GBDT::FlagPoll")[-2:]
         assert [p.args["hist_passes"] for p in polls] == [2 * per_tree,
                                                           4 * per_tree]
+        # float weights: the root goes through the 64-slot kernel's S = 1
+        # call, and the poll says so
+        assert [p.args["root_pass"] for p in polls] == ["onehot"] * 2
+
+
+def test_flag_poll_names_the_factored_root_and_counts_it_once(fused):
+    """use_quantized_grad over u8-layout bins: the root histogram is the
+    factored contraction, the poll record says so, and the root still counts
+    as ONE pass over the rows — hist_passes reads what it read before."""
+    X, y = make_synthetic_binary(n=1200, f=6)
+    counts = {}
+    for quant in (False, True):
+        tel.reset_counters()
+        bst = _booster(dict(STREAM, objective="binary", eval_fetch_freq=2,
+                            use_quantized_grad=quant,
+                            max_splits_per_round=1, num_leaves=4), X, y)
+        for _ in range(2):
+            bst.update()
+        assert bst.engine._fused_last
+        assert [t["num_leaves"] for t in
+                bst.dump_model()["tree_info"]] == [4, 4]
+        poll = tel.recent_spans(name="GBDT::FlagPoll")[-1]
+        assert poll.args["root_pass"] == ("factored" if quant else "onehot")
+        counts[quant] = poll.args["hist_passes"]
+    # root + 3 one-split rounds a tree on either path
+    assert counts[True] == counts[False] == 2 * 4
 
 
 def test_hist_pass_count_is_what_grow_tree_k_took(fused):
@@ -296,14 +322,20 @@ def test_counter_restarts_with_a_rebuilt_state_and_resets(fused):
     assert (tel.hist_pass_count(), tel.hist_pass_iteration()) == (0, 0)
 
 
-@pytest.mark.parametrize("name", ["binary", "multiclass"])
+@pytest.mark.parametrize("name", ["binary", "multiclass", "binary_int"])
 def test_fused_trees_are_byte_identical_to_the_parents(fused, name):
     """tests/fixtures/fused_parent_*.model were written by the commit before
-    the pass counter entered the growers' loop state and the fused state."""
-    if name == "binary":
+    the pass counter entered the growers' loop state and the fused state;
+    fused_parent_binary_int.model (use_quantized_grad: the int8 path, whose
+    root pass is the factored contraction since) by the commit before that
+    contraction — its int32 sums are exact, so the trees may not move."""
+    if name.startswith("binary"):
         X, y = make_synthetic_binary(n=2000, f=8)
-        bst = lgb.train(dict(STREAM, objective="binary"),
+        quant = name == "binary_int"
+        bst = lgb.train(dict(STREAM, objective="binary",
+                             use_quantized_grad=quant),
                         lgb.Dataset(X, label=y), num_boost_round=5)
+        assert bst.engine._root_pass == ("factored" if quant else "onehot")
     else:
         X, y = make_synthetic_multiclass(n=1500, f=8, k=3)
         bst = lgb.train(dict(STREAM, objective="multiclass", num_class=3),

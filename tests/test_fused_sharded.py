@@ -216,3 +216,36 @@ def test_checkpoint_resume_from_sharded_state(tmp_path, sampling):
                         lgb.Dataset(X, label=y), num_boost_round=6)
     assert _strip_params(full.model_to_string()) == \
         _strip_params(resumed.model_to_string())
+
+
+@needs_mesh
+def test_row_mesh_factored_root_equals_serial(monkeypatch):
+    """The int8 path's root pass is the factored contraction; under a
+    4-device row mesh every device contracts its own rows and the psum
+    adds exact int32 sums, so the model equals the serial learner's byte
+    for byte — and the factored kernel is what both programs traced."""
+    from lightgbm_tpu.pallas import stream_kernel
+    traced = []
+    real = stream_kernel._root_hist_factored
+
+    def spy(bins_T, *a, **k):
+        traced.append(bins_T.shape)
+        return real(bins_T, *a, **k)
+
+    monkeypatch.setattr(stream_kernel, "_root_hist_factored", spy)
+    X, y = make_synthetic_binary(n=3000, f=17)
+    params = {"objective": "binary", "verbosity": -1, "num_leaves": 15,
+              "min_data_in_leaf": 5, "max_bin": 63, "hist_backend": "stream",
+              "use_quantized_grad": True}
+    serial = lgb.train(dict(params), lgb.Dataset(X, label=y),
+                       num_boost_round=3)
+    assert len(traced) >= 1 and serial.engine._root_pass == "factored"
+    n_serial = len(traced)
+    mesh = lgb.train(dict(params, tree_learner="data", mesh_shape="data:4"),
+                     lgb.Dataset(X, label=y), num_boost_round=3)
+    assert mesh.engine._fused_last and mesh.engine._root_pass == "factored"
+    assert len(traced) > n_serial
+    # the mesh program's kernel sees one device's quarter of the rows
+    assert traced[-1][1] * 4 >= 3000 > traced[-1][1]
+    assert (_strip_params(mesh.model_to_string())
+            == _strip_params(serial.model_to_string()))

@@ -12,8 +12,9 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu.ops.grow import feature_local_bin
 from lightgbm_tpu.ops.histogram import _hist_segsum
-from lightgbm_tpu.pallas.stream_kernel import (build_route_tables, pack_bins_T,
-                                               route_and_hist)
+from lightgbm_tpu.pallas.stream_kernel import (NUM_TAB, T_SLOT_KEEP,
+                                               build_route_tables, pack_bins_T,
+                                               root_pass_kind, route_and_hist)
 
 
 def _dataset(n=2000, seed=11):
@@ -150,6 +151,122 @@ def test_root_pass_matches_segsum():
                                np.asarray(hist_ref[..., :2]),
                                rtol=2e-3, atol=2e-3)
     np.testing.assert_allclose(np.asarray(slot_cnt), [float(N)], atol=1e-6)
+
+
+# ---------------------------------------------------------------- root pass
+def _root_case(name):
+    """(bins (N, G) uint8, grad, hess int-valued f32, Bmax, bin_buckets,
+    block) of one factored-root case."""
+    rs = np.random.RandomState(sum(map(ord, name)))
+    n, bmax, bb, block = 2048, 64, None, 1024
+    if name == "g136_bin_buckets":
+        # bucket-sorted mixed cardinalities, as binning.device_group_order
+        # lays an MSLR-like table out
+        bb = ((64, 100), (32, 20), (16, 10), (8, 6))
+        bins = np.concatenate([rs.randint(0, b, (n, g)) for b, g in bb], 1)
+    elif name in ("g5", "g17"):
+        bins = rs.randint(0, 64, (n, int(name[1:])))
+    elif name == "n_not_a_block_multiple":
+        n = 1500
+        bins = rs.randint(0, 64, (n, 28))
+    elif name == "bins_at_digit_edges":
+        bins = rs.choice([0, 7, 8, 62, 63], (n, 28))
+    elif name == "bmax_odd_digits":          # 5 high digits, 7 bits a bin
+        bmax = 37
+        bins = rs.randint(0, bmax, (n, 9))
+    elif name == "bmax_127":
+        bmax = 127
+        bins = rs.choice([0, 63, 64, 120, 126], (n, 6))
+    else:                                    # HIGGS-like: G = 28, B = 64
+        bins = rs.randint(0, 64, (n, 28))
+    gi = rs.randint(-32, 33, n)
+    hi = rs.randint(0, 33, n)
+    if name == "weights_at_int8_limits":
+        gi = rs.choice([-127, 127], n)
+        hi = np.full(n, 127)
+    if name == "out_of_bag_zero_weights":
+        out = rs.rand(n) < 0.6
+        gi[out] = 0
+        hi[out] = 0
+    return (bins.astype(np.uint8), gi.astype(np.float32),
+            hi.astype(np.float32), bmax, bb, block)
+
+
+def _root_operands(bins, grad, hess, Bmax, block, L=8):
+    """(bins_T, leaf ids, w_T, tabs, bits) as the grower hands them to its
+    root pass: every row in leaf 0, tables that split nothing and keep leaf
+    0 in slot 0."""
+    N = bins.shape[0]
+    slay = pack_bins_T(jnp.asarray(bins), block, max_bins=Bmax)
+    w_T = jnp.zeros((8, slay.n_pad), jnp.float32)
+    w_T = (w_T.at[0, :N].set(jnp.asarray(grad))
+              .at[1, :N].set(jnp.asarray(hess)).at[2, :N].set(1.0))
+    tabs = jnp.zeros((NUM_TAB, L), jnp.float32).at[T_SLOT_KEEP, 0].set(1.0)
+    bits = jnp.zeros((-(-Bmax // 8) * 8, L), jnp.bfloat16)
+    return (slay.bins_T, jnp.zeros((1, slay.n_pad), jnp.int32), w_T, tabs,
+            bits)
+
+
+ROOT_CASES = ["g28_b64", "g136_bin_buckets", "g5", "g17",
+              "n_not_a_block_multiple", "bins_at_digit_edges",
+              "bmax_odd_digits", "bmax_127", "weights_at_int8_limits",
+              "out_of_bag_zero_weights", "compacted_view"]
+
+
+@pytest.mark.parametrize("case", ROOT_CASES)
+def test_factored_root_exact(case):
+    """The factored root pass (route_and_hist(root=True) on the int path
+    over u8-layout bins) against _hist_segsum and against the S = 1 call of
+    the 64-slot kernel it replaces: the same shape and dtype, every sum
+    EXACT."""
+    bins, gi, hi, Bmax, bb, block = _root_case(case)
+    N, G = bins.shape
+    L = 8
+    bins_T, lid, w_T, tabs, bits = _root_operands(bins, gi, hi, Bmax, block)
+    assert bins_T.dtype == jnp.int8
+    assert root_pass_kind(bins_T.dtype, True) == "factored"
+    in_bag = np.ones(N, bool)
+    if case == "compacted_view":
+        # what the grower streams under GOSS / bagging: in-bag rows
+        # partitioned to the front, the rest truncated or zero-weighted
+        from lightgbm_tpu.ops.compact import compact_transposed_view
+        in_bag = np.random.RandomState(3).rand(N) < 0.4
+        keep = jnp.asarray(in_bag, jnp.float32)
+        w_T = w_T.at[:3, :N].multiply(keep[None, :])
+        bins_T, w_T = compact_transposed_view(bins_T, w_T, 2, block, block)
+        lid = lid[:, :block]
+        assert bins_T.shape[1] == block and in_bag.sum() <= block
+    args = (bins_T, lid, w_T, tabs, bits, 1, Bmax, G, L)
+    kw = dict(block_rows=block, has_cat=False, int_weights=True,
+              bin_buckets=bb)
+    lid_new, hist, _ = route_and_hist(*args, root=True, **kw)
+    _, hist_onehot, _ = route_and_hist(*args, **kw)
+    w = jnp.asarray(in_bag, jnp.float32)
+    hist_ref = _hist_segsum(jnp.asarray(bins), jnp.zeros(N, jnp.int32),
+                            jnp.asarray(gi) * w, jnp.asarray(hi) * w, w, 1,
+                            Bmax)
+    assert hist.shape == hist_onehot.shape == (1, G, Bmax, 2)
+    assert hist.dtype == hist_onehot.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(hist_onehot))
+    np.testing.assert_array_equal(np.asarray(hist, np.float64),
+                                  np.asarray(hist_ref[..., :2], np.float64))
+    np.testing.assert_array_equal(np.asarray(lid_new), 0)
+
+
+def test_root_flag_leaves_the_other_paths_alone():
+    """root=True changes nothing where the factored form does not engage:
+    float weights, the packed-word layout, a multiclass program."""
+    assert root_pass_kind(jnp.int8, False) == "onehot"
+    assert root_pass_kind(jnp.int32, True) == "onehot"
+    assert root_pass_kind(jnp.int8, True, num_class=3) == "onehot"
+    bins, gi, hi, Bmax, _, block = _root_case("g5")
+    operands = _root_operands(bins, gi * 0.37, hi * 0.11, Bmax, block)
+    args = (*operands, 1, Bmax, bins.shape[1], 8)
+    kw = dict(block_rows=block, has_cat=False)
+    a = route_and_hist(*args, **kw)
+    b = route_and_hist(*args, root=True, **kw)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
 def test_int8_hist_exact():
