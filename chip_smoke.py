@@ -174,6 +174,106 @@ def kernel_exactness(dd, params):
                              np.bincount(np.asarray(lid), minlength=S)))
 
 
+def wide_kernel_exactness():
+    """The same on a table too wide for one M-tile, at the benchmark's wide
+    cell's tile shape (2,000 groups of 63 bins: sixteen tiles of 128 groups,
+    1024-row blocks): the route-only pass, the tiles' sweeps and the tiled
+    factored root against NumPy routing and _hist_segsum, exactly, with this
+    round's splits testing features of every tile."""
+    import jax.numpy as jnp
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu.ops.histogram import _hist_segsum
+    from lightgbm_tpu.pallas.stream_kernel import (build_route_tables,
+                                                   pack_bins_T,
+                                                   route_and_hist,
+                                                   stream_tiling)
+    G, Bmax, L, S = 2000, 63, 255, 64
+    plan = stream_tiling(Bmax, G, True)
+    check("wide: 2,000 groups go sixteen 128-group tiles at T=1024",
+          tuple(plan) == (1024, 128, 16, 128 * 64), str(plan))
+    T, N = plan.block_rows, 8 * plan.block_rows
+    rs = np.random.RandomState(1)
+    ds = lgb.Dataset(rs.randn(N, G).astype(np.float32),
+                     label=(rs.rand(N) < 0.5).astype(np.float32),
+                     params={"max_bin": Bmax, "verbosity": -1})
+    ds.construct()
+    dd = ds.device_data()
+    bins = dd.bins[:N]
+    check("wide: one group a column, 63 bins",
+          (dd.num_groups, dd.max_bins) == (G, Bmax),
+          f"G={dd.num_groups} Bmax={dd.max_bins}")
+    gi = jnp.asarray(rs.randint(-32, 33, N).astype(np.float32))
+    hi = jnp.asarray(rs.randint(0, 33, N).astype(np.float32))
+    cnt = jnp.ones(N, jnp.float32)
+    bins_T = pack_bins_T(bins, T, max_bins=Bmax,
+                         tile_groups=plan.tile_groups).bins_T
+    check("wide: u8 bins packed to whole tiles",
+          bins_T.dtype == jnp.int8 and bins_T.shape == (2048, N),
+          f"{bins_T.dtype} {bins_T.shape}")
+    w_T = (jnp.zeros((8, N), jnp.float32).at[0].set(gi).at[1].set(hi)
+           .at[2].set(cnt))
+    bits = jnp.zeros((64, L), jnp.bfloat16)
+    kw = dict(block_rows=T, has_cat=False, int_weights=True,
+              tile_groups=plan.tile_groups)
+    # rows over 32 leaves, every one split on a feature of its own (two a
+    # tile, the last in the ragged one): left rows keep leaf l in slot l,
+    # right rows move to leaf 32 + l in slot 32 + l
+    half = np.arange(L) < 32
+    feat = np.where(half, (np.arange(L) * 64 + 15) % G, 0).astype(np.int32)
+    thr = np.where(half, 20 + np.arange(L) % 24, 0).astype(np.int32)
+    ids = np.arange(L, dtype=np.int32)
+    cols = (half, feat, thr, 0 * ids, np.where(half, 32 + ids, 0),
+            np.where(half, ids + 1, 0), np.where(half, 33 + ids, 0), 0 * ids)
+    tabs = build_route_tables(*(jnp.asarray(c, jnp.int32) for c in cols),
+                              dd.routing, L)
+    lid = rs.randint(0, 32, N).astype(np.int32)
+    group_of = np.asarray(dd.routing.feat_group)
+    fb = np.asarray(bins)[np.arange(N), group_of[feat[lid]]]
+    want_leaf = np.where(fb <= thr[lid], lid, 32 + lid)
+    leaf = jnp.asarray(lid).reshape(1, -1)
+    new_leaf, hist, scnt = route_and_hist(bins_T, leaf, w_T, tabs, bits, S,
+                                          Bmax, G, L, **kw)
+    ref = _hist_segsum(bins, jnp.asarray(want_leaf), gi, hi, cnt, S, Bmax)
+    check("wide 64-slot pass: rows routed as NumPy routes them",
+          np.array_equal(np.asarray(new_leaf[0]), want_leaf))
+    check("wide 64-slot pass: int32 histogram == _hist_segsum exactly",
+          hist.dtype == jnp.int32 and np.array_equal(
+              np.asarray(hist, np.float64),
+              np.asarray(ref[..., :2], np.float64)))
+    # and against NumPy alone (np.add.at, int64), which shares no code with
+    # the program: leaf ids below 64 are this round's slots
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "benchmark"))
+    import reference_hist
+    plain = reference_hist.histograms(
+        np.asarray(bins), want_leaf, np.asarray(gi, np.int64),
+        np.asarray(hi, np.int64), S, Bmax)
+    check("wide 64-slot pass: int32 histogram == NumPy reference exactly",
+          np.array_equal(np.asarray(hist, np.int64), plain[..., :2]))
+    check("wide 64-slot pass: slot counts exact",
+          np.array_equal(np.asarray(scnt),
+                         np.bincount(want_leaf, minlength=S)))
+    only_leaf, _, only_cnt = route_and_hist(bins_T, leaf, w_T, tabs, bits, S,
+                                            Bmax, G, L, with_hist=False, **kw)
+    check("wide route-only pass: the same leaf ids and counts",
+          np.array_equal(np.asarray(only_leaf), np.asarray(new_leaf))
+          and np.array_equal(np.asarray(only_cnt), np.asarray(scnt)))
+    _, fact, _ = route_and_hist(bins_T, jnp.zeros((1, N), jnp.int32), w_T,
+                                tabs, bits, 1, Bmax, G, L, root=True, **kw)
+    ref = _hist_segsum(bins, jnp.zeros(N, jnp.int32), gi, hi, cnt, 1, Bmax)
+    check("wide factored root: int32 histogram == _hist_segsum exactly",
+          fact.dtype == jnp.int32 and np.array_equal(
+              np.asarray(fact, np.float64),
+              np.asarray(ref[..., :2], np.float64)))
+    plain = reference_hist.histograms(
+        np.asarray(bins), np.zeros(N, np.int64), np.asarray(gi, np.int64),
+        np.asarray(hi, np.int64), 1, Bmax)
+    # tolerance 0: the sums are integers, so a path of lower precision than
+    # the int8 x int8 -> int32 contraction fails this by construction
+    check("wide factored root: int32 histogram == NumPy reference exactly",
+          np.array_equal(np.asarray(fact, np.int64), plain[..., :2]))
+
+
 def main():
     try:
         import jax
@@ -270,6 +370,8 @@ def run(device, devs):
         with phase("kernel: route_and_hist vs _hist_segsum at the cell's "
                    "block shape"):
             kernel_exactness(bst.engine.dd, params)
+        with phase("kernel: the same over sixteen M-tiles (G = 2,000)"):
+            wide_kernel_exactness()
 
         # ----------------------------------------------------------- predict
         ref = None

@@ -129,6 +129,14 @@ def _to_2d_float(data, align_categories=None
         # columns" must stay distinguishable from "not a DataFrame" so the
         # column-count check above still fires for categorical predict frames
         return arr, feature_names, cat_idx, cat_lists
+    if isinstance(data, np.ndarray) and data.dtype == np.float32 \
+            and data.ndim == 2:
+        # a float32 table is held as it came, not as a float64 copy twice
+        # its size (6.4 GB beside a 400,000 x 2,000 table's 3.2 GB): every
+        # reader widens the piece it takes - a column, a row sample, a
+        # chunk - and float32 -> float64 is exact, so bins, thresholds and
+        # models are those of the widened copy
+        return data, feature_names, cat_idx, None
     arr = np.asarray(data, dtype=np.float64)
     if arr.ndim == 1:
         arr = arr.reshape(-1, 1)
@@ -1676,6 +1684,9 @@ class Booster:
             binned = construct_binned(np.asarray(X, np.float64),
                                       tb.bin_mappers, tb.group_features)
             bins = np.asarray(binned.bins)
+            if bins.dtype != np.uint8:
+                # the kernel's words hold four 8-bit bins
+                return host("a feature group of more than 256 bins")
             if cat_feats:
                 # the host walk routes NaN / unseen / negative categories
                 # RIGHT (bit absent from the bitset); the mapper bins them
@@ -1692,7 +1703,12 @@ class Booster:
                                               m.categories.astype(np.int64))
                     bins[~ok, int(routing_np["feat_group"][f])] = m.num_bins
         with _boundary("Predict::PackShip"):
-            slay = pack_bins_T(jnp.asarray(bins))
+            # the words are packed on the host (bins is NumPy): packed on
+            # the device op by op, the int32 copy of the batch and its four
+            # byte planes stay alive together for as long as the device
+            # lags the host - up to 2.8 GB beside the 0.2 GB bins of a
+            # 100,000 x 2,000 batch, another amount every call
+            bins_T = jnp.asarray(pack_bins_T(bins).bins_T)
         maxd = max(max(tree_max_depth(t) for t in use), 1)
         n = X.shape[0]
         es_freq, es_margin = (int(es[0]), float(es[1])) if es else (0, 0.0)
@@ -1710,7 +1726,7 @@ class Booster:
                 # input out of VMEM (the kernel never touches it)
                 cat_tab = cat_tab[:predict_kernel_CAT_DIGITS]
             with _boundary("Predict::Walk"):
-                s = predict_stream(slay.bins_T, jnp.asarray(tabs),
+                s = predict_stream(bins_T, jnp.asarray(tabs),
                                    jnp.asarray(cat_tab), L, len(trees_c),
                                    maxd, has_cat=bool(cat_feats),
                                    es_freq=es_freq, es_margin=es_margin)

@@ -13,6 +13,8 @@ which makes both EFB bundles and plain features uniform for the histogram/split 
 from __future__ import annotations
 
 import math
+import os
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -362,21 +364,42 @@ def _greedy_find_bin(distinct: np.ndarray, counts: np.ndarray, max_bin: int,
     mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
     uppers: List[float] = []
     lowers: List[float] = [float(distinct[0])]
-    cur = 0
-    for i in range(nd - 1):
+    # The reference walks every distinct value, cutting a bin after value i
+    # when i is a heavy hitter, when the bin has reached the mean size, or
+    # when i + 1 is a heavy hitter and the bin is at least half full.  The
+    # mean only changes at a cut, so the next cut is found by searching the
+    # running counts instead of stepping through them: one step a BIN, not a
+    # distinct value (a 200,000-row sample of a continuous column has as
+    # many distinct values, and a wide table thousands of such columns).
+    cum = np.cumsum(counts, dtype=np.int64)             # rows up to value i
+    cum_rest = np.cumsum(np.where(is_big, 0, counts), dtype=np.int64)
+    big_at = np.flatnonzero(is_big)
+    rest_total = rest_sample_cnt
+    start = 0
+    while start < nd - 1:
+        before = int(cum[start - 1]) if start else 0
+        # first value at which the bin holds ceil(mean) rows (counts are
+        # whole numbers, so that is the first with cur >= mean)
+        i = max(start, int(np.searchsorted(
+            cum, before + math.ceil(mean_bin_size))))
+        b = int(np.searchsorted(big_at, start))
+        if b < len(big_at):
+            j = int(big_at[b])                          # next heavy hitter
+            half = max(1.0, mean_bin_size * 0.5)
+            if j > start and int(cum[j - 1]) - before >= half:
+                j -= 1                                  # cut just before it
+            i = min(i, j)
+        if i >= nd - 1:
+            break
+        uppers.append(float(distinct[i]))
+        lowers.append(float(distinct[i + 1]))
+        if len(uppers) >= max_bin - 1:
+            break
         if not is_big[i]:
-            rest_sample_cnt -= int(counts[i])
-        cur += int(counts[i])
-        if is_big[i] or cur >= mean_bin_size or \
-                (is_big[i + 1] and cur >= max(1.0, mean_bin_size * 0.5)):
-            uppers.append(float(distinct[i]))
-            lowers.append(float(distinct[i + 1]))
-            if len(uppers) >= max_bin - 1:
-                break
-            cur = 0
-            if not is_big[i]:
-                rest_bin_cnt -= 1
-                mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
+            rest_bin_cnt -= 1
+            mean_bin_size = (rest_total - int(cum_rest[i])) \
+                / max(rest_bin_cnt, 1)
+        start = i + 1
     for i in range(len(uppers)):
         val = np.nextafter((uppers[i] + lowers[i + 1]) / 2.0, np.inf)
         if not bounds or val > np.nextafter(bounds[-1], np.inf):
@@ -804,11 +827,64 @@ def bin_rows_into(chunk: np.ndarray, bin_mappers: List[BinMapper],
             out[row0:row0 + n, gi] = col.astype(dtype)
 
 
+def _binned(bins, groups, group_bin_counts, group_offsets, feature_offsets,
+            feature_num_bins, bin_mappers) -> BinnedData:
+    """The BinnedData of a filled bin matrix and its _group_layout."""
+    return BinnedData(
+        bins=bins,
+        group_features=groups,
+        group_offsets=group_offsets.astype(np.int32),
+        group_bin_counts=np.asarray(group_bin_counts, dtype=np.int32),
+        feature_offsets=feature_offsets.astype(np.int32),
+        feature_num_bins=feature_num_bins.astype(np.int32),
+        bin_mappers=bin_mappers,
+        num_data=bins.shape[0],
+        num_features=len(bin_mappers),
+    )
+
+
 def construct_binned(data: np.ndarray, bin_mappers: List[BinMapper],
                      groups: Optional[List[List[int]]] = None) -> BinnedData:
     """Bin a raw (N, F) float matrix into the dense group-bin layout."""
+    fast = _construct_binned_rows(data, bin_mappers, groups)
+    if fast is not None:
+        return fast
     return construct_binned_columns(lambda f: data[:, f], data.shape[0],
                                     data.shape[1], bin_mappers, groups)
+
+
+def _construct_binned_rows(data, bin_mappers, groups) -> Optional[BinnedData]:
+    """construct_binned's table where every group is ONE numerical feature
+    of at most 256 bins (no bundles, no categories) and the matrix is
+    row-major float32 / float64: all rows through the native binner in one
+    call, a row read once and its bins written side by side, instead of one
+    strided pass over the whole table a column (0.8G values at 400,000 x
+    2,000: minutes).  The same bins, byte for byte (tested); None where it
+    does not apply."""
+    num_features = len(bin_mappers)
+    if groups is None:
+        groups = [[f] for f in range(num_features)]
+    if not isinstance(data, np.ndarray) or data.ndim != 2 \
+            or data.shape[1] != num_features \
+            or any(len(g) != 1 for g in groups) \
+            or any(m.bin_type == BIN_CATEGORICAL for m in bin_mappers):
+        return None
+    groups = device_group_order(groups, bin_mappers)
+    (group_bin_counts, group_offsets, feature_offsets, feature_num_bins,
+     dtype) = _group_layout(groups, bin_mappers, num_features)
+    if dtype != np.uint8:
+        return None
+    from .native import bin_rows
+    of = [bin_mappers[g[0]] for g in groups]
+    bins = bin_rows(data, [g[0] for g in groups],
+                    [np.asarray(m.upper_bounds, np.float64) for m in of],
+                    [m.missing_type for m in of], [m.num_bins for m in of])
+    if bins is None:
+        return None
+    for gi, g in enumerate(groups):
+        feature_offsets[g[0]] = group_offsets[gi]
+    return _binned(bins, groups, group_bin_counts, group_offsets,
+                   feature_offsets, feature_num_bins, bin_mappers)
 
 
 def construct_binned_columns(get_col, n: int, num_features: int,
@@ -879,17 +955,8 @@ def construct_binned_columns(get_col, n: int, num_features: int,
                 feature_offsets[f] = group_offsets[gi] + in_group - 1  # see split remap
                 in_group += m.num_bins - 1
 
-    return BinnedData(
-        bins=bins,
-        group_features=groups,
-        group_offsets=group_offsets.astype(np.int32),
-        group_bin_counts=np.asarray(group_bin_counts, dtype=np.int32),
-        feature_offsets=feature_offsets.astype(np.int32),
-        feature_num_bins=feature_num_bins.astype(np.int32),
-        bin_mappers=bin_mappers,
-        num_data=n,
-        num_features=num_features,
-    )
+    return _binned(bins, groups, group_bin_counts, group_offsets,
+                   feature_offsets, feature_num_bins, bin_mappers)
 
 
 def find_bin_mappers(data: np.ndarray, max_bin: int, min_data_in_bin: int,
@@ -909,17 +976,21 @@ def find_bin_mappers(data: np.ndarray, max_bin: int, min_data_in_bin: int,
     else:
         sample = data
     cat = set(int(c) for c in categorical_features)
-    mappers = []
-    for f in range(num_features):
+
+    def find(f):
         mb = max_bin if max_bin_by_feature is None else int(max_bin_by_feature[f])
         col = np.asarray(sample[:, f], dtype=np.float64)
         if f in cat:
-            mappers.append(BinMapper.find_categorical(col, mb, min_data_in_bin, use_missing))
-        else:
-            mappers.append(BinMapper.find_numerical(
-                col, mb, min_data_in_bin, use_missing, zero_as_missing,
-                forced_bounds=forced_bins[f] if forced_bins else None))
-    return mappers
+            return BinMapper.find_categorical(col, mb, min_data_in_bin, use_missing)
+        return BinMapper.find_numerical(
+            col, mb, min_data_in_bin, use_missing, zero_as_missing,
+            forced_bounds=forced_bins[f] if forced_bins else None)
+
+    # a column's mapper depends on that column alone, and its sort, unique
+    # and searches are NumPy calls that release the GIL: a thread a core
+    # (2,000 columns of a 200,000-row sample: 69 s one after the other)
+    with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
+        return list(pool.map(find, range(num_features)))
 
 
 # ---------------------------------------------------------------------------
@@ -1033,14 +1104,5 @@ def construct_binned_sparse(X, bin_mappers: List[BinMapper],
                 feature_offsets[f] = group_offsets[gi] + in_group - 1
                 in_group += m.num_bins - 1
 
-    return BinnedData(
-        bins=bins,
-        group_features=groups,
-        group_offsets=group_offsets.astype(np.int32),
-        group_bin_counts=np.asarray(group_bin_counts, dtype=np.int32),
-        feature_offsets=feature_offsets.astype(np.int32),
-        feature_num_bins=feature_num_bins.astype(np.int32),
-        bin_mappers=bin_mappers,
-        num_data=n,
-        num_features=num_features,
-    )
+    return _binned(bins, groups, group_bin_counts, group_offsets,
+                   feature_offsets, feature_num_bins, bin_mappers)

@@ -532,6 +532,14 @@ class Config:
     stochastic_rounding: bool = True
 
     # --- TPU-native knobs ---
+    # histogram formulation: auto | segsum | onehot | pallas | stream |
+    # scatter. auto = stream on a TPU at EVERY table width (a table whose
+    # one-hot does not fit VMEM whole is cut into M-tiles of whole feature
+    # groups by the kernel itself, so the fused iteration runs it too) and
+    # segsum elsewhere; under a mesh, stream where rows alone are sharded
+    # and onehot/segsum otherwise. pallas (slot-sorted row blocks, a row
+    # gather every round) is reachable by name only; scatter runs
+    # interpreted on the CPU only. LGBTPU_HIST_BACKEND overrides for A/B.
     hist_backend: str = "auto"
     # packed quantized-gradient histogram width (bits per grad/hess field
     # on the mesh wire): 32 = exact int32 lanes (default); 16 packs each

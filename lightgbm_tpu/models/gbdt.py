@@ -293,14 +293,26 @@ class GBDT:
         # them too); contraction backends have no block constraint but
         # reuse the same quantum for bounded jit-capacity buckets
         self._pack_block = 256
+        # how the stream kernel cuts this table (block rows, M-tiles), and
+        # what every flag poll publishes of it: static per compiled program
+        self._stream_tiling = None
+        self._poll_tiling = {}
         if self._grow_params.hist_backend == "stream":
-            from ..pallas.stream_kernel import (pack_bins_T,
-                                               stream_block_rows)
-            self._pack_block = stream_block_rows(
+            from ..pallas.stream_kernel import pack_bins_T, stream_tiling
+            tiling = self._stream_tiling = stream_tiling(
                 dd.max_bins, dd.num_groups, self._grow_params.int_hist,
                 bin_buckets=self._grow_params.bin_buckets)
-            packed = pack_bins_T(dd.bins, self._pack_block,
-                                 max_bins=dd.max_bins).bins_T
+            self._pack_block = tiling.block_rows
+            self._poll_tiling = {
+                "hist_tiles": tiling.num_tiles,
+                # one-hot rows of the table's own groups: the tiles' rows
+                # less the groups that pad the last
+                "hist_m_rows": (dd.num_groups * tiling.tile_m_rows
+                                // tiling.tile_groups if tiling.tile_groups
+                                else tiling.tile_m_rows)}
+            packed = pack_bins_T(
+                dd.bins, self._pack_block, max_bins=dd.max_bins,
+                tile_groups=tiling.tile_groups).bins_T
             if self._mesh_stream:
                 # rows were pre-padded to a whole kernel block per device, so
                 # the packed words split evenly across the row axis
@@ -758,6 +770,12 @@ class GBDT:
         data_parallel_tree_learner.cpp:285-299); feature-sharded meshes use
         the contraction backends, which GSPMD partitions automatically.
 
+        ``auto`` on a TPU is ``stream`` at every table width: a table whose
+        one-hot does not fit VMEM whole is cut into M-tiles by the kernel
+        itself (_stream_fits), so the fused iteration runs wide tables too.
+        ``pallas`` (slot-sorted row blocks, a row gather every round) is
+        reachable by name only; off the chip ``auto`` is ``segsum``.
+
         ``LGBTPU_HIST_BACKEND`` overrides the param (A/B experiments across
         the histogram formulations, docs/PERF.md) and passes through the
         same validation/mesh gates as the param itself."""
@@ -841,19 +859,24 @@ class GBDT:
         return contextlib.nullcontext()
 
     def _stream_fits(self) -> bool:
-        """The fused streaming kernel keeps the whole (G*B, 2S) histogram block
-        and the (L, T) leaf one-hot resident in VMEM (~16 MB/core); the block
-        row count steps down to 256 for wide layouts (stream_block_rows)."""
+        """Whether the streaming kernel takes this job, at any width: it keeps
+        one M-TILE's (tile rows, 2S) histogram block and the (L, T) leaf
+        one-hot resident in VMEM, and a table whose whole one-hot does not
+        fit is cut into tiles of whole groups (stream_tiling), so the group
+        count no longer decides.  What is left is the leaf one-hot, the slot
+        ids, and that 32 groups of this bin count make a tile at all (bf16
+        one-hots, the wider of the two)."""
+        from ..pallas.stream_kernel import stream_tiling
         L = max(self.config.num_leaves, 2)
         cfg_s = self.config.max_splits_per_round
         S = 2 * min(cfg_s if cfg_s > 0 else 64, max(L - 1, 1))
-        G = self.dd.num_groups
-        Bpad = -(-self.dd.max_bins // 8) * 8
-        hist_bytes = G * Bpad * S * 4
-        onehot_bytes = G * Bpad * 256 * 2       # (G*B, T) bf16 at minimum T
-        return (L <= 2048 and G <= 512 and hist_bytes <= 8 * 2 ** 20
-                and onehot_bytes <= 8 * 2 ** 20
-                and S <= 2 * 255)   # slot ids must stay bf16-exact (<= 255)
+        if L > 2048 or S > 2 * 255:   # slot ids must stay bf16-exact (<= 255)
+            return False
+        try:
+            stream_tiling(self.dd.max_bins, self.dd.num_groups, False)
+        except LightGBMError:
+            return False
+        return True
 
     def _resolved_max_splits(self) -> int:
         """Per-round split budget. auto (0): 1 on CPU backends — exact
@@ -902,7 +925,26 @@ class GBDT:
         m_tot = sum(bucket_run_rows(b, g) for b, g in buckets)
         if len(buckets) > 6 or m_tot >= 0.9 * len(counts) * bpad:
             return None
-        return tuple((int(b), int(g)) for b, g in buckets)
+        buckets = tuple((int(b), int(g)) for b, g in buckets)
+        from ..pallas.stream_kernel import stream_tiling
+        if stream_tiling(int(counts.max()), len(counts),
+                         self._resolved_int_hist(),
+                         bin_buckets=buckets).tile_groups:
+            # still too wide for one M-tile: tiles take the uniform axis
+            return None
+        return buckets
+
+    def _resolved_int_hist(self) -> bool:
+        """Quantized gradients contracted on the int8 MXU into exact int32
+        sums: the stream kernel's, where the levels fit int8 and a whole
+        table's worth of one level cannot overflow int32."""
+        c = self.config
+        return bool(c.use_quantized_grad
+                    and self._resolve_hist_backend() == "stream"
+                    and c.num_grad_quant_bins <= 254
+                    and c.num_grad_quant_bins % 2 == 0
+                    and (c.num_grad_quant_bins / 2)
+                    * self.dd.bins.shape[0] < 2 ** 31)
 
     def _resolved_packed_width(self) -> int:
         """Packed-wire width for the quantized histogram collective
@@ -963,12 +1005,7 @@ class GBDT:
             # int8 operand range, exact int32 accumulation bounds, and an
             # even level count (odd counts clip to a non-integer +half grid
             # value that the int8 kernel could not represent)
-            int_hist=(c.use_quantized_grad
-                      and self._resolve_hist_backend() == "stream"
-                      and c.num_grad_quant_bins <= 254
-                      and c.num_grad_quant_bins % 2 == 0
-                      and (c.num_grad_quant_bins / 2)
-                      * self.dd.bins.shape[0] < 2 ** 31),
+            int_hist=self._resolved_int_hist(),
             bin_buckets=self._resolved_bin_buckets(),
             has_cegb=(c.cegb_penalty_split > 0.0
                       or (c.cegb_penalty_feature_coupled is not None
@@ -1940,7 +1977,8 @@ class GBDT:
                 self._hist_passes_seen = passes
                 poll.set(hist_passes=hist_pass_count(),
                          **({"root_pass": self._root_pass}
-                            if self._root_pass else {}))
+                            if self._root_pass else {}),
+                         **self._poll_tiling)
         note_host_sync()
         self._nan_guard.resolve(pending, got[1:1 + len(pending)])
         if st is not None:

@@ -67,6 +67,11 @@ def _build_lib() -> Optional[ctypes.CDLL]:
                                       ctypes.POINTER(ctypes.c_uint16)]
     pd = ctypes.POINTER(ctypes.c_double)
     pi = ctypes.POINTER(ctypes.c_int32)
+    for fn, ptr in ((lib.lgbt_bin_rows_f32, ctypes.POINTER(ctypes.c_float)),
+                    (lib.lgbt_bin_rows_f64, pd)):
+        fn.argtypes = [ptr, ctypes.c_int64, ctypes.c_int64, pi,
+                       ctypes.c_int32, pd, ctypes.POINTER(ctypes.c_int64),
+                       pi, pi, ctypes.POINTER(ctypes.c_uint8)]
     lib.lgbt_predict_row.argtypes = [
         pd, pi, ctypes.c_int32, pi, pd, pi,
         ctypes.POINTER(ctypes.c_uint8), pi, pi, pi, pd, pi,
@@ -125,4 +130,37 @@ def value_to_bin(values: np.ndarray, upper_bounds: np.ndarray, missing_type: int
         ub.ctypes.data_as(ctypes.POINTER(ctypes.c_double)), len(ub),
         int(missing_type), int(num_bins), int(default_bin),
         out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint16)))
+    return out
+
+
+def bin_rows(data: np.ndarray, col_of_group, upper_bounds, missing_types,
+             num_bins) -> Optional[np.ndarray]:
+    """(N, G) uint8 bins of a row-major float32 / float64 table, group g
+    from column col_of_group[g] by value_to_bin's search, all rows in one
+    native call.  None where the library is unavailable or the table is not
+    one it reads in place (the caller bins column by column instead)."""
+    lib = get_lib()
+    if (lib is None or not isinstance(data, np.ndarray) or data.ndim != 2
+            or data.dtype not in (np.float32, np.float64)
+            or data.strides[1] != data.itemsize
+            or data.strides[0] % data.itemsize or data.strides[0] <= 0):
+        return None
+    cols = np.ascontiguousarray(col_of_group, np.int32)
+    bounds = np.ascontiguousarray(np.concatenate(upper_bounds), np.float64)
+    off = np.zeros(len(upper_bounds) + 1, np.int64)
+    np.cumsum([len(u) for u in upper_bounds], out=off[1:])
+    mt = np.ascontiguousarray(missing_types, np.int32)
+    nb = np.ascontiguousarray(num_bins, np.int32)
+    out = np.empty((data.shape[0], len(cols)), np.uint8)
+    f32 = data.dtype == np.float32
+    fn = lib.lgbt_bin_rows_f32 if f32 else lib.lgbt_bin_rows_f64
+    pi = ctypes.POINTER(ctypes.c_int32)
+    fn(data.ctypes.data_as(ctypes.POINTER(
+           ctypes.c_float if f32 else ctypes.c_double)),
+       data.shape[0], data.strides[0] // data.itemsize,
+       cols.ctypes.data_as(pi), len(cols),
+       bounds.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+       off.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+       mt.ctypes.data_as(pi), nb.ctypes.data_as(pi),
+       out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)))
     return out

@@ -159,8 +159,28 @@ void lgbt_parse_csv(const char* buf, int64_t len, char delim, int skip_header,
   }
 }
 
-// values[n] -> bins[n] via upper-bound binary search (reference:
-// BinMapper::ValueToBin). missing_type: 0 none, 1 zero-as-missing, 2 nan.
+// One value's bin by the upper-bound binary search (reference:
+// BinMapper::ValueToBin, bin.h:613). missing_type: 0 none, 1 zero-as-missing,
+// 2 nan.  NaN -> last bin under MissingType::NaN (2); otherwise NaN is binned
+// as 0.0 — the zero window [-kZeroThreshold, kZeroThreshold] is a real bin of
+// its own.
+static inline int32_t value_bin(double v, const double* upper_bounds,
+                                int32_t num_bounds, int32_t missing_type,
+                                int32_t num_bins) {
+  if (std::isnan(v)) {
+    if (missing_type == 2) return num_bins - 1;
+    v = 0.0;
+  }
+  // first index with upper_bounds[idx] >= v
+  int32_t lo = 0, hi = num_bounds - 1;
+  while (lo < hi) {
+    int32_t mid = (lo + hi) / 2;
+    if (upper_bounds[mid] < v) lo = mid + 1; else hi = mid;
+  }
+  return lo;
+}
+
+// values[n] -> bins[n], OpenMP over the values
 void lgbt_value_to_bin(const double* values, int64_t n,
                        const double* upper_bounds, int32_t num_bounds,
                        int32_t missing_type, int32_t num_bins,
@@ -168,26 +188,60 @@ void lgbt_value_to_bin(const double* values, int64_t n,
 #if defined(_OPENMP)
 #pragma omp parallel for schedule(static)
 #endif
-  // reference ValueToBin (bin.h:613): NaN -> last bin under
-  // MissingType::NaN (2); otherwise NaN is binned as 0.0 — the zero window
-  // [-kZeroThreshold, kZeroThreshold] is a real bin of its own
   for (int64_t i = 0; i < n; ++i) {
-    double v = values[i];
-    if (std::isnan(v)) {
-      if (missing_type == 2) {
-        out[i] = static_cast<uint16_t>(num_bins - 1);
-        continue;
-      }
-      v = 0.0;
-    }
-    // first index with upper_bounds[idx] >= v
-    int32_t lo = 0, hi = num_bounds - 1;
-    while (lo < hi) {
-      int32_t mid = (lo + hi) / 2;
-      if (upper_bounds[mid] < v) lo = mid + 1; else hi = mid;
-    }
-    out[i] = static_cast<uint16_t>(lo);
+    out[i] = static_cast<uint16_t>(value_bin(values[i], upper_bounds,
+                                             num_bounds, missing_type,
+                                             num_bins));
   }
+}
+
+
+}  // extern "C": a template has C++ linkage
+
+// A whole row-major table at once: out[i, g] = bin of X[i, col_of_group[g]]
+// by value_bin, rows in parallel.  The column-at-a-time path reads and
+// writes every cache line of the table once a COLUMN (a stride of a row);
+// here a row is read once and its bins written side by side.  Group g's
+// upper bounds are bounds[bounds_off[g] .. bounds_off[g + 1]).
+template <typename T>
+static void bin_rows(const T* X, int64_t n, int64_t row_stride,
+                     const int32_t* col_of_group, int32_t num_groups,
+                     const double* bounds, const int64_t* bounds_off,
+                     const int32_t* missing_type, const int32_t* num_bins,
+                     uint8_t* out) {
+#if defined(_OPENMP)
+#pragma omp parallel for schedule(static)
+#endif
+  for (int64_t i = 0; i < n; ++i) {
+    const T* row = X + i * row_stride;
+    uint8_t* row_out = out + i * num_groups;
+    for (int32_t g = 0; g < num_groups; ++g) {
+      row_out[g] = static_cast<uint8_t>(value_bin(
+          static_cast<double>(row[col_of_group[g]]), bounds + bounds_off[g],
+          static_cast<int32_t>(bounds_off[g + 1] - bounds_off[g]),
+          missing_type[g], num_bins[g]));
+    }
+  }
+}
+
+extern "C" {
+
+void lgbt_bin_rows_f32(const float* X, int64_t n, int64_t row_stride,
+                       const int32_t* col_of_group, int32_t num_groups,
+                       const double* bounds, const int64_t* bounds_off,
+                       const int32_t* missing_type, const int32_t* num_bins,
+                       uint8_t* out) {
+  bin_rows(X, n, row_stride, col_of_group, num_groups, bounds, bounds_off,
+           missing_type, num_bins, out);
+}
+
+void lgbt_bin_rows_f64(const double* X, int64_t n, int64_t row_stride,
+                       const int32_t* col_of_group, int32_t num_groups,
+                       const double* bounds, const int64_t* bounds_off,
+                       const int32_t* missing_type, const int32_t* num_bins,
+                       uint8_t* out) {
+  bin_rows(X, n, row_stride, col_of_group, num_groups, bounds, bounds_off,
+           missing_type, num_bins, out);
 }
 
 

@@ -582,12 +582,15 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     if use_stream:
         from ..pallas.stream_kernel import (NUM_TAB, build_route_tables,
                                             pack_bins_T, route_and_hist,
-                                            route_replay, stream_block_rows)
-        T_rows = stream_block_rows(Bmax, G, params.int_hist,
-                                   bin_buckets=params.bin_buckets)
+                                            route_replay, stream_tiling)
+        # rows a kernel block, and groups an M-tile where the table's
+        # one-hot does not fit VMEM whole (0: one tile)
+        T_rows, tile_groups = stream_tiling(
+            Bmax, G, params.int_hist, bin_buckets=params.bin_buckets)[:2]
         if packed is None:
             with jax.named_scope("pack_bins"):
-                bins_T = pack_bins_T(bins, T_rows, max_bins=Bmax).bins_T
+                bins_T = pack_bins_T(bins, T_rows, max_bins=Bmax,
+                                     tile_groups=tile_groups).bins_T
         else:
             # bare array (int metadata would turn into tracers as a jit arg)
             bins_T = packed.bins_T if hasattr(packed, "bins_T") else packed
@@ -667,7 +670,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                         block_rows=T_rows, has_cat=params.has_categorical,
                         two_pass=params.hist_two_pass, int_weights=use_int,
                         with_hist=with_hist,
-                        bin_buckets=params.bin_buckets, root=root)
+                        bin_buckets=params.bin_buckets, root=root,
+                        tile_groups=tile_groups)
                     if with_hist:
                         if use_packed:
                             pw, pscales = pack_gh_wire(h, row_axis, packed_w,
@@ -712,7 +716,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                     block_rows=T_rows, has_cat=params.has_categorical,
                     two_pass=params.hist_two_pass, int_weights=use_int,
                     with_hist=with_hist, bin_buckets=params.bin_buckets,
-                    root=root)
+                    root=root, tile_groups=tile_groups)
 
         zL = jnp.zeros(L, i32)
         tabs0 = build_route_tables(zL, zL, zL, zL, zL, zL, zL,
@@ -1802,10 +1806,17 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     if use_stream:
         from ..pallas.stream_kernel import (build_route_tables, pack_bins_T,
                                             route_and_hist,
-                                            stream_block_rows)
-        T_rows = stream_block_rows(Bmax, G, params.int_hist,
-                                   bin_buckets=params.bin_buckets,
-                                   hist_channels=2 * S * K)
+                                            stream_tiling)
+        T_rows, tile_groups = stream_tiling(
+            Bmax, G, params.int_hist, bin_buckets=params.bin_buckets,
+            hist_channels=2 * S * K)[:2]
+        if tile_groups:
+            # the engine sends such a table down the per-class scan
+            # (gbdt._use_batched_multiclass: the widened block must fit VMEM)
+            raise ValueError(
+                f"grow_tree_k runs one M-tile; {G} groups of {Bmax} bins "
+                f"with {2 * S * K} histogram columns need {tile_groups}-"
+                "group tiles")
         if packed is None:
             with jax.named_scope("pack_bins"):
                 bins_T = pack_bins_T(bins, T_rows, max_bins=Bmax).bins_T

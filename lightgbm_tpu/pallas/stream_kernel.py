@@ -51,6 +51,11 @@ from ..runtime import on_tpu, pallas_interpret
 from ..utils.log import LightGBMError
 
 NUM_TAB = 24          # per-leaf table rows (padded to a sublane multiple)
+# a u8 block of more groups than this is not widened to int32 whole for the
+# route's group select (a (G_pad, T) int32 temporary): the select goes this
+# many groups at a time instead.  Every table narrow enough for the kernel
+# before it tiled its M-axis (G <= 512) is widened whole, as it was.
+WIDE_ROUTE_GROUPS = 512
 MAX_SLOTS = 255       # slot table rows are single bf16 digits (exact <= 256)
 
 import os as _os
@@ -113,9 +118,21 @@ def _route_step(iv, bins_ref, bins32, GW, T, u8_layout):
         # 4-per-word form (28 B/row either way at G=28) but no per-group
         # shift/mask unpack work in the kernel
         grpi = wordi * 4 + jax.lax.shift_right_logical(shift, 3)
-        gp_iota = jax.lax.broadcasted_iota(i32, bins32.shape, 0)
-        gb = jnp.sum(jnp.where(gp_iota == grpi, bins32, 0), axis=0,
-                     keepdims=True)                      # (1, T)
+        if bins32 is not None:
+            gp_iota = jax.lax.broadcasted_iota(i32, bins32.shape, 0)
+            gb = jnp.sum(jnp.where(gp_iota == grpi, bins32, 0), axis=0,
+                         keepdims=True)                  # (1, T)
+        else:
+            # a table too wide to widen whole (WIDE_ROUTE_GROUPS): the same
+            # select, one run of groups at a time
+            gb = jnp.zeros((1, T), i32)
+            g_pad = bins_ref.shape[0]
+            for g0 in range(0, g_pad, WIDE_ROUTE_GROUPS):
+                g1 = min(g0 + WIDE_ROUTE_GROUPS, g_pad)   # the last run ragged
+                part = bins_ref[g0:g1, :].astype(i32)
+                gp_iota = jax.lax.broadcasted_iota(i32, part.shape, 0)
+                gb = gb + jnp.sum(jnp.where(gp_iota == grpi - g0, part, 0),
+                                  axis=0, keepdims=True)
     else:
         # packed: select the split feature's group word, then its byte
         words = bins_ref[...]                            # (GW, T) i32
@@ -141,19 +158,14 @@ def _route_step(iv, bins_ref, bins32, GW, T, u8_layout):
             iv[T_SLOT_KEEP:T_SLOT_KEEP + 1, :])
 
 
-def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
-                       newleaf_ref, *outs, T, G, B, S, L, GW,
-                       has_cat: bool, two_pass: bool = True,
-                       int_weights: bool = False, f32_dots: bool = False,
-                       u8_layout: bool = False, with_hist: bool = True,
-                       bin_buckets=None, m_rows: int = 0, K: int = 1):
-    if with_hist:
-        hist_ref, cnt_ref = outs
-    else:
-        # route-only variant: no histogram output ref exists at all, so the
-        # (G*B, 2*S*K) VMEM-resident block is never allocated
-        hist_ref, (cnt_ref,) = None, outs
-    b = pl.program_id(0)
+def _route_rows(bins_ref, leaf_ref, tabs_ref, bits_ref, newleaf_ref, *,
+                T, B, L, GW, has_cat, f32_dots, u8_layout, K):
+    """Route one (K, T) block of rows through this round's split tables:
+    writes every row's new leaf id to newleaf_ref and returns (each class's
+    (1, T) histogram slot, -1 for none; the block's bins widened to int32 in
+    the u8 layout, None otherwise or where the table is too wide to widen
+    whole).  `bins_ref` holds EVERY group's bins of the block: a split may
+    test any of them."""
     i32, f32 = jnp.int32, jnp.float32
     # interpret mode on CPU: XLA:CPU's Eigen DotThunk rejects bf16 at some
     # shapes; f32 operands carry the identical (bf16-rounded) values, so the
@@ -168,7 +180,9 @@ def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
     # the class x slot channel axis (vs K separate kernel launches each
     # rebuilding the one-hot).
     l_iota = jax.lax.broadcasted_iota(i32, (L, T), 0)
-    bins32 = bins_ref[...].astype(i32) if u8_layout else None  # (G_pad, T)
+    bins32 = (bins_ref[...].astype(i32)                      # (G_pad, T)
+              if u8_layout and bins_ref.shape[0] <= WIDE_ROUTE_GROUPS
+              else None)
     # FOLDED multiclass route gather (docs/PERF.md lever): the K per-class
     # (NUM_TAB, L) @ (L, T) table dots merge into ONE block-diagonal
     # (K*NUM_TAB, K*L) @ (K*L, T) dot — class k's leaf one-hot occupies
@@ -240,14 +254,14 @@ def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
             new_lid = new_lid + vals2[0:1, :].astype(i32)
         newleaf_ref[k:k + 1, :] = new_lid
         slots.append(slot1 - 1)
+    return slots, bins32
 
-    # ---------------- histogram ----------------
-    @pl.when(b == 0)
-    def _():
-        if with_hist:
-            hist_ref[...] = jnp.zeros_like(hist_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
+def _count_slots(cnt_ref, w_ref, slots, *, T, S, K, f32_dots):
+    """cnt_ref += this block's exact per-slot data counts.  Returns the slot
+    iota and one-hots, which the histogram contraction shares."""
+    i32, f32 = jnp.int32, jnp.float32
+    bf16 = f32 if f32_dots else jnp.bfloat16
     s_iota = jax.lax.broadcasted_iota(i32, (S, T), 0)
     slot_ohs = [(s_iota == slot).astype(bf16) for slot in slots]  # (S, T) ea
     slot_oh = (jnp.concatenate(slot_ohs, axis=0) if K > 1
@@ -260,12 +274,17 @@ def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
     cnt_ref[0:1, :] += jax.lax.dot_general(
         cnt_row.astype(bf16), slot_oh, (((1,), (1,)), ((), ())),
         preferred_element_type=f32)
-    if not with_hist:
-        # route-only round (a tree's LAST split round: the children's
-        # histograms would never be scanned, so the dominant one-hot
-        # contraction — and the whole VMEM-resident histogram block — is
-        # dropped)
-        return
+    return s_iota, slot_ohs
+
+
+def _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
+                     slot_ohs, *, T, G, B, S, two_pass, int_weights, f32_dots,
+                     u8_layout, bin_buckets, m_rows, K):
+    """hist_ref += the one-hot contraction of this block's G groups (the
+    first G rows of `bins_ref`, widened as `bins32` in the u8 layout)
+    against the rows' slot-folded grad/hess."""
+    i32, f32 = jnp.int32, jnp.float32
+    bf16 = f32 if f32_dots else jnp.bfloat16
     w2 = w_ref[0:2 * K, :]                                   # (2K, T) f32
     w_hi, w_lo = _wsplit(w2)
 
@@ -419,6 +438,82 @@ def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
         hist_ref[...] += dot(oh, A_hi)
 
 
+def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
+                       newleaf_ref, *outs, T, G, B, S, L, GW,
+                       has_cat: bool, two_pass: bool = True,
+                       int_weights: bool = False, f32_dots: bool = False,
+                       u8_layout: bool = False, with_hist: bool = True,
+                       bin_buckets=None, m_rows: int = 0, K: int = 1,
+                       slot_out: bool = False):
+    """The pass over a table of one M-tile: route, count, contract, one grid
+    axis over the row blocks.  Without with_hist it only routes and counts;
+    with slot_out it then also writes each row's histogram slot (-1: none)
+    to a last (K, T) output, for _hist_tiles_kernel's sweeps."""
+    if slot_out:
+        *outs, slot_ref = outs
+    if with_hist:
+        hist_ref, cnt_ref = outs
+    else:
+        # route-only variant: no histogram output ref exists at all, so the
+        # (G*B, 2*S*K) VMEM-resident block is never allocated
+        hist_ref, (cnt_ref,) = None, outs
+    b = pl.program_id(0)
+    slots, bins32 = _route_rows(
+        bins_ref, leaf_ref, tabs_ref, bits_ref, newleaf_ref, T=T, B=B, L=L,
+        GW=GW, has_cat=has_cat, f32_dots=f32_dots, u8_layout=u8_layout, K=K)
+
+    # ---------------- histogram ----------------
+    @pl.when(b == 0)
+    def _():
+        if with_hist:
+            hist_ref[...] = jnp.zeros_like(hist_ref)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    s_iota, slot_ohs = _count_slots(cnt_ref, w_ref, slots, T=T, S=S, K=K,
+                                    f32_dots=f32_dots)
+    if slot_out:
+        for k in range(K):
+            slot_ref[k:k + 1, :] = slots[k]
+    if not with_hist:
+        # route-only round (a tree's LAST split round: the children's
+        # histograms would never be scanned, so the dominant one-hot
+        # contraction — and the whole VMEM-resident histogram block — is
+        # dropped)
+        return
+    if u8_layout and bins32 is None:
+        bins32 = bins_ref[...].astype(jnp.int32)
+    _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
+                     slot_ohs, T=T, G=G, B=B, S=S, two_pass=two_pass,
+                     int_weights=int_weights, f32_dots=f32_dots,
+                     u8_layout=u8_layout, bin_buckets=bin_buckets,
+                     m_rows=m_rows, K=K)
+
+
+def _hist_tiles_kernel(bins_ref, slot_ref, w_ref, hist_ref, *, T, Gt, B, S,
+                       two_pass, int_weights, f32_dots, u8_layout, K):
+    """The contraction of a table of several M-tiles: grid (tile j, row
+    block b), rows innermost, so tile j's (Gt*B, 2*S*K) histogram block
+    stays in VMEM over its whole sweep of the rows.  `bins_ref` holds tile
+    j's groups only; every sweep contracts against the same slots, which the
+    route-only pass before it wrote."""
+    i32, f32 = jnp.int32, jnp.float32
+    bf16 = f32 if f32_dots else jnp.bfloat16
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        hist_ref[...] = jnp.zeros_like(hist_ref)
+
+    slots = [slot_ref[k:k + 1, :] for k in range(K)]
+    s_iota = jax.lax.broadcasted_iota(i32, (S, T), 0)
+    slot_ohs = [(s_iota == slot).astype(bf16) for slot in slots]
+    bins32 = bins_ref[...].astype(i32) if u8_layout else None    # (Gt, T)
+    _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
+                     slot_ohs, T=T, G=Gt, B=B, S=S, two_pass=two_pass,
+                     int_weights=int_weights, f32_dots=f32_dots,
+                     u8_layout=u8_layout, bin_buckets=None, m_rows=Gt * B,
+                     K=K)
+
+
 # The ROOT pass has one slot: every row is in leaf 0 and nothing is routed.
 # The one-hot formulation above would contract its (M, T) bin one-hot against
 # a 2-column operand that the MXU pads to a 128-column tile — a full pass's
@@ -457,10 +552,11 @@ def root_pass_kind(bins_dtype, int_weights: bool, num_class: int = 1) -> str:
             and num_class == 1 else "onehot")
 
 
-def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots):
+def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots,
+                      row_axis=0):
     i32 = jnp.int32
 
-    @pl.when(pl.program_id(0) == 0)
+    @pl.when(pl.program_id(row_axis) == 0)
     def _():
         hist_ref[...] = jnp.zeros_like(hist_ref)
 
@@ -509,29 +605,38 @@ def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots):
 
 
 def _root_hist_factored(bins_T, w_T, bmax: int, num_groups: int,
-                        block_rows: int):
+                        block_rows: int, tile_groups: int = 0):
     """(1, G, bmax, 2) int32 root histogram of integer-valued grad/hess rows
     over u8-layout bins: what route_and_hist(..., num_slots=1) returns for
     rows that all sit in one leaf, by the factored contraction.  Rows
-    past the data carry zero weights and add nothing, as in every pass."""
+    past the data carry zero weights and add nothing, as in every pass.
+    With tile_groups the groups go `tile_groups` a sweep of the rows (grid
+    (tile, row block)), each sweep with its own resident block."""
     GW, n_pad = bins_T.shape
     T, G, GF = block_rows, num_groups, ROOT_GF
     H = -(-bmax // 8)
     HP = H + (H & 1)          # digits go two a word array
-    NG = -(-G // GF)
     R = 2 * HP * GF
+    # one tile: grid (row block,), the whole table's groups a block.  Tiled:
+    # grid (tile, row block), a tile's groups and its own result rows
+    tiles = GW // tile_groups if tile_groups else 1
+    NGt = (tile_groups or -(-G // GF) * GF) // GF       # 16-groups a tile
+    NG = tiles * NGt
+    at = (lambda f: lambda j, b: f(j, b)) if tile_groups \
+        else (lambda f: lambda b: f(0, b))
     out = pl.pallas_call(
-        functools.partial(_root_hist_kernel, T=T, NG=NG, HP=HP,
-                          f32_dots=pallas_interpret()),
-        grid=(n_pad // T,),
+        functools.partial(_root_hist_kernel, T=T, NG=NGt, HP=HP,
+                          f32_dots=pallas_interpret(),
+                          row_axis=1 if tile_groups else 0),
+        grid=(tiles, n_pad // T) if tile_groups else (n_pad // T,),
         in_specs=[
-            pl.BlockSpec((GW, T), lambda b: (0, b)),
-            pl.BlockSpec((w_T.shape[0], T), lambda b: (0, b)),
+            pl.BlockSpec((tile_groups or GW, T), at(lambda j, b: (j, b))),
+            pl.BlockSpec((w_T.shape[0], T), at(lambda j, b: (0, b))),
         ],
-        out_specs=pl.BlockSpec((NG * R, 8 * GF), lambda b: (0, 0)),
+        out_specs=pl.BlockSpec((NGt * R, 8 * GF), at(lambda j, b: (j, 0))),
         out_shape=jax.ShapeDtypeStruct((NG * R, 8 * GF), jnp.int32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",) * (2 if tile_groups else 1)),
         interpret=pallas_interpret(),
     )(bins_T, w_T)
     # rows (group, c, hi, f), columns (lo, f'): keep f == f'
@@ -544,9 +649,15 @@ def _root_hist_factored(bins_T, w_T, bmax: int, num_groups: int,
 
 # Mosaic's scoped-VMEM limit for one kernel on this compiler (jax 0.9.0,
 # libtpu 0.0.34, TPU v5e): kernel-internal temporaries past 16 MiB fail to
-# compile with "Scoped allocation with size ... and limit 16.00M".  No
-# pallas_call here raises it (no vmem_limit_bytes), so this is the limit.
+# compile with "Scoped allocation with size ... and limit 16.00M".  The
+# one-tile kernels do not raise it (no vmem_limit_bytes), so this is the limit
 SCOPED_VMEM_LIMIT = 16 * 2 ** 20
+# ... but _hist_tiles_kernel's: its histogram block changes with the tile, so
+# the pipeline holds two of it (the one-tile kernel's never moves and is held
+# once), 4 MiB more than the same tile costs there: "size 16.50M" for 128
+# groups of 64 bins at T = 1024 by the same AOT compile.  A quarter of a
+# v5e core's 128 MiB.
+TILES_VMEM_LIMIT = 32 * 2 ** 20
 
 
 def stream_vmem_estimate(m_rows: int, block_rows: int, int_hist: bool,
@@ -574,15 +685,52 @@ def stream_vmem_estimate(m_rows: int, block_rows: int, int_hist: bool,
             + 640 * block_rows + 2 ** 18)
 
 
-def stream_block_rows(bmax: int, num_groups: int = 28,
-                      int_hist: bool = False,
-                      bin_buckets=None, hist_channels: int = 0) -> int:
-    """Rows per kernel block, sized so the (G*B, T) one-hot operand stays
-    within ~8 MB of VMEM: int8 one-hots (quantized-gradient path) take
-    4096-row blocks (measured ~3% faster than 2048 end to end), bf16
+class StreamTiling(NamedTuple):
+    """How route_and_hist cuts a table: `block_rows` rows a grid step, and
+    the one-hot M-axis in `num_tiles` tiles of `tile_groups` whole groups
+    (`tile_m_rows` one-hot rows each), one sweep of the rows a tile.
+    tile_groups == 0 says the whole table is one tile."""
+    block_rows: int
+    tile_groups: int
+    num_tiles: int
+    tile_m_rows: int
+
+
+def _tile_fits(m_rows: int, T: int, int_hist: bool, hist_channels: int):
+    """Whether a (m_rows, T) one-hot with its (m_rows, C) histogram block is
+    a tile the kernel takes: inside the one-hot budget, and the estimate of
+    its scoped VMEM under the compiler's limit."""
+    # int8 one-hots get a 9 MB budget: at MSLR shapes (G=136, B=64) that
+    # admits T=1024 (8.9 MB one-hot + 4.45 MB hist block still compiles),
+    # measured 3% faster end-to-end than the T=512 the 8 MB budget forces.
+    budget = (9 if int_hist else 8) * 2 ** 20
+    if hist_channels:
+        # the (m_rows, C) histogram block stays VMEM-resident across the
+        # whole grid; the binary path's C=2S block was small enough to
+        # ignore, the K-widened block is not
+        budget -= max(0, m_rows * hist_channels * 4 - 2 * 2 ** 20)
+    return (m_rows * T * (1 if int_hist else 2) <= budget
+            and stream_vmem_estimate(m_rows, T, int_hist, hist_channels)
+            <= SCOPED_VMEM_LIMIT)
+
+
+def stream_tiling(bmax: int, num_groups: int = 28, int_hist: bool = False,
+                  bin_buckets=None, hist_channels: int = 0) -> StreamTiling:
+    """Rows per kernel block and groups per M-tile.
+
+    A table whose whole (G*B, T) one-hot fits at some block size is ONE
+    tile, at the largest such size: int8 one-hots (quantized-gradient path)
+    take 4096-row blocks (measured ~3% faster than 2048 end to end), bf16
     one-hots 2048 (4096 at bf16 REGRESSES 5x — VMEM pressure kills the
-    pipeline). Wide layouts (many EFB groups, e.g. high-dimensional sparse
-    data) step down to 512/256-row blocks.  No tier is taken whose
+    pipeline, and small bucketed m_rows would otherwise re-admit it), wider
+    layouts step down to 256, the last resort of a table over the one-hot
+    budget.  A table over the compiler's limit even there is cut
+    into tiles of whole groups (a multiple of 32, the int8 sublane tiling;
+    as even as that allows), at the first block size of 1024, 512, 256 at
+    which 32 groups fit: 128 groups of 64 bins at T = 1024 for int8
+    one-hots, the shape the 136-group table runs at in one tile.  Tiles
+    take the uniform (G*B) axis: `bin_buckets` counts only while its
+    bucketed sum makes the table one tile.  No tile is taken whose
     stream_vmem_estimate exceeds SCOPED_VMEM_LIMIT.
 
     hist_channels: column count of the VMEM-resident histogram block
@@ -590,53 +738,67 @@ def stream_block_rows(bmax: int, num_groups: int = 28,
     footprint is charged against the one-hot budget, so the widened
     K-channel program steps the block size down instead of blowing VMEM.
 
-    LGBTPU_BLOCK_ROWS overrides the choice; a value the kernel cannot run
-    (not a lane multiple, or over the VMEM limit on the chip) is an error
-    here, before the compiler says it less clearly."""
+    Off the chip (interpreted kernels) the block is 1024 rows, to keep the
+    dots narrow for XLA:CPU; the tiles are the chip's.
+
+    LGBTPU_BLOCK_ROWS overrides the block size; a value the kernel cannot
+    run (not a lane multiple, or over the VMEM limit on the chip) is an
+    error here, before the compiler says it less clearly."""
     B = -(-bmax // 8) * 8
     if bin_buckets is not None:
         m_rows = -(-sum(bucket_run_rows(bk, gk)
                         for bk, gk in bin_buckets) // 128) * 128
     else:
         m_rows = num_groups * B
-    env = _os.environ.get("LGBTPU_BLOCK_ROWS")
-    if env:
-        if not env.isdigit() or int(env) <= 0 or int(env) % 128:
-            raise LightGBMError(
-                f"LGBTPU_BLOCK_ROWS={env!r} must be a positive multiple of "
-                "128 (the TPU lane width)")
-        T = int(env)
-        est = stream_vmem_estimate(m_rows, T, int_hist, hist_channels)
-        if on_tpu() and est > SCOPED_VMEM_LIMIT:
-            raise LightGBMError(
-                f"LGBTPU_BLOCK_ROWS={T} needs about {est / 2 ** 20:.1f} MiB "
-                f"of scoped VMEM for a ({m_rows}, {T}) "
-                f"{'int8' if int_hist else 'bf16'} one-hot; the limit is "
-                f"{SCOPED_VMEM_LIMIT // 2 ** 20} MiB — use a smaller block")
-        return T
-    if not on_tpu():
-        # CPU interpret mode: keep dots narrow for XLA:CPU
-        return 1024
-    oh_bytes = 1 if int_hist else 2
-    # int8 one-hots get a 9 MB budget: at MSLR shapes (G=136, B=64) that
-    # admits T=1024 (8.9 MB one-hot + 4.45 MB hist block still compiles),
-    # measured 3% faster end-to-end than the T=512 the 8 MB budget forces.
-    # bf16 is hard-capped at 2048: T=4096 at bf16 REGRESSED 5x even when
-    # the one-hot fit the budget (VMEM pressure kills the pipeline), and
-    # small bucketed m_rows would otherwise re-admit it
-    budget = (9 if int_hist else 8) * 2 ** 20
-    if hist_channels:
-        # the (m_rows, C) histogram block stays VMEM-resident across the
-        # whole grid; the binary path's C=2S block was small enough to
-        # ignore, the K-widened block is not
-        budget -= max(0, m_rows * hist_channels * 4 - 2 * 2 ** 20)
     tiers = (4096, 2048, 1024, 512, 256) if int_hist \
         else (2048, 1024, 512, 256)
-    for T in tiers:
-        if m_rows * T * oh_bytes <= budget and stream_vmem_estimate(
-                m_rows, T, int_hist, hist_channels) <= SCOPED_VMEM_LIMIT:
-            return T
-    return 256
+    whole = next((T for T in tiers
+                  if _tile_fits(m_rows, T, int_hist, hist_channels)), None)
+    if whole is None and stream_vmem_estimate(
+            m_rows, 256, int_hist, hist_channels) <= SCOPED_VMEM_LIMIT:
+        whole = 256     # over the one-hot budget, under the compiler's limit
+    env = _os.environ.get("LGBTPU_BLOCK_ROWS")
+    if env and (not env.isdigit() or int(env) <= 0 or int(env) % 128):
+        raise LightGBMError(
+            f"LGBTPU_BLOCK_ROWS={env!r} must be a positive multiple of "
+            "128 (the TPU lane width)")
+    if whole is not None:
+        if env:
+            T = int(env)
+            est = stream_vmem_estimate(m_rows, T, int_hist, hist_channels)
+            if on_tpu() and est > SCOPED_VMEM_LIMIT:
+                raise LightGBMError(
+                    f"LGBTPU_BLOCK_ROWS={T} needs about "
+                    f"{est / 2 ** 20:.1f} MiB of scoped VMEM for a "
+                    f"({m_rows}, {T}) {'int8' if int_hist else 'bf16'} "
+                    f"one-hot; the limit is "
+                    f"{SCOPED_VMEM_LIMIT // 2 ** 20} MiB — use a smaller "
+                    "block")
+            return StreamTiling(T, 0, 1, m_rows)
+        # CPU interpret mode: keep dots narrow for XLA:CPU
+        return StreamTiling(whole if on_tpu() else 1024, 0, 1, m_rows)
+    for T in ((int(env),) if env else (1024, 512, 256)):
+        most = max((g for g in range(32, num_groups + 32, 32)
+                    if _tile_fits(g * B, T, int_hist, hist_channels)),
+                   default=0)
+        if most:
+            tiles = -(-num_groups // most)
+            groups = -(-(-(-num_groups // tiles)) // 32) * 32
+            return StreamTiling(T, groups, tiles, groups * B)
+    raise LightGBMError(
+        f"no 32 groups of {B} bins fit the stream kernel's VMEM at "
+        f"{'LGBTPU_BLOCK_ROWS=' + env if env else 'any block size'} "
+        f"(histogram block of {hist_channels or 128} columns); use "
+        "hist_backend=segsum or onehot")
+
+
+def stream_block_rows(bmax: int, num_groups: int = 28,
+                      int_hist: bool = False,
+                      bin_buckets=None, hist_channels: int = 0) -> int:
+    """Rows per kernel block: stream_tiling()'s, for the callers that run
+    one tile (a table wider than that takes the tiling as a whole)."""
+    return stream_tiling(bmax, num_groups, int_hist, bin_buckets,
+                         hist_channels).block_rows
 
 
 class StreamLayout(NamedTuple):
@@ -656,35 +818,86 @@ def _use_u8_layout(max_bin_value: int = 127) -> bool:
 
 
 def pack_bins_T(bins: jax.Array, block_rows: int = 1024,
-                max_bins: int = 256) -> StreamLayout:
+                max_bins: int = 256, tile_groups: int = 0) -> StreamLayout:
     """(N, G) uint8 -> transposed (GW_pad, N_pad) i32 packed layout, or the
     (G_pad, N_pad) i8 unpacked layout when bins fit int8 (the kernel
-    dispatches on the dtype)."""
+    dispatches on the dtype).  With tile_groups (stream_tiling's, a
+    multiple of 32) the groups pad to whole tiles; padded groups hold bin 0
+    and their histogram rows are dropped.  A NumPy table is packed in NumPy
+    and comes back as NumPy (predict's batches: the device is then handed
+    the words and nothing else); a device table is packed on the device."""
+    xp = np if isinstance(bins, np.ndarray) else jnp
     n, g = bins.shape
     n_pad = -(-n // block_rows) * block_rows
+    per = tile_groups or 32            # i8 tiling: 32-sublane multiples
     if max_bins <= 127 and _use_u8_layout():
-        g_pad = -(-g // 32) * 32           # i8 tiling: 32-sublane multiples
-        w = jnp.pad(bins, ((0, n_pad - n), (0, g_pad - g))).astype(jnp.int8)
+        g_pad = -(-g // per) * per
+        w = xp.pad(bins, ((0, n_pad - n), (0, g_pad - g))).astype(xp.int8)
         return StreamLayout(bins_T=w.T, n_pad=n_pad, num_groups=g)
-    gw = -(-g // 4)
-    gw_pad = -(-gw // 8) * 8
-    w = jnp.pad(bins, ((0, n_pad - n), (0, gw_pad * 4 - g))).astype(jnp.int32)
+    gw_pad = -(-g // per) * per // 4   # 4 groups a word, 8-sublane multiples
+    w = xp.pad(bins, ((0, n_pad - n), (0, gw_pad * 4 - g))).astype(xp.int32)
     w = w.reshape(n_pad, gw_pad, 4)
     packed = (w[..., 0] | (w[..., 1] << 8) | (w[..., 2] << 16) | (w[..., 3] << 24))
     return StreamLayout(bins_T=packed.T, n_pad=n_pad, num_groups=g)
+
+
+def _route_and_hist_tiled(bins_T, slot, w_T, num_slots, bmax, num_groups,
+                          block_rows, two_pass, int_weights, num_class,
+                          tile_groups):
+    """The histogram half of route_and_hist over several M-tiles, of rows to
+    which the route-only pass has given slots (`slot`)."""
+    S, G, K, T, Gt = num_slots, num_groups, num_class, block_rows, tile_groups
+    if _ABLATE:
+        raise ValueError("LGBTPU_KABLATE probes require one M-tile")
+    u8_layout = bins_T.dtype == jnp.int8
+    per_row = 1 if u8_layout else 4       # groups a row of bins_T
+    GW, n_pad = bins_T.shape
+    tiles = GW * per_row // Gt
+    if Gt % 32 or tiles * Gt != GW * per_row or tiles * Gt < G:
+        raise ValueError(f"bins_T of {GW * per_row} groups is not packed to "
+                         f"tiles of {Gt} groups (pack_bins_T(tile_groups=))")
+    B = -(-bmax // 8) * 8
+    hist_dtype = jnp.int32 if int_weights else jnp.float32
+    hist = pl.pallas_call(
+        functools.partial(_hist_tiles_kernel, T=T, Gt=Gt, B=B, S=S,
+                          two_pass=two_pass, int_weights=int_weights,
+                          f32_dots=pallas_interpret(), u8_layout=u8_layout,
+                          K=K),
+        grid=(tiles, n_pad // T),
+        in_specs=[
+            pl.BlockSpec((Gt // per_row, T), lambda j, b: (j, b)),
+            pl.BlockSpec((K, T), lambda j, b: (0, b)),
+            pl.BlockSpec((w_T.shape[0], T), lambda j, b: (0, b)),
+        ],
+        out_specs=pl.BlockSpec((None, Gt * B, 2 * S * K),
+                               lambda j, b: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((tiles, Gt * B, 2 * S * K),
+                                       hist_dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=TILES_VMEM_LIMIT),
+        interpret=pallas_interpret(),
+    )(bins_T, slot, w_T)
+    # (tile, b, g in tile) rows -> (K, S, G, Bmax, 2); int histograms are
+    # unscaled by the caller
+    hist4 = hist.reshape(tiles, B, Gt, K, 2, S).transpose(3, 5, 0, 2, 1, 4)
+    hist4 = hist4.reshape(K, S, tiles * Gt, B, 2)[:, :, :G, :bmax, :]
+    return hist4[0] if K == 1 else hist4
 
 
 @functools.partial(watched_jit, name="route_and_hist", warn_after=0,
                    static_argnames=("num_slots", "bmax", "num_groups",
                                     "num_leaves", "block_rows", "has_cat",
                                     "two_pass", "int_weights", "with_hist",
-                                    "bin_buckets", "num_class", "root"))
+                                    "bin_buckets", "num_class", "root",
+                                    "tile_groups"))
 def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
                    tabs: jax.Array, bits: jax.Array, num_slots: int, bmax: int,
                    num_groups: int, num_leaves: int, block_rows: int = 1024,
                    has_cat: bool = True, two_pass: bool = True,
                    int_weights: bool = False, with_hist: bool = True,
-                   bin_buckets=None, num_class: int = 1, root: bool = False):
+                   bin_buckets=None, num_class: int = 1, root: bool = False,
+                   tile_groups: int = 0):
     """One fused streaming pass: route rows through this round's splits and
     build grad/hess histograms and exact data counts of the rows' NEW slots.
 
@@ -707,12 +920,27 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     `tabs` splits nothing (the grower's root pass, num_slots == 1): leaf ids
     come back as they went in, and where root_pass_kind() says so the
     histogram is built by the factored contraction instead.
+
+    tile_groups (stream_tiling's, with bins_T packed to it) cuts the one-hot
+    M-axis into tiles of that many groups where the whole does not fit VMEM:
+    the route-only pass (which has no M-axis) routes the rows once and
+    writes their slots, then one pallas_call whose grid is (tile, row block)
+    contracts: a tile's histogram block stays in VMEM over its sweep of the
+    rows, reads only its own groups' rows of bins_T, and every sweep sees the
+    same slots.  0 is one tile: the one fused kernel.
     """
     if (root and num_slots == 1 and with_hist and root_pass_kind(
             bins_T.dtype, int_weights, num_class) == "factored"):
-        hist = _root_hist_factored(bins_T, w_T, bmax, num_groups, block_rows)
+        hist = _root_hist_factored(bins_T, w_T, bmax, num_groups, block_rows,
+                                   tile_groups)
         # the slot's count is the caller's own row count; no grower reads it
         return leaf_id, hist, jnp.sum(w_T[2]).reshape(1)
+    if tile_groups and bin_buckets is not None:
+        raise ValueError("M-tiles take the uniform one-hot axis: no "
+                         "bin_buckets with tile_groups")
+    tiled = with_hist and tile_groups > 0
+    if tiled:
+        with_hist = False      # this call routes and counts; the tiles follow
     GW, n_pad = bins_T.shape
     T = block_rows
     NB = n_pad // T
@@ -749,12 +977,16 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     ]
     if not with_hist:
         del out_specs[1], out_shape[1]
+    if tiled:
+        out_specs.append(out_specs[0])
+        out_shape.append(out_shape[0])
     outs = pl.pallas_call(
         functools.partial(_route_hist_kernel, T=T, G=G, B=B, S=S, L=L, GW=GW,
                           has_cat=has_cat, two_pass=two_pass,
                           int_weights=int_weights, f32_dots=pallas_interpret(),
                           u8_layout=u8_layout, with_hist=with_hist,
-                          bin_buckets=bin_buckets, m_rows=m_rows, K=K),
+                          bin_buckets=bin_buckets, m_rows=m_rows, K=K,
+                          slot_out=tiled),
         grid=(NB,),
         in_specs=[
             pl.BlockSpec((GW, T), lambda b: (0, b)),
@@ -773,6 +1005,12 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     def _cnt_out(cnt):
         return cnt.reshape(-1) if K == 1 else cnt.reshape(K, S)
 
+    if tiled:
+        new_leaf, cnt, slot = outs
+        hist4 = _route_and_hist_tiled(
+            bins_T, slot, w_T, S, bmax, G, T, two_pass, int_weights, K,
+            tile_groups)
+        return new_leaf, hist4, _cnt_out(cnt)
     if not with_hist:
         new_leaf, cnt = outs
         shape4 = (S, G, bmax, 2) if K == 1 else (K, S, G, bmax, 2)
@@ -820,7 +1058,9 @@ def _route_replay_kernel(nr_ref, bins_ref, tabs_ref, newleaf_ref, *,
     i32, f32 = jnp.int32, jnp.float32
     bf16 = f32 if f32_dots else jnp.bfloat16
     l_iota = jax.lax.broadcasted_iota(i32, (L, T), 0)
-    bins32 = bins_ref[...].astype(i32) if u8_layout else None
+    bins32 = (bins_ref[...].astype(i32)       # as _route_rows widens them
+              if u8_layout and bins_ref.shape[0] <= WIDE_ROUTE_GROUPS
+              else None)
     n_rounds = nr_ref[0]
 
     def step(r, lid):

@@ -172,3 +172,39 @@ def test_device_predict_early_stop_multiclass_stays_host():
     p = bst.predict(X, pred_early_stop=True, pred_early_stop_freq=2,
                     pred_early_stop_margin=0.5)
     assert p.shape == (600, 3)
+
+
+@pytest.mark.parametrize("rows, groups", [(77, 5), (1025, 28), (1024, 136),
+                                          (3000, 2000)])
+def test_host_pack_gives_the_device_packs_words(rows, groups):
+    """predict packs its batch's words on the host: pack_bins_T, handed a
+    NumPy table, gives NumPy and the same (GW_pad, N_pad) int32 words as it
+    packs on the device, every bin value a uint8 holds, ragged rows and
+    groups."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.pallas.stream_kernel import pack_bins_T
+    bins = np.random.RandomState(rows).randint(
+        0, 256, (rows, groups)).astype(np.uint8)
+    got = pack_bins_T(bins).bins_T
+    want = pack_bins_T(jnp.asarray(bins)).bins_T
+    assert isinstance(got, np.ndarray) and got.dtype == np.int32
+    assert not isinstance(want, np.ndarray)
+    np.testing.assert_array_equal(got, np.asarray(want))
+
+
+def test_group_wider_than_a_byte_walks_on_the_host():
+    """The kernel's words hold four 8-bit bins: a model whose groups hold
+    more than 256 bins leaves the device path and says why, and the host
+    walk's answer is the one predict returns."""
+    rng = np.random.RandomState(11)
+    X = rng.randn(800, 3)
+    y = (X[:, 0] + 0.3 * X[:, 1] > 0).astype(float)
+    bst = lgb.train({"objective": "binary", "num_leaves": 7, "max_bin": 400,
+                     "min_data_in_bin": 1, "verbosity": -1},
+                    lgb.Dataset(X, label=y), num_boost_round=4)
+    assert np.asarray(bst.engine.train_data.binned.bins).dtype != np.uint8
+    assert bst._try_device_predict(X, bst._all_trees(), 1) is None
+    assert bst.last_predict_path == \
+        "host (a feature group of more than 256 bins)"
+    p = bst.predict(X, raw_score=True)
+    assert p.shape == (800,) and np.isfinite(p).all()

@@ -253,6 +253,41 @@ def test_factored_root_exact(case):
     np.testing.assert_array_equal(np.asarray(lid_new), 0)
 
 
+def test_factored_root_exact_over_sixteen_tiles():
+    """G = 2,000 at the benchmark's wide cell's tile shape: the factored
+    root a tile a sweep, against the tiled S = 1 one-hot root and
+    _hist_segsum, every sum exact; the last tile holds 80 groups of 128."""
+    from lightgbm_tpu.pallas.stream_kernel import stream_tiling
+    rs = np.random.RandomState(29)
+    N, G, Bmax, L = 1024, 2000, 63, 8
+    plan = stream_tiling(Bmax, G, True)
+    assert (plan.tile_groups, plan.num_tiles) == (128, 16)
+    bins = rs.randint(0, Bmax, (N, G)).astype(np.uint8)
+    gi = rs.randint(-32, 33, N).astype(np.float32)
+    hi = rs.randint(0, 33, N).astype(np.float32)
+    bins_T = pack_bins_T(jnp.asarray(bins), plan.block_rows, max_bins=Bmax,
+                         tile_groups=plan.tile_groups).bins_T
+    assert bins_T.shape == (2048, N) and bins_T.dtype == jnp.int8
+    w_T = (jnp.zeros((8, N), jnp.float32).at[0].set(jnp.asarray(gi))
+              .at[1].set(jnp.asarray(hi)).at[2].set(1.0))
+    tabs = jnp.zeros((NUM_TAB, L), jnp.float32).at[T_SLOT_KEEP, 0].set(1.0)
+    bits = jnp.zeros((64, L), jnp.bfloat16)
+    args = (bins_T, jnp.zeros((1, N), jnp.int32), w_T, tabs, bits, 1, Bmax,
+            G, L)
+    kw = dict(block_rows=plan.block_rows, has_cat=False, int_weights=True,
+              tile_groups=plan.tile_groups)
+    _, hist, _ = route_and_hist(*args, root=True, **kw)
+    _, hist_onehot, cnt = route_and_hist(*args, **kw)
+    ref = _hist_segsum(jnp.asarray(bins), jnp.zeros(N, jnp.int32),
+                       jnp.asarray(gi), jnp.asarray(hi),
+                       jnp.ones(N, jnp.float32), 1, Bmax)
+    assert hist.shape == hist_onehot.shape == (1, G, Bmax, 2)
+    np.testing.assert_array_equal(np.asarray(hist), np.asarray(hist_onehot))
+    np.testing.assert_array_equal(np.asarray(hist, np.float64),
+                                  np.asarray(ref[..., :2], np.float64))
+    assert float(cnt[0]) == N
+
+
 def test_root_flag_leaves_the_other_paths_alone():
     """root=True changes nothing where the factored form does not engage:
     float weights, the packed-word layout, a multiclass program."""

@@ -414,13 +414,19 @@ def test_no_duplicate_handlers_on_reimport():
     import logging
     shared = logging.getLogger("lightgbm_tpu")
     before = list(shared.handlers)
-    importlib.reload(logmod)     # simulates a second import of the module
-    assert shared.handlers == before
-    # a pre-configured level must survive re-import untouched
+    # a reload defines LightGBMError anew; modules that import it at call
+    # time would then raise a class that `lgb.LightGBMError` (bound at the
+    # first import) no longer names, and a later test file on this worker
+    # would miss it in pytest.raises: put the first class back
+    error_class = logmod.LightGBMError
     old_level = shared.level
     try:
+        importlib.reload(logmod)     # simulates a second import of the module
+        assert shared.handlers == before
+        # a pre-configured level must survive re-import untouched
         shared.setLevel(logging.ERROR)
         importlib.reload(logmod)
         assert shared.level == logging.ERROR
     finally:
         shared.setLevel(old_level)
+        logmod.LightGBMError = error_class

@@ -81,6 +81,15 @@ def _multiclass_k10():
             X, rs.randint(0, 10, len(X)).astype(np.float64), {})
 
 
+def _epsilon_like():
+    """benchmark cell epsilon_train: 2,000 dense columns, 63 bins, 255
+    leaves, quantized — sixteen M-tiles of 128 groups."""
+    X, rs = _rows(4096, 2000, 3)
+    return ({"objective": "binary", "num_leaves": 255, "max_bin": 63,
+             "use_quantized_grad": True, "num_grad_quant_bins": 64},
+            X, (rs.rand(len(X)) < 0.5).astype(np.float64), {})
+
+
 def _lower_iteration(sharding, params, X, y, ds_kw):
     """Build the Booster as on the chip and lower — for the topology's TPU —
     the program its first ``update()`` would launch (the fused iteration),
@@ -114,14 +123,15 @@ def _lower_iteration(sharding, params, X, y, ds_kw):
 
 @pytest.fixture(scope="module")
 def iterations(tpu):
-    """The three iteration programs ``python bench.py`` runs, at their full
-    widths and small N: lowered one after another (tracing holds the GIL),
-    compiled side by side (XLA does not)."""
+    """The three iteration programs ``python bench.py`` runs and the wide
+    benchmark cell's, at their full widths and small N: lowered one after
+    another (tracing holds the GIL), compiled side by side (XLA does not)."""
     from concurrent.futures import ThreadPoolExecutor
     lowered = {name: _lower_iteration(tpu, *make())
                for name, make in (("higgs", _higgs_like),
                                   ("mslr", _mslr_like),
-                                  ("multiclass", _multiclass_k10))}
+                                  ("multiclass", _multiclass_k10),
+                                  ("epsilon", _epsilon_like))}
     with ThreadPoolExecutor(len(lowered)) as pool:
         texts = {name: pool.submit(lambda lo=lo: lo.compile().as_text())
                  for name, (_, lo) in lowered.items()}
@@ -141,6 +151,52 @@ def test_mslr_like_lambdarank_iteration_compiles(iterations):
     assert "tpu_custom_call" in text
     assert eng._grow_params.int_hist and eng._pack_block == 1024
     assert eng.dd.num_groups == 136
+
+
+def test_epsilon_like_iteration_compiles(iterations):
+    """hist_backend=auto resolves `stream` at G = 2,000 on a TPU and the
+    fused iteration compiles: the route-only pass, the tiles' sweeps and the
+    tiled factored root, each a `route_and_hist` call of its own result type."""
+    import re
+    eng, text = iterations["epsilon"]
+    assert "tpu_custom_call" in text
+    assert eng._grow_params.int_hist and eng._grow_params.bin_buckets is None
+    assert tuple(eng._stream_tiling) == (1024, 128, 16, 128 * 64)
+    assert eng._packed.shape[0] == 2048 and eng._root_pass == "factored"
+    calls = re.findall(r"^\s*%route_and_hist[.\d]* = (.*?) custom-call\(",
+                       text, re.M)
+    kinds = {re.sub(r"\{[^}]*\}", "", c) for c in calls}
+    n = eng._packed.shape[1]
+    assert kinds == {
+        # the route-only pass before a tiled pass: leaf ids, counts, slots
+        f"(s32[1,{n}], f32[1,64], s32[1,{n}])",
+        # the sweeps: one call, a histogram block a tile
+        "s32[16,8192,128]",
+        # the tiled factored root
+        "s32[32768,128]",
+        # a tree's last round routes and counts only
+        f"(s32[1,{n}], f32[1,128])",
+    }
+
+
+def test_route_replay_compiles_at_2000_groups(tpu):
+    """The fused route replay (GOSS / bagging with route_fusion, refit) over
+    a table too wide to widen whole: hist_backend=auto sends such a table to
+    the stream kernel too, so the replay must compile there."""
+    bins_T = jax.eval_shape(
+        lambda b: stream_kernel.pack_bins_T(b, 1024, max_bins=63).bins_T,
+        jax.ShapeDtypeStruct((4096, 2000), jnp.uint8))
+    assert bins_T.dtype == jnp.int8 \
+        and bins_T.shape[0] > stream_kernel.WIDE_ROUTE_GROUPS
+    rounds, L = 12, 256
+    args = _abstract((np.zeros(bins_T.shape, np.int8),
+                      np.zeros((rounds * stream_kernel.NUM_TAB, L),
+                               np.float32),
+                      np.zeros((), np.int32)), tpu)
+    text = stream_kernel.route_replay.lower(
+        *args, num_leaves=L, block_rows=1024,
+        rounds_buf=rounds).compile().as_text()
+    assert "tpu_custom_call" in text
 
 
 def test_multiclass_k10_iteration_compiles(iterations):
@@ -210,6 +266,13 @@ def test_over_limit_block_rows_rejected_before_the_compiler(tpu, monkeypatch):
     assert stream_kernel.stream_block_rows(63, 136, True) == 1024
     assert stream_kernel.stream_block_rows(63, 28, True) == 4096
     assert stream_kernel.stream_block_rows(63, 28, False) == 2048
+    # a table too wide for one tile at any block size is cut, not refused:
+    # 2,000 groups go 128 a tile at the 136-group table's block size
+    assert tuple(stream_kernel.stream_tiling(63, 2000, True)) \
+        == (1024, 128, 16, 8192)
+    assert tuple(stream_kernel.stream_tiling(63, 2000, False)) \
+        == (1024, 64, 32, 4096)
+    assert est(8192, 1024, True) <= limit < est(2000 * 64, 256, True)
     assert est(m, 512, False) <= limit < est(m, 512, False, hist_channels=256)
     assert est(m, 512, False) <= limit < est(m, 1024, False)
     assert est(28 * 64, 2048, False) <= limit < est(28 * 64, 4096, False)
@@ -221,6 +284,9 @@ def test_over_limit_block_rows_rejected_before_the_compiler(tpu, monkeypatch):
     with pytest.raises(LightGBMError, match="scoped VMEM.*limit is 16 MiB"):
         stream_kernel.stream_block_rows(63, 136, False)
     assert stream_kernel.stream_block_rows(63, 136, True) == 1024  # int8 fits
+    monkeypatch.setenv("LGBTPU_BLOCK_ROWS", "512")      # tiles follow it
+    assert tuple(stream_kernel.stream_tiling(63, 2000, True)) \
+        == (512, 224, 9, 224 * 64)
     monkeypatch.setenv("LGBTPU_BLOCK_ROWS", "1000")
     with pytest.raises(LightGBMError, match="multiple of 128"):
         stream_kernel.stream_block_rows(63, 28, True)
