@@ -19,6 +19,15 @@ so a cold and a warm run can be compared, not to be read as a benchmark.
 
     python chip_smoke.py                      # on the chip (through chiprun)
     CHIP_SMOKE_ROWS=10500000 python chip_smoke.py
+
+`--digest` is the identity run instead: the benchmark's three training
+configurations at their own shapes and reduced rows, 33 trees each, and as
+the last line the model text's digest and `hist_passes` at every flag poll,
+by configuration.  It imports the program from the WORKING DIRECTORY, so two
+checkouts are compared by running this one file from each; a change that
+keeps the trees prints the parent's line.
+
+    (cd scratch_src/parent && python ../../chip_smoke.py --digest)
 """
 import contextlib
 import gc
@@ -172,6 +181,67 @@ def kernel_exactness(dd, params):
           np.array_equal(np.asarray(new_leaf[0]), np.asarray(lid))
           and np.array_equal(np.asarray(scnt),
                              np.bincount(np.asarray(lid), minlength=S)))
+    small_pass_exactness("one tile", bins, slay.bins_T, dd.routing, gi, hi,
+                         kw, G, Bmax, L,
+                         [(0, 3, 24, 4, True), (2, 17, 40, 5, False)])
+
+
+def small_pass_exactness(tag, bins, bins_T, routing, gi, hi, kw, G, Bmax, L,
+                         splits):
+    """The small-slot pass (a round that splits one or two leaves) on the
+    chip: route_and_hist_live at k = 1 and k = 2 against the 64-slot pass on
+    the same rows and against NumPy alone (reference_hist: np.add.at, int64),
+    tolerance 0.  splits: (leaf, feature, threshold bin, new leaf, smaller
+    child is the left one) in slot order; rows sit in leaves 0..3, so some
+    are in no slot."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.pallas.stream_kernel import (build_route_tables,
+                                                   route_and_hist,
+                                                   route_and_hist_live)
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "benchmark"))
+    import reference_hist
+    N = bins_T.shape[1]
+    rs = np.random.RandomState(2)
+    lid = rs.randint(0, 4, N).astype(np.int32)
+    leaf = jnp.asarray(lid).reshape(1, -1)
+    bins_np = np.asarray(bins)
+    group_of = np.asarray(routing.feat_group)
+    w_T = (jnp.zeros((8, N), jnp.float32).at[0].set(gi).at[1].set(hi)
+           .at[2].set(1.0))
+    bits = jnp.zeros((-(-Bmax // 8) * 8, L), jnp.bfloat16)
+    for k in (1, 2):
+        cols = np.zeros((7, L), np.int32)   # chosen feat thr dir new sl1 sr1
+        want_leaf, slot = lid.copy(), np.full(N, -1, np.int64)
+        for s, (at, feat, thr, new, left) in enumerate(splits[:k]):
+            cols[:, at] = (1, feat, thr, 0, new, (s + 1) * left,
+                           (s + 1) * (not left))
+            right = (lid == at) & (bins_np[:, group_of[feat]] > thr)
+            want_leaf[right] = new
+            slot[(lid == at) & (right != left)] = s
+        tabs = build_route_tables(*(jnp.asarray(c) for c in cols),
+                                  jnp.zeros(L, jnp.int32), routing, L)
+        full = route_and_hist(bins_T, leaf, w_T, tabs, bits, 64, Bmax, G, L,
+                              **kw)
+        live = route_and_hist_live(jnp.int32(k), bins_T, leaf, w_T, tabs,
+                                   bits, 64, Bmax, G, L, **kw)
+        check(f"{tag} small-slot pass, k={k}: leaf ids, int32 histograms and "
+              "slot counts == the 64-slot pass's exactly",
+              all(a.shape == b.shape and a.dtype == b.dtype
+                  and np.array_equal(np.asarray(a), np.asarray(b))
+                  for a, b in zip(full, live)))
+        plain = reference_hist.histograms(
+            bins_np, slot, np.asarray(gi, np.int64), np.asarray(hi, np.int64),
+            k, Bmax)
+        check(f"{tag} small-slot pass, k={k}: rows routed as NumPy routes "
+              "them, histogram == NumPy reference exactly",
+              np.array_equal(np.asarray(live[0][0]), want_leaf)
+              and np.array_equal(np.asarray(live[1][:k], np.int64),
+                                 plain[..., :2])
+              and not np.asarray(live[1][k:]).any()
+              and np.array_equal(np.asarray(live[2][:k]),
+                                 plain[:, 0, :, 2].sum(1)),
+              f"{int((slot >= 0).sum())} of {N} rows in a slot")
 
 
 def wide_kernel_exactness():
@@ -272,9 +342,71 @@ def wide_kernel_exactness():
     # the int8 x int8 -> int32 contraction fails this by construction
     check("wide factored root: int32 histogram == NumPy reference exactly",
           np.array_equal(np.asarray(fact, np.int64), plain[..., :2]))
+    # the rounds of one and two splits, on features of the fourth, the
+    # eleventh and the last (ragged) tile: a lost tile shows here
+    small_pass_exactness("wide", bins, bins_T, dd.routing, gi, hi, kw, G,
+                         Bmax, L, [(0, 1300, 30, 4, True),
+                                   (2, 1999, 25, 5, False)])
+    small_pass_exactness("wide, first tiles", bins, bins_T, dd.routing, gi,
+                         hi, kw, G, Bmax, L, [(1, 400, 35, 6, False),
+                                              (3, 5, 28, 7, True)])
+
+
+DIGEST_ROWS = {"higgs_like": 2_000_000, "mslr_like": 600_000,
+               "epsilon_like": 60_000}
+DIGEST_TREES = 33              # polls at trees 16 and 32, and the last
+
+
+def digest():
+    """The identity run (the module docstring): no check of its own, the
+    caller compares two checkouts' last lines."""
+    import hashlib
+    import importlib.util
+    here = os.getcwd()
+    sys.path.insert(0, here)
+    import jax
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import telemetry
+    if jax.devices()[0].platform != "tpu":
+        print("chip_smoke --digest: needs a TPU", file=sys.stderr)
+        return 2
+    out = {"program": os.path.dirname(os.path.abspath(lgb.__file__))}
+    for name, rows in DIGEST_ROWS.items():
+        bench_dir = os.path.join(here, "benchmark")
+        with open(os.path.join(bench_dir, "configs", f"{name}.json")) as f:
+            config = json.load(f)
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(bench_dir, "generators",
+                               f"{config['generator']}.py"))
+        gen = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(gen)
+        data = gen.make(3000003100, rows, config["shape"])
+        params = dict(config["params"], verbosity=-1)
+        telemetry.reset_counters()
+        since = time.time_ns()
+        bst = lgb.train(params, lgb.Dataset(data["X"], label=data["y"],
+                                            group=data.get("sizes")),
+                        num_boost_round=DIGEST_TREES)
+        text = bst.model_to_string().split("\nparameters:")[0]
+        polls = [(r.args["iteration"], r.args["hist_passes"])
+                 for r in telemetry.recent_spans(name="GBDT::FlagPoll",
+                                                 since_unix_ns=since)
+                 if r.args and "hist_passes" in r.args]
+        out[name] = {"rows": rows, "trees": bst.num_trees(),
+                     "sha256": hashlib.sha256(text.encode()).hexdigest(),
+                     "polls": polls,
+                     "small_passes": getattr(
+                         telemetry, "hist_small_pass_count", lambda: None)()}
+        say(f"{name}: {out[name]}")
+        del bst, data
+        gc.collect()
+    print(json.dumps(out))
+    return 0
 
 
 def main():
+    if "--digest" in sys.argv[1:]:
+        return digest()
     try:
         import jax
     except ImportError as e:

@@ -405,7 +405,7 @@ class GBDT:
                                        mesh=vote_mesh, row_axis=vote_axis,
                                        compact_rows=compact_rows)
                 # the voting grower keeps no round histogram pass to count
-                return (out + (jnp.zeros((), jnp.int32),) if with_passes
+                return (out + (jnp.zeros(2, jnp.int32),) if with_passes
                         else out)
 
             # the voting fn replaces grow_tree as THE grow partial, so the
@@ -423,7 +423,7 @@ class GBDT:
         self._fused_last = False
         self._compact_overflow = False
         self._overflow_seen = 0
-        self._hist_passes_seen = 0
+        self._hist_passes_seen = 0, 0
         # batched device-flag fetch cadence: eval_fetch_freq, or auto —
         # 16 wherever the fused one-launch path is the default (TPU, any
         # row-sharded stream mesh: each blocking flag read costs a full
@@ -1713,10 +1713,11 @@ class GBDT:
             overflow=jnp.asarray(0, jnp.int32),
             finished=jnp.asarray(False),
             ok=jnp.asarray(True),
-            hist_passes=jnp.asarray(0, jnp.int32))
+            hist_passes=jnp.asarray(0, jnp.int32),
+            hist_small_passes=jnp.asarray(0, jnp.int32))
         self._train_state = st
         self._overflow_seen = 0
-        self._hist_passes_seen = 0
+        self._hist_passes_seen = 0, 0
         return st
 
     def _fused_compact_rows(self, sample_mode: str, mask_arg=None) -> int:
@@ -1907,7 +1908,8 @@ class GBDT:
                     score=new_score, grad=g, hess=h, leaf_id=leaf_id,
                     mask=mask, key=qkey, sampled=nc, overflow=over,
                     finished=fin, ok=ok,
-                    hist_passes=state.hist_passes + grown)
+                    hist_passes=state.hist_passes + grown[0],
+                    hist_small_passes=state.hist_small_passes + grown[1])
                 return new_state, arrays, new_obj
 
             out_sh = None
@@ -1963,27 +1965,30 @@ class GBDT:
         pending = self._nan_guard.take_pending()
         fetch = [self._finished_dev] + [ok for _, ok in pending]
         if st is not None:
-            fetch += [st.sampled, st.overflow, st.hist_passes]
-        from ..telemetry import (hist_pass_count, note_hist_passes,
-                                 note_host_sync)
+            fetch += [st.sampled, st.overflow, st.hist_passes,
+                      st.hist_small_passes]
+        from ..telemetry import (hist_pass_count, hist_small_pass_count,
+                                 note_hist_passes, note_host_sync)
         with _tel_tracer.boundary("GBDT::FlagPoll",
                                   iteration=self.iter_) as poll:
             got = jax.device_get(fetch)
             if st is not None:
                 # the device's count of histogram passes rides the fetch:
                 # publish what it grew since the last poll
-                passes = int(got[-1])
-                note_hist_passes(passes - self._hist_passes_seen, self.iter_)
-                self._hist_passes_seen = passes
+                passes, small = int(got[-2]), int(got[-1])
+                seen, seen_small = self._hist_passes_seen
+                note_hist_passes(passes - seen, self.iter_, small - seen_small)
+                self._hist_passes_seen = passes, small
                 poll.set(hist_passes=hist_pass_count(),
+                         hist_small_passes=hist_small_pass_count(),
                          **({"root_pass": self._root_pass}
                             if self._root_pass else {}),
                          **self._poll_tiling)
         note_host_sync()
         self._nan_guard.resolve(pending, got[1:1 + len(pending)])
         if st is not None:
-            self._last_sampled_rows = int(got[-3])
-            overflow = int(got[-2])
+            self._last_sampled_rows = int(got[-4])
+            overflow = int(got[-3])
             if overflow > getattr(self, "_overflow_seen", 0):
                 self._overflow_seen = overflow
                 if not getattr(self, "_compact_overflow", False):

@@ -184,6 +184,9 @@ class _GrowState(NamedTuple):
                                 # histogram: the root pass + every round body
                                 # run with_hist (the route-only sprint round
                                 # is not one)
+    hist_small_passes: jax.Array  # () i32 — those of hist_passes that took
+                                # the stream kernel's small-slot pass (a
+                                # round that split one or two leaves)
     best_gain: jax.Array
     best_feat: jax.Array
     best_thr: jax.Array
@@ -363,9 +366,10 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
               with_passes: bool = False,
               ) -> Tuple[TreeArrays, jax.Array]:
     """Grow one tree. Returns (TreeArrays, leaf_id[N]); with_passes=True
-    appends the () i32 count of histogram-building passes over the rows
-    this tree took (root pass + rounds; the fused iteration sums it into
-    its state for telemetry.hist_pass_count()).
+    appends the (2,) i32 counts of histogram-building passes over the rows
+    this tree took (root pass + rounds) and of those among them that took
+    the small-slot pass (the fused iteration sums them into its state for
+    telemetry.hist_pass_count() and the flag poll's record).
 
     grad/hess must already include any bagging mask; cnt_w is the mask itself.
     monotone: (F,) i32 in {-1,0,1} (reference: monotone_constraints.hpp, basic method).
@@ -581,8 +585,9 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
             fp_bin = make_sharded_bin_gather(mesh, feature_axis, fp_plan.gs)
     if use_stream:
         from ..pallas.stream_kernel import (NUM_TAB, build_route_tables,
-                                            pack_bins_T, route_and_hist,
-                                            route_replay, stream_tiling)
+                                            pack_bins_T, route_and_hist_live,
+                                            route_replay, small_pass_index,
+                                            stream_tiling)
         # rows a kernel block, and groups an M-tile where the table's
         # one-hot does not fit VMEM whole (0: one tile)
         T_rows, tile_groups = stream_tiling(
@@ -663,10 +668,10 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 D_rows = mesh.shape[row_axis]
 
             def _rh(bT, lid_row, wT, tb, bi, num_slots, with_hist=True,
-                    root=False):
-                def _local(bT, lid_row, wT, tb, bi):
-                    nl, h, c = route_and_hist(
-                        bT, lid_row, wT, tb, bi, num_slots, Bmax, G, L,
+                    root=False, live_slots=None):
+                def _local(bT, lid_row, wT, tb, bi, live=None):
+                    nl, h, c = route_and_hist_live(
+                        live, bT, lid_row, wT, tb, bi, num_slots, Bmax, G, L,
                         block_rows=T_rows, has_cat=params.has_categorical,
                         two_pass=params.hist_two_pass, int_weights=use_int,
                         with_hist=with_hist,
@@ -702,17 +707,21 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
 
                 hspec = (P(None, row_axis, None, None) if use_rs
                          else P(None, None, None, None))
+                # the round's split count is replicated: every device takes
+                # the same branch, and the psum follows as for any pass
+                live = () if live_slots is None else (live_slots,)
                 wrapped = shard_map_rows(
                     _local, mesh,
                     (P(None, row_axis), P(None, row_axis),
-                     P(None, row_axis), P(None, None), P(None, None)),
+                     P(None, row_axis), P(None, None), P(None, None))
+                    + (P(),) * len(live),
                     (P(None, row_axis), hspec, P(None)))
-                return wrapped(bT, lid_row, wT, tb, bi)
+                return wrapped(bT, lid_row, wT, tb, bi, *live)
         else:
             def _rh(bT, lid_row, wT, tb, bi, num_slots, with_hist=True,
-                    root=False):
-                return route_and_hist(
-                    bT, lid_row, wT, tb, bi, num_slots, Bmax, G, L,
+                    root=False, live_slots=None):
+                return route_and_hist_live(
+                    live_slots, bT, lid_row, wT, tb, bi, num_slots, Bmax, G, L,
                     block_rows=T_rows, has_cat=params.has_categorical,
                     two_pass=params.hist_two_pass, int_weights=use_int,
                     with_hist=with_hist, bin_buckets=params.bin_buckets,
@@ -846,6 +855,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         cegb_lazy=(cegb_lazy if use_lazy else jnp.zeros((1, 1), bool)),
         round_idx=jnp.asarray(0, i32),
         hist_passes=jnp.asarray(1, i32),
+        hist_small_passes=jnp.asarray(0, i32),
         best_gain=jnp.full(L, NEG_INF, hdt).at[0].set(root_split.gain[0]),
         best_feat=jnp.zeros(L, i32).at[0].set(root_split.feature[0]),
         best_thr=jnp.zeros(L, i32).at[0].set(root_split.threshold[0]),
@@ -1006,6 +1016,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
             leaf_thr = jnp.zeros(L, i32).at[old_idx].set(thr, mode="drop")
             leaf_dir = jnp.zeros(L, i32).at[old_idx].set(dirf, mode="drop")
             smaller_is_left = lc <= rc
+            took_small = 0
 
             if use_stream:
                 # fused route+hist streaming kernel: one sequential pass over rows
@@ -1021,10 +1032,16 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                     leaf_chosen.astype(i32), leaf_feat, leaf_thr, leaf_dir,
                     leaf_new_id, sl1, sr1, jnp.zeros(L, i32), routing, L)
                 lid_h = st.leaf_id_c if use_compact else st.leaf_id
+                # live slots are 0..k-1 (si1, pair_valid): a round of one
+                # or two takes the small-slot pass where there is one
+                live = k if with_hist else None
+                which = small_pass_index(live, bins_T_h.dtype, use_int, S)
+                if which is not None:
+                    took_small = (which > 0).astype(i32)
                 with jax.named_scope("route_and_hist"):
                     new_leaf_row, hist_small, slot_cnt = _rh(
                         bins_T_h, lid_h.reshape(1, -1), w_T_h, tabs,
-                        bits_l.T, S, with_hist=with_hist)
+                        bits_l.T, S, with_hist=with_hist, live_slots=live)
                 if use_int and with_hist:
                     hist_small = hist_small.astype(f32) * hscale
                 if use_compact and fuse:
@@ -1528,9 +1545,10 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 # feature_histogram.hpp:196; skipped leaves keep theirs)
                 st2 = st2._replace(adv_split_ok=jnp.where(
                     valid2[:, None], res.feat_ok, st2.adv_split_ok))
-            return st2._replace(num_leaves_cur=cur + k, progressed=k > 0,
-                                round_idx=st.round_idx + 1,
-                                hist_passes=st.hist_passes + 1)
+            return st2._replace(
+                num_leaves_cur=cur + k, progressed=k > 0,
+                round_idx=st.round_idx + 1, hist_passes=st.hist_passes + 1,
+                hist_small_passes=st.hist_small_passes + took_small)
 
         return body
 
@@ -1634,7 +1652,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     if use_lazy:
         out += (final.cegb_lazy,)
     if with_passes:
-        out += (final.hist_passes,)
+        out += (jnp.stack([final.hist_passes, final.hist_small_passes]),)
     return out
 
 
@@ -1686,8 +1704,9 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     """Grow K class trees in LOCKSTEP inside one widened XLA program
     (batched multiclass). Returns (TreeArrays with a leading K axis,
     leaf_id (K, N)) — the same stacked layout the per-class lax.scan path
-    produces; with_passes=True appends the () i32 count of
-    histogram-building passes, as grow_tree does.
+    produces; with_passes=True appends the (2,) i32 counts of
+    histogram-building passes, as grow_tree does (no lockstep pass is a
+    small-slot one).
 
     grad/hess: (K, N) class-major gradient channels (bagging mask applied).
     gh_scales: (K, 2) per-class (grad_scale, hess_scale) or None.
@@ -2336,5 +2355,6 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         leaf_depth=final.depth,
     )
     if with_passes:
-        return tree, final.leaf_id[:, :N], final.hist_passes
+        return (tree, final.leaf_id[:, :N],
+                jnp.stack([final.hist_passes, jnp.zeros((), i32)]))
     return tree, final.leaf_id[:, :N]

@@ -552,27 +552,36 @@ def root_pass_kind(bins_dtype, int_weights: bool, num_class: int = 1) -> str:
             and num_class == 1 else "onehot")
 
 
-def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots,
-                      row_axis=0):
-    i32 = jnp.int32
+def _weight_bytes(w_ref, slot=None, S: int = 1):
+    """The (1, T) int32 rows the factored LHS's digit tests are multiplied
+    by: grad, then hess, as the int8 byte a word's matching lanes take.  With
+    `slot` (each row's histogram slot, -1 for none) a row a (c, s): the byte
+    where the row sits in slot s, 0 elsewhere — the slot mask on the weighted
+    operand."""
+    wb = jnp.round(w_ref[0:2, :]).astype(jnp.int32) & 0xFF   # (2, T)
+    if slot is None:
+        return [wb[c:c + 1, :] for c in range(2)]
+    return [jnp.where(slot == s, wb[c:c + 1, :], 0)
+            for c in range(2) for s in range(S)]
 
-    @pl.when(pl.program_id(row_axis) == 0)
-    def _():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
+
+def _factored_accumulate(hist_ref, bins_ref, w_rows, *, T, NG, HP, f32_dots):
+    """hist_ref += the factored contraction of the block's NG 16-feature
+    groups: group g's rows [g * R, (g + 1) * R), R = len(w_rows) * HP * 16,
+    are (w row, hi, f) against the columns (lo, f')."""
+    i32 = jnp.int32
 
     def is_zero(x):
         """0x01 in every byte of x (bytes 0..15) that is 0, else 0x00."""
         return jax.lax.shift_right_logical(0x10101010 - x, 4) & _BYTES
 
-    R = 2 * HP * ROOT_GF
+    R = len(w_rows) * HP * ROOT_GF
     top = jax.lax.broadcasted_iota(i32, (8, T), 0) < 4       # sublanes 0..3
     # a word array (8, T) holds TWO digits of one group's 16 features: the
     # even digit in sublanes 0..3, the odd one in 4..7; pair[p] is the digit
     # pair (2p, 2p + 1) in every byte, to test such an array against
     odd = jnp.where(top, 0, _BYTES)
     pair = [odd + 2 * p * _BYTES for p in range(max(HP // 2, 4))]
-    # grad, hess as the int8 byte a word's matching lanes are multiplied by
-    wb = jnp.round(w_ref[0:2, :]).astype(i32) & 0xFF         # (2, T)
     for a in range(-(-NG // 2)):  # static unroll: 32 features a word array
         words = pltpu.bitcast(bins_ref[a * 32:(a + 1) * 32, :], i32)  # (8, T)
         hi = jax.lax.shift_right_logical(words, 3) & 0x0F0F0F0F
@@ -584,9 +593,10 @@ def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots,
             mine = top if half == 0 else ~top
             hi_g = jnp.where(mine, hi, hi_sw)
             lo_g = jnp.where(mine, lo, lo_sw)
+            # the digit tests are built once and shared by every w row
             hi_oh = [is_zero(hi_g ^ pair[p]) for p in range(HP // 2)]
-            lhs = jnp.concatenate([oh * wb[c:c + 1, :] for c in range(2)
-                                   for oh in hi_oh], axis=0)  # (R/4, T) words
+            lhs = jnp.concatenate([oh * w for w in w_rows for oh in hi_oh],
+                                  axis=0)                    # (R/4, T) words
             rhs = jnp.concatenate([is_zero(lo_g ^ pair[q]) for q in range(4)],
                                   axis=0)                    # (32, T) words
             lhs8 = pltpu.bitcast(lhs, jnp.int8)              # (R, T)
@@ -604,6 +614,95 @@ def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots,
             hist_ref[g * R:(g + 1) * R, :] += d
 
 
+def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots,
+                      row_axis=0):
+    @pl.when(pl.program_id(row_axis) == 0)
+    def _():
+        hist_ref[...] = jnp.zeros_like(hist_ref)
+
+    _factored_accumulate(hist_ref, bins_ref, _weight_bytes(w_ref), T=T,
+                         NG=NG, HP=HP, f32_dots=f32_dots)
+
+
+# A round that splits one or two leaves has one or two live slots, and the
+# 64-slot pass would again pay a full 128-column tile for 2 or 4 columns.
+# The SMALL-SLOT pass is the root's contraction with a slot mask on the
+# weighted operand, LHS rows (c, s, hi, f) = 2*S*H*16 a group of 16 features:
+# S*G*B*32 MACs a row, a quarter and a half of a full pass.  Unlike the root
+# its rows are routed first.  At S = 4 the form no longer wins.
+SMALL_PASS_SLOTS = 2
+
+
+def small_pass_index(live_slots, bins_dtype, int_weights: bool,
+                     num_slots: int, num_class: int = 1):
+    """Which pass a histogram round of `live_slots` (traced: the leaves it
+    splits; live slots are always 0..live_slots-1) takes, as the index of
+    route_and_hist_live's switch: s in 1..min(SMALL_PASS_SLOTS, num_slots)
+    is the small-slot pass of s slots, 0 the `num_slots` one-hot pass.  None
+    where the program has no small-slot pass: where the root would not be
+    factored either (root_pass_kind)."""
+    if (live_slots is None
+            or root_pass_kind(bins_dtype, int_weights, num_class)
+            != "factored"):
+        return None
+    return jnp.where(
+        (live_slots >= 1) & (live_slots <= min(SMALL_PASS_SLOTS, num_slots)),
+        live_slots, 0).astype(jnp.int32)
+
+
+def _route_small_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
+                             hist_ref, newleaf_ref, cnt_ref, *, T, B, S, L,
+                             NG, HP, has_cat, f32_dots):
+    """The small-slot pass over a table of one M-tile: route, count, and the
+    factored contraction of the rows' slot-masked grad/hess, the bins block
+    read once and no slot leaving the kernel."""
+    (slot,), _ = _route_rows(
+        bins_ref, leaf_ref, tabs_ref, bits_ref, newleaf_ref, T=T, B=B, L=L,
+        GW=bins_ref.shape[0], has_cat=has_cat, f32_dots=f32_dots,
+        u8_layout=True, K=1)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        hist_ref[...] = jnp.zeros_like(hist_ref)
+        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    _count_slots(cnt_ref, w_ref, [slot], T=T, S=S, K=1, f32_dots=f32_dots)
+    _factored_accumulate(hist_ref, bins_ref, _weight_bytes(w_ref, slot, S),
+                         T=T, NG=NG, HP=HP, f32_dots=f32_dots)
+
+
+def _small_hist_tiles_kernel(bins_ref, slot_ref, w_ref, hist_ref, *, T, S,
+                             NG, HP, f32_dots):
+    """The small-slot contraction of a table of several M-tiles, grid (tile,
+    row block) as _hist_tiles_kernel's: of rows to which the route-only pass
+    has given slots."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        hist_ref[...] = jnp.zeros_like(hist_ref)
+
+    _factored_accumulate(hist_ref, bins_ref,
+                         _weight_bytes(w_ref, slot_ref[0:1, :], S), T=T,
+                         NG=NG, HP=HP, f32_dots=f32_dots)
+
+
+def _factored_digits(bmax: int):
+    """High digits of a bin, b = hi * 8 + lo, padded to go two a word
+    array."""
+    H = -(-bmax // 8)
+    return H + (H & 1)
+
+
+def _factored_unpack(out, NG: int, S: int, HP: int, G: int, bmax: int):
+    """A factored call's int32 rows (group, c, s, hi, f) x columns (lo, f')
+    -> the (S, G, bmax, 2) histogram: keep f == f'."""
+    GF = ROOT_GF
+    same = jnp.eye(GF, dtype=bool)[:, None, :]
+    diag = jnp.sum(jnp.where(same, out.reshape(NG, 2, S, HP, GF, 8, GF), 0),
+                   axis=6)                              # (NG, 2, S, HP, GF, 8)
+    hist = diag.transpose(2, 0, 4, 3, 5, 1).reshape(S, NG * GF, HP * 8, 2)
+    return hist[:, :G, :bmax, :]
+
+
 def _root_hist_factored(bins_T, w_T, bmax: int, num_groups: int,
                         block_rows: int, tile_groups: int = 0):
     """(1, G, bmax, 2) int32 root histogram of integer-valued grad/hess rows
@@ -614,8 +713,7 @@ def _root_hist_factored(bins_T, w_T, bmax: int, num_groups: int,
     (tile, row block)), each sweep with its own resident block."""
     GW, n_pad = bins_T.shape
     T, G, GF = block_rows, num_groups, ROOT_GF
-    H = -(-bmax // 8)
-    HP = H + (H & 1)          # digits go two a word array
+    HP = _factored_digits(bmax)
     R = 2 * HP * GF
     # one tile: grid (row block,), the whole table's groups a block.  Tiled:
     # grid (tile, row block), a tile's groups and its own result rows
@@ -639,12 +737,85 @@ def _root_hist_factored(bins_T, w_T, bmax: int, num_groups: int,
             dimension_semantics=("arbitrary",) * (2 if tile_groups else 1)),
         interpret=pallas_interpret(),
     )(bins_T, w_T)
-    # rows (group, c, hi, f), columns (lo, f'): keep f == f'
-    same = jnp.eye(GF, dtype=bool)[:, None, :]
-    diag = jnp.sum(jnp.where(same, out.reshape(NG, 2, HP, GF, 8, GF), 0),
-                   axis=5)                                   # (NG, 2, HP, GF, 8)
-    hist = diag.transpose(0, 3, 2, 4, 1).reshape(NG * GF, HP * 8, 2)
-    return hist[None, :G, :bmax, :]
+    return _factored_unpack(out, NG, 1, HP, G, bmax)
+
+
+def _route_small_hist(bins_T, leaf_id, w_T, tabs, bits, S: int, bmax: int,
+                      num_groups: int, num_leaves: int, block_rows: int,
+                      has_cat: bool, tile_groups: int = 0):
+    """The small-slot pass (S <= SMALL_PASS_SLOTS live slots, integer-valued
+    grad/hess, u8-layout bins): (new leaf ids (1, N_pad), (S, G, bmax, 2)
+    int32 histograms of the routed rows' slots, (S,) exact slot counts), the
+    S-slot one-hot pass's results.  One tile: one fused call.  Tiled: the
+    route-only pass writes the slots, then one factored call over grid
+    (tile, row block) reads them.  The calls' result types are their own:
+    no reader of the 64-slot passes' or the root's matches them."""
+    GW, n_pad = bins_T.shape
+    T, G, L, GF = block_rows, num_groups, num_leaves, ROOT_GF
+    B = -(-bmax // 8) * 8
+    HP = _factored_digits(bmax)
+    R = 2 * S * HP * GF
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",) * (2 if tile_groups else 1))
+    if tile_groups:
+        # the counts a sublane wide whatever S: a 2-column second result
+        # is the one-hot root's to the trace's readers
+        new_leaf, cnt, slot = _route_hist_call(
+            bins_T, leaf_id, w_T, tabs, bits, S=8, G=G, B=B, L=L, T=T, K=1,
+            has_cat=has_cat, two_pass=True, int_weights=True, with_hist=False,
+            bin_buckets=None, m_rows=0, slot_out=True)
+        cnt = cnt[:, :S]
+        tiles, NGt = GW // tile_groups, tile_groups // GF
+        NG = tiles * NGt
+        # a leading unit axis keeps the result from reading as the sweeps'
+        # (tiles, rows, 128) block or the root's (rows, 128)
+        out = pl.pallas_call(
+            functools.partial(_small_hist_tiles_kernel, T=T, S=S, NG=NGt,
+                              HP=HP, f32_dots=pallas_interpret()),
+            grid=(tiles, n_pad // T),
+            in_specs=[
+                pl.BlockSpec((tile_groups, T), lambda j, b: (j, b)),
+                pl.BlockSpec((1, T), lambda j, b: (0, b)),
+                pl.BlockSpec((w_T.shape[0], T), lambda j, b: (0, b)),
+            ],
+            out_specs=pl.BlockSpec((None, None, NGt * R, 8 * GF),
+                                   lambda j, b: (j, 0, 0, 0)),
+            out_shape=jax.ShapeDtypeStruct((tiles, 1, NGt * R, 8 * GF),
+                                           jnp.int32),
+            compiler_params=params,
+            interpret=pallas_interpret(),
+        )(bins_T, slot, w_T)
+    else:
+        NG = -(-G // GF)
+        # the histogram first: a result tuple that opens with the leaf ids
+        # is the 64-slot pass's to the trace's readers
+        out, new_leaf, cnt = pl.pallas_call(
+            functools.partial(_route_small_hist_kernel, T=T, B=B, S=S, L=L,
+                              NG=NG, HP=HP, has_cat=has_cat,
+                              f32_dots=pallas_interpret()),
+            grid=(n_pad // T,),
+            in_specs=[
+                pl.BlockSpec((GW, T), lambda b: (0, b)),
+                pl.BlockSpec((1, T), lambda b: (0, b)),
+                pl.BlockSpec((w_T.shape[0], T), lambda b: (0, b)),
+                pl.BlockSpec((NUM_TAB, L), lambda b: (0, 0)),
+                pl.BlockSpec((B, L), lambda b: (0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((NG * R, 8 * GF), lambda b: (0, 0)),
+                pl.BlockSpec((1, T), lambda b: (0, b)),
+                pl.BlockSpec((1, S), lambda b: (0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((NG * R, 8 * GF), jnp.int32),
+                jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
+                jax.ShapeDtypeStruct((1, S), jnp.float32),
+            ],
+            compiler_params=params,
+            interpret=pallas_interpret(),
+        )(bins_T, leaf_id, w_T, tabs, bits)
+    return (new_leaf, _factored_unpack(out, NG, S, HP, G, bmax),
+            cnt.reshape(-1))
 
 
 # Mosaic's scoped-VMEM limit for one kernel on this compiler (jax 0.9.0,
@@ -885,19 +1056,64 @@ def _route_and_hist_tiled(bins_T, slot, w_T, num_slots, bmax, num_groups,
     return hist4[0] if K == 1 else hist4
 
 
+def _route_hist_call(bins_T, leaf_id, w_T, tabs, bits, *, S, G, B, L, T, K,
+                     has_cat, two_pass, int_weights, with_hist, bin_buckets,
+                     m_rows, slot_out):
+    """The one pallas_call of _route_hist_kernel: (new leaf ids, [histogram
+    block with with_hist], slot counts, [slots with slot_out])."""
+    GW, n_pad = bins_T.shape
+    hist_dtype = jnp.int32 if int_weights else jnp.float32
+    out_specs = [
+        pl.BlockSpec((K, T), lambda b: (0, b)),
+        pl.BlockSpec((m_rows, 2 * S * K), lambda b: (0, 0)),
+        pl.BlockSpec((1, S * K), lambda b: (0, 0)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((K, n_pad), jnp.int32),
+        jax.ShapeDtypeStruct((m_rows, 2 * S * K), hist_dtype),
+        jax.ShapeDtypeStruct((1, S * K), jnp.float32),
+    ]
+    if not with_hist:
+        del out_specs[1], out_shape[1]
+    if slot_out:
+        out_specs.append(out_specs[0])
+        out_shape.append(out_shape[0])
+    return pl.pallas_call(
+        functools.partial(_route_hist_kernel, T=T, G=G, B=B, S=S, L=L, GW=GW,
+                          has_cat=has_cat, two_pass=two_pass,
+                          int_weights=int_weights, f32_dots=pallas_interpret(),
+                          u8_layout=bins_T.dtype == jnp.int8,
+                          with_hist=with_hist, bin_buckets=bin_buckets,
+                          m_rows=m_rows, K=K, slot_out=slot_out),
+        grid=(n_pad // T,),
+        in_specs=[
+            pl.BlockSpec((GW, T), lambda b: (0, b)),
+            pl.BlockSpec((K, T), lambda b: (0, b)),
+            pl.BlockSpec((w_T.shape[0], T), lambda b: (0, b)),
+            pl.BlockSpec((NUM_TAB, K * L), lambda b: (0, 0)),
+            pl.BlockSpec((B, K * L), lambda b: (0, 0)),
+        ],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pallas_interpret(),
+    )(bins_T, leaf_id, w_T, tabs, bits)
+
+
 @functools.partial(watched_jit, name="route_and_hist", warn_after=0,
                    static_argnames=("num_slots", "bmax", "num_groups",
                                     "num_leaves", "block_rows", "has_cat",
                                     "two_pass", "int_weights", "with_hist",
                                     "bin_buckets", "num_class", "root",
-                                    "tile_groups"))
+                                    "tile_groups", "small_slots"))
 def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
                    tabs: jax.Array, bits: jax.Array, num_slots: int, bmax: int,
                    num_groups: int, num_leaves: int, block_rows: int = 1024,
                    has_cat: bool = True, two_pass: bool = True,
                    int_weights: bool = False, with_hist: bool = True,
                    bin_buckets=None, num_class: int = 1, root: bool = False,
-                   tile_groups: int = 0):
+                   tile_groups: int = 0, small_slots: int = 0):
     """One fused streaming pass: route rows through this round's splits and
     build grad/hess histograms and exact data counts of the rows' NEW slots.
 
@@ -921,6 +1137,11 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     come back as they went in, and where root_pass_kind() says so the
     histogram is built by the factored contraction instead.
 
+    small_slots = s > 0 is the caller's statement that `tabs` gives rows the
+    slots 0..s-1 and no other: the small-slot pass builds those — the same
+    exact int32 sums — and leaves zeros in the others (route_and_hist_live
+    chooses it by a round's own count, where small_pass_index() allows).
+
     tile_groups (stream_tiling's, with bins_T packed to it) cuts the one-hot
     M-axis into tiles of that many groups where the whole does not fit VMEM:
     the route-only pass (which has no M-axis) routes the rows once and
@@ -938,12 +1159,24 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     if tile_groups and bin_buckets is not None:
         raise ValueError("M-tiles take the uniform one-hot axis: no "
                          "bin_buckets with tile_groups")
+    if small_slots:
+        S = small_slots
+        if (not with_hist or root or not 0 < S <= min(SMALL_PASS_SLOTS,
+                                                      num_slots)
+                or root_pass_kind(bins_T.dtype, int_weights, num_class)
+                != "factored"):
+            raise ValueError(f"no small-slot pass of {S} slots in this "
+                             "program (small_pass_index)")
+        new_leaf, hist, cnt = _route_small_hist(
+            bins_T, leaf_id, w_T, tabs, bits, S, bmax, num_groups,
+            num_leaves, block_rows, has_cat, tile_groups)
+        return (new_leaf,
+                jnp.pad(hist, ((0, num_slots - S),) + ((0, 0),) * 3),
+                jnp.pad(cnt, (0, num_slots - S)))
     tiled = with_hist and tile_groups > 0
     if tiled:
         with_hist = False      # this call routes and counts; the tiles follow
-    GW, n_pad = bins_T.shape
     T = block_rows
-    NB = n_pad // T
     S, G, L, K = num_slots, num_groups, num_leaves, num_class
     if S > MAX_SLOTS:
         raise ValueError(f"stream kernel supports at most {MAX_SLOTS} "
@@ -951,7 +1184,6 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     if K > 1 and _ABLATE:
         raise ValueError("LGBTPU_KABLATE probes require num_class == 1")
     B = -(-bmax // 8) * 8
-    u8_layout = bins_T.dtype == jnp.int8
     if bin_buckets is not None:
         if _ABLATE:
             raise ValueError("LGBTPU_KABLATE probes require the uniform "
@@ -965,42 +1197,11 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
         m_rows = G * B
 
     hist_dtype = jnp.int32 if int_weights else jnp.float32
-    out_specs = [
-        pl.BlockSpec((K, T), lambda b: (0, b)),
-        pl.BlockSpec((m_rows, 2 * S * K), lambda b: (0, 0)),
-        pl.BlockSpec((1, S * K), lambda b: (0, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((K, n_pad), jnp.int32),
-        jax.ShapeDtypeStruct((m_rows, 2 * S * K), hist_dtype),
-        jax.ShapeDtypeStruct((1, S * K), jnp.float32),
-    ]
-    if not with_hist:
-        del out_specs[1], out_shape[1]
-    if tiled:
-        out_specs.append(out_specs[0])
-        out_shape.append(out_shape[0])
-    outs = pl.pallas_call(
-        functools.partial(_route_hist_kernel, T=T, G=G, B=B, S=S, L=L, GW=GW,
-                          has_cat=has_cat, two_pass=two_pass,
-                          int_weights=int_weights, f32_dots=pallas_interpret(),
-                          u8_layout=u8_layout, with_hist=with_hist,
-                          bin_buckets=bin_buckets, m_rows=m_rows, K=K,
-                          slot_out=tiled),
-        grid=(NB,),
-        in_specs=[
-            pl.BlockSpec((GW, T), lambda b: (0, b)),
-            pl.BlockSpec((K, T), lambda b: (0, b)),
-            pl.BlockSpec((w_T.shape[0], T), lambda b: (0, b)),
-            pl.BlockSpec((NUM_TAB, K * L), lambda b: (0, 0)),
-            pl.BlockSpec((B, K * L), lambda b: (0, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=pallas_interpret(),
-    )(bins_T, leaf_id, w_T, tabs, bits)
+    outs = _route_hist_call(
+        bins_T, leaf_id, w_T, tabs, bits, S=S, G=G, B=B, L=L, T=T, K=K,
+        has_cat=has_cat, two_pass=two_pass, int_weights=int_weights,
+        with_hist=with_hist, bin_buckets=bin_buckets, m_rows=m_rows,
+        slot_out=tiled)
 
     def _cnt_out(cnt):
         return cnt.reshape(-1) if K == 1 else cnt.reshape(K, S)
@@ -1041,6 +1242,32 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     if K == 1:
         hist4 = hist4[0]
     return new_leaf, hist4, _cnt_out(cnt)
+
+
+def route_and_hist_live(live_slots, bins_T, leaf_id, w_T, tabs, bits,
+                        num_slots: int, bmax: int, num_groups: int,
+                        num_leaves: int, *, int_weights: bool = False,
+                        with_hist: bool = True, **static):
+    """route_and_hist for a round that knows how many leaves it splits
+    (`live_slots`, traced; `tabs` gives rows the slots 0..live_slots-1 and no
+    other): a round of one or two takes the small-slot pass, every other
+    count the num_slots pass, where small_pass_index() has one to take.  The
+    switch stands OUTSIDE the jitted route_and_hist, one call of it a branch:
+    a kernel called inside a branch of its own would lose the jitted name the
+    device trace knows it by."""
+    call = functools.partial(
+        route_and_hist, num_slots=num_slots, bmax=bmax,
+        num_groups=num_groups, num_leaves=num_leaves,
+        int_weights=int_weights, with_hist=with_hist, **static)
+    which = small_pass_index(live_slots if with_hist else None, bins_T.dtype,
+                             int_weights, num_slots,
+                             static.get("num_class", 1))
+    if which is None:
+        return call(bins_T, leaf_id, w_T, tabs, bits)
+    return jax.lax.switch(
+        which, [functools.partial(call, small_slots=S)
+                for S in range(min(SMALL_PASS_SLOTS, num_slots) + 1)],
+        bins_T, leaf_id, w_T, tabs, bits)
 
 
 def _route_replay_kernel(nr_ref, bins_ref, tabs_ref, newleaf_ref, *,

@@ -51,6 +51,9 @@ class ShardedTrainState(NamedTuple):
         grown so far (ops/grow.py counts them per tree; the poll publishes
         the sum as ``telemetry.hist_pass_count()``).  Checkpoints do not
         hold this state — a resumed run rebuilds it and counts from 0
+      * ``hist_small_passes`` — () i32, those of ``hist_passes`` that took
+        the stream kernel's small-slot pass (rounds that split one or two
+        leaves); rides the same fetch onto the poll's record
     """
     score: jax.Array
     grad: jax.Array
@@ -63,6 +66,7 @@ class ShardedTrainState(NamedTuple):
     finished: jax.Array
     ok: jax.Array
     hist_passes: jax.Array
+    hist_small_passes: jax.Array
 
 
 def state_shardings(mesh, row_axis: Optional[str], num_class: int,
@@ -103,4 +107,4 @@ def state_shardings(mesh, row_axis: Optional[str], num_class: int,
     return ShardedTrainState(
         score=score, grad=grad, hess=hess, leaf_id=leaf, mask=row,
         key=rep, sampled=rep, overflow=rep, finished=rep, ok=rep,
-        hist_passes=rep)
+        hist_passes=rep, hist_small_passes=rep)

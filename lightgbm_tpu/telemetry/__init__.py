@@ -36,7 +36,8 @@ from .quality import (QualityMonitor, QualityProfile, js_divergence,
                       psi, quality_sidecar_path)
 from .tracer import SpanRecord, SpanTracer, global_tracer
 from .watchdog import (WatchEntry, get_recompile_threshold, hist_pass_count,
-                       hist_pass_iteration, host_sync_count, launch_count,
+                       hist_pass_iteration, hist_small_pass_count,
+                       host_sync_count, launch_count,
                        note_hist_passes, note_host_sync, note_launch,
                        recompile_counts, reset_counters,
                        reset_watchdog, set_recompile_threshold,
@@ -51,7 +52,8 @@ __all__ = [
     "watched_jit", "recompile_counts", "watchdog_summary",
     "set_recompile_threshold", "get_recompile_threshold", "reset_watchdog",
     "launch_count", "host_sync_count", "note_host_sync", "note_launch",
-    "hist_pass_count", "hist_pass_iteration", "note_hist_passes",
+    "hist_pass_count", "hist_pass_iteration", "hist_small_pass_count",
+    "note_hist_passes",
     "reset_counters", "costmodel", "cost_summary", "machine_balance",
     "memory_snapshot", "device_memory_gb", "host_rss_gb",
     "TraceContext", "TailRing", "AccessLog", "TRACE_HEADER",
@@ -188,6 +190,7 @@ def summary() -> Dict[str, Any]:
         # histogram passes the fused iterations grew, as of the flag poll
         # at `iteration` (telemetry/watchdog.py)
         "hist_passes": {"count": hist_pass_count(),
+                        "small": hist_small_pass_count(),
                         "iteration": hist_pass_iteration()},
     }
     if global_registry.sink_path:

@@ -56,6 +56,7 @@ _host_syncs = 0
 # fetch it already makes — so the count is as of `_hist_pass_iteration`,
 # up to eval_fetch_freq - 1 trees behind the device.
 _hist_passes = 0
+_hist_small_passes = 0      # those of them that took the small-slot pass
 _hist_pass_iteration = 0
 
 
@@ -75,17 +76,25 @@ def hist_pass_count() -> int:
     return _hist_passes
 
 
+def hist_small_pass_count() -> int:
+    """Those of :func:`hist_pass_count` that took the stream kernel's
+    small-slot pass (rounds that split one or two leaves)."""
+    return _hist_small_passes
+
+
 def hist_pass_iteration() -> int:
     """The boosting iteration at which :func:`hist_pass_count` was last
     read off the device."""
     return _hist_pass_iteration
 
 
-def note_hist_passes(n: int, iteration: int) -> None:
-    """Add ``n`` passes read off the device at ``iteration`` (the
-    engine's flag poll calls this with the delta since its last poll)."""
-    global _hist_passes, _hist_pass_iteration
+def note_hist_passes(n: int, iteration: int, small: int = 0) -> None:
+    """Add ``n`` passes, ``small`` of them small-slot ones, read off the
+    device at ``iteration`` (the engine's flag poll calls this with the
+    deltas since its last poll)."""
+    global _hist_passes, _hist_small_passes, _hist_pass_iteration
     _hist_passes += n
+    _hist_small_passes += small
     _hist_pass_iteration = iteration
 
 
@@ -112,10 +121,12 @@ def reset_counters() -> None:
     this is the A/B counterpart for the globals — bench arms call it at
     the start of each timed arm so launches/iter and host_syncs/iter are
     attributable to THAT arm, not contaminated by the previous one."""
-    global _launches, _host_syncs, _hist_passes, _hist_pass_iteration
+    global _launches, _host_syncs, _hist_passes, _hist_small_passes, \
+        _hist_pass_iteration
     _launches = 0
     _host_syncs = 0
     _hist_passes = 0
+    _hist_small_passes = 0
     _hist_pass_iteration = 0
 
 

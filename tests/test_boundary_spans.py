@@ -97,7 +97,7 @@ def test_ring_is_bounded_and_counts_what_it_overwrote(monkeypatch):
     assert tr.recent_spans() == [] and tr.ring_overwritten == 0
     s = tel.summary()
     assert s["recent_spans"] == [] and s["recent_spans_overwritten"] == 0
-    assert s["hist_passes"] == {"count": 0, "iteration": 0}
+    assert s["hist_passes"] == {"count": 0, "small": 0, "iteration": 0}
 
 
 def test_parents_are_kept_per_thread():
@@ -342,6 +342,13 @@ def test_fused_trees_are_byte_identical_to_the_parents(fused, name):
                         lgb.Dataset(X, label=y), num_boost_round=4)
         assert bst.engine._mc_batched_last
     assert bst.engine._fused_last
+    # the rounds that split one and two leaves take the small-slot pass on
+    # the int8 path (two a 15-leaf tree here), and on no other
+    poll = tel.recent_spans(name="GBDT::FlagPoll")[-1]
+    assert poll.args["iteration"] == bst.current_iteration()
+    assert poll.args["hist_small_passes"] == (
+        2 * 5 if name == "binary_int" else 0)
+    assert poll.args["hist_small_passes"] == tel.hist_small_pass_count()
     got = bst.model_to_string().split("\nparameters:")[0]
     want = (FIX / f"fused_parent_{name}.model").read_text()
     if got != want:

@@ -249,3 +249,48 @@ def test_row_mesh_factored_root_equals_serial(monkeypatch):
     assert traced[-1][1] * 4 >= 3000 > traced[-1][1]
     assert (_strip_params(mesh.model_to_string())
             == _strip_params(serial.model_to_string()))
+
+
+@needs_mesh
+def test_row_mesh_small_slot_pass_equals_serial(monkeypatch):
+    """The rounds that split one and two leaves take the small-slot pass
+    under a 4-device row mesh as in the serial learner: the round's split
+    count is replicated, every device takes the same branch over its own
+    rows and the psum adds exact int32 sums - the branch is traced in both
+    programs (S = 1 and S = 2), the device's count of small passes is the
+    same, and the model equals the serial one byte for byte."""
+    from lightgbm_tpu import telemetry as tel
+    from lightgbm_tpu.pallas import stream_kernel
+    traced = []
+    real = stream_kernel._route_small_hist
+
+    def spy(bins_T, leaf_id, w_T, tabs, bits, S, *a, **k):
+        traced.append((S, bins_T.shape[1]))
+        return real(bins_T, leaf_id, w_T, tabs, bits, S, *a, **k)
+
+    monkeypatch.setattr(stream_kernel, "_route_small_hist", spy)
+    # fused on one device too: the count of small passes rides its state
+    monkeypatch.setenv("LGBTPU_FUSE_ITER", "1")
+    # a width no other test of this process trains on, so route_and_hist's
+    # own jit cache holds neither program: both are traced under the spy
+    X, y = make_synthetic_binary(n=3000, f=19)
+    params = {"objective": "binary", "verbosity": -1, "num_leaves": 15,
+              "min_data_in_leaf": 5, "max_bin": 63, "hist_backend": "stream",
+              "use_quantized_grad": True}
+    tel.reset_counters()
+    serial = lgb.train(dict(params), lgb.Dataset(X, label=y),
+                       num_boost_round=3)
+    assert serial.engine._fused_last
+    assert {s for s, _ in traced} == {1, 2}
+    n_serial, small_serial = len(traced), tel.hist_small_pass_count()
+    assert small_serial == 2 * 3
+    tel.reset_counters()
+    mesh = lgb.train(dict(params, tree_learner="data", mesh_shape="data:4"),
+                     lgb.Dataset(X, label=y), num_boost_round=3)
+    assert mesh.engine._fused_last
+    assert {s for s, _ in traced[n_serial:]} == {1, 2}
+    # the mesh program's kernels see one device's quarter of the rows
+    assert all(n * 4 >= 3000 > n for _, n in traced[n_serial:])
+    assert tel.hist_small_pass_count() == small_serial
+    assert (_strip_params(mesh.model_to_string())
+            == _strip_params(serial.model_to_string()))

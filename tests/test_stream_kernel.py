@@ -12,9 +12,12 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu.ops.grow import feature_local_bin
 from lightgbm_tpu.ops.histogram import _hist_segsum
+from lightgbm_tpu.pallas import stream_kernel as sk
 from lightgbm_tpu.pallas.stream_kernel import (NUM_TAB, T_SLOT_KEEP,
                                                build_route_tables, pack_bins_T,
-                                               root_pass_kind, route_and_hist)
+                                               root_pass_kind, route_and_hist,
+                                               route_and_hist_live,
+                                               small_pass_index)
 
 
 def _dataset(n=2000, seed=11):
@@ -302,6 +305,156 @@ def test_root_flag_leaves_the_other_paths_alone():
     b = route_and_hist(*args, root=True, **kw)
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+# ---------------------------------------------------------- small-slot pass
+SMALL_L = 8
+# (leaf, group, threshold, new leaf id, smaller child is the left one) of
+# the leaves a round of k splits, in slot order; rows start spread over
+# leaves 0..3, so leaves 1 and 3 (and 2 at k = 1) hold rows of no slot
+SMALL_SPLITS = [(0, 3, 24, 4, True), (2, 1, 40, 5, False),
+                (1, 0, 9, 6, True)]
+
+
+def _small_operands(case, k, block=None):
+    """A round that splits k leaves over a _root_case table: (operands of
+    route_and_hist, the case's static arguments, NumPy's new leaf ids and
+    slots).  case "categorical": leaf 0's split is a bitset over its bins."""
+    name = "g28_b64" if case == "categorical" else case
+    bins, gi, hi, Bmax, bb, blk = _root_case(name)
+    block = block or blk
+    N, G = bins.shape
+    L = SMALL_L
+    rs = np.random.RandomState(k + sum(map(ord, case)))
+    lid = rs.randint(0, 4, N).astype(np.int32)
+    tabs = np.zeros((NUM_TAB, L), np.float32)
+    bits = np.zeros((-(-Bmax // 8) * 8, L), np.float32)
+    new_lid, slot = lid.copy(), np.full(N, -1, np.int32)
+    for s, (leaf, grp, thr, new, left_smaller) in enumerate(SMALL_SPLITS[:k]):
+        grp = min(grp, G - 1)
+        col = bins[:, grp].astype(np.int64)
+        go_left = col <= thr
+        tabs[sk.T_CHOSEN, leaf] = 1
+        tabs[sk.T_NEWID_LO, leaf] = new
+        tabs[sk.T_WORD_LO, leaf] = grp >> 2
+        tabs[sk.T_SHIFT, leaf] = (grp & 3) << 3
+        tabs[sk.T_NBINS, leaf] = Bmax
+        tabs[sk.T_THR, leaf] = thr
+        if case == "categorical" and s == 0:
+            cats = rs.rand(Bmax) < 0.4
+            bits[:Bmax, leaf] = cats
+            tabs[sk.T_ISCAT, leaf] = 1
+            go_left = cats[col]
+        tabs[sk.T_SLOT_L if left_smaller else sk.T_SLOT_R, leaf] = s + 1
+        rows = lid == leaf
+        new_lid[rows & ~go_left] = new
+        slot[rows & (go_left == left_smaller)] = s
+    slay = pack_bins_T(jnp.asarray(bins), block, max_bins=Bmax)
+    pad = slay.n_pad - N
+    w_T = jnp.zeros((8, slay.n_pad), jnp.float32)
+    w_T = (w_T.at[0, :N].set(jnp.asarray(gi))
+              .at[1, :N].set(jnp.asarray(hi)).at[2, :N].set(1.0))
+    operands = (slay.bins_T, jnp.asarray(np.pad(lid, (0, pad)))[None, :],
+                w_T, jnp.asarray(tabs), jnp.asarray(bits, jnp.bfloat16))
+    static = dict(bmax=Bmax, num_groups=G, num_leaves=L, block_rows=block,
+                  has_cat=case == "categorical", int_weights=True,
+                  bin_buckets=bb)
+    return operands, static, (bins, gi, hi, new_lid, slot)
+
+
+SMALL_CASES = ["g28_b64", "g136_bin_buckets", "g5", "g17",
+               "n_not_a_block_multiple", "bins_at_digit_edges",
+               "weights_at_int8_limits", "out_of_bag_zero_weights",
+               "categorical"]
+
+
+@pytest.mark.parametrize("k", [1, 2])
+@pytest.mark.parametrize("case", SMALL_CASES)
+def test_small_slot_pass_exact(case, k):
+    """A round of k = 1 or 2 splits through route_and_hist_live takes the
+    small-slot pass and returns what the 64-slot pass returns on the same
+    rows — new leaf ids, int32 histograms (zeros in the slots past k), exact
+    slot counts — and what NumPy's route + _hist_segsum give: every sum
+    EXACT.  Slot 0's smaller child is the left one, slot 1's the right one;
+    rows of the leaves that are not split sit in neither slot."""
+    operands, static, (bins, gi, hi, new_lid, slot) = _small_operands(case, k)
+    N, G = bins.shape
+    Bmax = static["bmax"]
+    assert int(small_pass_index(jnp.int32(k), jnp.int8, True, 64)) == k
+    full = route_and_hist(*operands, num_slots=64, **static)
+    live = route_and_hist_live(jnp.int32(k), *operands, num_slots=64,
+                               **static)
+    small = route_and_hist(*operands, num_slots=64, small_slots=k, **static)
+    for a, b, c in zip(full, live, small):
+        assert a.shape == b.shape == c.shape and a.dtype == b.dtype == c.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+    new_leaf, hist, cnt = live
+    assert hist.shape == (64, G, Bmax, 2) and hist.dtype == jnp.int32
+    np.testing.assert_array_equal(np.asarray(new_leaf[0, :N]), new_lid)
+    ref = _hist_segsum(jnp.asarray(bins), jnp.asarray(slot), jnp.asarray(gi),
+                       jnp.asarray(hi), jnp.ones(N, jnp.float32), k, Bmax)
+    np.testing.assert_array_equal(np.asarray(hist[:k], np.float64),
+                                  np.asarray(ref[..., :2], np.float64))
+    assert not np.asarray(hist[k:]).any()
+    np.testing.assert_array_equal(
+        np.asarray(cnt), np.bincount(slot[slot >= 0], minlength=64))
+    assert 0 < (slot >= 0).sum() < N and len(set(slot)) == k + 1
+
+
+@pytest.mark.parametrize("k", [0, 3, 64])
+def test_other_split_counts_take_the_64_slot_pass(k):
+    """k = 0, 3 and 64: small_pass_index says 0 and the switch's result is
+    the 64-slot pass's — at k = 3 on tables whose third slot the small pass
+    would lose."""
+    assert int(small_pass_index(jnp.int32(k), jnp.int8, True, 64)) == 0
+    operands, static, (bins, _, _, _, slot) = _small_operands(
+        "g5", min(k, 3))
+    full = route_and_hist(*operands, num_slots=64, **static)
+    live = route_and_hist_live(jnp.int32(k), *operands, num_slots=64,
+                               **static)
+    for a, b in zip(full, live):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    if k == 3:
+        assert np.asarray(live[1][2]).any() and (slot == 2).any()
+
+
+@pytest.mark.parametrize("path", ["float", "packed_words", "num_class_3"])
+def test_small_slot_pass_engages_on_the_factored_path_only(path):
+    """Float gradients, the packed-word layout and a multiclass program keep
+    the one-hot pass at every k: one kernel is traced, not a switch over
+    three, and small_slots is refused, as root_pass_kind() says "onehot" there."""
+    dtype = jnp.int32 if path == "packed_words" else jnp.int8
+    int_w = path != "float"
+    K = 3 if path == "num_class_3" else 1
+    assert root_pass_kind(dtype, int_w, K) == "onehot"
+    assert small_pass_index(jnp.int32(1), dtype, int_w, 64, K) is None
+    if path == "num_class_3":
+        return
+    operands, static, _ = _small_operands("g5", 1)
+    static["int_weights"] = int_w
+    if path == "packed_words":
+        # four groups a word, the layout LGBTPU_STREAM_PACKED keeps
+        b8 = np.asarray(operands[0]).view(np.uint8).astype(np.uint32)
+        words = sum(b8[j::4] << (8 * j) for j in range(4))
+        operands = (jnp.asarray(words.astype(np.int32)),) + operands[1:]
+
+    def live(k, *ops):
+        return route_and_hist_live(k, *ops, num_slots=64, **static)
+
+    for k in (1, 2):
+        text = str(jax.make_jaxpr(live)(jnp.int32(k), *operands))
+        assert text.count("pallas_call[") == 1
+    full = route_and_hist(*operands, num_slots=64, **static)
+    for a, b in zip(full, live(jnp.int32(1), *operands)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    with pytest.raises(ValueError, match="small-slot"):
+        route_and_hist(*operands, num_slots=64, small_slots=1, **static)
+    # and the factored path does trace one
+    operands, static, _ = _small_operands("g5", 1)
+    assert str(jax.make_jaxpr(
+        lambda k, *ops: route_and_hist_live(k, *ops, num_slots=64, **static)
+    )(jnp.int32(1), *operands)).count("pallas_call[") == 3
 
 
 def test_int8_hist_exact():

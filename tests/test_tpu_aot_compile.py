@@ -157,17 +157,13 @@ def test_epsilon_like_iteration_compiles(iterations):
     """hist_backend=auto resolves `stream` at G = 2,000 on a TPU and the
     fused iteration compiles: the route-only pass, the tiles' sweeps and the
     tiled factored root, each a `route_and_hist` call of its own result type."""
-    import re
     eng, text = iterations["epsilon"]
     assert "tpu_custom_call" in text
     assert eng._grow_params.int_hist and eng._grow_params.bin_buckets is None
     assert tuple(eng._stream_tiling) == (1024, 128, 16, 128 * 64)
     assert eng._packed.shape[0] == 2048 and eng._root_pass == "factored"
-    calls = re.findall(r"^\s*%route_and_hist[.\d]* = (.*?) custom-call\(",
-                       text, re.M)
-    kinds = {re.sub(r"\{[^}]*\}", "", c) for c in calls}
     n = eng._packed.shape[1]
-    assert kinds == {
+    assert _route_and_hist_kinds(text) == {
         # the route-only pass before a tiled pass: leaf ids, counts, slots
         f"(s32[1,{n}], f32[1,64], s32[1,{n}])",
         # the sweeps: one call, a histogram block a tile
@@ -176,7 +172,84 @@ def test_epsilon_like_iteration_compiles(iterations):
         "s32[32768,128]",
         # a tree's last round routes and counts only
         f"(s32[1,{n}], f32[1,128])",
-    }
+    } | SMALL_KINDS["epsilon"](n)
+
+
+def _route_and_hist_kinds(text):
+    """The result types of a compiled program's `route_and_hist` calls."""
+    import re
+    calls = re.findall(r"^\s*%route_and_hist[.\d]* = (.*?) custom-call\(",
+                       text, re.M)
+    return {re.sub(r"\{[^}]*\}", "", c) for c in calls}
+
+
+# the small-slot pass's calls (a round that splits one or two leaves) by
+# program: result types of their own, under the jitted name
+SMALL_KINDS = {
+    # one tile: the fused call, the histogram block first
+    "higgs": lambda n: {f"(s32[512,128], s32[1,{n}], f32[1,1])",
+                        f"(s32[1024,128], s32[1,{n}], f32[1,2])"},
+    "mslr": lambda n: {f"(s32[2304,128], s32[1,{n}], f32[1,1])",
+                       f"(s32[4608,128], s32[1,{n}], f32[1,2])"},
+    # tiled: the route pre-pass with its counts 8 wide, then the factored
+    # call over grid (tile, row block), a 4-D block
+    "epsilon": lambda n: {f"(s32[1,{n}], f32[1,8], s32[1,{n}])",
+                          "s32[16,1,2048,128]", "s32[16,1,4096,128]"},
+}
+
+
+@pytest.mark.parametrize("name", sorted(SMALL_KINDS))
+def test_small_slot_kernels_compile_under_their_own_result_types(iterations,
+                                                                 name):
+    """G = 28 T = 4096, G = 136 T = 1024 (bucketed) and the 128-group tile:
+    the S = 1 and S = 2 small-slot kernels compile for the v5e inside the
+    fused iteration's switch, every branch's call still named
+    `%route_and_hist.N`, and none of the accepted trace readers' patterns for
+    the 64-slot passes, the tiled sweeps or the root matches them - while the
+    new reader matches them and nothing else."""
+    import importlib.util
+    import re
+    from pathlib import Path
+    eng, text = iterations[name]
+    n = eng._packed.shape[1]
+    kinds = _route_and_hist_kinds(text)
+    assert SMALL_KINDS[name](n) <= kinds
+    if name != "epsilon":
+        m_rows = eng._stream_tiling.tile_m_rows
+        assert f"(s32[1,{n}], s32[{m_rows},128], f32[1,64])" in kinds
+
+    layers = Path(__file__).resolve().parents[1] / "benchmark" / "layers"
+
+    def reader(stem):
+        spec = importlib.util.spec_from_file_location(
+            stem.replace(".", "_"), layers / f"{stem}.py")
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    lines = {re.sub(r"\{[^}]*\}", "", m[2]): m[1] + m[2]
+             for m in re.finditer(
+                 r"^\s*(%route_and_hist[.\d]* = )(.*?) custom-call\(", text,
+                 re.M)}
+    small = [lines[k] for k in SMALL_KINDS[name](n)]
+    others = [v for k, v in lines.items() if k not in SMALL_KINDS[name](n)]
+    roof, root = reader("hist_kernel_roofline"), reader("root_pass_ms_per_tree")
+    new = reader("small_pass_ms_per_tree")
+    tiles = (layers / "hist_tiles_roofline.py").read_text()
+    sweeps = re.search(r'^SWEEPS = re.compile\(r"(.*)"\)$', tiles, re.M)[1]
+    accepted = [roof.PASS, re.compile(sweeps), re.compile(root.FACTORED),
+                re.compile(root.ONEHOT)]
+    for line in small:
+        assert not any(p.match(line) for p in accepted), line
+        assert any(re.match(p, line)
+                   for p in (new.FUSED, new.TILES, new.PREPASS)), line
+        # the tiled pre-pass reads as a pre-pass to hist_tiles_roofline, and
+        # is left out there by its width: 2 x 8 columns are not 128
+        pre = re.match(new.PREPASS, line)
+        assert not pre or "f32[1,8]" in line
+    for line in others:
+        assert not any(re.match(p, line)
+                       for p in (new.FUSED, new.TILES, new.PREPASS)), line
 
 
 def test_route_replay_compiles_at_2000_groups(tpu):

@@ -21,6 +21,7 @@ from lightgbm_tpu.ops.histogram import _hist_segsum
 from lightgbm_tpu.pallas.stream_kernel import (NUM_TAB, WIDE_ROUTE_GROUPS,
                                                build_route_tables,
                                                pack_bins_T, route_and_hist,
+                                               route_and_hist_live,
                                                route_replay, stream_tiling)
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "benchmark"))
@@ -170,6 +171,42 @@ def test_tiled_factored_root_exact(wide, tile_groups):
     np.testing.assert_array_equal(np.asarray(hist),
                                   _segsum(wide, np.zeros(N, np.int32), 1))
     assert not np.asarray(new_leaf).any()
+
+
+@pytest.mark.parametrize("k", [1, 2])
+@pytest.mark.parametrize("tile_groups", TILES)
+def test_tiled_small_slot_pass_exact(wide, tile_groups, k):
+    """A round of k = 1 or 2 splits on the tiled table: the route-only
+    pre-pass writes the slots, the factored contraction sweeps the rows a
+    tile.  The splits test features of the first and the last (ragged) tile;
+    slot 0's smaller child is the left one, slot 1's the right one."""
+    splits = dict(list(SPLITS.items())[:k])
+    sl, sr = {0: 1}, ({2: 2} if k == 2 else {})
+    bins_T, leaf, w_T, _, bits = _operands(wide, tile_groups)
+    tabs = _route_tables(wide["ds"].device_data().routing, splits, sl=sl,
+                         sr=sr)
+    new_leaf = reference_hist.route(wide["bins"], wide["leaf"], splits)
+    went_right = new_leaf != wide["leaf"]
+    slot = np.full(N, -1)
+    slot[(wide["leaf"] == 0) & ~went_right] = 0
+    if k == 2:
+        slot[(wide["leaf"] == 2) & went_right] = 1
+    want = reference_hist.histograms(wide["bins"], slot, wide["grad"],
+                                     wide["hess"], k, 63)
+    kw = dict(has_cat=False, int_weights=True, tile_groups=tile_groups)
+    got = route_and_hist_live(jnp.int32(k), bins_T, leaf, w_T, tabs, bits,
+                              64, 63, F, L, **kw)
+    full = route_and_hist(bins_T, leaf, w_T, tabs, bits, 64, 63, F, L, **kw)
+    for a, b in zip(got, full):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    got_leaf, hist, cnt = got
+    np.testing.assert_array_equal(np.asarray(got_leaf)[0, :N], new_leaf)
+    np.testing.assert_array_equal(np.asarray(hist[:k]), want[..., :2])
+    assert not np.asarray(hist[k:]).any()
+    np.testing.assert_array_equal(np.asarray(cnt[:k]),
+                                  want[:, 0, :, 2].sum(1))
+    assert 0 < (slot == k - 1).sum() < N
 
 
 @pytest.mark.parametrize("tile_groups", TILES)
