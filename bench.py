@@ -946,289 +946,6 @@ def run_goss():
     return ok
 
 
-def _histfloor_child():
-    """One histogram-floor arm in a subprocess (device count and backend
-    env are fixed at jax init).  Prints one JSON line tagged hf_child."""
-    import lightgbm_tpu as lgb
-    from lightgbm_tpu.telemetry import launch_count
-
-    arm = os.environ["HF_ARM"]
-    rows = int(os.environ["HF_ROWS"])
-    iters = int(os.environ["HF_ITERS"])
-    leaves = int(os.environ.get("HF_LEAVES", "255"))
-    lr = float(os.environ.get("HF_LR", "0.1"))
-    n_dev = int(os.environ.get("HF_DEV", "0"))
-    try:
-        X, y = make_higgs_like(rows, N_FEATURES)
-        n_te = max(rows // 10, 2000)
-        params = {
-            "objective": "binary", "num_leaves": leaves,
-            "learning_rate": lr, "max_bin": 63, "verbosity": -1,
-            "max_splits_per_round": 64,
-        }
-        warmup = 1
-        goss = False
-        if arm in ("onehot", "segsum", "scatter", "stream"):
-            params["hist_backend"] = arm
-            if arm == "scatter":
-                # segsum/onehot auto-resolve double on CPU; pin single so
-                # the A/B compares formulations, not precisions
-                params["hist_precision"] = "single"
-        elif arm in ("fusion_off", "fusion_on"):
-            goss = True
-            params.update({
-                "hist_backend": "stream", "data_sample_strategy": "goss",
-                "route_fusion": "on" if arm == "fusion_on" else "off"})
-            # steady-state sampled regime: time AFTER the reference's
-            # 1/learning_rate unsampled warmup iterations (goss.hpp)
-            warmup = int(1.0 / lr) + 1
-        elif arm.startswith("packed"):
-            params.update({
-                "tree_learner": "data", "hist_backend": "stream",
-                "use_quantized_grad": True, "num_grad_quant_bins": 64,
-                "hist_comms": "psum",
-                "hist_packed_width": int(arm[len("packed"):])})
-            if n_dev > 0:
-                params["mesh_shape"] = f"data:{n_dev}"
-        else:
-            raise ValueError(f"unknown histfloor arm {arm!r}")
-
-        ds = lgb.Dataset(X[:-n_te], label=y[:-n_te])
-        bst = lgb.Booster(params, ds)
-        for _ in range(warmup):
-            bst.update()
-        bst.engine.score.block_until_ready()
-        l0 = launch_count()
-        t0 = time.time()
-        for _ in range(iters):
-            bst.update()
-        bst.engine.score.block_until_ready()
-        s_per_tree = (time.time() - t0) / iters
-        lpi = (launch_count() - l0) / iters
-        auc = float(auc_score(y[-n_te:],
-                              np.asarray(bst.predict(X[-n_te:],
-                                                     raw_score=True))))
-        eng = bst.engine
-        cm = eng._comms_model() or {}
-        sampled = eng._last_sampled_rows or 0
-        from lightgbm_tpu.runtime import device_record
-        out = {
-            "hf_child": 1, **device_record(), "arm": arm,
-            "backend": eng._grow_params.hist_backend,
-            "s_per_tree": round(s_per_tree, 4),
-            "auc": round(auc, 5),
-            "launches_per_iter": round(lpi, 3),
-            "goss": goss,
-            "sampled_fraction": (round(sampled / max(eng.num_data, 1), 4)
-                                 if goss else 1.0),
-            "compact_rows": eng._last_compact_rows,
-            "route_passes_per_tree": eng._route_only_passes_per_tree(),
-            "bytes_per_round": cm.get("per_round_bytes", 0),
-            "packed_width": cm.get("packed_width", 32),
-            "devices": cm.get("devices", 1),
-        }
-        print(json.dumps(out), flush=True)
-        return True
-    except Exception as e:  # noqa: BLE001 — the parent reports the arm
-        print(json.dumps({"hf_child": 1, "error": repr(e)}), flush=True)
-        return False
-
-
-def _histfloor_projection(out, leaves):
-    """TPU roofline projection (s/tree at HIGGS 10.5M-row shapes) from an
-    arm's measured sampling/routing structure and the trace-measured
-    per-pass constants in docs/PERF.md: 12 ms MXU one-hot dot + ~4 ms VPU
-    fixed work per histogram pass (both scale with the streamed row
-    count), 46 ms GOSS partition sort, 2.3 ms per full-data route-only
-    pass.  The scatter formulation has no competitive TPU projection
-    (scatter runs ~11M rows/s there — the reason the one-hot formulation
-    exists); its CPU wall-clock column carries its story."""
-    import math
-    S = 64
-    passes = max(math.ceil(math.log2(max(leaves, 2))),
-                 math.ceil((leaves - 1) / S)) + 1
-    frac = out.get("sampled_fraction") or 1.0
-    t = passes * (12e-3 + 4e-3) * frac
-    if out.get("goss"):
-        t += 46e-3
-    t += 2.3e-3 * out.get("route_passes_per_tree", 0)
-    return round(t, 4)
-
-
-def run_histfloor():
-    """BENCH_TASK=histfloor: the histogram-formulation floor A/B
-    (docs/PERF.md "histogram-formulation floor") — one-hot baseline vs
-    the three floor-breaking candidates behind ``hist_backend`` /
-    ``hist_packed_width`` / ``route_fusion``:
-
-      * scatter  — Pallas scatter-add histograms (no one-hot operand)
-      * packed16 — int16-packed quantized grad/hess collective wire on a
-                   4-way mesh (bytes/round must measure exactly HALF the
-                   exact int32 wire; packed8 would quarter it)
-      * fusion   — GOSS+stream route fusion (per-round full-data
-                   route-only passes fold into ONE post-growth replay;
-                   hist/route_only_passes drops to 1/tree)
-
-    Every arm trains the HIGGS-like protocol in its own subprocess and is
-    gated on holdout AUC (same gate as the main run).  The headline value
-    is the winning candidate's TPU roofline projection (sim-flagged: this
-    box measures CPU wall clock; the projection applies the docs/PERF.md
-    trace-measured per-pass constants to the arm's measured sampling and
-    routing structure).  Full results -> BENCH_HISTFLOOR.json + one
-    BENCH_HISTORY.jsonl line; BENCH_HISTFLOOR_SMOKE=1 runs a reduced CI
-    matrix that never clobbers the committed artifact."""
-    import subprocess
-
-    smoke = os.environ.get("BENCH_HISTFLOOR_SMOKE", "") == "1"
-    rows = int(os.environ.get("BENCH_HISTFLOOR_ROWS",
-                              "20000" if smoke else "100000"))
-    iters = int(os.environ.get("BENCH_HISTFLOOR_ITERS",
-                               "4" if smoke else "30"))
-    # smoke keeps >= 65 leaves: the fusion gate needs a full S=64 round
-    # budget (min(max_splits_per_round, num_leaves-1) >= 64)
-    leaves = int(os.environ.get("BENCH_HISTFLOOR_LEAVES",
-                                "127" if smoke else "255"))
-    lr = 0.5 if smoke else 0.1
-    auc_gate = float(os.environ.get("BENCH_HISTFLOOR_AUC_GATE",
-                                    "0.78" if smoke else str(AUC_GATE)))
-    proj_gate = float(os.environ.get("BENCH_HISTFLOOR_PROJ_GATE", "0.10"))
-    mesh_d = 4
-    forced_cpu, have = _arm_devices(mesh_d, "BENCH_TASK=histfloor mesh arms")
-
-    def child(arm, n_dev=0):
-        env = dict(os.environ)
-        env.update({"_BENCH_HISTFLOOR_CHILD": "1", "HF_ARM": arm,
-                    "HF_ROWS": str(rows), "HF_ITERS": str(iters),
-                    "HF_LEAVES": str(leaves), "HF_LR": str(lr),
-                    "HF_DEV": str(n_dev)})
-        # a caller's exported A/B knobs must not leak into the matrix
-        for k in ("LGBTPU_HIST_BACKEND", "LGBTPU_HIST_PACKED_WIDTH",
-                  "LGBTPU_ROUTE_FUSION", "LGBTPU_HIST_COMMS",
-                  "LGBTPU_FUSE_ITER", "LGBTPU_COMPACT"):
-            env.pop(k, None)
-        # single-device arms run on whatever the host has; mesh arms on
-        # chips when there are mesh_d of them, else virtual CPU devices
-        env = _arm_env(env, n_dev > 0 and forced_cpu, have, n_dev)
-        r = subprocess.run([sys.executable, os.path.abspath(__file__)],
-                           env=env, capture_output=True, text=True,
-                           cwd=os.path.dirname(os.path.abspath(__file__)))
-        out = None
-        for line in r.stdout.splitlines():
-            try:
-                obj = json.loads(line)
-            except ValueError:
-                continue
-            if obj.get("hf_child"):
-                out = obj
-        if r.returncode != 0 or out is None or "error" in (out or {}):
-            sys.stderr.write(r.stdout[-2000:] + r.stderr[-2000:])
-            raise RuntimeError(f"histfloor arm {arm} (devices={n_dev}) "
-                               f"failed: {(out or {}).get('error')}")
-        return out
-
-    arms = {}
-    for arm in ("onehot", "scatter", "stream", "fusion_off", "fusion_on"):
-        arms[arm] = child(arm)
-        print(f"histfloor arm {arm}: {arms[arm]['s_per_tree']} s/tree, "
-              f"AUC {arms[arm]['auc']}", flush=True)
-    for arm in ("packed32", "packed16"):
-        arms[arm] = child(arm, n_dev=mesh_d)
-        print(f"histfloor arm {arm} ({mesh_d}-dev): "
-              f"{arms[arm]['s_per_tree']} s/tree, AUC {arms[arm]['auc']}, "
-              f"{arms[arm]['bytes_per_round']} bytes/round", flush=True)
-
-    failures = []
-    for name, a in arms.items():
-        if a["auc"] < auc_gate:
-            failures.append(f"{name}: AUC {a['auc']} < gate {auc_gate}")
-    # packed int16 halves the per-round psum_scatter payload EXACTLY
-    # (carry-free int packing — not a compression estimate)
-    b32, b16 = arms["packed32"]["bytes_per_round"], \
-        arms["packed16"]["bytes_per_round"]
-    if b16 * 2 != b32 or b32 <= 0:
-        failures.append(f"packed16 bytes/round {b16} != half of int32 "
-                        f"wire {b32}")
-    if arms["packed16"]["packed_width"] != 16:
-        failures.append("packed16 arm did not engage the packed wire")
-    # fusion folds the per-round route-only passes into ONE replay
-    if arms["fusion_on"]["route_passes_per_tree"] != 1:
-        failures.append(f"fusion_on routes "
-                        f"{arms['fusion_on']['route_passes_per_tree']} "
-                        f"passes/tree (expected 1)")
-    if arms["fusion_off"]["route_passes_per_tree"] <= 1:
-        failures.append("fusion_off arm did not exercise per-round "
-                        "route-only passes")
-    if arms["fusion_on"]["compact_rows"] <= 0:
-        failures.append("fusion arms never compacted (GOSS warmup?)")
-
-    # TPU roofline projections (sim: this box times CPU wall clock)
-    for name, a in arms.items():
-        a["s_per_tree_tpu_projected"] = (
-            None if a["backend"] == "scatter"
-            else _histfloor_projection(a, leaves))
-    candidates = {k: v["s_per_tree_tpu_projected"]
-                  for k, v in arms.items()
-                  if k not in ("onehot", "fusion_off", "packed32")
-                  and v["s_per_tree_tpu_projected"] is not None}
-    winner = min(candidates, key=candidates.get)
-    proj = candidates[winner]
-    if not smoke and proj > proj_gate:
-        failures.append(f"winning backend {winner} projects {proj} s/tree "
-                        f"> gate {proj_gate}")
-
-    ok = not failures
-    worst_auc = min(a["auc"] for a in arms.values())
-    record = {
-        "metric": "histfloor_winner_s_per_tree_projected",
-        "value": proj,
-        "unit": (f"s/tree TPU roofline projection at HIGGS 10.5M-row "
-                 f"shapes, winning candidate {winner} (one-hot baseline "
-                 f"projects "
-                 f"{arms['onehot']['s_per_tree_tpu_projected']}; CPU "
-                 f"wall-clock A/B at {rows} rows: onehot "
-                 f"{arms['onehot']['s_per_tree']}, scatter "
-                 f"{arms['scatter']['s_per_tree']}, stream "
-                 f"{arms['stream']['s_per_tree']}, fusion "
-                 f"{arms['fusion_on']['s_per_tree']}; worst holdout AUC "
-                 f"{worst_auc:.4f} "
-                 f"{'>=' if worst_auc >= auc_gate else '< GATE '}"
-                 f"{auc_gate}; packed16 wire {b16} bytes/round = half of "
-                 f"{b32})"),
-        "vs_baseline": (round(
-            arms["onehot"]["s_per_tree_tpu_projected"] / max(proj, 1e-12),
-            3) if ok else 0.0),
-        "sim_note": (
-            "projection applies docs/PERF.md trace-measured per-pass "
-            "constants (12 ms MXU dot + 4 ms VPU per pass, 46 ms GOSS "
-            "partition, 2.3 ms route-only pass) to each arm's measured "
-            "sampling/routing structure; CPU wall-clock columns on this "
-            "box are serialized-kernel artifacts, and the 4-dev packed "
-            "arms run forced-CPU virtual devices — the bytes/round "
-            "columns carry what hardware realizes"
-            if forced_cpu else ""),
-        "smoke": smoke,
-        "gates": {"auc": auc_gate, "projection": proj_gate,
-                  "failures": failures},
-        "arms": arms,
-    }
-    _emit(record, source=arms["stream"])
-    if failures:
-        for msg in failures:
-            print(f"BENCH_HISTFLOOR gate FAIL: {msg}", flush=True)
-    if not smoke:
-        _append_history(record, ok=ok)
-        if ok:
-            # the committed artifact holds the last PASSING full-size
-            # measurement; smoke/failed runs report via stdout + exit code
-            from lightgbm_tpu.robustness.checkpoint import atomic_open
-            with atomic_open(os.path.join(
-                    os.path.dirname(os.path.abspath(__file__)),
-                    "BENCH_HISTFLOOR.json"), "w") as fh:
-                json.dump(record, fh, indent=2)
-                fh.write("\n")
-    return ok
-
-
 def main():
     import lightgbm_tpu as lgb
 
@@ -3511,8 +3228,6 @@ if __name__ == "__main__":
         sys.exit(0 if _ingest_child() else 1)
     if os.environ.get("_BENCH_WIDE_CHILD", "") == "1":
         sys.exit(0 if _wide_child() else 1)
-    if os.environ.get("_BENCH_HISTFLOOR_CHILD", "") == "1":
-        sys.exit(0 if _histfloor_child() else 1)
     if os.environ.get("BENCH_MULTICHIP", "") == "1":
         sys.exit(0 if run_multichip_bench() else 1)
     if os.environ.get("BENCH_SERVE", "") == "1":
@@ -3523,10 +3238,9 @@ if __name__ == "__main__":
         sys.exit(0 if run_drift_bench() else 1)
     task = os.environ.get("BENCH_TASK", "")
     if task not in ("", "higgs", "ranking", "multiclass", "goss", "ingest",
-                    "wide", "histfloor", "pipeline", "multimodel"):
+                    "wide", "pipeline", "multimodel"):
         sys.exit(f"unknown BENCH_TASK={task!r}; one of higgs, ranking, "
-                 "multiclass, goss, ingest, wide, histfloor, pipeline, "
-                 "multimodel")
+                 "multiclass, goss, ingest, wide, pipeline, multimodel")
     if task == "pipeline":
         sys.exit(0 if run_pipeline_bench() else 1)
     if task == "multimodel":
@@ -3537,8 +3251,6 @@ if __name__ == "__main__":
         sys.exit(0 if run_ingest() else 1)
     if task == "wide":
         sys.exit(0 if run_wide() else 1)
-    if task == "histfloor":
-        sys.exit(0 if run_histfloor() else 1)
     ok = True
     if task in ("", "higgs"):
         ok = main() and ok

@@ -178,8 +178,7 @@ _PARAM_ALIASES: Dict[str, List[str]] = {
     "quant_train_renew_leaf": [],
     "stochastic_rounding": [],
     # --- TPU-specific knobs (new in this framework) ---
-    "hist_backend": [],          # auto | segsum | onehot | pallas | stream
-                                 # | scatter
+    "hist_backend": [],          # auto | segsum | onehot | stream
     "hist_packed_width": ["histogram_packed_width"],  # 32 | 16 | 8
     "route_fusion": ["goss_route_fusion"],  # auto | on | off
     "hist_precision": [],        # auto | mixed (two-pass bf16, ~f32) | single
@@ -532,14 +531,15 @@ class Config:
     stochastic_rounding: bool = True
 
     # --- TPU-native knobs ---
-    # histogram formulation: auto | segsum | onehot | pallas | stream |
-    # scatter. auto = stream on a TPU at EVERY table width (a table whose
-    # one-hot does not fit VMEM whole is cut into M-tiles of whole feature
-    # groups by the kernel itself, so the fused iteration runs it too) and
-    # segsum elsewhere; under a mesh, stream where rows alone are sharded
-    # and onehot/segsum otherwise. pallas (slot-sorted row blocks, a row
-    # gather every round) is reachable by name only; scatter runs
-    # interpreted on the CPU only. LGBTPU_HIST_BACKEND overrides for A/B.
+    # histogram formulation: auto | segsum | onehot | stream (the rule
+    # that resolves auto is ops/histogram.resolve_hist_backend). auto =
+    # stream on a TPU at EVERY table width (a table whose one-hot does not
+    # fit VMEM whole is cut into M-tiles of whole feature groups by the
+    # kernel itself, so the fused iteration runs it too) and segsum (the
+    # reference) elsewhere; under a mesh, stream where rows alone are
+    # sharded and onehot (TPU) / segsum otherwise; onehot too where the
+    # stream kernel does not take the job (over 2,048 leaves or 255 splits
+    # a round). LGBTPU_HIST_BACKEND overrides for A/B.
     hist_backend: str = "auto"
     # packed quantized-gradient histogram width (bits per grad/hess field
     # on the mesh wire): 32 = exact int32 lanes (default); 16 packs each
@@ -591,7 +591,7 @@ class Config:
     hist_comms: str = "psum"
     # reduce_scatter wire dtype: f32, or bf16_pair — remote contributions
     # ride the HIGH half of the f32->bf16 high/low split (the hist
-    # kernel's two-pass trick, pallas/hist_kernel._wsplit) at 2 bytes per
+    # kernel's two-pass trick, pallas/stream_kernel._wsplit) at 2 bytes per
     # element while each device's own slice contribution stays exact f32
     # and the cross-device accumulation runs in f32. Halves the wire
     # payload; opt-in (not bit-identical to psum).

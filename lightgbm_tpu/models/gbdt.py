@@ -24,6 +24,8 @@ from ..device_data import DeviceData, to_device
 from ..metrics import Metric
 from ..objectives import ObjectiveFunction
 from ..ops.grow import GrowParams, grow_tree
+from ..ops.histogram import (HIST_BACKENDS, check_hist_backend,
+                             hist_backend_refusal, resolve_hist_backend)
 from ..ops.split import leaf_output
 from ..ops.predict import StackedTrees, _walk_one_tree
 from ..robustness import chaos as _chaos
@@ -37,11 +39,6 @@ from ..telemetry import (costmodel as _tel_cost,
 from ..tree import Tree, TreeArrays, finalize_tree
 from ..utils.log import LightGBMError, log_info, log_warning
 from .sample_strategy import create_sample_strategy
-
-# accepted hist_backend values (docs/PERF.md "histogram-formulation floor"):
-# three A/B-able formulations — one-hot/segsum contractions, the fused
-# stream kernel, and the scatter-add tile — plus the pallas direct kernel
-HIST_BACKENDS = ("auto", "segsum", "onehot", "pallas", "stream", "scatter")
 
 # span name -> per-iteration record key for the telemetry phase splits
 _PHASE_KEYS = {
@@ -274,18 +271,6 @@ class GBDT:
                 "extra_trees, feature_fraction_bynode, cegb_*, or "
                 "linear_tree; remove those parameters or use "
                 "a rows-only mesh (tree_learner=data, mesh_shape=data:D)")
-        if (self._feature_mode or self._mesh_2d) and \
-                self._grow_params.hist_backend not in ("segsum", "onehot"):
-            # checked here (not just in grow_tree) so the engine never
-            # pre-packs a pallas bin copy of the group-sharded matrix —
-            # pack_bins would replicate the full (N, G) block per device
-            _mode = ("the 2D data x feature mesh" if self._mesh_2d
-                     else "tree_learner=feature")
-            raise LightGBMError(
-                f"{_mode} needs hist_backend=segsum or "
-                f"onehot (got {self._grow_params.hist_backend!r}: the "
-                "stream/pallas kernels pack row-major group words, which "
-                "group sharding cannot slice)")
         packed = None
         # row-compaction capacity quantum: compacted views must stay whole
         # multiples of the stream kernel block (smaller-tier K-widened
@@ -319,9 +304,6 @@ class GBDT:
                 from jax.sharding import NamedSharding, PartitionSpec as P
                 packed = jax.device_put(
                     packed, NamedSharding(self.mesh, P(None, self._row_axis)))
-        elif self._grow_params.hist_backend == "pallas":
-            from ..pallas.hist_kernel import pack_bins
-            packed = pack_bins(dd.bins)
         # NOTE: `packed` must be a jit ARGUMENT, not a closure capture —
         # captured arrays are embedded in the HLO as constants, and a 10M-row
         # packed bin matrix (hundreds of MB) blows up compilation
@@ -550,8 +532,6 @@ class GBDT:
                 "'pad'")
         gp = self._grow_params
         eligible = (mode != "off"
-                    and gp.hist_backend in ("stream", "segsum", "onehot",
-                                        "scatter")
                     and (self.mesh is None or self._mesh_stream
                          or self._voting or self._feature_mode))
         if not eligible and not _tel_tracer.enabled:
@@ -763,65 +743,35 @@ class GBDT:
         spec = bins_sharding(self.mesh, self.config.tree_learner).spec
         return len(spec) == 1 or spec[1] is None
 
+    def _mesh_kind(self) -> str:
+        """How the bin matrix is sharded (ops.histogram.MESH_KINDS)."""
+        if self.mesh is None:
+            return "none"
+        if self._voting_planned:
+            return "voting"
+        if self._mesh_shards_rows_only():
+            return "rows"
+        return ("feature" if self.config.tree_learner == "feature"
+                else "rows_x_feature")
+
     def _resolve_hist_backend(self) -> str:
-        """Pick the histogram backend. Under a row-sharded mesh the stream
-        kernel runs per-device inside shard_map with a histogram psum (the
-        reference's per-worker fast path + ReduceScatter,
-        data_parallel_tree_learner.cpp:285-299); feature-sharded meshes use
-        the contraction backends, which GSPMD partitions automatically.
+        """The histogram formulation this job runs: the facts
+        ops.histogram.resolve_hist_backend decides from, gathered.  Under a
+        row-sharded mesh the stream kernel runs per-device inside shard_map
+        with a histogram psum (the reference's per-worker fast path +
+        ReduceScatter, data_parallel_tree_learner.cpp:285-299); feature-
+        sharded meshes run the contractions, which GSPMD partitions.
 
-        ``auto`` on a TPU is ``stream`` at every table width: a table whose
-        one-hot does not fit VMEM whole is cut into M-tiles by the kernel
-        itself (_stream_fits), so the fused iteration runs wide tables too.
-        ``pallas`` (slot-sorted row blocks, a row gather every round) is
-        reachable by name only; off the chip ``auto`` is ``segsum``.
-
-        ``LGBTPU_HIST_BACKEND`` overrides the param (A/B experiments across
-        the histogram formulations, docs/PERF.md) and passes through the
-        same validation/mesh gates as the param itself."""
+        ``LGBTPU_HIST_BACKEND`` overrides the param (A/B runs; read here and
+        nowhere else) and passes through the same validation as the param."""
         import os as _os
-        b = (_os.environ.get("LGBTPU_HIST_BACKEND", "")
-             or self.config.hist_backend)
-        if b not in HIST_BACKENDS:
-            raise LightGBMError(
-                f"unknown hist_backend={b!r}; one of {HIST_BACKENDS}")
         tpu = on_tpu()
-        if b == "scatter" and tpu:
-            raise LightGBMError(
-                "hist_backend=scatter cannot run on a TPU: Mosaic has no "
-                "lowering for the kernel's scatter-add (\"Unimplemented "
-                "primitive in Pallas TPU lowering for KernelType.TC: "
-                "scatter-add\"), so it only ever runs interpreted on the "
-                "CPU — use hist_backend=stream (or auto)")
-        if self.mesh is not None:
-            if b == "scatter":
-                raise LightGBMError(
-                    "hist_backend=scatter is single-device only (the "
-                    "scatter tile is one unsharded VMEM block); use "
-                    "hist_backend=stream or the contraction backends "
-                    "under a mesh")
-            if self._voting_planned:
-                # the PV-Tree shard_map learner ignores the hist backend;
-                # avoid packing a stream layout it would never read
-                return "onehot" if tpu else "segsum"
-            rows_only = self._mesh_shards_rows_only()
-            if b == "stream" or (b == "auto" and tpu and rows_only
-                                 and self._stream_fits()):
-                if not rows_only:
-                    raise LightGBMError(
-                        "hist_backend=stream under a mesh needs row-only "
-                        "sharding (tree_learner=data on a data-only mesh); "
-                        "feature/2D sharding cannot stream packed group "
-                        "words — use hist_backend=segsum or onehot")
-                return "stream"
-            if b != "auto":
-                return b
-            return "onehot" if tpu else "segsum"
-        if b != "auto":
-            return b
-        if tpu and self._stream_fits():
-            return "stream"
-        return "pallas" if tpu else "segsum"
+        return resolve_hist_backend(
+            _os.environ.get("LGBTPU_HIST_BACKEND", "")
+            or self.config.hist_backend,
+            tpu=tpu, mesh=self._mesh_kind(),
+            # asked on a TPU only: off it the kernel module stays unimported
+            stream_fits=tpu and self._stream_fits())
 
     def _resolve_hist_precision(self) -> str:
         """Histogram/scan precision. 'double' mirrors the reference's
@@ -834,14 +784,11 @@ class GBDT:
         p = self.config.hist_precision
         backend = self._resolve_hist_backend()
         if p == "auto":
-            return "double" if backend in ("segsum", "onehot") \
-                and platform_name() == "cpu" \
+            return "double" if platform_name() == "cpu" \
+                and hist_backend_refusal(backend, double=True) is None \
                 and not self._voting_planned else "single"
-        if p == "double" and backend in ("stream", "pallas", "scatter"):
-            raise LightGBMError(
-                "hist_precision=double requires hist_backend=segsum or "
-                "onehot (the TPU stream/pallas/scatter kernels are "
-                "f32/int8)")
+        if p == "double":
+            check_hist_backend(backend, double=True)
         if p == "double" and self._voting_planned:
             raise LightGBMError(
                 "hist_precision=double is not supported with "
@@ -1204,12 +1151,6 @@ class GBDT:
             raise LightGBMError(
                 f"unknown hist_backend={c.hist_backend!r}; one of "
                 f"{HIST_BACKENDS}")
-        if c.hist_backend == "scatter" and c.tree_learner == "feature":
-            raise LightGBMError(
-                "hist_backend=scatter is not supported with "
-                "tree_learner=feature (the scatter tile is one unsharded "
-                "VMEM block; group sharding cannot slice it) — use "
-                "hist_backend=segsum or onehot")
         if c.hist_packed_width not in (32, 16, 8):
             raise LightGBMError(
                 f"hist_packed_width={c.hist_packed_width!r} is not one of "
@@ -1745,8 +1686,6 @@ class GBDT:
                 "'pad'")
         gp = self._grow_params
         eligible = (cmode in ("auto", "pad")
-                    and gp.hist_backend in ("stream", "segsum", "onehot",
-                                        "scatter")
                     and (self.mesh is None or self._mesh_stream
                          or self._voting or self._feature_mode))
         if not eligible:

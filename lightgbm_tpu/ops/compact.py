@@ -1,20 +1,14 @@
-"""Slot compaction: sort rows by histogram slot and emit fixed-size row blocks that
-each belong to exactly ONE slot, as a compact gather plan.
+"""Row compaction for sampled trees (GOSS / bagging): one stable partition per
+tree moves the in-bag rows to the front of a fixed-capacity view, so every
+histogram pass of that tree scans the SAMPLED row count.
 
 Reference analog: src/treelearner/data_partition.hpp (LightGBM keeps rows of one leaf
 contiguous via a parallel stable partition so per-leaf histograms scan a contiguous
-range) and src/treelearner/cuda/cuda_data_partition.cu (prefix-sum compaction on
-device). The TPU re-design reaches the same contiguity with a device-wide key sort +
-per-block gather indices:
+range), src/boosting/bagging.hpp (the in-bag prefix) and
+src/treelearner/cuda/cuda_data_partition.cu (prefix-sum compaction on device). The
+TPU re-design reaches the same contiguity with a device-wide key sort.
 
-  * rows are sorted by slot (invalid rows, slot < 0, sort to the end),
-  * each slot's run is covered by ceil(count/T) blocks of T rows; a block's rows are
-    fetched through a gather-index vector, with out-of-run positions pointing at a
-    zero pad row (so no in-kernel row masking is needed),
-  * per-block scalars (slot, is_first, is_last) are scalar-prefetched by the Pallas
-    kernel so block -> histogram-slot mapping costs one SMEM read.
-
-Everything here is O(N log N) sort + O(S) scalar math — no (N, S) intermediates.
+Everything here is an O(N log N) sort + gathers — no (N, S) intermediates.
 """
 from __future__ import annotations
 
@@ -36,8 +30,7 @@ class SamplePlan(NamedTuple):
     instead of ``N / T``, so the dominant one-hot MAC cost scales with the
     SAMPLED row count.  Positions past ``nc`` hold out-of-bag rows whose
     grad/hess/count weights are already exactly 0 (the mask multiplied
-    them), so no in-kernel masking is needed — the same pad-row trick
-    ``BlockPlan`` uses.
+    them), so no in-kernel masking is needed.
 
     Bit-exactness contract: the stable partition keeps sampled rows in
     original relative order, and truncating the all-zero-weight tail
@@ -63,17 +56,6 @@ def plan_sample_rows(mask: jax.Array, capacity: int) -> SamplePlan:
     _, perm = jax.lax.sort_key_val(key, jnp.arange(n, dtype=i32))
     return SamplePlan(perm=perm[:capacity],
                       nc=jnp.sum(in_bag.astype(i32)))
-
-
-def check_compact_supported(hist_backend: str, mesh) -> None:
-    """Eligibility guard shared by grow_tree and grow_tree_k (the engine
-    pre-screens the same conditions; this catches direct callers)."""
-    if hist_backend == "pallas":
-        raise ValueError("row compaction supports the stream/segsum/onehot/"
-                         "scatter histogram backends only")
-    if mesh is not None and hist_backend != "stream":
-        raise ValueError("row compaction under a mesh requires "
-                         "hist_backend=stream (per-shard partition)")
 
 
 def compact_row_views(bins: jax.Array, grad: jax.Array, hess: jax.Array,
@@ -125,79 +107,3 @@ def compact_transposed_view(bins_T: jax.Array, w_T: jax.Array,
                 (P(None, row_axis), P(None, row_axis)),
                 (P(None, row_axis), P(None, row_axis)))(bins_T, w_T)
         return _local(bins_T, w_T)
-
-
-class BlockPlan(NamedTuple):
-    gather_idx: jax.Array    # (NB*T,) i32 — source row per block position; n = pad row
-    scalars: jax.Array       # (NB, 3) i32 — (slot | -1, is_first, is_last)
-    counts: jax.Array        # (S,) i32 — rows per slot (for empty-slot masking)
-
-
-def num_blocks(n: int, num_slots: int, block_rows: int) -> int:
-    """Static worst-case block count: every slot may add one partial block."""
-    return -(-n // block_rows) + num_slots
-
-
-def plan_blocks(slot: jax.Array, num_slots: int, block_rows: int) -> BlockPlan:
-    """Build the sorted-row block plan for one histogram round.
-
-    slot: (N,) int32, histogram slot per row; negative = row not needed.
-    """
-    n = slot.shape[0]
-    T = block_rows
-    S = num_slots
-    NB = num_blocks(n, S, T)
-    i32 = jnp.int32
-
-    key = jnp.where(slot >= 0, slot, S).astype(i32)
-    sorted_key, perm = jax.lax.sort_key_val(key, jnp.arange(n, dtype=i32))
-
-    # run boundaries per slot (S+1 values; run_start[S] = first invalid row)
-    run_start = jnp.searchsorted(sorted_key, jnp.arange(S + 1, dtype=i32)).astype(i32)
-    counts = run_start[1:] - run_start[:-1]                      # (S,)
-    blocks_per_slot = -(-counts // T)
-    blk_off = jnp.concatenate([jnp.zeros(1, i32),
-                               jnp.cumsum(blocks_per_slot).astype(i32)])
-    total_blocks = blk_off[S]
-
-    b = jnp.arange(NB, dtype=i32)
-    s_of_b = (jnp.searchsorted(blk_off, b, side="right") - 1).astype(i32)
-    s_of_b = jnp.clip(s_of_b, 0, S - 1)
-    local = b - blk_off[s_of_b]
-    pos = run_start[s_of_b] + local * T                          # sorted-space start
-    real = b < total_blocks
-    first = real & (local == 0)
-    last = real & (local == blocks_per_slot[s_of_b] - 1)
-    # trailing pad blocks keep the LAST real block's slot (not -1 -> window 0):
-    # the Pallas output pipeline flushes the current VMEM buffer when the output
-    # block index changes or the grid ends, so pad blocks must stay on the last
-    # written window (their gather rows are all the zero pad row; first/last = 0
-    # means they neither reset nor rewrite the accumulator)
-    last_slot = jnp.max(jnp.where(blocks_per_slot > 0,
-                                  jnp.arange(S, dtype=i32), 0))
-    scalars = jnp.stack([jnp.where(real, s_of_b, last_slot),
-                         first.astype(i32), last.astype(i32)], axis=1)
-
-    # per-block gather indices into the original row order; out-of-run -> pad row n
-    gpos = pos[:, None] + jnp.arange(T, dtype=i32)[None, :]      # (NB, T)
-    in_run = real[:, None] & (gpos < run_start[s_of_b + 1][:, None])
-    src = jnp.take(perm, jnp.clip(gpos, 0, n - 1), axis=0)
-    gather_idx = jnp.where(in_run, src, n).reshape(-1)
-    return BlockPlan(gather_idx=gather_idx, scalars=scalars, counts=counts)
-
-
-def plan_single_slot(n: int, block_rows: int) -> BlockPlan:
-    """Trivial plan for the root histogram (every row in slot 0) — no sort needed."""
-    T = block_rows
-    NB = num_blocks(n, 1, T)
-    i32 = jnp.int32
-    b = jnp.arange(NB, dtype=i32)
-    nb_real = -(-n // T)
-    real = b < nb_real
-    scalars = jnp.stack([jnp.where(real, 0, -1),
-                         (b == 0).astype(i32),
-                         (b == nb_real - 1).astype(i32)], axis=1)
-    gpos = (b[:, None] * T + jnp.arange(T, dtype=i32)[None, :]).reshape(-1)
-    gather_idx = jnp.where(gpos < n, gpos, n)
-    return BlockPlan(gather_idx=gather_idx, scalars=scalars,
-                     counts=jnp.full((1,), n, i32))

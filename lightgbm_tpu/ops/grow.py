@@ -29,7 +29,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..tree import TreeArrays
-from .histogram import build_histograms, build_histograms_k
+from .histogram import (build_histograms, build_histograms_k,
+                        check_hist_backend, mesh_kind)
 from .split import (NEG_INF, EPS_HESS, FeatureLayout, SplitResult,
                     categorical_left_bitset, constrained_child_outputs,
                     find_best_splits, gather_feature_histograms, leaf_output,
@@ -375,9 +376,9 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     monotone: (F,) i32 in {-1,0,1} (reference: monotone_constraints.hpp, basic method).
     interaction_groups: (C, F) bool — allowed-feature groups (col_sampler.hpp).
     key: PRNGKey for per-node feature sampling / extra_trees random thresholds.
-    packed: precomputed packed-bin layout (StreamLayout for the stream backend,
-    packed (N, GW) words for the sorted pallas backend) — bins never change, so
-    the engine packs once per training run instead of once per tree.
+    packed: precomputed packed-bin layout of the stream backend (a StreamLayout
+    or its bins_T) — bins never change, so the engine packs once per training
+    run instead of once per tree.
     forced: static forced-split levels (reference: serial_tree_learner.cpp:628
     ForceSplits) — tuple of (leaf_ids, feats, thr_bins, default_lefts) tuples
     applied as unrolled rounds before gain-driven growth.
@@ -504,16 +505,13 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     # COMPOUND (feature, data) axis + a row-axis psum_scatter in the build
     use_2d = use_fp and row_axis is not None
     use_compact = compact_rows > 0
-    if use_compact:
-        from .compact import check_compact_supported
-        # feature-parallel replicates rows, so its compaction is the
-        # single-device stable partition (bins' sharded GROUP axis is
-        # untouched by the row gather); the 2D mesh shards rows too, so
-        # it keeps the mesh check (compaction unsupported there — GOSS/
-        # bagging run via exact zero-weight masking)
-        check_compact_supported(params.hist_backend,
-                                None if (use_fp and not use_2d) else mesh)
-    bins_packed = None
+    # feature-parallel replicates rows, so its compaction is the
+    # single-device stable partition (bins' sharded GROUP axis is untouched
+    # by the row gather); the 2D mesh shards rows too, and GOSS/bagging run
+    # there via exact zero-weight masking
+    check_hist_backend(params.hist_backend,
+                       mesh=mesh_kind(mesh, row_axis, feature_axis),
+                       double=params.hist_double, compact=use_compact)
     fuse, R_buf = False, 1   # GOSS+stream fusion (resolved in the stream block)
     Bpad = -(-Bmax // 8) * 8
     # reduce_scatter comms (docs/DISTRIBUTED.md): the histogram block is
@@ -542,12 +540,6 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     # traffic is best-split records, owner-shard categorical bitsets, and
     # one int32 per row for routing
     if use_fp:
-        if params.hist_backend not in ("segsum", "onehot"):
-            raise ValueError(
-                "feature-sharded growth (tree_learner=feature or the 2D "
-                "mesh) needs a contraction/segsum histogram backend (the "
-                "stream/pallas kernels pack row-major group words, which "
-                "group sharding cannot slice)")
         if not params.plain_growth or forced:
             raise ValueError(
                 "feature-sharded growth (tree_learner=feature or the 2D "
@@ -741,27 +733,17 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         if use_int:
             root_hist = root_hist.astype(f32) * hscale
     else:
-        if params.hist_backend == "pallas":
-            if packed is not None:
-                bins_packed = packed
-            else:
-                from ..pallas.hist_kernel import pack_bins
-                bins_packed = pack_bins(bins)
-
         if use_fp:
             # shard-local build: each device histograms only its G/D group
             # slice (zero collective — per-group sums are independent)
-            def _build_ns(bins_x, slot_x, g_x, h_x, c_x, nslots,
-                          packed_x=None):
+            def _build_ns(bins_x, slot_x, g_x, h_x, c_x, nslots):
                 return (fp_hist_1 if nslots == 1 else fp_hist_S)(
                     bins_x, slot_x, g_x, h_x, c_x)
         else:
-            def _build_ns(bins_x, slot_x, g_x, h_x, c_x, nslots,
-                          packed_x=None):
+            def _build_ns(bins_x, slot_x, g_x, h_x, c_x, nslots):
                 return build_histograms(
                     bins_x, slot_x, g_x, h_x, c_x, nslots, Bmax,
-                    backend=params.hist_backend, bins_packed=packed_x,
-                    acc_dtype=hdt)
+                    backend=params.hist_backend, acc_dtype=hdt)
         leaf_id = jnp.zeros(N, i32)
         leaf_id_c = jnp.zeros(1, i32)
         if use_compact:
@@ -776,8 +758,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 1)[..., :2]
         else:
             root_hist = _build_ns(
-                bins, leaf_id, grad, hess, cnt_w, 1,
-                packed_x=bins_packed)[..., :2]
+                bins, leaf_id, grad, hess, cnt_w, 1)[..., :2]
     root_g = jnp.sum(grad, dtype=hdt)
     root_h = jnp.sum(hess, dtype=hdt)
     root_c = jnp.sum(cnt_w, dtype=hdt)
@@ -1112,8 +1093,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                     hist3 = _build_ns(bins_c, jnp.take(slot, c_perm, axis=0),
                                       grad_c, hess_c, cnt_c, S)
                 else:
-                    hist3 = _build_ns(bins, slot, grad, hess, cnt_w, S,
-                                      packed_x=bins_packed)
+                    hist3 = _build_ns(bins, slot, grad, hess, cnt_w, S)
                 hist_small = hist3[..., :2]
                 # any one group's bins partition the slot's rows, so group 0's
                 # count channel sums to the exact per-slot data count
@@ -1716,7 +1696,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     against the stacked class x slot channel axis: the stream backend runs
     ONE route_and_hist kernel over (K, N) leaf ids with a (m_rows, 2*S*K)
     histogram block (the reference's one-histogram-pass-serves-all-classes
-    layout, cuda_histogram_constructor.cu), the onehot/pallas backends go
+    layout, cuda_histogram_constructor.cu), the segsum/onehot backends go
     through build_histograms_k. Everything per-class (candidate selection,
     split scans, node bookkeeping) is computed batched over the K axis with
     the SAME per-class arithmetic as grow_tree, and classes whose per-class
@@ -1772,10 +1752,9 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # ---- root ----
     use_stream = params.hist_backend == "stream"
     use_compact = compact_rows > 0
-    if use_compact:
-        from .compact import check_compact_supported
-        check_compact_supported(params.hist_backend, mesh)
-    bins_packed = None
+    check_hist_backend(params.hist_backend,
+                       mesh=mesh_kind(mesh, row_axis, feature_axis),
+                       double=params.hist_double, compact=use_compact)
     Bpad = -(-Bmax // 8) * 8
     # reduce_scatter comms for the widened K-class block: identical design
     # to grow_tree's (see there), scattering over the group axis of the
@@ -1783,7 +1762,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     use_rs = (mesh is not None and use_stream
               and params.hist_comms == "reduce_scatter")
     use_fp = mesh is not None and feature_axis is not None
-    if use_fp and (row_axis is None or use_stream):
+    if use_fp and row_axis is None:
         raise ValueError(
             "grow_tree_k shards the feature axis only as part of the 2D "
             "data x feature mesh with a contraction/segsum backend; use "
@@ -1798,11 +1777,6 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # 2D mesh: same ShardPlan machinery as grow_tree's, keyed by the
         # compound (feature, data) axis; the K-class build is the widened
         # variant of make_sharded_hist_2d
-        if params.hist_backend not in ("segsum", "onehot"):
-            raise ValueError(
-                "the 2D mesh needs a contraction/segsum histogram backend "
-                "(the stream/pallas kernels pack row-major group words, "
-                "which group sharding cannot slice)")
         from ..parallel.comms import (make_rs_context, make_sharded_hist_2d,
                                       make_sharded_bin_gather_2d)
         fp_plan, fp_split, fp_bitset = make_rs_context(
@@ -1922,12 +1896,6 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             root_hist = root_hist.astype(f32) \
                 * hscale[:, None, None, None, :]
     else:
-        if params.hist_backend == "pallas":
-            if packed is not None:
-                bins_packed = packed
-            else:
-                from ..pallas.hist_kernel import pack_bins
-                bins_packed = pack_bins(bins)
         leaf_id = jnp.zeros((K, N), i32)
         leaf_id_c = jnp.zeros((1, 1), i32)
         if use_compact:
@@ -1939,15 +1907,14 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             root_hist = build_histograms_k(
                 bins_c, jnp.zeros((K, compact_rows), i32), grad_c, hess_c,
                 cnt_c, K, 1, Bmax, backend=params.hist_backend,
-                bins_packed=None, acc_dtype=hdt)[..., :2]
+                acc_dtype=hdt)[..., :2]
         elif use_fp:
             root_hist = fp_hist_1(bins, leaf_id, grad, hess,
                                   cnt_w)[..., :2]
         else:
             root_hist = build_histograms_k(
                 bins, leaf_id, grad, hess, cnt_w, K, 1, Bmax,
-                backend=params.hist_backend, bins_packed=bins_packed,
-                acc_dtype=hdt)[..., :2]
+                backend=params.hist_backend, acc_dtype=hdt)[..., :2]
     root_g = jnp.sum(grad, axis=1, dtype=hdt)                # (K,)
     root_h = jnp.sum(hess, axis=1, dtype=hdt)
     root_c = jnp.broadcast_to(jnp.sum(cnt_w, dtype=hdt), (K,))
@@ -2226,15 +2193,13 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     hist3 = build_histograms_k(
                         bins_c, jnp.take(slot, c_perm, axis=1), grad_c,
                         hess_c, cnt_c, K, S, Bmax,
-                        backend=params.hist_backend, bins_packed=None,
-                        acc_dtype=hdt)
+                        backend=params.hist_backend, acc_dtype=hdt)
                 elif use_fp:
                     hist3 = fp_hist_S(bins, slot, grad, hess, cnt_w)
                 else:
                     hist3 = build_histograms_k(
                         bins, slot, grad, hess, cnt_w, K, S, Bmax,
-                        backend=params.hist_backend, bins_packed=bins_packed,
-                        acc_dtype=hdt)
+                        backend=params.hist_backend, acc_dtype=hdt)
                 hist_small = hist3[..., :2]
                 slot_cnt = hist3[:, :, 0, :, 2].sum(axis=-1)
             lc_x = jnp.where(smaller_is_left, slot_cnt, pc - slot_cnt)

@@ -12,33 +12,128 @@ with w = (grad, hess, count). ``slot`` assigns each row to the histogram slot of
 over the data. Histogram layout is (S, G, Bmax, 3) — groups padded to a common bin count,
 which keeps shapes static for XLA.
 
-Backends:
-  * ``segsum``  — jax.ops.segment_sum scatter (correct everywhere; fast on CPU).
-  * ``onehot``  — blocked one-hot matmul (MXU path, pure XLA).
-  * ``pallas``  — fused Pallas TPU kernel (see pallas/hist_kernel.py).
-  * ``scatter`` — Pallas scatter-add into a VMEM-resident tile, no one-hot
-    (pallas/scatter_hist_kernel.py; VMEM-gated with one-hot fallback —
-    the cuda_histogram_constructor formulation).
+The formulations (``hist_backend``; HIST_BACKENDS is the one list of the names):
+  * ``segsum`` — jax.ops.segment_sum scatter.  The reference every other one is
+    tested against, and what ``auto`` is off the chip.
+  * ``onehot`` — blocked one-hot matmul, pure XLA, so GSPMD can partition it: what
+    a feature-sharded or 2D mesh and the voting learner run on a TPU.
+  * ``stream`` — the growers' fused route + histogram Pallas kernel
+    (pallas/stream_kernel.py) over a packed transposed bin copy: what ``auto``
+    is on a TPU and what every benchmark cell runs.  It is a fork of the
+    growers (``use_stream``), not of build_histograms.
+  * ``auto``   — resolve_hist_backend()'s choice from what it can observe.
+
+The rule lives here and nowhere else: resolve_hist_backend() turns a requested
+name into a resolved one (the engine gathers the facts; build_histograms*'s
+``auto`` goes through the same function), and hist_backend_refusal() says which
+jobs (mesh kind, double precision, row compaction) a formulation cannot run —
+the engine's validation and both growers ask it instead of listing names.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from ..runtime import on_tpu
+from ..utils.log import LightGBMError
 
 NUM_CHANNELS = 3  # grad, hess, count
+
+HIST_BACKENDS = ("auto", "segsum", "onehot", "stream")
+# how the bin matrix is sharded: not at all, over rows alone (tree_learner=
+# data), over feature groups (tree_learner=feature), over both (the 2D mesh),
+# or over rows under the voting learner's own shard_map grower
+MESH_KINDS = ("none", "rows", "feature", "rows_x_feature", "voting")
+
+
+def mesh_kind(mesh, row_axis, feature_axis) -> str:
+    """The MESH_KINDS name of a grower's (mesh, row_axis, feature_axis)."""
+    if mesh is None:
+        return "none"
+    if feature_axis is None:
+        return "rows"
+    return "feature" if row_axis is None else "rows_x_feature"
+
+
+def hist_backend_refusal(backend: str, *, mesh: str = "none",
+                         double: bool = False,
+                         compact: bool = False) -> Optional[str]:
+    """THE capability check: why ``backend`` cannot run a job, or None.
+
+    mesh: a MESH_KINDS name; double: hist_precision=double (f64 histograms);
+    compact: GOSS/bagging row compaction.  Anything but ``stream`` is a
+    contraction XLA partitions and widens freely.
+    """
+    if mesh not in MESH_KINDS:
+        raise ValueError(f"unknown mesh kind {mesh!r}; one of {MESH_KINDS}")
+    if backend == "stream":
+        if mesh in ("feature", "rows_x_feature"):
+            return ("feature-sharded growth (tree_learner=feature or the 2D "
+                    "data x feature mesh) needs hist_backend=segsum or "
+                    "onehot: the stream kernel packs row-major group words, "
+                    "which group sharding cannot slice")
+        if double:
+            return ("hist_precision=double requires hist_backend=segsum or "
+                    "onehot (the stream kernel is f32/int8)")
+    if compact and (mesh == "rows_x_feature"
+                    or (mesh == "rows" and backend != "stream")):
+        return ("row compaction under a row-sharded mesh requires "
+                "hist_backend=stream on a rows-only mesh (per-shard "
+                "partition)")
+    return None
+
+
+def check_hist_backend(backend: str, **job) -> None:
+    """Raise hist_backend_refusal()'s reason as a LightGBMError."""
+    why = hist_backend_refusal(backend, **job)
+    if why:
+        raise LightGBMError(why)
+
+
+def resolve_hist_backend(requested: str, *, tpu: bool, mesh: str = "none",
+                         stream_fits: bool = False) -> str:
+    """THE rule: a requested ``hist_backend`` to the formulation that runs.
+
+    tpu: on a TPU or not; mesh: a MESH_KINDS name; stream_fits: whether the
+    stream kernel takes the job (GBDT._stream_fits: leaves, splits a round
+    and bin width; never for a bare build_histograms call, which has no
+    route to fuse).  A name the job cannot run is a LightGBMError, except
+    under the voting learner, which ignores the request (its shard_map
+    grower never reads the stream layout).
+    """
+    if requested not in HIST_BACKENDS:
+        raise LightGBMError(
+            f"unknown hist_backend={requested!r}; one of {HIST_BACKENDS}")
+    contraction = "onehot" if tpu else "segsum"
+    if mesh == "voting":
+        return contraction
+    if requested != "auto":
+        check_hist_backend(requested, mesh=mesh)
+        return requested
+    if tpu and stream_fits \
+            and hist_backend_refusal("stream", mesh=mesh) is None:
+        return "stream"
+    return contraction
+
+
+def _op_backend(backend: str) -> str:
+    """build_histograms*'s backend: the engine's rule with no stream kernel
+    on offer (``stream`` is the growers' fused pass, not an op)."""
+    backend = resolve_hist_backend(backend, tpu=on_tpu())
+    if backend == "stream":
+        raise ValueError(
+            "hist_backend=stream is the growers' fused route+histogram "
+            "kernel (pallas/stream_kernel.route_and_hist); build_histograms "
+            "runs segsum or onehot")
+    return backend
 
 
 def build_histograms(bins: jax.Array, slot: jax.Array, grad: jax.Array,
                      hess: jax.Array, cnt: jax.Array, num_slots: int,
                      max_group_bins: int, backend: str = "auto",
                      block_rows: int = 16384, dtype=jnp.float32,
-                     bins_packed: Optional[jax.Array] = None,
                      acc_dtype=jnp.float32) -> jax.Array:
     """Build per-slot histograms.
 
@@ -49,38 +144,19 @@ def build_histograms(bins: jax.Array, slot: jax.Array, grad: jax.Array,
       cnt: (N,) float32 count weight (the bagging mask itself; 1.0 = in-bag).
       num_slots: S (static).
       max_group_bins: Bmax (static).
-      acc_dtype: accumulator dtype. float64 (hist_precision=double, segsum/
-        onehot only; needs an enclosing jax.enable_x64) mirrors the
+      acc_dtype: accumulator dtype. float64 (hist_precision=double; needs an
+        enclosing jax.enable_x64) mirrors the
         reference's float32-gradients-into-double-histograms arithmetic
         (hist_t, src/io/dense_bin.hpp) so near-tied split gains resolve the
         same way stock LightGBM resolves them.
     Returns:
       (S, G, Bmax, 3) acc_dtype histograms.
     """
-    if backend == "auto":
-        backend = "pallas" if on_tpu() else "segsum"
-    if backend == "segsum":
+    if _op_backend(backend) == "segsum":
         return _hist_segsum(bins, slot, grad, hess, cnt, num_slots, max_group_bins,
                             acc_dtype)
-    if backend == "onehot":
-        return _hist_onehot(bins, slot, grad, hess, cnt, num_slots, max_group_bins,
-                            block_rows, dtype, acc_dtype)
-    if backend == "pallas":
-        from ..pallas.hist_kernel import build_histograms_sorted
-        return build_histograms_sorted(bins, slot, grad, hess, cnt, num_slots,
-                                       max_group_bins, bins_packed=bins_packed)
-    if backend == "scatter":
-        from ..pallas.scatter_hist_kernel import (build_histograms_scatter,
-                                                  scatter_hist_fits)
-        if scatter_hist_fits(num_slots, bins.shape[1], max_group_bins):
-            return build_histograms_scatter(bins, slot, grad, hess, cnt,
-                                            num_slots, max_group_bins)
-        # VMEM gate refused the scatter tile: automatic one-hot fallback
-        # (same histogram from the contraction formulation —
-        # tests/test_hist_backends.py asserts the identity)
-        return _hist_onehot(bins, slot, grad, hess, cnt, num_slots,
-                            max_group_bins, block_rows, dtype, acc_dtype)
-    raise ValueError(f"unknown hist backend {backend!r}")
+    return _hist_onehot(bins, slot, grad, hess, cnt, num_slots, max_group_bins,
+                        block_rows, dtype, acc_dtype)
 
 
 def _hist_segsum(bins, slot, grad, hess, cnt, num_slots, max_group_bins,
@@ -150,60 +226,27 @@ def build_histograms_k(bins: jax.Array, slot: jax.Array, grad: jax.Array,
                        num_slots: int, max_group_bins: int,
                        backend: str = "auto", block_rows: int = 16384,
                        dtype=jnp.float32,
-                       bins_packed: Optional[jax.Array] = None,
                        acc_dtype=jnp.float32) -> jax.Array:
     """Per-class per-slot histograms for the BATCHED MULTICLASS path.
 
     slot/grad/hess: (K, N) — class k's histogram slot / gradient per row;
     cnt: (N,) shared count weight. Returns (K, S, G, Bmax, 3) acc_dtype.
 
-    The onehot and pallas backends amortize the class-independent bin
-    one-hot across the stacked class x slot channel axis — ONE widened
-    contraction serves all K classes' gradient channels (the reference's
-    single histogram pass over all class gradients,
-    cuda_histogram_constructor.cu) — while segsum vmaps the per-class
-    scatter so each class's sums are bit-identical to a standalone call.
+    onehot amortizes the class-independent bin one-hot across the stacked
+    class x slot channel axis — ONE widened contraction serves all K
+    classes' gradient channels (the reference's single histogram pass over
+    all class gradients, cuda_histogram_constructor.cu) — while segsum
+    vmaps the per-class scatter so each class's sums are bit-identical to
+    a standalone call.
     """
-    if backend == "auto":
-        backend = "pallas" if on_tpu() else "segsum"
-    if backend == "segsum":
+    if _op_backend(backend) == "segsum":
         return jax.vmap(
             lambda s, g, h: _hist_segsum(bins, s, g, h, cnt, num_slots,
                                          max_group_bins, acc_dtype)
         )(slot, grad, hess)
-    if backend == "onehot":
-        return _hist_onehot_k(bins, slot, grad, hess, cnt, num_class,
-                              num_slots, max_group_bins, block_rows, dtype,
-                              acc_dtype)
-    if backend == "pallas":
-        from ..pallas.hist_kernel import (build_histograms_sorted,
-                                          build_histograms_wide,
-                                          wide_hist_fits)
-        if wide_hist_fits(num_class, num_slots, max_group_bins,
-                          bins.shape[1]):
-            return build_histograms_wide(bins, slot, grad, hess, cnt,
-                                         num_slots, max_group_bins,
-                                         bins_packed=bins_packed)
-        # widened block too large for VMEM: per-class sorted kernels
-        # (scan-equivalent cost, always correct)
-        return jnp.stack([
-            build_histograms_sorted(bins, slot[k], grad[k], hess[k], cnt,
-                                    num_slots, max_group_bins,
-                                    bins_packed=bins_packed)
-            for k in range(num_class)])
-    if backend == "scatter":
-        from ..pallas.scatter_hist_kernel import (build_histograms_scatter_k,
-                                                  scatter_hist_fits)
-        if scatter_hist_fits(num_slots, bins.shape[1], max_group_bins,
-                             num_class):
-            return build_histograms_scatter_k(bins, slot, grad, hess, cnt,
-                                              num_class, num_slots,
-                                              max_group_bins)
-        # VMEM gate refused the widened scatter tile: one-hot fallback
-        return _hist_onehot_k(bins, slot, grad, hess, cnt, num_class,
-                              num_slots, max_group_bins, block_rows, dtype,
-                              acc_dtype)
-    raise ValueError(f"unknown hist backend {backend!r}")
+    return _hist_onehot_k(bins, slot, grad, hess, cnt, num_class,
+                          num_slots, max_group_bins, block_rows, dtype,
+                          acc_dtype)
 
 
 def _hist_onehot_k(bins, slot, grad, hess, cnt, num_class, num_slots,

@@ -44,7 +44,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .hist_kernel import _wsplit  # shared f32 -> (hi, lo) bf16 split
 from ..telemetry.watchdog import watched_jit
 from ..binning import bucket_group_pad, bucket_run_rows
 from ..runtime import on_tpu, pallas_interpret
@@ -275,6 +274,13 @@ def _count_slots(cnt_ref, w_ref, slots, *, T, S, K, f32_dots):
         cnt_row.astype(bf16), slot_oh, (((1,), (1,)), ((), ())),
         preferred_element_type=f32)
     return s_iota, slot_ohs
+
+
+def _wsplit(w):
+    """Split f32 weights into (hi, lo) bf16 parts: w ~= hi + lo exactly enough."""
+    hi = w.astype(jnp.bfloat16)
+    lo = (w - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, lo
 
 
 def _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
@@ -980,19 +986,12 @@ class StreamLayout(NamedTuple):
     num_groups: int
 
 
-def _use_u8_layout(max_bin_value: int = 127) -> bool:
-    """Unpacked (G_pad, N_pad) int8 bins: identical HBM bytes to the packed
-    4-per-word form, but the kernel skips all shift/mask unpack work.
-    Requires bins < 128 (int8); LGBTPU_STREAM_PACKED=1 forces the old
-    packed layout."""
-    return _os.environ.get("LGBTPU_STREAM_PACKED", "") != "1"
-
-
 def pack_bins_T(bins: jax.Array, block_rows: int = 1024,
                 max_bins: int = 256, tile_groups: int = 0) -> StreamLayout:
     """(N, G) uint8 -> transposed (GW_pad, N_pad) i32 packed layout, or the
-    (G_pad, N_pad) i8 unpacked layout when bins fit int8 (the kernel
-    dispatches on the dtype).  With tile_groups (stream_tiling's, a
+    (G_pad, N_pad) i8 unpacked layout when bins fit int8 (max_bins <= 127:
+    identical HBM bytes, and the kernel, which dispatches on the dtype,
+    skips all shift/mask unpack work).  With tile_groups (stream_tiling's, a
     multiple of 32) the groups pad to whole tiles; padded groups hold bin 0
     and their histogram rows are dropped.  A NumPy table is packed in NumPy
     and comes back as NumPy (predict's batches: the device is then handed
@@ -1001,7 +1000,7 @@ def pack_bins_T(bins: jax.Array, block_rows: int = 1024,
     n, g = bins.shape
     n_pad = -(-n // block_rows) * block_rows
     per = tile_groups or 32            # i8 tiling: 32-sublane multiples
-    if max_bins <= 127 and _use_u8_layout():
+    if max_bins <= 127:
         g_pad = -(-g // per) * per
         w = xp.pad(bins, ((0, n_pad - n), (0, g_pad - g))).astype(xp.int8)
         return StreamLayout(bins_T=w.T, n_pad=n_pad, num_groups=g)

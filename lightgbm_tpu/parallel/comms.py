@@ -24,7 +24,7 @@ same shard_map that runs the per-device streaming kernel,
 
 `hist_comms_dtype=bf16_pair` additionally halves the wire payload: remote
 contributions ride the HIGH half of the f32 high/low bf16 split (the same
-two-pass trick the histogram kernel uses, pallas/hist_kernel._wsplit), each
+two-pass trick the histogram kernel uses, pallas/stream_kernel._wsplit), each
 device's own-slice contribution stays exact f32 (its low half never needed
 the wire), and the cross-device accumulation runs in f32 — contributions are
 quantized at most once and partial sums never round to bf16.  Opt-in: not

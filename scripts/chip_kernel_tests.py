@@ -2,10 +2,9 @@
 
 tier-1 runs these files on the CPU with Pallas interpreted; this runs the same
 assertions against the compiled Mosaic kernels: the stream kernel (bucketed
-M-axis, int8 exactness, final sprint), the four `hist_backend=pallas` kernels,
-`predict_stream`, batched multiclass (K > 1 route folding), row compaction
-and GOSS route fusion (`route_replay`).  JAX is initialised on the TPU before pytest imports
-anything, and `--noconftest` keeps tests/conftest.py from being loaded as a
+M-axis, int8 exactness, final sprint), `predict_stream`, batched multiclass
+(K > 1 route folding), row compaction and GOSS route fusion (`route_replay`).
+JAX is initialised on the TPU before pytest imports anything, and `--noconftest` keeps tests/conftest.py from being loaded as a
 plugin (a test module that imports its helpers still can: by then the
 platform is fixed).  Tests whose expectation is CPU-specific can fail here for
 that reason — read each failure; this is an investigation, not a gate.
@@ -19,11 +18,9 @@ ROOT = Path(__file__).resolve().parent.parent
 sys.path.insert(0, str(ROOT))
 sys.path.insert(0, str(ROOT / "tests"))
 
-# hist_backend=scatter is refused on a TPU by design (no Mosaic lowering)
-DEFAULT = ["tests/test_stream_kernel.py", "tests/test_pallas_hist.py",
+DEFAULT = ["tests/test_stream_kernel.py",
            "tests/test_predict_kernel.py", "tests/test_multiclass_batched.py",
-           "tests/test_sample_compact.py", "tests/test_hist_backends.py",
-           "-k", "not scatter"]
+           "tests/test_sample_compact.py", "tests/test_hist_backends.py"]
 
 
 def main() -> int:

@@ -147,14 +147,8 @@ def measure(workload: Optional[Dict[str, Any]] = None) -> Dict[str, Any]:
     entries["grow_tree_feature"] = _measure_feature_grow(w)
     if entries["grow_tree_feature"].get("available") is False:
         unavailable.append("grow_tree_feature")
-    # histogram-floor backends (PR "break the histogram floor"): the
-    # scatter-add grow program (single device) and the packed-int16-wire
-    # quantized grow program (4-device CPU mesh) — each in a subprocess
-    # for the same jax-init reasons as the feature entry
-    entries["grow_tree_scatter"] = _measure_backend_grow(
-        w, {"hist_backend": "scatter", "hist_precision": "single"}, 0)
-    if entries["grow_tree_scatter"].get("available") is False:
-        unavailable.append("grow_tree_scatter")
+    # the packed-int16-wire quantized grow program (4-device CPU mesh),
+    # in a subprocess for the same jax-init reasons as the feature entry
     entries["grow_tree_packed16"] = _measure_backend_grow(
         w, {"hist_backend": "stream", "tree_learner": "data",
             "use_quantized_grad": True, "hist_packed_width": 16}, 4)

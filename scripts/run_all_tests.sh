@@ -145,20 +145,11 @@ BENCH_TASK=goss \
 BENCH_ROWS="${BENCH_ROWS:-100000}" \
 BENCH_GOSS_ITERS="${BENCH_GOSS_ITERS:-5}" \
     python bench.py
-# histogram-formulation floor: the backend identity matrix (scatter
-# bitwise vs segsum, packed-wire byte halving, route-fusion bit-identity
-# + validation/env plumbing) then the reduced A/B matrix — every arm
-# AUC-gated, packed16 bytes/round must measure exactly half the int32
-# wire, fusion must drop hist/route_only_passes to 1/tree
-# (docs/PERF.md "histogram-formulation floor").  BENCH_HISTFLOOR_SMOKE=1
-# never clobbers the committed BENCH_HISTFLOOR.json artifact.
+# the histogram formulations: the rule that picks one (row by row), the
+# layout matrix of onehot and stream against segsum, packed-wire byte
+# halving, route-fusion bit-identity + validation/env plumbing
 echo "=== stage: histogram backend fast tier ==="
 python -m pytest tests/test_hist_backends.py -x -q -m 'not slow'
-echo "=== stage: histogram floor bench smoke (BENCH_TASK=histfloor) ==="
-BENCH_TASK=histfloor \
-BENCH_HISTFLOOR_SMOKE=1 \
-BENCH_HISTORY=0 \
-    python bench.py
 # perf sentinel: compiled-program cost budgets (per-entry XLA flops,
 # peak-HBM bytes, launches/iter on a fixed small workload vs
 # PERF_BUDGETS.json — deterministic, so the gate holds on any test box)

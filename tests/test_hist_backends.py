@@ -1,13 +1,11 @@
-"""Histogram-formulation floor A/B: backend identity matrix + fusion/packing.
+"""The histogram formulations behind ``hist_backend`` / env
+``LGBTPU_HIST_BACKEND``, and the options that ride on ``stream``.
 
-Three candidate formulations ride behind ``hist_backend`` / env
-``LGBTPU_HIST_BACKEND`` (docs/PERF.md "histogram-formulation floor"):
-
-  * ``scatter`` — Pallas scatter-add into a VMEM tile (no one-hot operand).
-    Bitwise-identical to ``segsum`` at the op level AND as trained models
-    once ``hist_precision=single`` is pinned (segsum/onehot auto-resolve
-    double on CPU; scatter is single-only).  VMEM-gated with an automatic
-    one-hot fallback.
+  * the rule — ``ops.histogram.resolve_hist_backend`` (a requested name to
+    the formulation that runs) and ``hist_backend_refusal`` (which jobs a
+    formulation cannot run), held row by row on the pure functions, plus
+    the engine-level refusals; ``segsum`` is the reference every trained
+    model here is compared against.
   * ``hist_packed_width`` 16/8 — the quantized grad/hess pair rides one
     int32/int16 wire lane through the mesh collective, halving/quartering
     psum_scatter bytes.  Kernel arithmetic stays exact int32; only the
@@ -33,12 +31,13 @@ import jax.numpy as jnp
 
 import lightgbm_tpu as lgb
 import lightgbm_tpu.telemetry as tel
-from lightgbm_tpu.ops.histogram import build_histograms
-from lightgbm_tpu.pallas.scatter_hist_kernel import scatter_hist_fits
+from lightgbm_tpu.ops import histogram as hist_ops
+from lightgbm_tpu.ops.histogram import (build_histograms,
+                                        hist_backend_refusal,
+                                        resolve_hist_backend)
 from lightgbm_tpu.utils.log import LightGBMError
 
-from conftest import (make_synthetic_binary, make_synthetic_multiclass,
-                      make_synthetic_regression)
+from conftest import make_synthetic_binary, make_synthetic_regression
 
 N_DEV = len(jax.devices())
 needs_mesh = pytest.mark.skipif(N_DEV < 4, reason="needs a >=4-device mesh")
@@ -79,8 +78,6 @@ def _datasets():
 
 
 def _train(params, data_kw, ds_kw, backend, rounds=6, **extra):
-    # max_bin=63 keeps Bmax under the scatter VMEM gate (128) so the
-    # scatter kernel actually runs instead of its one-hot fallback
     p = dict(params, num_leaves=15, verbosity=-1, min_data_in_leaf=5,
              max_bin=63, hist_backend=backend, hist_precision="single",
              **extra)
@@ -90,8 +87,110 @@ def _train(params, data_kw, ds_kw, backend, rounds=6, **extra):
 
 
 # ---------------------------------------------------------------------------
-# op-level identity + VMEM gate
+# the rule, row by row, on the pure functions
 # ---------------------------------------------------------------------------
+
+_REFUSED = "refused"
+# (on a TPU, mesh kind, requested, stream kernel takes the job) -> resolved
+_RESOLUTION = [
+    # off the chip auto is the reference, under every mesh
+    (False, "none", "auto", True, "segsum"),
+    (False, "none", "segsum", True, "segsum"),
+    (False, "none", "onehot", True, "onehot"),
+    (False, "none", "stream", True, "stream"),
+    (False, "rows", "auto", True, "segsum"),
+    (False, "rows", "segsum", True, "segsum"),
+    (False, "rows", "onehot", True, "onehot"),
+    (False, "rows", "stream", True, "stream"),
+    (False, "feature", "auto", True, "segsum"),
+    (False, "feature", "segsum", True, "segsum"),
+    (False, "feature", "onehot", True, "onehot"),
+    (False, "feature", "stream", True, _REFUSED),
+    (False, "rows_x_feature", "auto", True, "segsum"),
+    (False, "rows_x_feature", "segsum", True, "segsum"),
+    (False, "rows_x_feature", "onehot", True, "onehot"),
+    (False, "rows_x_feature", "stream", True, _REFUSED),
+    # the voting learner's own grower ignores the request
+    (False, "voting", "auto", True, "segsum"),
+    (False, "voting", "segsum", True, "segsum"),
+    (False, "voting", "onehot", True, "segsum"),
+    (False, "voting", "stream", True, "segsum"),
+    # on the chip auto is stream wherever rows alone are sharded
+    (True, "none", "auto", True, "stream"),
+    (True, "none", "segsum", True, "segsum"),
+    (True, "none", "onehot", True, "onehot"),
+    (True, "none", "stream", True, "stream"),
+    (True, "rows", "auto", True, "stream"),
+    (True, "rows", "segsum", True, "segsum"),
+    (True, "rows", "onehot", True, "onehot"),
+    (True, "rows", "stream", True, "stream"),
+    (True, "feature", "auto", True, "onehot"),
+    (True, "feature", "segsum", True, "segsum"),
+    (True, "feature", "onehot", True, "onehot"),
+    (True, "feature", "stream", True, _REFUSED),
+    (True, "rows_x_feature", "auto", True, "onehot"),
+    (True, "rows_x_feature", "segsum", True, "segsum"),
+    (True, "rows_x_feature", "onehot", True, "onehot"),
+    (True, "rows_x_feature", "stream", True, _REFUSED),
+    (True, "voting", "auto", True, "onehot"),
+    (True, "voting", "segsum", True, "onehot"),
+    (True, "voting", "onehot", True, "onehot"),
+    (True, "voting", "stream", True, "onehot"),
+    # the stream kernel does not take the job (over 2,048 leaves, over 255
+    # splits a round, bins too wide for a tile): auto falls to onehot — the
+    # row that read `pallas` until its kernel left — and a name still holds
+    (True, "none", "auto", False, "onehot"),
+    (True, "rows", "auto", False, "onehot"),
+    (True, "none", "stream", False, "stream"),
+    (False, "none", "auto", False, "segsum"),
+]
+
+
+@pytest.mark.parametrize(
+    "tpu,mesh,requested,fits,expect", _RESOLUTION,
+    ids=[f"{'tpu' if t else 'cpu'}-{m}-{r}{'' if f else '-nofit'}"
+         for t, m, r, f, _ in _RESOLUTION])
+def test_hist_backend_resolution(tpu, mesh, requested, fits, expect):
+    kw = dict(tpu=tpu, mesh=mesh, stream_fits=fits)
+    if expect is _REFUSED:
+        with pytest.raises(LightGBMError, match="group sharding"):
+            resolve_hist_backend(requested, **kw)
+    else:
+        assert resolve_hist_backend(requested, **kw) == expect
+
+
+# (backend, job) -> a word of the refusal, or None where it runs
+_CAPABILITY = [
+    ("stream", dict(double=True), "double"),
+    ("segsum", dict(double=True), None),
+    ("onehot", dict(double=True), None),
+    ("stream", dict(mesh="feature"), "group sharding"),
+    ("stream", dict(mesh="rows_x_feature"), "group sharding"),
+    ("stream", dict(mesh="rows"), None),
+    ("stream", dict(mesh="rows", compact=True), None),
+    ("segsum", dict(mesh="rows", compact=True), "compaction"),
+    ("onehot", dict(mesh="rows_x_feature", compact=True), "compaction"),
+    ("onehot", dict(mesh="feature", compact=True), None),
+    ("segsum", dict(mesh="voting", compact=True), None),
+    ("segsum", dict(compact=True), None),
+    ("stream", dict(compact=True), None),
+]
+
+
+@pytest.mark.parametrize(
+    "backend,job,word", _CAPABILITY,
+    ids=[f"{b}-" + "-".join(f"{k}={v}" for k, v in j.items())
+         for b, j, _ in _CAPABILITY])
+def test_hist_backend_capability(backend, job, word):
+    why = hist_backend_refusal(backend, **job)
+    if word is None:
+        assert why is None
+        hist_ops.check_hist_backend(backend, **job)
+    else:
+        assert word in why
+        with pytest.raises(LightGBMError, match=word):
+            hist_ops.check_hist_backend(backend, **job)
+
 
 def _op_inputs(n=4096, g=4, bmax=32, s=8, seed=0):
     rs = np.random.RandomState(seed)
@@ -103,88 +202,87 @@ def _op_inputs(n=4096, g=4, bmax=32, s=8, seed=0):
     return bins, slot, grad, hess, cnt, s, bmax
 
 
-def test_scatter_op_bitwise_vs_segsum():
-    bins, slot, grad, hess, cnt, s, bmax = _op_inputs()
-    assert scatter_hist_fits(s, bins.shape[1], bmax)
-    h_sc = build_histograms(bins, slot, grad, hess, cnt, s, bmax,
-                            backend="scatter")
-    h_ss = build_histograms(bins, slot, grad, hess, cnt, s, bmax,
-                            backend="segsum")
-    # same row-major accumulation order as segment_sum -> byte equality
-    assert np.array_equal(np.asarray(h_sc), np.asarray(h_ss))
-    # one-hot reassociates the sum: allclose, not byte-equal
-    h_oh = build_histograms(bins, slot, grad, hess, cnt, s, bmax,
-                            backend="onehot")
-    np.testing.assert_allclose(np.asarray(h_sc), np.asarray(h_oh),
+@pytest.mark.parametrize("tpu", [False, True], ids=["cpu", "tpu"])
+def test_op_auto_is_the_engines_auto(tpu, monkeypatch):
+    """build_histograms(backend="auto") asks the engine's rule (with no
+    stream kernel on offer), so the two cannot disagree: the histogram is
+    byte-for-byte the one the resolved name builds."""
+    monkeypatch.setattr(hist_ops, "on_tpu", lambda: tpu)
+    name = resolve_hist_backend("auto", tpu=tpu, mesh="none",
+                                stream_fits=False)
+    assert name == ("onehot" if tpu else "segsum")
+    bins, slot, grad, hess, cnt, s, bmax = _op_inputs(n=1024)
+    h_auto = build_histograms(bins, slot, grad, hess, cnt, s, bmax)
+    h_name = build_histograms(bins, slot, grad, hess, cnt, s, bmax,
+                              backend=name)
+    assert np.array_equal(np.asarray(h_auto), np.asarray(h_name))
+    k_auto = hist_ops.build_histograms_k(
+        bins, slot[None], grad[None], hess[None], cnt, 1, s, bmax)
+    np.testing.assert_allclose(np.asarray(k_auto[0]), np.asarray(h_auto),
                                rtol=1e-5, atol=1e-5)
 
 
-def test_scatter_vmem_gate_falls_back_to_onehot():
-    # bmax > 128 refuses the scatter tile -> automatic one-hot fallback
-    bins, slot, grad, hess, cnt, s, _ = _op_inputs(bmax=32)
-    bmax = 200
-    assert not scatter_hist_fits(s, bins.shape[1], bmax)
-    h_sc = build_histograms(bins, slot, grad, hess, cnt, s, bmax,
-                            backend="scatter")
-    h_oh = build_histograms(bins, slot, grad, hess, cnt, s, bmax,
-                            backend="onehot")
-    assert np.array_equal(np.asarray(h_sc), np.asarray(h_oh))
-    # and the fallback is still a correct histogram
-    h_ss = build_histograms(bins, slot, grad, hess, cnt, s, bmax,
-                            backend="segsum")
-    np.testing.assert_allclose(np.asarray(h_sc), np.asarray(h_ss),
-                               rtol=1e-5, atol=1e-5)
-    # group-count gate (G > 64) closes too
-    assert not scatter_hist_fits(s, 65, 32)
+def test_op_refuses_stream_and_unknown_names():
+    bins, slot, grad, hess, cnt, s, bmax = _op_inputs(n=256)
+    with pytest.raises(ValueError, match="fused"):
+        build_histograms(bins, slot, grad, hess, cnt, s, bmax,
+                         backend="stream")
+    with pytest.raises(LightGBMError, match="unknown hist_backend"):
+        build_histograms(bins, slot, grad, hess, cnt, s, bmax,
+                         backend="vector")
 
 
 # ---------------------------------------------------------------------------
-# trained-model identity matrix (CPU fast tier)
+# trained-model layout matrix (NaN routing, a categorical column, EFB
+# bundles) on the formulations that remain, against the reference
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name,params,data_kw,ds_kw", _datasets())
-def test_scatter_model_bitwise_vs_segsum(name, params, data_kw, ds_kw):
-    """scatter grows the SAME trees as segsum byte-for-byte once
-    hist_precision=single is pinned (the default auto resolves double for
-    segsum on CPU but scatter is single-only — that A/B would compare
-    precisions, not formulations)."""
-    a = _train(params, data_kw, ds_kw, "segsum")
-    b = _train(params, data_kw, ds_kw, "scatter")
-    # the scatter tile must actually fit (else this compares the one-hot
-    # fallback, not the formulation under test)
-    dd = b.engine.dd
-    assert scatter_hist_fits(14, dd.num_groups, dd.max_bins)
-    assert _strip_params(a.model_to_string()) == \
-        _strip_params(b.model_to_string())
+_STRUCTURE = ("num_leaves", "split_feature", "threshold", "decision_type",
+              "left_child", "right_child", "leaf_count")
+# stream at one split a round grows segsum's trees when the gradients are
+# quantized (exact int32 sums on both sides); at the default 64 splits a
+# round it grows other trees by design
+_BACKEND_EXTRA = {
+    "onehot": {},
+    "stream": {"use_quantized_grad": True, "num_grad_quant_bins": 64,
+               "max_splits_per_round": 1},
+}
+_GOSS = {"data_sample_strategy": "goss", "top_rate": 0.2, "other_rate": 0.2,
+         "learning_rate": 0.5}
 
 
-@pytest.mark.slow
-def test_scatter_multiclass_and_bagging_identity():
-    X, y = make_synthetic_multiclass(n=1200, f=8, k=3)
-    mc = {"objective": "multiclass", "num_class": 3}
-    a = _train(mc, dict(data=X, label=y), {}, "segsum")
-    b = _train(mc, dict(data=X, label=y), {}, "scatter")
-    assert _strip_params(a.model_to_string()) == \
-        _strip_params(b.model_to_string())
-
-    Xb, yb = make_synthetic_binary(n=1500, f=8)
-    bag = {"objective": "binary", "bagging_fraction": 0.6,
-           "bagging_freq": 1, "bagging_seed": 3}
-    a = _train(bag, dict(data=Xb, label=yb), {}, "segsum")
-    b = _train(bag, dict(data=Xb, label=yb), {}, "scatter")
-    assert _strip_params(a.model_to_string()) == \
-        _strip_params(b.model_to_string())
+def _assert_same_structure(a, b, X, atol=1e-5):
+    ta, tb = a.engine.models, b.engine.models
+    assert len(ta) == len(tb)
+    for i, (x, y) in enumerate(zip(ta, tb)):
+        for f in _STRUCTURE:
+            assert np.array_equal(getattr(x, f), getattr(y, f)), (i, f)
+    np.testing.assert_allclose(b.predict(X), a.predict(X), rtol=0, atol=atol)
 
 
-def test_scatter_goss_identity():
+@pytest.mark.parametrize("backend", sorted(_BACKEND_EXTRA))
+@pytest.mark.parametrize("name,params,data_kw,ds_kw", _datasets(),
+                         ids=[d[0] for d in _datasets()])
+def test_model_structure_vs_segsum(name, params, data_kw, ds_kw, backend):
+    extra = _BACKEND_EXTRA[backend]
+    a = _train(params, data_kw, ds_kw, "segsum", **extra)
+    b = _train(params, data_kw, ds_kw, backend, **extra)
+    assert b.engine._grow_params.hist_backend == backend
+    _assert_same_structure(a, b, data_kw["data"])
+
+
+@pytest.mark.parametrize("backend", sorted(_BACKEND_EXTRA))
+def test_model_structure_vs_segsum_goss(backend):
     X, y = make_synthetic_binary(n=2000, f=8)
-    goss = {"objective": "binary", "data_sample_strategy": "goss",
-            "top_rate": 0.2, "other_rate": 0.2, "learning_rate": 0.5}
-    a = _train(goss, dict(data=X, label=y), {}, "segsum", rounds=6)
-    b = _train(goss, dict(data=X, label=y), {}, "scatter", rounds=6)
+    p = dict({"objective": "binary"}, **_GOSS)
+    extra = _BACKEND_EXTRA[backend]
+    a = _train(p, dict(data=X, label=y), {}, "segsum", **extra)
+    b = _train(p, dict(data=X, label=y), {}, backend, **extra)
     assert b.engine._last_compact_rows > 0   # sampling actually engaged
-    assert _strip_params(a.model_to_string()) == \
-        _strip_params(b.model_to_string())
+    # same trees; the f32 leaf values' last digits, amplified by GOSS's
+    # (1 - top_rate) / other_rate = 4 and learning_rate 0.5, move
+    # probabilities by up to 3.8e-5 here, so 1e-5 does not hold
+    _assert_same_structure(a, b, X, atol=1e-4)
 
 
 @pytest.mark.slow
@@ -194,7 +292,7 @@ def test_checkpoint_resume_identity_per_backend(tmp_path):
     allclose rather than byte equality — test_continued.py's contract)."""
     X, y = make_synthetic_binary(n=1200, f=8)
     Xv = X[:200]
-    for backend in ("segsum", "onehot", "scatter", "stream"):
+    for backend in ("segsum", "onehot", "stream"):
         params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
                   "min_data_in_leaf": 5, "max_bin": 63,
                   "hist_backend": backend, "hist_precision": "single"}
@@ -230,13 +328,27 @@ def test_invalid_backend_rejected_before_training():
                   "hist_backend")
 
 
-def test_scatter_rejects_feature_parallel():
-    _expect_error({"objective": "binary", "hist_backend": "scatter",
-                   "tree_learner": "feature"}, "single-device")
+@pytest.mark.parametrize("how", ["param", "env"])
+@pytest.mark.parametrize("name", ["pallas", "scatter"])
+def test_removed_backends_are_refused(name, how, monkeypatch):
+    """The two formulations that left with their kernels are names like
+    any other unknown one, by parameter and by LGBTPU_HIST_BACKEND."""
+    params = {"objective": "binary"}
+    if how == "env":
+        monkeypatch.setenv("LGBTPU_HIST_BACKEND", name)
+    else:
+        params["hist_backend"] = name
+    _expect_error(params, f"unknown hist_backend='{name}'")
 
 
-def test_scatter_rejects_double_precision():
-    _expect_error({"objective": "binary", "hist_backend": "scatter",
+@needs_mesh
+def test_stream_rejects_feature_parallel():
+    _expect_error({"objective": "binary", "hist_backend": "stream",
+                   "tree_learner": "feature"}, "group sharding")
+
+
+def test_stream_rejects_double_precision():
+    _expect_error({"objective": "binary", "hist_backend": "stream",
                    "hist_precision": "double"}, "double")
 
 
@@ -256,10 +368,10 @@ def test_route_fusion_validation():
 
 
 def test_env_override_hist_backend(monkeypatch):
-    monkeypatch.setenv("LGBTPU_HIST_BACKEND", "scatter")
+    monkeypatch.setenv("LGBTPU_HIST_BACKEND", "onehot")
     bst = lgb.train({"objective": "binary", "verbosity": -1,
                      "num_leaves": 7}, _tiny(), num_boost_round=1)
-    assert bst.engine._grow_params.hist_backend == "scatter"
+    assert bst.engine._grow_params.hist_backend == "onehot"
     monkeypatch.setenv("LGBTPU_HIST_BACKEND", "vector")
     with pytest.raises(LightGBMError, match="hist_backend"):
         lgb.train({"objective": "binary", "verbosity": -1,
@@ -417,9 +529,8 @@ def test_route_fusion_mesh_identity():
 
 
 # ---------------------------------------------------------------------------
-# unit tier: wire-packing algebra, the comms byte model, and the scatter
-# VMEM gate — pure math, no training, so they stay in the fast tier even
-# on a throttled box
+# unit tier: wire-packing algebra and the comms byte model — pure math, no
+# training, so they stay in the fast tier even on a throttled box
 # ---------------------------------------------------------------------------
 
 from lightgbm_tpu.parallel.comms import (hist_comms_bytes_per_round,
@@ -522,28 +633,6 @@ def test_bytes_model_reduce_scatter_packs_block_not_records():
     assert (bf - rec) * 2 == b32 - rec
 
 
-def test_scatter_fits_bin_and_group_caps():
-    assert scatter_hist_fits(14, 4, 128)
-    assert not scatter_hist_fits(14, 4, 129)   # > one 128-lane tile
-    assert scatter_hist_fits(14, 64, 32)
-    assert not scatter_hist_fits(14, 65, 32)   # static unroll cap
-
-
-def test_scatter_fits_vmem_budget_boundary():
-    # tile = S * G * B * cp * 4 with cp=4 (binary): S*64*128*16 bytes
-    # crosses the 12 MB budget exactly between S=96 and S=97
-    assert scatter_hist_fits(96, 64, 128)
-    assert not scatter_hist_fits(97, 64, 128)
-
-
-def test_scatter_fits_multiclass_widens_channels():
-    # num_class=3 -> 9 channels pad to 12: budget shrinks 3x vs binary
-    # (S=32 x 3 classes lands EXACTLY on the 12 MB budget and still fits)
-    assert scatter_hist_fits(32, 64, 128, num_class=3)
-    assert scatter_hist_fits(33, 64, 128)
-    assert not scatter_hist_fits(33, 64, 128, num_class=3)
-
-
 def test_unpack_floored_mod_keeps_low_field():
     # the low (hess) field is non-negative by construction; floored
     # mod/div must recover it even under a negative packed lane
@@ -575,11 +664,3 @@ def test_bytes_model_packed_width_overrides_bf16_pair():
               mode="reduce_scatter", packed_width=16)
     assert hist_comms_bytes_per_round(dtype="bf16_pair", **kw) == \
         hist_comms_bytes_per_round(dtype="f32", **kw)
-
-
-def test_scatter_block_rows_shrinks_with_classes():
-    from lightgbm_tpu.pallas.scatter_hist_kernel import scatter_block_rows
-    assert scatter_block_rows(28) == 8192
-    assert scatter_block_rows(28, num_class=4) == 2048
-    # floor: never below one 1024-row grid step
-    assert scatter_block_rows(28, num_class=64) == 1024

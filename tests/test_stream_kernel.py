@@ -434,7 +434,7 @@ def test_small_slot_pass_engages_on_the_factored_path_only(path):
     operands, static, _ = _small_operands("g5", 1)
     static["int_weights"] = int_w
     if path == "packed_words":
-        # four groups a word, the layout LGBTPU_STREAM_PACKED keeps
+        # four groups a word, the layout of max_bin > 127
         b8 = np.asarray(operands[0]).view(np.uint8).astype(np.uint32)
         words = sum(b8[j::4] << (8 * j) for j in range(4))
         operands = (jnp.asarray(words.astype(np.int32)),) + operands[1:]

@@ -316,16 +316,6 @@ def test_serving_programs_compile(tpu, tmp_path):
                               num_class=1).compile()
 
 
-def test_scatter_backend_is_refused_on_a_tpu(tpu):
-    """Mosaic has no scatter-add lowering: refuse at parameter resolution
-    with a LightGBMError, not a NotImplementedError from inside a jit."""
-    X, rs = _rows(1024, 8, 4)
-    with pytest.raises(LightGBMError, match="scatter.*cannot run on a TPU"):
-        lgb.Booster({"objective": "binary", "hist_backend": "scatter",
-                     "verbosity": -1},
-                    lgb.Dataset(X, label=(rs.rand(len(X)) < 0.5) * 1.0))
-
-
 def test_over_limit_block_rows_rejected_before_the_compiler(tpu, monkeypatch):
     """A bf16 one-hot at G=136 sits on the 16 MiB scoped-VMEM limit: the
     compiler's own words are "Scoped allocation with size 17.36M and limit

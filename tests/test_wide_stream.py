@@ -440,7 +440,7 @@ def test_one_tile_fused_fixture_still_matches(monkeypatch):
 
 @pytest.mark.parametrize("groups", [28, 136, 600, 2000])
 def test_auto_is_stream_at_every_width_on_a_tpu(monkeypatch, groups):
-    """hist_backend=auto on a TPU: never `pallas` for want of width.  The
+    """hist_backend=auto on a TPU: never a fall-back for want of width.  The
     engine is built here on the CPU (auto -> segsum) and then asked what it
     would resolve where runtime.on_tpu() says yes."""
     from lightgbm_tpu.models import gbdt
