@@ -350,6 +350,46 @@ def wide_kernel_exactness():
     small_pass_exactness("wide, first tiles", bins, bins_T, dd.routing, gi,
                          hi, kw, G, Bmax, L, [(1, 400, 35, 6, False),
                                               (3, 5, 28, 7, True)])
+    tail_width_identity(ds, N)
+
+
+def tail_width_identity(ds, n_rows):
+    """The rounds' tails on the chip at the wide shape: one 41-leaf tree
+    (a budget of 40 splits a round) whose rounds subtract and scan a chunk
+    of tail_chunk(40) = 8 pairs a step (splits of 1, 2, 4, 8, 16 and 9: one
+    step, and two), against the same tree with every round's tail 40 pairs
+    wide at once (tail_chunk patched here, not an option of the program):
+    model text, every row's leaf and the pass count, tolerance 0."""
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import telemetry
+    from lightgbm_tpu.ops import grow
+    params = {"objective": "binary", "num_leaves": 41, "max_bin": 63,
+              "verbosity": -1, "use_quantized_grad": True,
+              "num_grad_quant_bins": 64, "stochastic_rounding": False,
+              "eval_fetch_freq": 1}
+    real, grown = grow.tail_chunk, {}
+    check("wide tails: a budget of 40 goes 8 pairs a step", real(40) == 8)
+    for name, chunk in (("chunked", real), ("budget", lambda b: 0)):
+        before = telemetry.scan_slot_count()
+        grow.tail_chunk = chunk
+        try:
+            bst = lgb.Booster(params, ds)
+            bst.update()
+        finally:
+            grow.tail_chunk = real
+        poll = telemetry.recent_spans(name="GBDT::FlagPoll")[-1].args
+        grown[name] = (bst.model_to_string().split("\nparameters:")[0],
+                       np.asarray(bst.engine._train_state.leaf_id)[:n_rows],
+                       bst.engine._train_state.hist_passes.item(),
+                       poll["scan_slots"] - before)
+    (text, leaf, passes, slots), budget = grown["chunked"], grown["budget"]
+    check("wide tails: the tree, every row's leaf and the pass count are "
+          "the 40-pair tails' exactly",
+          text == budget[0] and np.array_equal(leaf, budget[1])
+          and passes == budget[2], f"{passes} passes")
+    check("wide tails: the rounds took whole chunks of 8, fewer than 40",
+          slots % 8 == 0 and slots < budget[3] == 40 * (passes - 1),
+          f"scan_slots {slots} against {budget[3]}")
 
 
 DIGEST_ROWS = {"higgs_like": 2_000_000, "mslr_like": 600_000,

@@ -387,7 +387,7 @@ class GBDT:
                                        mesh=vote_mesh, row_axis=vote_axis,
                                        compact_rows=compact_rows)
                 # the voting grower keeps no round histogram pass to count
-                return (out + (jnp.zeros(2, jnp.int32),) if with_passes
+                return (out + (jnp.zeros(3, jnp.int32),) if with_passes
                         else out)
 
             # the voting fn replaces grow_tree as THE grow partial, so the
@@ -405,7 +405,7 @@ class GBDT:
         self._fused_last = False
         self._compact_overflow = False
         self._overflow_seen = 0
-        self._hist_passes_seen = 0, 0
+        self._hist_passes_seen = 0, 0, 0
         # batched device-flag fetch cadence: eval_fetch_freq, or auto —
         # 16 wherever the fused one-launch path is the default (TPU, any
         # row-sharded stream mesh: each blocking flag read costs a full
@@ -1655,10 +1655,11 @@ class GBDT:
             finished=jnp.asarray(False),
             ok=jnp.asarray(True),
             hist_passes=jnp.asarray(0, jnp.int32),
-            hist_small_passes=jnp.asarray(0, jnp.int32))
+            hist_small_passes=jnp.asarray(0, jnp.int32),
+            scan_slots=jnp.asarray(0, jnp.int32))
         self._train_state = st
         self._overflow_seen = 0
-        self._hist_passes_seen = 0, 0
+        self._hist_passes_seen = 0, 0, 0
         return st
 
     def _fused_compact_rows(self, sample_mode: str, mask_arg=None) -> int:
@@ -1848,7 +1849,8 @@ class GBDT:
                     mask=mask, key=qkey, sampled=nc, overflow=over,
                     finished=fin, ok=ok,
                     hist_passes=state.hist_passes + grown[0],
-                    hist_small_passes=state.hist_small_passes + grown[1])
+                    hist_small_passes=state.hist_small_passes + grown[1],
+                    scan_slots=state.scan_slots + grown[2])
                 return new_state, arrays, new_obj
 
             out_sh = None
@@ -1905,29 +1907,31 @@ class GBDT:
         fetch = [self._finished_dev] + [ok for _, ok in pending]
         if st is not None:
             fetch += [st.sampled, st.overflow, st.hist_passes,
-                      st.hist_small_passes]
+                      st.hist_small_passes, st.scan_slots]
         from ..telemetry import (hist_pass_count, hist_small_pass_count,
-                                 note_hist_passes, note_host_sync)
+                                 note_hist_passes, note_host_sync,
+                                 scan_slot_count)
         with _tel_tracer.boundary("GBDT::FlagPoll",
                                   iteration=self.iter_) as poll:
             got = jax.device_get(fetch)
             if st is not None:
                 # the device's count of histogram passes rides the fetch:
                 # publish what it grew since the last poll
-                passes, small = int(got[-2]), int(got[-1])
-                seen, seen_small = self._hist_passes_seen
-                note_hist_passes(passes - seen, self.iter_, small - seen_small)
-                self._hist_passes_seen = passes, small
+                sampled, overflow, *now = (int(v) for v in got[-5:])
+                passes, small, slots = (
+                    a - b for a, b in zip(now, self._hist_passes_seen))
+                note_hist_passes(passes, self.iter_, small, slots)
+                self._hist_passes_seen = now
                 poll.set(hist_passes=hist_pass_count(),
                          hist_small_passes=hist_small_pass_count(),
+                         scan_slots=scan_slot_count(),
                          **({"root_pass": self._root_pass}
                             if self._root_pass else {}),
                          **self._poll_tiling)
         note_host_sync()
         self._nan_guard.resolve(pending, got[1:1 + len(pending)])
         if st is not None:
-            self._last_sampled_rows = int(got[-4])
-            overflow = int(got[-3])
+            self._last_sampled_rows = sampled
             if overflow > getattr(self, "_overflow_seen", 0):
                 self._overflow_seen = overflow
                 if not getattr(self, "_compact_overflow", False):

@@ -124,6 +124,40 @@ class GrowParams(NamedTuple):
                     or self.bynode_fraction < 1.0 or self.path_smooth > 0.0)
 
 
+# pairs a step of a histogram round's tail where the round adapts to its count
+TAIL_CHUNK = 8
+
+
+def tail_chunk(budget: int) -> int:
+    """How many pairs (a split leaf and its new sibling) a step of what
+    follows a histogram round's kernel call in grow_tree — the histogram
+    subtraction, the cache update and the children's split scan — takes
+    under a split budget: a round that knows its split count k runs
+    ceil(k / chunk) steps, so a round of one stops paying for the budget's
+    64.  0 where the budget is not cut: under two chunks, or not whole
+    chunks."""
+    return (TAIL_CHUNK if budget >= 2 * TAIL_CHUNK
+            and budget % TAIL_CHUNK == 0 else 0)
+
+
+def _cache_order(hist: jax.Array) -> jax.Array:
+    """(S, G, Bmax, 2) histograms as grow_tree's cache holds them: (S, 2 *
+    Bmax, G), a leaf's grad bins then its hess bins, the groups minor-most.
+    Over the 4-D shape XLA's gather and scatter each want the whole cache in
+    a layout of their own (the size-2 channel axis draws a (2, 128) tiling
+    for the one, the bins an (8, 128) for the other): two copies of the
+    cache a round, 257 MB each on a 2,000-column table.  Over this shape
+    they agree, and a round moves its pairs' slabs only."""
+    hist = jnp.swapaxes(hist, 1, 3)
+    return hist.reshape(hist.shape[0], -1, hist.shape[3])
+
+
+def _hist_order(cache: jax.Array) -> jax.Array:
+    """Rows of the cache back as (S, G, Bmax, 2) histograms."""
+    return jnp.swapaxes(
+        cache.reshape(cache.shape[0], 2, -1, cache.shape[2]), 1, 3)
+
+
 class RoutingLayout(NamedTuple):
     """Static per-feature arrays used to route rows at a split."""
     feat_group: jax.Array       # (F,) i32 — group column holding the feature
@@ -188,6 +222,11 @@ class _GrowState(NamedTuple):
     hist_small_passes: jax.Array  # () i32 — those of hist_passes that took
                                 # the stream kernel's small-slot pass (a
                                 # round that split one or two leaves)
+    scan_slots: jax.Array       # () i32 — pairs (a split leaf and its new
+                                # sibling) the rounds' subtraction and child
+                                # split scan ran over: whole chunks of
+                                # tail_chunk() where a round adapts to its
+                                # split count, the round's budget elsewhere
     best_gain: jax.Array
     best_feat: jax.Array
     best_thr: jax.Array
@@ -195,7 +234,7 @@ class _GrowState(NamedTuple):
     best_left_g: jax.Array
     best_left_h: jax.Array
     best_left_c: jax.Array
-    hist: jax.Array             # (L, G, Bmax, 3)
+    hist: jax.Array             # (L, 2 * Bmax, G): _cache_order
     num_leaves_cur: jax.Array   # () i32
     progressed: jax.Array       # () bool
     col_mask: jax.Array         # (F,) bool feature sampling mask for this tree
@@ -367,9 +406,10 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
               with_passes: bool = False,
               ) -> Tuple[TreeArrays, jax.Array]:
     """Grow one tree. Returns (TreeArrays, leaf_id[N]); with_passes=True
-    appends the (2,) i32 counts of histogram-building passes over the rows
-    this tree took (root pass + rounds) and of those among them that took
-    the small-slot pass (the fused iteration sums them into its state for
+    appends the (3,) i32 counts of histogram-building passes over the rows
+    this tree took (root pass + rounds), of those among them that took the
+    small-slot pass, and of the pairs its rounds' tails ran over
+    (tail_chunk; the fused iteration sums them into its state for
     telemetry.hist_pass_count() and the flag poll's record).
 
     grad/hess must already include any bagging mask; cnt_w is the mask itself.
@@ -791,7 +831,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                          jnp.full((1, F, Bmax), BIG, f32))
                         if use_amono else None))
 
-    hist = jnp.zeros((L, G_h, Bmax, 2), hdt).at[0].set(root_hist[0])
+    hist = jnp.zeros((L, 2 * Bmax, G_h), hdt).at[0].set(
+        _cache_order(root_hist)[0])
     if use_fp:
         # pin the histogram STATE to the group sharding for the whole
         # while_loop: every per-round build/subtract then stays shard-local
@@ -800,7 +841,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         from jax.sharding import NamedSharding, PartitionSpec as _P
         g_spec = (feature_axis, row_axis) if use_2d else feature_axis
         hist = jax.lax.with_sharding_constraint(
-            hist, NamedSharding(mesh, _P(None, g_spec, None, None)))
+            hist, NamedSharding(mesh, _P(None, None, g_spec)))
     state = _GrowState(
         leaf_id=leaf_id,
         leaf_id_c=leaf_id_c,
@@ -837,6 +878,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         round_idx=jnp.asarray(0, i32),
         hist_passes=jnp.asarray(1, i32),
         hist_small_passes=jnp.asarray(0, i32),
+        scan_slots=jnp.asarray(0, i32),
         best_gain=jnp.full(L, NEG_INF, hdt).at[0].set(root_split.gain[0]),
         best_feat=jnp.zeros(L, i32).at[0].set(root_split.feature[0]),
         best_thr=jnp.zeros(L, i32).at[0].set(root_split.threshold[0]),
@@ -892,8 +934,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 pg, ph, pc = (st.sum_g[pair_old], st.sum_h[pair_old],
                               st.cnt[pair_old])
                 # left sums from the leaf histogram at the forced threshold
-                hf_f = gather_feature_histograms(st.hist[pair_old], layout,
-                                                 pg, ph)
+                hf_f = gather_feature_histograms(
+                    _hist_order(st.hist[pair_old]), layout, pg, ph)
                 hsel = hf_f[jnp.arange(S), feat]             # (S, Bmax, 2)
                 bin_le = (jnp.arange(Bmax)[None, :] <= thr[:, None])
                 nanb = routing.nan_bin[feat]                 # (S,)
@@ -943,7 +985,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 rg, rh, rc = pg - lg, ph - lh, pc - lc
 
             # ---- categorical bitsets for the chosen splits ----
-            parent_hist = st.hist[pair_old]                       # (S, G, Bmax, 2)
+            if params.has_categorical:
+                parent_hist = _hist_order(st.hist[pair_old])      # (S, G, Bmax, 2)
             if params.has_categorical and (use_rs or use_fp):
                 # owner-shard recompute + tiny masked psum (the histogram
                 # slice never leaves its device)
@@ -997,7 +1040,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
             leaf_thr = jnp.zeros(L, i32).at[old_idx].set(thr, mode="drop")
             leaf_dir = jnp.zeros(L, i32).at[old_idx].set(dirf, mode="drop")
             smaller_is_left = lc <= rc
-            took_small = 0
+            which, took_small = None, 0
 
             if use_stream:
                 # fused route+hist streaming kernel: one sequential pass over rows
@@ -1023,8 +1066,6 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                     new_leaf_row, hist_small, slot_cnt = _rh(
                         bins_T_h, lid_h.reshape(1, -1), w_T_h, tabs,
                         bits_l.T, S, with_hist=with_hist, live_slots=live)
-                if use_int and with_hist:
-                    hist_small = hist_small.astype(f32) * hscale
                 if use_compact and fuse:
                     # GOSS+stream fusion: stash this round's tables — the
                     # full-data route-only pass is REPLAYED in one fused
@@ -1081,10 +1122,10 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 new_leaf_c = st.leaf_id_c
 
             # ---- histograms for the smaller children + EXACT slot counts ----
-            smaller_id_pre = jnp.where(smaller_is_left, pair_old, pair_new)
             if not use_stream:   # stream path built these in the fused kernel
+                smaller_id = jnp.where(smaller_is_left, pair_old, pair_new)
                 slot_map = jnp.full(L, -1, i32).at[
-                    jnp.where(pair_valid, smaller_id_pre, drop)].set(
+                    jnp.where(pair_valid, smaller_id, drop)].set(
                         jnp.arange(S, dtype=i32), mode="drop")
                 slot = slot_map[new_leaf_id]
                 if use_compact:
@@ -1428,107 +1469,168 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                                     progressed=k > 0,
                                     round_idx=st.round_idx + 1)
 
-            # ---- histogram subtraction for the larger siblings ----
-            smaller_id = smaller_id_pre
-            larger_id = jnp.where(smaller_is_left, pair_new, pair_old)
-            hist_large = parent_hist - hist_small
-            sm_idx = jnp.where(pair_valid, smaller_id, drop)
-            lg_idx = jnp.where(pair_valid, larger_id, drop)
-            new_hist = (st2.hist.at[sm_idx].set(hist_small, mode="drop")
-                               .at[lg_idx].set(hist_large, mode="drop"))
-            st2 = st2._replace(hist=new_hist)
-
-            # ---- best splits for the 2S children ----
-            # Under intermediate monotone constraints, other leaves' entries
-            # may have tightened, which invalidates their cached best splits;
-            # the reference re-finds splits for every leaf in
-            # leaves_need_update (serial_tree_learner.cpp Split ->
-            # RecomputeBestSplitForLeaf). Recomputing ALL leaves is
-            # equivalent (unchanged bounds reproduce the cached result) and
-            # stays one dense scan.
-            if use_imono:
-                # children always recompute; other leaves only when their
-                # entry actually tightened (leaves_need_update). Unchanged
-                # leaves keep their cached best split — also keeps by-node /
-                # extra_trees draws stable for them (the reference's
-                # RecomputeBestSplitForLeaf redraws GetByNode only for
-                # recomputed leaves, serial_tree_learner.cpp:1053)
-                ids2 = jnp.arange(L)
-                if use_amono:
-                    # fresh children inherit the parent's sticky
-                    # is_splittable_ flags (FindBestSplits propagates
-                    # parent-unsplittable to both children without scanning,
-                    # serial_tree_learner.cpp:399)
-                    st2 = st2._replace(adv_split_ok=st2.adv_split_ok.at[
-                        new_idx].set(st2.adv_split_ok[pair_old], mode="drop"))
-                child2 = jnp.zeros(L, bool) \
-                    .at[old_idx].set(pair_valid, mode="drop") \
-                    .at[new_idx].set(pair_valid, mode="drop")
-                valid2 = child2 | imono_changed
-            else:
-                ids2 = jnp.concatenate([pair_old, pair_new])
-                valid2 = jnp.concatenate([pair_valid, pair_valid])
-            hist2 = new_hist[ids2]
-            rkey = (jax.random.fold_in(key, 2 + st.round_idx)
-                    if key is not None else None)
-            rows2 = L if use_imono else 2 * S
-            len_ids2 = rows2
-            cmask2 = node_col_mask(st.col_mask[None, :],
-                                   st2.used_feat[ids2] if use_inter
-                                   else jnp.zeros((rows2, F), bool),
-                                   rkey, rows=rows2)
-            with jax.named_scope("find_splits"):
-                if use_rs or use_fp:
-                    # shard-local scan on each device's group slice + tiny
-                    # best-record all_gather (bit-identical to the full scan)
-                    res = (rs_split if use_rs else fp_split)(
-                        hist2, st2.sum_g[ids2], st2.sum_h[ids2],
-                        st2.cnt[ids2], st.col_mask)
-                else:
-                    res = find_splits(hist2, st2.sum_g[ids2], st2.sum_h[ids2],
-                              st2.cnt[ids2],
-                              col_mask=cmask2,
-                              adv_bounds=((st2.adv_vmin[ids2],
-                                           st2.adv_vmax[ids2])
-                                          if use_amono else None),
-                              splittable=(st2.adv_split_ok[ids2]
-                                          if use_amono else None),
-                              out_lo=st2.out_lo[ids2] if use_output else None,
-                              out_hi=st2.out_hi[ids2] if use_output else None,
-                              slot_depth=st2.depth[ids2] if use_mono else None,
-                              parent_out=st2.leaf_out[ids2] if use_output else None,
-                              extra_key=(jax.random.fold_in(key, 100000 + st.round_idx)
-                                         if use_extra else None),
-                              cegb_penalty=(cegb_pen(
-                                  st2.cnt[ids2], st2.cegb_used,
-                                  lazy_unused_counts(
-                                      st2.cegb_lazy,
-                                      jnp.full(L, -1, i32).at[
-                                          jnp.where(valid2, ids2, drop)].set(
-                                          jnp.arange(len_ids2, dtype=i32),
-                                          mode="drop")[st2.leaf_id],
-                                      len_ids2) if use_lazy else None)
-                                            if use_cegb else None))
-            ids2_m = jnp.where(valid2, ids2, drop)
-            st2 = st2._replace(
-                best_gain=st2.best_gain.at[ids2_m].set(res.gain, mode="drop"),
-                best_feat=st2.best_feat.at[ids2_m].set(res.feature, mode="drop"),
-                best_thr=st2.best_thr.at[ids2_m].set(res.threshold, mode="drop"),
-                best_dir=st2.best_dir.at[ids2_m].set(res.dir_flags, mode="drop"),
-                best_left_g=st2.best_left_g.at[ids2_m].set(res.left_sum_g, mode="drop"),
-                best_left_h=st2.best_left_h.at[ids2_m].set(res.left_sum_h, mode="drop"),
-                best_left_c=st2.best_left_c.at[ids2_m].set(res.left_count, mode="drop"),
-            )
             if use_amono:
+                # fresh children inherit the parent's sticky is_splittable_
+                # flags (FindBestSplits propagates parent-unsplittable to
+                # both children without scanning, serial_tree_learner.cpp:399)
+                st2 = st2._replace(adv_split_ok=st2.adv_split_ok.at[
+                    new_idx].set(st2.adv_split_ok[pair_old], mode="drop"))
+
+            # the part of the round that follows the kernel call runs a
+            # chunk of C pairs a step, as many steps as hold the round's k
+            # live pairs (they are pairs 0..k-1), where the round knows its
+            # count and no draw depends on the width (by-node sampling and
+            # extra_trees draw a row a pair); the whole budget at once
+            # elsewhere (C = 0)
+            adapts = (which is not None and forced_level is None
+                      and not params.has_categorical
+                      and not (use_imono or use_bynode or use_extra))
+            C = tail_chunk(S) if adapts else 0
+
+            def tail(lo, cache, best):
+                """Pairs lo .. lo + C - 1 of the round (all S where C is 0):
+                the larger siblings' histograms by subtraction, both
+                children's into the cache, and the children's best splits
+                into the records `best`.  No pair reads another's, so the
+                chunks may run one after the other and the pairs past k not
+                at all."""
+                def cut(a):
+                    return jax.lax.dynamic_slice_in_dim(a, lo, C) if C else a
+                p_old, p_new, p_valid = (cut(pair_old), cut(pair_new),
+                                         cut(pair_valid))
+                small_left = cut(smaller_is_left)
+                small = cut(hist_small)
+                if use_stream and use_int:
+                    small = small.astype(f32) * hscale
+                    if adapts and S >= 2 * TAIL_CHUNK:
+                        # the slots past k hold zeros already: the select
+                        # keeps a CPU from contracting the scale into the
+                        # subtraction below, which it does in one fusion and
+                        # not in another (cut or uncut, 8 pairs or 64; the
+                        # chip rounds twice wherever the two land).  Smaller
+                        # budgets keep the expression their trees grew by
+                        small = jnp.where(p_valid[:, None, None, None],
+                                          small, 0.0)
+                # ---- histogram subtraction for the larger siblings ----
+                parent = (cut(parent_hist) if params.has_categorical
+                          else _hist_order(cache[p_old]))     # (C, G, Bmax, 2)
+                large = parent - small
+                sm_idx = jnp.where(p_valid,
+                                   jnp.where(small_left, p_old, p_new), drop)
+                lg_idx = jnp.where(p_valid,
+                                   jnp.where(small_left, p_new, p_old), drop)
+                cache = (cache.at[sm_idx].set(_cache_order(small),
+                                              mode="drop")
+                              .at[lg_idx].set(_cache_order(large),
+                                              mode="drop"))
+
+                # ---- best splits for the 2C children ----
+                # Under intermediate monotone constraints, other leaves'
+                # entries may have tightened, which invalidates their cached
+                # best splits; the reference re-finds splits for every leaf
+                # in leaves_need_update (serial_tree_learner.cpp Split ->
+                # RecomputeBestSplitForLeaf). Recomputing ALL leaves is
+                # equivalent (unchanged bounds reproduce the cached result)
+                # and stays one dense scan.
+                if use_imono:
+                    # children always recompute; other leaves only when
+                    # their entry actually tightened (leaves_need_update).
+                    # Unchanged leaves keep their cached best split — also
+                    # keeps by-node / extra_trees draws stable for them (the
+                    # reference's RecomputeBestSplitForLeaf redraws GetByNode
+                    # only for recomputed leaves,
+                    # serial_tree_learner.cpp:1053)
+                    ids2 = jnp.arange(L)
+                    child2 = jnp.zeros(L, bool) \
+                        .at[old_idx].set(pair_valid, mode="drop") \
+                        .at[new_idx].set(pair_valid, mode="drop")
+                    valid2 = child2 | imono_changed
+                    hist2 = _hist_order(cache)
+                else:
+                    ids2 = jnp.concatenate([p_old, p_new])
+                    valid2 = jnp.concatenate([p_valid, p_valid])
+                    # what the cache would read back at ids2: the split leaf
+                    # keeps the left child, the new leaf takes the right one
+                    sl4 = small_left[:, None, None, None]
+                    hist2 = jnp.concatenate([jnp.where(sl4, small, large),
+                                             jnp.where(sl4, large, small)])
+                rkey = (jax.random.fold_in(key, 2 + st.round_idx)
+                        if key is not None else None)
+                rows2 = ids2.shape[0]
+                cmask2 = node_col_mask(st.col_mask[None, :],
+                                       st2.used_feat[ids2] if use_inter
+                                       else jnp.zeros((rows2, F), bool),
+                                       rkey, rows=rows2)
+                with jax.named_scope("find_splits"):
+                    if use_rs or use_fp:
+                        # shard-local scan on each device's group slice +
+                        # tiny best-record all_gather (bit-identical to the
+                        # full scan)
+                        res = (rs_split if use_rs else fp_split)(
+                            hist2, st2.sum_g[ids2], st2.sum_h[ids2],
+                            st2.cnt[ids2], st.col_mask)
+                    else:
+                        res = find_splits(
+                            hist2, st2.sum_g[ids2], st2.sum_h[ids2],
+                            st2.cnt[ids2],
+                            col_mask=cmask2,
+                            adv_bounds=((st2.adv_vmin[ids2],
+                                         st2.adv_vmax[ids2])
+                                        if use_amono else None),
+                            splittable=(st2.adv_split_ok[ids2]
+                                        if use_amono else None),
+                            out_lo=st2.out_lo[ids2] if use_output else None,
+                            out_hi=st2.out_hi[ids2] if use_output else None,
+                            slot_depth=st2.depth[ids2] if use_mono else None,
+                            parent_out=(st2.leaf_out[ids2] if use_output
+                                        else None),
+                            extra_key=(jax.random.fold_in(
+                                key, 100000 + st.round_idx)
+                                       if use_extra else None),
+                            cegb_penalty=(cegb_pen(
+                                st2.cnt[ids2], st2.cegb_used,
+                                lazy_unused_counts(
+                                    st2.cegb_lazy,
+                                    jnp.full(L, -1, i32).at[
+                                        jnp.where(valid2, ids2, drop)].set(
+                                        jnp.arange(rows2, dtype=i32),
+                                        mode="drop")[st2.leaf_id],
+                                    rows2) if use_lazy else None)
+                                          if use_cegb else None))
+                ids2_m = jnp.where(valid2, ids2, drop)
+                best = tuple(
+                    b.at[ids2_m].set(r, mode="drop") for b, r in zip(
+                        best, (res.gain, res.feature, res.threshold,
+                               res.dir_flags, res.left_sum_g, res.left_sum_h,
+                               res.left_count)))
                 # flags refresh only for leaves that actually rescanned
                 # (each FindBestThreshold call rewrites is_splittable_,
                 # feature_histogram.hpp:196; skipped leaves keep theirs)
-                st2 = st2._replace(adv_split_ok=jnp.where(
-                    valid2[:, None], res.feat_ok, st2.adv_split_ok))
+                split_ok = (jnp.where(valid2[:, None], res.feat_ok,
+                                      st2.adv_split_ok)
+                            if use_amono else st2.adv_split_ok)
+                return cache, best, split_ok
+
+            best = (st2.best_gain, st2.best_feat, st2.best_thr, st2.best_dir,
+                    st2.best_left_g, st2.best_left_h, st2.best_left_c)
+            if C:
+                steps = (k + (C - 1)) // C
+                cache, best = jax.lax.fori_loop(
+                    0, steps, lambda i, cb: tail(i * C, *cb)[:2],
+                    (st2.hist, best))
+                split_ok, scanned = st2.adv_split_ok, steps * C
+            else:
+                cache, best, split_ok = tail(0, st2.hist, best)
+                scanned = S
             return st2._replace(
+                hist=cache, best_gain=best[0], best_feat=best[1],
+                best_thr=best[2], best_dir=best[3], best_left_g=best[4],
+                best_left_h=best[5], best_left_c=best[6],
+                adv_split_ok=split_ok,
                 num_leaves_cur=cur + k, progressed=k > 0,
                 round_idx=st.round_idx + 1, hist_passes=st.hist_passes + 1,
-                hist_small_passes=st.hist_small_passes + took_small)
+                hist_small_passes=st.hist_small_passes + took_small,
+                scan_slots=st.scan_slots + scanned)
 
         return body
 
@@ -1542,11 +1644,14 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     # fused kernel cost is linear in the slot budget S — run the first
     # log2(S) rounds as specialized small-S bodies, then loop at full S
     if use_stream and S > 64:
-        # the kernel's MXU cost is quantized to 128-column tiles of the
-        # (T, 2S) operand, so any budget <= 64 costs one tile per round —
-        # rounds are only worth specializing down to a 64 budget.  Round r
-        # can split at most 2^r leaves, so 7 budget-64 rounds cover growth
-        # to 128 leaves before the full-S while_loop takes over.
+        # the 64-slot KERNEL's MXU cost is quantized to 128-column tiles of
+        # the (T, 2S) operand, so a kernel budget under 64 buys nothing —
+        # what a round of fewer splits saves it saves inside the 64-budget
+        # body, by its own split count: the small-slot pass
+        # (route_and_hist_live) and a tail of as many chunks as hold its
+        # pairs (tail_chunk).
+        # Round r can split at most 2^r leaves, so 7 budget-64 rounds cover
+        # growth to 128 leaves before the full-S while_loop takes over.
         b64 = make_body(64)
         for _ in range(7):
             state = jax.lax.cond(cond(state), b64, lambda s: s, state)
@@ -1632,7 +1737,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     if use_lazy:
         out += (final.cegb_lazy,)
     if with_passes:
-        out += (jnp.stack([final.hist_passes, final.hist_small_passes]),)
+        out += (jnp.stack([final.hist_passes, final.hist_small_passes,
+                           final.scan_slots]),)
     return out
 
 
@@ -1669,6 +1775,8 @@ class _GrowStateK(NamedTuple):
     progressed: jax.Array       # (K,) bool
     hist_passes: jax.Array      # () i32 — as _GrowState.hist_passes: one
                                 # lockstep pass serves all K classes
+    scan_slots: jax.Array       # () i32 — as _GrowState.scan_slots: every
+                                # class's pairs, each round at its budget
 
 
 def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
@@ -1684,9 +1792,9 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     """Grow K class trees in LOCKSTEP inside one widened XLA program
     (batched multiclass). Returns (TreeArrays with a leading K axis,
     leaf_id (K, N)) — the same stacked layout the per-class lax.scan path
-    produces; with_passes=True appends the (2,) i32 counts of
+    produces; with_passes=True appends the (3,) i32 counts of
     histogram-building passes, as grow_tree does (no lockstep pass is a
-    small-slot one).
+    small-slot one, and every round's tail is as wide as its budget).
 
     grad/hess: (K, N) class-major gradient channels (bagging mask applied).
     gh_scales: (K, 2) per-class (grad_scale, hess_scale) or None.
@@ -1970,6 +2078,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         num_leaves_cur=jnp.ones(K, i32),
         progressed=jnp.ones(K, bool),
         hist_passes=jnp.asarray(1, i32),
+        scan_slots=jnp.asarray(0, i32),
     )
 
     def cond_k(st: _GrowStateK):
@@ -2278,7 +2387,8 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             return st2._replace(
                 num_leaves_cur=cur + ksp,
                 progressed=jnp.where(active, ksp > 0, st.progressed),
-                hist_passes=st.hist_passes + 1)
+                hist_passes=st.hist_passes + 1,
+                scan_slots=st.scan_slots + K * S)
         return body
 
     # streaming rounds: same specialized small-S prefix as grow_tree
@@ -2321,5 +2431,6 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     )
     if with_passes:
         return (tree, final.leaf_id[:, :N],
-                jnp.stack([final.hist_passes, jnp.zeros((), i32)]))
+                jnp.stack([final.hist_passes, jnp.zeros((), i32),
+                           final.scan_slots]))
     return tree, final.leaf_id[:, :N]

@@ -54,6 +54,8 @@ class ShardedTrainState(NamedTuple):
       * ``hist_small_passes`` — () i32, those of ``hist_passes`` that took
         the stream kernel's small-slot pass (rounds that split one or two
         leaves); rides the same fetch onto the poll's record
+      * ``scan_slots`` — () i32, the pairs the histogram rounds' split
+        scans ran over (ops/grow.py ``tail_chunk``); the same fetch
     """
     score: jax.Array
     grad: jax.Array
@@ -67,6 +69,7 @@ class ShardedTrainState(NamedTuple):
     ok: jax.Array
     hist_passes: jax.Array
     hist_small_passes: jax.Array
+    scan_slots: jax.Array
 
 
 def state_shardings(mesh, row_axis: Optional[str], num_class: int,
@@ -107,4 +110,4 @@ def state_shardings(mesh, row_axis: Optional[str], num_class: int,
     return ShardedTrainState(
         score=score, grad=grad, hess=hess, leaf_id=leaf, mask=row,
         key=rep, sampled=rep, overflow=rep, finished=rep, ok=rep,
-        hist_passes=rep, hist_small_passes=rep)
+        hist_passes=rep, hist_small_passes=rep, scan_slots=rep)

@@ -40,7 +40,8 @@ from .watchdog import (WatchEntry, get_recompile_threshold, hist_pass_count,
                        host_sync_count, launch_count,
                        note_hist_passes, note_host_sync, note_launch,
                        recompile_counts, reset_counters,
-                       reset_watchdog, set_recompile_threshold,
+                       reset_watchdog, scan_slot_count,
+                       set_recompile_threshold,
                        watchdog_summary, watched_jit)
 
 __all__ = [
@@ -53,7 +54,7 @@ __all__ = [
     "set_recompile_threshold", "get_recompile_threshold", "reset_watchdog",
     "launch_count", "host_sync_count", "note_host_sync", "note_launch",
     "hist_pass_count", "hist_pass_iteration", "hist_small_pass_count",
-    "note_hist_passes",
+    "note_hist_passes", "scan_slot_count",
     "reset_counters", "costmodel", "cost_summary", "machine_balance",
     "memory_snapshot", "device_memory_gb", "host_rss_gb",
     "TraceContext", "TailRing", "AccessLog", "TRACE_HEADER",
@@ -191,6 +192,7 @@ def summary() -> Dict[str, Any]:
         # at `iteration` (telemetry/watchdog.py)
         "hist_passes": {"count": hist_pass_count(),
                         "small": hist_small_pass_count(),
+                        "scan_slots": scan_slot_count(),
                         "iteration": hist_pass_iteration()},
     }
     if global_registry.sink_path:

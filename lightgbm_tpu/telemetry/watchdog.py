@@ -57,6 +57,7 @@ _host_syncs = 0
 # up to eval_fetch_freq - 1 trees behind the device.
 _hist_passes = 0
 _hist_small_passes = 0      # those of them that took the small-slot pass
+_scan_slots = 0             # pairs the rounds' split scans ran over
 _hist_pass_iteration = 0
 
 
@@ -82,19 +83,31 @@ def hist_small_pass_count() -> int:
     return _hist_small_passes
 
 
+def scan_slot_count() -> int:
+    """Cumulative pairs (a split leaf and its new sibling) the histogram
+    rounds' subtraction and child split scan ran over, as of the same poll:
+    whole chunks of ops/grow.py ``tail_chunk`` where a round adapts to its
+    own split count, the round's budget elsewhere."""
+    return _scan_slots
+
+
 def hist_pass_iteration() -> int:
     """The boosting iteration at which :func:`hist_pass_count` was last
     read off the device."""
     return _hist_pass_iteration
 
 
-def note_hist_passes(n: int, iteration: int, small: int = 0) -> None:
-    """Add ``n`` passes, ``small`` of them small-slot ones, read off the
-    device at ``iteration`` (the engine's flag poll calls this with the
-    deltas since its last poll)."""
-    global _hist_passes, _hist_small_passes, _hist_pass_iteration
+def note_hist_passes(n: int, iteration: int, small: int = 0,
+                     scan_slots: int = 0) -> None:
+    """Add ``n`` passes, ``small`` of them small-slot ones, and the
+    ``scan_slots`` their rounds scanned, read off the device at
+    ``iteration`` (the engine's flag poll calls this with the deltas since
+    its last poll)."""
+    global _hist_passes, _hist_small_passes, _scan_slots, \
+        _hist_pass_iteration
     _hist_passes += n
     _hist_small_passes += small
+    _scan_slots += scan_slots
     _hist_pass_iteration = iteration
 
 
@@ -122,11 +135,12 @@ def reset_counters() -> None:
     the start of each timed arm so launches/iter and host_syncs/iter are
     attributable to THAT arm, not contaminated by the previous one."""
     global _launches, _host_syncs, _hist_passes, _hist_small_passes, \
-        _hist_pass_iteration
+        _scan_slots, _hist_pass_iteration
     _launches = 0
     _host_syncs = 0
     _hist_passes = 0
     _hist_small_passes = 0
+    _scan_slots = 0
     _hist_pass_iteration = 0
 
 

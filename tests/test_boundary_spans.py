@@ -97,7 +97,8 @@ def test_ring_is_bounded_and_counts_what_it_overwrote(monkeypatch):
     assert tr.recent_spans() == [] and tr.ring_overwritten == 0
     s = tel.summary()
     assert s["recent_spans"] == [] and s["recent_spans_overwritten"] == 0
-    assert s["hist_passes"] == {"count": 0, "small": 0, "iteration": 0}
+    assert s["hist_passes"] == {"count": 0, "small": 0, "scan_slots": 0,
+                                "iteration": 0}
 
 
 def test_parents_are_kept_per_thread():
