@@ -12,7 +12,8 @@ way, then prints as its last line
     {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
 
 and exits 0.  Any failed check, or no TPU, exits non-zero with the reason and
-prints no result.  With >= 4 devices it adds the data-parallel mesh leg.
+prints no result.  With >= 4 devices it adds the data-parallel mesh leg, at
+the shape of a benchmark configuration (MESH_CONFIG: the four-chip cell's).
 
 It reports no s/tree or rows/s: the wall and compile seconds it prints exist
 so a cold and a warm run can be compared, not to be read as a benchmark.
@@ -28,6 +29,14 @@ checkouts are compared by running this one file from each; a change that
 keeps the trees prints the parent's line.
 
     (cd scratch_src/parent && python ../../chip_smoke.py --digest)
+
+`--mesh [configuration file]` is the mesh leg alone (four chips): the file's
+generator, width and parameters at CHIP_SMOKE_ROWS rows, the per-device
+kernel's root and 64-slot passes reduced over the mesh against the NumPy
+reference on the whole table, the two collectives' models, and the devices'
+memory balance with nothing else in the process.
+
+    chiprun --chips 4 -- python chip_smoke.py --mesh
 """
 import contextlib
 import gc
@@ -40,6 +49,7 @@ import traceback
 import numpy as np
 
 ROWS = int(os.environ.get("CHIP_SMOKE_ROWS", 2_097_152))
+MESH_CONFIG = "benchmark/configs/criteo_dp_like.json"
 HOLDOUT = 100_000
 ITERS = 6                      # one warm-up iteration plus five more
 FAILED = []
@@ -445,7 +455,8 @@ def digest():
 
 
 def main():
-    if "--digest" in sys.argv[1:]:
+    argv = sys.argv[1:]
+    if "--digest" in argv:
         return digest()
     try:
         import jax
@@ -460,7 +471,35 @@ def main():
               f"{device['platform']!r} ({device['kind']} x {device['count']})",
               file=sys.stderr)
         return 2
+    if "--mesh" in argv:
+        given = argv[argv.index("--mesh") + 1:]
+        return run_mesh(device, devs, given[0] if given else MESH_CONFIG)
     return run(device, devs)
+
+
+def finish(device):
+    if FAILED:
+        print("chip_smoke FAILED: " + "; ".join(FAILED), flush=True)
+        return 1
+    print(json.dumps({"ok": True, "device": device}), flush=True)
+    return 0
+
+
+def run_mesh(device, devs, config):
+    """The mesh leg alone, its memory checks on peaks (nothing else has
+    touched the devices)."""
+    clock = CompileClock()
+    if device["count"] < 4:
+        print(f"chip_smoke --mesh: needs 4 chips; JAX found "
+              f"{device['count']}", file=sys.stderr)
+        return 2
+    from lightgbm_tpu import runtime
+    runtime.configure_compile_cache()
+    with phase(f"mesh: {config} over {device['count']} devices"):
+        mesh_leg(config, devs, alone=True)
+    clock.report()
+    say(f"total wall {time.time() - _T0:.1f} s")
+    return finish(device)
 
 
 def run(device, devs):
@@ -575,8 +614,10 @@ def run(device, devs):
     if device["count"] >= 4 and bst is not None:
         del bst, trained
         gc.collect()
-        with phase(f"mesh: tree_learner=data over {device['count']} devices"):
-            mesh_leg(params, X_tr, y_tr, X_te, y_te, devs)
+        del X_tr, y_tr, X_te, y_te
+        gc.collect()
+        with phase(f"mesh: {MESH_CONFIG} over {device['count']} devices"):
+            mesh_leg(MESH_CONFIG, devs)
     else:
         say(f"mesh leg skipped: {device['count']} device(s) visible, "
             "needs >= 4")
@@ -591,11 +632,7 @@ def run(device, devs):
     clock.report()
     say(f"total wall {time.time() - _T0:.1f} s")
 
-    if FAILED:
-        print("chip_smoke FAILED: " + "; ".join(FAILED), flush=True)
-        return 1
-    print(json.dumps({"ok": True, "device": device}), flush=True)
-    return 0
+    return finish(device)
 
 
 def serve_requests(bst, ref, X_te):
@@ -645,19 +682,39 @@ def serve_requests(bst, ref, X_te):
             app.shutdown()
 
 
-def mesh_leg(params, X_tr, y_tr, X_te, y_te, devs):
+def mesh_leg(config, devs, alone=False):
+    """tree_learner=data over every device, at the shape `config` (a
+    benchmark configuration file) states: its generator, width and
+    parameters, CHIP_SMOKE_ROWS rows.  `alone`: nothing ran on the devices
+    before, so their PEAKS are this leg's and are held to the balance too."""
+    import importlib.util
     import jax
 
     import bench
     import lightgbm_tpu as lgb
 
-    def in_use():
-        return [(d.memory_stats() or {}).get("bytes_in_use", 0) for d in devs]
+    cfg = json.loads(open(config).read())
+    spec = importlib.util.spec_from_file_location(
+        "generator", os.path.join(os.path.dirname(config), "..", "generators",
+                                  cfg["generator"] + ".py"))
+    gen = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(gen)
+    data = gen.make(1, ROWS + HOLDOUT, cfg["shape"])
+    X_tr, y_tr = data["X"][:ROWS], data["y"][:ROWS]
+    X_te, y_te = data["X"][ROWS:], data["y"][ROWS:]
+    params = dict(cfg["params"], verbosity=-1)
+    say(f"mesh: {cfg['name']}: {ROWS} x {X_tr.shape[1]} rows, {params}")
+
+    def stat(key):
+        return [(d.memory_stats() or {}).get(key, 0) for d in devs]
 
     def strip(model_str):
         return model_str.split("\nparameters:")[0]
 
-    base = in_use()
+    def mib(values):
+        return [round(v / 2 ** 20, 1) for v in values]
+
+    base = stat("bytes_in_use")
     models = {}
     for mode in ("psum", "reduce_scatter"):
         p = dict(params, tree_learner="data", hist_comms=mode)
@@ -675,16 +732,25 @@ def mesh_leg(params, X_tr, y_tr, X_te, y_te, devs):
                   span == {d.id for d in devs}
                   and not eng.dd.bins.sharding.is_fully_replicated,
                   f"{eng.dd.bins.sharding}")
-            delta = [b - a for a, b in zip(base, in_use())]
-            # a whole unsharded bin matrix on one device doubles its share;
-            # the objective's unsharded label (4 B/row) is within the bound
+            delta = [b - a for a, b in zip(base, stat("bytes_in_use"))]
+            # the table goes a shard to a device and the label with it: what
+            # is left to device 0 alone is small change (layouts, masks)
             check("mesh: per-device bytes_in_use balanced (none piled on "
                   "device 0)", min(delta) > 0
-                  and max(delta) <= 1.5 * min(delta),
-                  f"delta MiB {[round(d / 2 ** 20, 1) for d in delta]}")
+                  and max(delta) <= 1.1 * min(delta), f"delta MiB {mib(delta)}")
+            if alone:
+                # no device-0 transient either: a table shipped through one
+                # chip first reads 4x its shard there (PR 22: the pad too)
+                peak = stat("peak_bytes_in_use")
+                check("mesh: per-device peak_bytes_in_use balanced (no "
+                      "table through device 0)", min(peak) > 0
+                      and max(peak) <= 1.1 * min(peak), f"peak MiB {mib(peak)}")
             check_trees(bst, len(y_tr), params["num_leaves"], "mesh")
+            mesh_kernel_exactness(eng)
             auc = bench.auc_score(y_te, bst.predict(X_te, raw_score=True))
-            check("mesh: holdout AUC sane", auc > 0.7, f"{auc:.4f}")
+            check("mesh: holdout predict took the device path",
+                  bst.last_predict_path == "device", bst.last_predict_path)
+            check("mesh: holdout AUC sane", auc > 0.6, f"{auc:.4f}")
         models[mode] = strip(bst.model_to_string())
         del bst, eng
         gc.collect()
@@ -696,6 +762,90 @@ def mesh_leg(params, X_tr, y_tr, X_te, y_te, devs):
     say("mesh: serial vs data-parallel models byte-identical: "
         f"{strip(serial.model_to_string()) == models['psum']} "
         "(reported, not required)")
+
+
+def mesh_kernel_exactness(eng):
+    """One root pass and one 64-slot pass of the per-device kernel over the
+    engine's own sharded table, reduced over the mesh by each collective
+    (the grower's `psum`, comms.reduce_hist), against
+    benchmark/reference_hist.py on the WHOLE table (int64 NumPy;
+    `_hist_segsum` sums in float32 and is itself inexact past 2^24 a bin:
+    the root's zero bins at 4M rows): integers, so at tolerance 0.  A shard that the reduction lost, or a
+    collective that dropped a group slice, cannot pass."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), "benchmark"))
+    import reference_hist
+    from lightgbm_tpu.pallas.stream_kernel import (build_route_tables,
+                                                   route_and_hist)
+    from lightgbm_tpu.parallel.comms import (make_rs_context,
+                                             reduce_hist_rows)
+    from lightgbm_tpu.parallel.mesh import shard_map_rows
+    from lightgbm_tpu.telemetry import watched_jit
+    dd, mesh, ax = eng.dd, eng.mesh, eng._row_axis
+    G, Bmax, L = dd.num_groups, dd.max_bins, eng._grow_params.num_leaves
+    T, N, S = eng._pack_block, dd.bins.shape[0], 64
+    say(f"mesh kernel: G={G} Bmax={Bmax} L={L} T={T}, "
+        f"{N // mesh.devices.size} rows a shard, bins_T {eng._packed.shape} "
+        f"{eng._packed.dtype}")
+    rs = np.random.RandomState(0)
+    live = np.arange(N) < eng.num_data
+    gi = rs.randint(-32, 33, N) * live
+    hi = rs.randint(0, 33, N) * live
+    lid = rs.randint(0, S, N)
+    w_T = np.zeros((8, N), np.float32)
+    w_T[0], w_T[1], w_T[2] = gi, hi, live
+    cols = NamedSharding(mesh, P(None, ax))
+    w_T = jax.device_put(w_T, cols)
+    zL = jnp.zeros(L, jnp.int32)
+    bits = jnp.zeros((-(-Bmax // 8) * 8, L), jnp.bfloat16)
+    keep = jnp.where(jnp.arange(L) < S, jnp.arange(L) + 1, 0).astype(jnp.int32)
+    plan = make_rs_context(mesh, ax, dd.layout, dd.routing, G, Bmax,
+                           eng._grow_params)[0]
+
+    def reduced(slots, leaf, tabs, root, scatter, limbs=1):
+        def local(bT, lf, wT, tb, bi):
+            _, h, c = route_and_hist(bT, lf, wT, tb, bi, slots, Bmax, G, L,
+                                     block_rows=T, has_cat=False,
+                                     int_weights=True, root=root)
+            # the grower's own collective, and its int32 count psum
+            h = reduce_hist_rows(h, ax, 1, plan if scatter else None,
+                                 limbs=limbs)
+            return h, jax.lax.psum(c.astype(jnp.int32), ax)
+        out = P(None, ax, None, None) if scatter else P()
+        fn = watched_jit(shard_map_rows(
+            local, mesh, (P(None, ax),) * 3 + (P(None, None),) * 2,
+            (out, P())), name="chip_smoke_mesh_pass", warn_after=0)
+        h, c = fn(eng._packed, jax.device_put(leaf.reshape(1, -1), cols),
+                  w_T, tabs, bits)
+        return np.asarray(h)[:, :G], np.asarray(c)
+
+    table = np.asarray(dd.bins)
+    for tag, slots, leaf, keep_tab, root in (
+            ("root pass", 1, np.zeros(N, np.int32), zL.at[0].set(1), True),
+            ("64-slot pass", S, lid.astype(np.int32), keep, False)):
+        tabs = build_route_tables(zL, zL, zL, zL, zL, zL, zL, keep_tab,
+                                  dd.routing, L)
+        at = np.where(live, leaf, -1)
+        ref = reference_hist.histograms(table, at, gi, hi, slots, Bmax)
+        got = {}
+        for name, scatter in (("psum", False), ("reduce_scatter", True)):
+            h, c = got[name] = reduced(slots, leaf, tabs, root, scatter)
+            check(f"mesh {tag}, {name}: reduced int32 histogram == the "
+                  "whole table's, exactly",
+                  h.dtype == np.int32 and np.array_equal(h, ref[..., :2]))
+            check(f"mesh {tag}, {name}: reduced slot counts exact (int32)",
+                  c.dtype == np.int32 and np.array_equal(
+                      c.reshape(-1)[:slots], ref[:, 0, :, 2].sum(axis=-1)),
+                  f"{c.reshape(-1)[:4]}")
+        check(f"mesh {tag}: psum and reduce_scatter byte-identical",
+              got["psum"][0].tobytes() == got["reduce_scatter"][0].tobytes())
+        h2 = reduced(slots, leaf, tabs, root, False, limbs=2)[0]
+        check(f"mesh {tag}: the two-limb reduce is float32 of the whole "
+              "table's sums", h2.dtype == np.float32 and np.array_equal(
+                  h2, ref[..., :2].astype(np.float32)))
 
 
 if __name__ == "__main__":

@@ -18,7 +18,7 @@ import numpy as np
 
 from .binning import BinnedData, construct_binned, find_bin_mappers, find_feature_groups
 from .config import Config, resolve_aliases
-from .device_data import DeviceData, to_device
+from .device_data import DeviceData, device_view, to_device
 from .metrics import create_metrics
 from .objectives import create_objective
 from .telemetry import boundary as _boundary
@@ -936,7 +936,28 @@ class Dataset:
             self.raw_data = None
         return self
 
-    def device_data(self) -> DeviceData:
+    def device_view(self) -> DeviceData:
+        """The layouts and dimensions of the binned table, without the
+        table (``bins`` is None)."""
+        self.construct()
+        return device_view(self.binned)
+
+    def device_data(self, sharding=None, pad_rows_to: int = 256,
+                    pad_groups_to: int = 1, view=None) -> DeviceData:
+        """The binned table on the device, shipped once and cached.  With
+        ``sharding`` (an engine about to train over an in-process mesh) the
+        table goes straight from the host to the mesh, a shard to a device,
+        padded as asked (``view``: the engine's ``device_view()``); that
+        copy is the engine's and is not cached here, and a cached
+        single-device copy is dropped for it (a full N x G matrix on device
+        0 for the whole run: +56 MiB at 2.1M rows on four v5e chips,
+        chip_smoke.py, PR 22)."""
+        if sharding is not None:
+            self.construct()
+            self._device = None
+            return to_device(self.binned, pad_rows_to=pad_rows_to,
+                             sharding=sharding, pad_groups_to=pad_groups_to,
+                             view=view)
         if self._device is None:
             self.construct()
             ship = None
@@ -947,14 +968,6 @@ class Dataset:
                 ship = self.ingest_stats.get("chunk_rows")
             self._device = to_device(self.binned, ship_chunk_rows=ship)
         return self._device
-
-    def release_device_data(self) -> None:
-        """Drop the cached single-device copy of the binned matrix.  A mesh
-        learner shards its own copy across the devices; keeping this one
-        alive piles a full N x G matrix on device 0 (seen on four v5e chips:
-        +56 MiB on device 0 at 2.1M rows — chip_smoke.py, PR 22).  The next
-        ``device_data()`` ships it again."""
-        self._device = None
 
     def bin_mappers(self):
         self.construct()

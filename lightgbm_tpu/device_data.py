@@ -157,17 +157,61 @@ def ship_binned_chunks(bins: np.ndarray, n_pad: int,
     return buf[:n_pad] if n_ship != n_pad else buf
 
 
-def to_device(binned: BinnedData, pad_rows_to: int = 256,
-              sharding=None, ship_chunk_rows=None) -> DeviceData:
-    from .telemetry import boundary
+def _ship_shards(bins: np.ndarray, n_pad: int, g_pad: int,
+                 sharding) -> jax.Array:
+    """Place a host table straight onto a mesh: each device is handed its
+    own (rows, groups) block, cut from the host array and zero-filled where
+    it reaches past the table (the row pad to whole kernel blocks a shard,
+    the group pad of a feature-sharded mesh).  No device holds more than
+    its shard at any time and the pad is never a device copy — the
+    in-process form of parallel/dist_data.py ``make_global_bins``."""
+    n, g = bins.shape
+
+    def block(index):
+        rows, cols = (sl.indices(dim) for sl, dim in
+                      zip(index, (n_pad, g_pad)))
+        part = np.ascontiguousarray(bins[rows[0]:min(rows[1], n),
+                                         cols[0]:min(cols[1], g)])
+        short = (rows[1] - rows[0] - part.shape[0],
+                 cols[1] - cols[0] - part.shape[1])
+        if any(short):
+            part = np.pad(part, ((0, short[0]), (0, short[1])))
+        return part
+
+    return jax.make_array_from_callback((n_pad, g_pad), sharding, block)
+
+
+def device_view(binned: BinnedData) -> DeviceData:
+    """The layouts and the table's dimensions without the table (``bins``
+    is None): what an engine decides its mesh padding from before any row
+    is shipped."""
     layout, routing, Bmax = build_layouts(binned)
+    return DeviceData(bins=None, layout=layout, routing=routing,
+                      num_data=binned.bins.shape[0],
+                      num_features=binned.num_features,
+                      num_groups=binned.num_groups, max_bins=Bmax)
+
+
+def to_device(binned: BinnedData, pad_rows_to: int = 256,
+              sharding=None, ship_chunk_rows=None,
+              pad_groups_to: int = 1, view: DeviceData = None) -> DeviceData:
+    """``device_view`` (or the caller's, ``view``) with the binned table on
+    the device.  With ``sharding`` (an in-process mesh) the table goes a
+    shard to a device from the host (``_ship_shards``), rows padded to
+    ``pad_rows_to`` and groups to ``pad_groups_to``; without, whole onto
+    the default device."""
+    from .telemetry import boundary
+    view = view or device_view(binned)
     bins = binned.bins
-    n = bins.shape[0]
+    n, g = bins.shape
     n_pad = -(-n // pad_rows_to) * pad_rows_to
     # the span ends with the bins ON the device: the transfer is
     # asynchronous, and everything built next reads them anyway
-    with boundary("Dataset::Ship", rows=n, groups=bins.shape[1]):
-        if ship_chunk_rows and _ship_supported():
+    with boundary("Dataset::Ship", rows=n, groups=g) as span:
+        if sharding is not None:
+            arr = _ship_shards(bins, n_pad, -(-g // pad_groups_to)
+                               * pad_groups_to, sharding)
+        elif ship_chunk_rows and _ship_supported():
             arr = ship_binned_chunks(bins, n_pad, int(ship_chunk_rows))
         elif isinstance(bins, np.memmap):
             # out-of-core bins: transfer straight from the mapping (pages
@@ -181,9 +225,9 @@ def to_device(binned: BinnedData, pad_rows_to: int = 256,
             if n_pad != n:
                 bins = np.pad(bins, ((0, n_pad - n), (0, 0)))
             arr = jnp.asarray(bins)
-        if sharding is not None:
-            arr = jax.device_put(arr, sharding)
         arr.block_until_ready()
-    return DeviceData(bins=arr, layout=layout, routing=routing,
-                      num_data=n, num_features=binned.num_features,
-                      num_groups=binned.num_groups, max_bins=Bmax)
+        if sharding is not None:
+            shards = arr.addressable_shards
+            span.set(shards=len({s.device for s in shards}),
+                     bytes_per_shard=int(shards[0].data.nbytes))
+    return view._replace(bins=arr)

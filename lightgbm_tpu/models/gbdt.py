@@ -108,7 +108,14 @@ class GBDT:
                                      pad_rows_for_mesh)
         self.mesh = create_mesh(config.mesh_shape, config.tree_learner,
                                 config.num_machines)
-        dd: DeviceData = train_data.device_data()
+        self._dist_mode = getattr(train_data, "_dist", None) is not None
+        # an in-process mesh is handed its table a shard to a device, padded
+        # on the host (Dataset.device_data(sharding=)): until then `dd` is
+        # the layouts and dimensions alone, which is all the choice of
+        # backend and of padding reads
+        in_process_mesh = self.mesh is not None and not self._dist_mode
+        dd: DeviceData = (train_data.device_view() if in_process_mesh
+                          else train_data.device_data())
         self._row_sharding = None
         self._row_axis = None
         self._mesh_stream = False
@@ -132,7 +139,6 @@ class GBDT:
                 voting_supported(dd.layout, dd.routing)
                 and not any(m.bin_type == 1
                             for m in train_data.bin_mappers()))
-        self._dist_mode = getattr(train_data, "_dist", None) is not None
         if self._dist_mode:
             # multi-process training on a distributed-loaded dataset: each
             # process holds only its binned row shard; assemble ONE global
@@ -182,11 +188,6 @@ class GBDT:
                                       bin_buckets=bb),
                     stream_block_rows(dd.max_bins, dd.num_groups, False,
                                       bin_buckets=bb))
-            n_pad = pad_rows_for_mesh(dd.bins.shape[0], self.mesh,
-                                      base=pad_base)
-            bins = dd.bins
-            if n_pad != bins.shape[0]:
-                bins = jnp.pad(bins, ((0, n_pad - bins.shape[0]), (0, 0)))
             sh = bins_sharding(self.mesh, config.tree_learner)
             self._mesh_2d = (config.tree_learner == "data"
                              and len(sh.spec) > 1 and sh.spec[1] is not None)
@@ -196,19 +197,16 @@ class GBDT:
             # the 2D mesh the feature-local block is further psum_scattered
             # over the row axis at the group dim, so groups pad to a
             # multiple of D_rows * D_feat.
+            g_mult = 1
             if len(sh.spec) > 1 and sh.spec[1] is not None:
-                ax = int(self.mesh.shape[sh.spec[1]])
+                g_mult = int(self.mesh.shape[sh.spec[1]])
                 if self._mesh_2d:
-                    ax *= int(self.mesh.shape[sh.spec[0]])
-                g = bins.shape[1]
-                g_pad = -(-g // ax) * ax
-                if g_pad != g:
-                    bins = jnp.pad(bins, ((0, 0), (0, g_pad - g)))
-            bins = jax.device_put(bins, sh)
-            dd = dd._replace(bins=bins)
-            # the engine now holds the sharded matrix; the Dataset's cached
-            # unsharded copy would sit whole on device 0 for the whole run
-            train_data.release_device_data()
+                    g_mult *= int(self.mesh.shape[sh.spec[0]])
+            # straight from the host, a shard to a device: no device ever
+            # holds more than its shard, and the pad is never a device copy
+            dd = train_data.device_data(
+                sharding=sh, view=dd, pad_groups_to=g_mult,
+                pad_rows_to=pad_rows_for_mesh(1, self.mesh, base=pad_base))
             if config.tree_learner != "feature":
                 # rows are the sharded axis: keep every per-row array (score, grad,
                 # hess, bagging mask) on the same sharding so each eager op compiles
@@ -235,7 +233,10 @@ class GBDT:
         # row-pad mask: padded rows contribute nothing (distributed layouts
         # pad per shard, so the mask is not a prefix — Dataset knows)
         pad_mask = train_data.get_true_row_mask(n)
-        self._pad_mask = self._shard_row_array(jnp.asarray(pad_mask))
+        # an in-process mesh takes it from the host to its sharding, never
+        # whole on device 0 first
+        self._pad_mask = self._shard_row_array(
+            pad_mask if in_process_mesh else jnp.asarray(pad_mask))
 
         k = self.num_tree_per_iteration
         self._score_shape = (n,) if k == 1 else (n, k)
@@ -295,15 +296,23 @@ class GBDT:
                 "hist_m_rows": (dd.num_groups * tiling.tile_m_rows
                                 // tiling.tile_groups if tiling.tile_groups
                                 else tiling.tile_m_rows)}
-            packed = pack_bins_T(
-                dd.bins, self._pack_block, max_bins=dd.max_bins,
-                tile_groups=tiling.tile_groups).bins_T
             if self._mesh_stream:
-                # rows were pre-padded to a whole kernel block per device, so
-                # the packed words split evenly across the row axis
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                packed = jax.device_put(
-                    packed, NamedSharding(self.mesh, P(None, self._row_axis)))
+                # a shard at a time, each on its own device, as one program
+                # (its temporaries fuse): rows were padded to a whole
+                # kernel block per device, so no shard pads a row
+                from jax.sharding import PartitionSpec as P
+                from ..parallel.mesh import shard_map_rows
+                packed = watched_jit(shard_map_rows(
+                    lambda b: pack_bins_T(
+                        b, self._pack_block, max_bins=dd.max_bins,
+                        tile_groups=tiling.tile_groups).bins_T,
+                    self.mesh, (P(self._row_axis),),
+                    P(None, self._row_axis)), name="pack_bins_shards",
+                    owner=self)(dd.bins)
+            else:
+                packed = pack_bins_T(
+                    dd.bins, self._pack_block, max_bins=dd.max_bins,
+                    tile_groups=tiling.tile_groups).bins_T
         # NOTE: `packed` must be a jit ARGUMENT, not a closure capture —
         # captured arrays are embedded in the HLO as constants, and a 10M-row
         # packed bin matrix (hundreds of MB) blows up compilation
@@ -406,6 +415,7 @@ class GBDT:
         self._compact_overflow = False
         self._overflow_seen = 0
         self._hist_passes_seen = 0, 0, 0
+        self._poll_iter_seen = 0
         # batched device-flag fetch cadence: eval_fetch_freq, or auto —
         # 16 wherever the fused one-launch path is the default (TPU, any
         # row-sharded stream mesh: each blocking flag read costs a full
@@ -418,12 +428,12 @@ class GBDT:
             self._finished_check_every = 16
         else:
             self._finished_check_every = 1
-        # Pallas leaf-value gather: single-device TPU only (a mesh shards the
-        # row axis; XLA partitions the plain gather there instead). The
-        # kernel holds an (L, T) one-hot in VMEM, so bound L like the stream
-        # kernel does.
+        # Pallas leaf-value gather: a single TPU device, or the row-sharded
+        # stream mesh a shard at a time (_leaf_gather_fn; any other mesh
+        # leaves the plain gather to XLA's partitioner). The kernel holds
+        # an (L, T) one-hot in VMEM, so bound L like the stream kernel does.
         self._use_leaf_gather_kernel = (
-            on_tpu() and self.mesh is None
+            on_tpu() and (self.mesh is None or self._mesh_stream)
             and max(self.config.num_leaves, 2) <= 2048)
         self._rng = np.random.RandomState(config.feature_fraction_seed)
         self._saved_state: Optional[Tuple] = None
@@ -502,6 +512,22 @@ class GBDT:
         spec = self._row_sharding.spec
         return jax.device_put(
             a, NamedSharding(self._row_sharding.mesh, P(spec[0], None)))
+
+    def _leaf_gather_fn(self):
+        """values[leaf_id] by the streaming one-hot kernel
+        (stream_kernel.leaf_gather).  Under the row-sharded stream mesh
+        every device gathers its own rows inside shard_map: GSPMD cannot
+        partition a Pallas call, and XLA's own gather of a small table over
+        26M rows a chip took 0.26 s of a 0.88 s tree on four v5e chips
+        (PR 34's chip runs, ISSUE 35)."""
+        from ..pallas.stream_kernel import leaf_gather
+        if self.mesh is None:
+            return leaf_gather
+        from jax.sharding import PartitionSpec as P
+        from ..parallel.mesh import shard_map_rows
+        return shard_map_rows(lambda lid, values: leaf_gather(lid, values),
+                              self.mesh, (P(self._row_axis), P()),
+                              P(self._row_axis))
 
     # ------------------------------------------------------------------
     def _row_compaction_capacity(self, mask) -> int:
@@ -697,16 +723,42 @@ class GBDT:
         # programs only (the batched-multiclass wire stays exact int32;
         # the per-class scan reduces K packed single-class blocks)
         pw = gp.hist_packed_width if gp.int_hist and kb == 1 else 32
-        per_round = hist_comms_bytes_per_round(
-            S, self.dd.num_groups, self.dd.max_bins, d, gp.hist_comms,
-            cdtype, num_class=kb, packed_width=pw)
+        per_pass = functools.partial(
+            hist_comms_bytes_per_round, num_groups=self.dd.num_groups,
+            bmax=self.dd.max_bins, d=d, mode=gp.hist_comms, dtype=cdtype,
+            num_class=kb, packed_width=pw)
+        # an exact int32 block that may pass 2^31 in total crosses in two
+        # 16-bit limbs (comms.reduce_hist_rows); the packed wire never does
+        limbs = gp.hist_reduce_limbs if pw == 32 else 1
+        per_round = limbs * per_pass(S)
         self._comms_model_cache = {
             "mode": gp.hist_comms, "dtype": cdtype,
             "devices": d, "per_round_bytes": per_round,
-            "packed_width": pw,
+            "packed_width": pw, "limbs": limbs,
+            "root_bytes_per_limb": per_pass(1),
+            "trees_per_iter": k // kb,
             "hist_block_bytes": per_round,
             "per_iter_bytes": per_round * rounds2 * (k // kb)}
         return self._comms_model_cache
+
+    def _poll_comm_fields(self) -> Dict[str, int]:
+        """What the flag poll publishes of the histogram collective, static
+        per compiled program: the devices of the mesh, the int32 words an
+        entry crosses in, and the payload one device materialises out of
+        the root pass's reduce and out of a budget round's, a limb
+        (comms.hist_comms_bytes_per_round).  Bytes only where the grower
+        issues the collective itself - the stream kernel under a
+        row-sharded mesh; 1, 1, 0, 0 on one device."""
+        cm = self._comms_model() if self._mesh_stream else None
+        if cm is None:
+            d = 1 if self.mesh is None else int(self.mesh.devices.size)
+            return {"mesh_devices": d, "hist_reduce_limbs": 1,
+                    "hist_comm_bytes_root": 0, "hist_comm_bytes_round": 0}
+        return {"mesh_devices": cm["devices"],
+                "hist_reduce_limbs": cm["limbs"],
+                "hist_comm_bytes_root": cm["root_bytes_per_limb"],
+                "hist_comm_bytes_round":
+                    cm["per_round_bytes"] // cm["limbs"]}
 
     def _route_only_passes_per_tree(self) -> int:
         """Full-data route-only passes one grown tree costs (telemetry
@@ -881,17 +933,38 @@ class GBDT:
             return None
         return buckets
 
+    def _int_hist_rows(self):
+        """(rows one device's int32 accumulators sum over, rows of the whole
+        table): the same but under the row-sharded stream mesh, where a
+        device contracts its own shard and the collective sums the rest."""
+        n = self.dd.bins.shape[0]
+        d = int(self.mesh.shape[self._row_axis]) if self._mesh_stream else 1
+        return n // d, n
+
     def _resolved_int_hist(self) -> bool:
         """Quantized gradients contracted on the int8 MXU into exact int32
-        sums: the stream kernel's, where the levels fit int8 and a whole
-        table's worth of one level cannot overflow int32."""
+        sums: the stream kernel's, where the levels fit int8 and one
+        DEVICE's rows of one level cannot overflow int32 (the whole
+        table's may, under a mesh: _resolved_reduce_limbs)."""
         c = self.config
         return bool(c.use_quantized_grad
                     and self._resolve_hist_backend() == "stream"
                     and c.num_grad_quant_bins <= 254
                     and c.num_grad_quant_bins % 2 == 0
                     and (c.num_grad_quant_bins / 2)
-                    * self.dd.bins.shape[0] < 2 ** 31)
+                    * self._int_hist_rows()[0] < 2 ** 31)
+
+    def _resolved_reduce_limbs(self) -> int:
+        """int32 words a histogram entry crosses the mesh in: 1, or 2 (high
+        and low 16 bits, summed apart: comms.split_limbs) where a whole
+        table's worth of one level would overflow the one - 67M rows at 64
+        levels.  The reference widens its accumulators by the leaf's row
+        count the same way (gradient_discretizer.cpp, hist_bits)."""
+        if not (self._mesh_stream and self._resolved_int_hist()):
+            return 1
+        whole = (self.config.num_grad_quant_bins / 2) \
+            * self._int_hist_rows()[1]
+        return 1 if whole < 2 ** 31 else 2
 
     def _resolved_packed_width(self) -> int:
         """Packed-wire width for the quantized histogram collective
@@ -953,6 +1026,7 @@ class GBDT:
             # even level count (odd counts clip to a non-integer +half grid
             # value that the int8 kernel could not represent)
             int_hist=self._resolved_int_hist(),
+            hist_reduce_limbs=self._resolved_reduce_limbs(),
             bin_buckets=self._resolved_bin_buckets(),
             has_cegb=(c.cegb_penalty_split > 0.0
                       or (c.cegb_penalty_feature_coupled is not None
@@ -1385,6 +1459,46 @@ class GBDT:
             # per-iteration state (e.g. lambdarank position biases) threads
             # through the jit as argument + output so the trace stays pure
             self._grad_state_names = list(objective.state_attrs())
+            self._bound_rows = self._shard_bind()
+
+    def _shard_bind(self):
+        """One-time placement of the objective's per-row arrays (label,
+        weight, ...) for an in-process row-sharded mesh: padded on the host
+        to the table's rows and put on the row sharding, so the gradient
+        program reads them where its score lives — an array left on the
+        default device is placed again at every launch.  The objective's
+        own attributes go back to the host (nothing of them stays on device
+        0; its eager methods take NumPy as the multi-process path's do).
+        -> {attr: sharded array}, empty where it does not apply (no row
+        sharding, multi-process, or an objective that binds per-query
+        lists)."""
+        if self._row_sharding is None or self._dist_mode:
+            return {}
+        obj, n = self.objective, self.dd.bins.shape[0]
+        held = {a: getattr(obj, a) for a in self._grad_attr_names}
+        if not held or any(getattr(v, "ndim", 0) < 1
+                           or v.shape[0] != self.num_data
+                           for v in held.values()):
+            return {}
+        bound = {}
+        with _tel_tracer.boundary("GBDT::ShardBind", rows=n,
+                                  arrays=len(held)):
+            for a, v in held.items():
+                host = np.asarray(v)
+                setattr(obj, a, host)
+                pad = [(0, n - host.shape[0])] + [(0, 0)] * (host.ndim - 1)
+                bound[a] = self._shard_row_array(np.pad(host, pad))
+            jax.block_until_ready(bound)
+        return bound
+
+    def _bound_objective(self):
+        """The objective's arrays the gradient programs take as arguments:
+        its bound per-row arrays (their sharded copies where _shard_bind
+        made them) and its per-iteration state."""
+        bound = {a: getattr(self.objective, a)
+                 for a in self._grad_attr_names + self._grad_state_names}
+        bound.update(self._bound_rows)
+        return bound
 
     def _gradient_graph(self, score, bound, pad_mask, qkey, quantize=True):
         """Traced gradient chain shared by the fused-gradient and
@@ -1405,7 +1519,9 @@ class GBDT:
         for a in attr_names:
             setattr(objective, a, bound[a])
         try:
-            s = score[:num_data]
+            # bound arrays that _shard_bind padded to the table's rows take
+            # the whole padded score: nothing is cut from a sharded array
+            s = score if self._bound_rows else score[:num_data]
             if double:
                 g, h = objective.get_gradients(s.astype(jnp.float64))
                 g = g.astype(jnp.float32)
@@ -1417,7 +1533,7 @@ class GBDT:
             for a in attr_names:
                 setattr(objective, a, old[a])
         n = score.shape[0]
-        if n != num_data:
+        if n != g.shape[0]:
             pad = [(0, n - num_data)] + [(0, 0)] * (g.ndim - 1)
             g, h = jnp.pad(g, pad), jnp.pad(h, pad)
         pm = pad_mask if g.ndim == 1 else pad_mask[:, None]
@@ -1443,8 +1559,7 @@ class GBDT:
             self._grad_fn = watched_jit(_fn, name="gradients", owner=self)
         qkey = jax.random.PRNGKey(
             (self.config.data_random_seed + 11) * 131071 + self.iter_)
-        bound = {a: getattr(self.objective, a)
-                 for a in self._grad_attr_names + self._grad_state_names}
+        bound = self._bound_objective()
         with self._grow_x64_ctx():
             out = self._grad_fn(self.score, bound, self._pad_mask, qkey)
         for a, v in out[5].items():
@@ -1660,6 +1775,7 @@ class GBDT:
         self._train_state = st
         self._overflow_seen = 0
         self._hist_passes_seen = 0, 0, 0
+        self._poll_iter_seen = self.iter_
         return st
 
     def _fused_compact_rows(self, sample_mode: str, mask_arg=None) -> int:
@@ -1764,10 +1880,8 @@ class GBDT:
             # rows, so its one "shard" is the full row count
             D = (int(self.mesh.shape[row_axis])
                  if self.mesh is not None and row_axis is not None else 1)
-            gather = None
-            if self._use_leaf_gather_kernel:
-                from ..pallas.stream_kernel import leaf_gather
-                gather = leaf_gather
+            gather = (self._leaf_gather_fn()
+                      if self._use_leaf_gather_kernel else None)
             def _fn(state, bound, pad_mask, mask_arg, qkey, skey, gkey,
                     bins, colm, packed, rate, compact_rows=0,
                     sample_mode="none"):
@@ -1879,8 +1993,7 @@ class GBDT:
         skey = strategy.traced_key(self.iter_)
         if skey is None:
             skey = jnp.zeros(2, jnp.uint32)
-        bound = {a: getattr(self.objective, a)
-                 for a in self._grad_attr_names + self._grad_state_names}
+        bound = self._bound_objective()
         with self._grow_x64_ctx():
             new_state, arrays, new_obj = self._iter_fn(
                 state, bound, self._pad_mask, mask_arg, qkey, skey, gkey,
@@ -1911,6 +2024,7 @@ class GBDT:
         from ..telemetry import (hist_pass_count, hist_small_pass_count,
                                  note_hist_passes, note_host_sync,
                                  scan_slot_count)
+        comm = self._poll_comm_fields()
         with _tel_tracer.boundary("GBDT::FlagPoll",
                                   iteration=self.iter_) as poll:
             got = jax.device_get(fetch)
@@ -1920,14 +2034,26 @@ class GBDT:
                 sampled, overflow, *now = (int(v) for v in got[-5:])
                 passes, small, slots = (
                     a - b for a, b in zip(now, self._hist_passes_seen))
-                note_hist_passes(passes, self.iter_, small, slots)
+                # host arithmetic on the same count: under the row mesh
+                # every one of those passes reduced its histogram, a
+                # tree's first the root's one slot, the others a round's
+                reduced = passes if comm["hist_comm_bytes_round"] else 0
+                roots = min(reduced, (self.iter_ - self._poll_iter_seen)
+                            * self._comms_model()["trees_per_iter"]
+                            ) if reduced else 0
+                note_hist_passes(
+                    passes, self.iter_, small, slots, comm_rounds=reduced,
+                    comm_bytes=comm["hist_reduce_limbs"] * (
+                        roots * comm["hist_comm_bytes_root"]
+                        + (reduced - roots) * comm["hist_comm_bytes_round"]))
                 self._hist_passes_seen = now
+                self._poll_iter_seen = self.iter_
                 poll.set(hist_passes=hist_pass_count(),
                          hist_small_passes=hist_small_pass_count(),
                          scan_slots=scan_slot_count(),
                          **({"root_pass": self._root_pass}
                             if self._root_pass else {}),
-                         **self._poll_tiling)
+                         **self._poll_tiling, **comm)
         note_host_sync()
         self._nan_guard.resolve(pending, got[1:1 + len(pending)])
         if st is not None:
@@ -2322,7 +2448,7 @@ class GBDT:
                     # ~100M rows/s; the streaming one-hot contraction runs
                     # at bandwidth
                     if self._score_add_fn is None:
-                        from ..pallas.stream_kernel import leaf_gather
+                        leaf_gather = self._leaf_gather_fn()
 
                         def _sadd(score, lid, lv, rate, col):
                             delta = leaf_gather(lid, lv * rate)

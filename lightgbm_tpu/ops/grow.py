@@ -80,6 +80,10 @@ class GrowParams(NamedTuple):
     # gains resolve exactly as stock LightGBM resolves them
     hist_double: bool = False
     int_hist: bool = False       # int8 quantized-gradient histograms (stream)
+    # int32 words an int histogram entry is reduced across a mesh in: 2
+    # (high and low 16 bits apart, comms.split_limbs) where the whole
+    # table's sum of one level could pass 2^31 though a device's cannot
+    hist_reduce_limbs: int = 1
     # bucketed one-hot M-axis for the stream kernel: static runs of
     # (bucket_bins, group_count) over the bucket-sorted group layout
     # (binning.device_group_order); None = uniform G * Bmax rows
@@ -392,6 +396,18 @@ def feature_local_bin(group_bin: jax.Array, feat: jax.Array,
     return jnp.where(bundled, fb_b, v)
 
 
+def _int_counts(use_stream: bool, mesh) -> bool:
+    """The growers' one static choice of count dtype: leaf counts are int32
+    where a row mesh runs the stream kernel - a device's float32 slot
+    counts are exact (under 2^24 rows of a round's smaller child a device:
+    any leaf under 33.5M rows a device), their sum across devices, the
+    parent's count and the larger child's are taken in int32, and
+    `leaf_count` / `internal_count` come out int32 - and the histogram
+    dtype elsewhere (one device: float32 steps by 2 above 2^24 rows,
+    ROADMAP C12)."""
+    return use_stream and mesh is not None
+
+
 def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Array,
               col_mask: jax.Array, layout: FeatureLayout, routing: RoutingLayout,
               params: GrowParams, monotone: Optional[jax.Array] = None,
@@ -541,6 +557,12 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     # ---- root ----
     use_stream = params.hist_backend == "stream"
     use_fp = mesh is not None and feature_axis is not None
+    int_counts = _int_counts(use_stream, mesh)
+    cdt = i32 if int_counts else hdt
+
+    def count_h(c):
+        """A count of the state's (`cdt`) in the histogram dtype."""
+        return c.astype(hdt) if int_counts else c
     # 2D rows x feature-groups mesh: the fp machinery keyed by the
     # COMPOUND (feature, data) axis + a row-axis psum_scatter in the build
     use_2d = use_fp and row_axis is not None
@@ -563,6 +585,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     use_rs = (mesh is not None and use_stream
               and params.hist_comms == "reduce_scatter")
     G_h = G   # histogram-state group count (mesh-padded in rs mode)
+    plan = None
     if use_rs:
         if not params.plain_growth or forced or params.hist_double:
             raise ValueError(
@@ -685,6 +708,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
             # the reference's per-worker histogram construction followed by
             # ReduceScatter (data_parallel_tree_learner.cpp:285-299)
             from jax.sharding import PartitionSpec as P
+            from ..parallel.comms import reduce_hist_rows
             from ..parallel.mesh import shard_map_rows
 
             # packed-wire quantized histograms (hist_packed_width 16 / 8):
@@ -721,13 +745,12 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                                 with jax.named_scope("hist_psum_packed"):
                                     pw = jax.lax.psum(pw, row_axis)
                             h = unpack_gh_wire(pw, pscales, packed_w)
-                        elif use_rs:
-                            h = reduce_hist(h, row_axis, 1, plan,
-                                            params.hist_comms_dtype,
-                                            chunks=params.hist_comms_chunks)
                         else:
-                            with jax.named_scope("hist_psum"):
-                                h = jax.lax.psum(h, row_axis)
+                            h = reduce_hist_rows(
+                                h, row_axis, 1, plan,
+                                params.hist_comms_dtype,
+                                params.hist_comms_chunks,
+                                params.hist_reduce_limbs)
                     elif use_rs:
                         # route-only rounds: slice-shaped zeros keep the
                         # sharded out_spec consistent (hist never read)
@@ -735,7 +758,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                                       h.dtype)
                     # route-only psum rounds return all-zero hists on every
                     # device — already replicated, no collective needed
-                    return nl, h, jax.lax.psum(c, row_axis)
+                    with jax.named_scope("slot_count_psum"):
+                        return nl, h, jax.lax.psum(c.astype(i32), row_axis)
 
                 hspec = (P(None, row_axis, None, None) if use_rs
                          else P(None, None, None, None))
@@ -801,7 +825,11 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 bins, leaf_id, grad, hess, cnt_w, 1)[..., :2]
     root_g = jnp.sum(grad, dtype=hdt)
     root_h = jnp.sum(hess, dtype=hdt)
-    root_c = jnp.sum(cnt_w, dtype=hdt)
+    if int_counts:
+        root_n = jnp.sum(cnt_w.astype(i32), dtype=i32)   # cnt_w: a 0/1 mask
+        root_c = root_n.astype(hdt)
+    else:
+        root_n = root_c = jnp.sum(cnt_w, dtype=hdt)
     root_out = leaf_output(root_g, root_h, params.lambda_l1, params.lambda_l2,
                            params.max_delta_step)
     used0 = jnp.zeros((L if use_inter else 1, F if use_inter else 1), bool)
@@ -850,11 +878,11 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         left_child=jnp.zeros(L, i32), right_child=jnp.zeros(L, i32),
         split_gain=jnp.zeros(L, f32),
         internal_value=jnp.zeros(L, f32), internal_weight=jnp.zeros(L, f32),
-        internal_count=jnp.zeros(L, f32),
+        internal_count=jnp.zeros(L, i32 if int_counts else f32),
         cat_bitset=jnp.zeros((L, Bmax), bool),
         sum_g=jnp.zeros(L, hdt).at[0].set(root_g),
         sum_h=jnp.zeros(L, hdt).at[0].set(root_h),
-        cnt=jnp.zeros(L, hdt).at[0].set(root_c),
+        cnt=jnp.zeros(L, cdt).at[0].set(root_n),
         depth=jnp.zeros(L, i32),
         leaf_parent=jnp.full(L, -1, i32),
         out_lo=jnp.full(L if use_output else 1, -BIG, f32),
@@ -931,8 +959,9 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 thr = jnp.asarray(list(f_thrs) + [0] * (S - nf), i32)
                 dirf = jnp.asarray([1 if d else 0 for d in f_dl]
                                    + [0] * (S - nf), i32)
-                pg, ph, pc = (st.sum_g[pair_old], st.sum_h[pair_old],
+                pg, ph, pn = (st.sum_g[pair_old], st.sum_h[pair_old],
                               st.cnt[pair_old])
+                pc = count_h(pn)
                 # left sums from the leaf histogram at the forced threshold
                 hf_f = gather_feature_histograms(
                     _hist_order(st.hist[pair_old]), layout, pg, ph)
@@ -977,8 +1006,9 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 thr = st.best_thr[pair_old]
                 dirf = st.best_dir[pair_old]
                 gain = st.best_gain[pair_old]
-                pg, ph, pc = (st.sum_g[pair_old], st.sum_h[pair_old],
+                pg, ph, pn = (st.sum_g[pair_old], st.sum_h[pair_old],
                               st.cnt[pair_old])
+                pc = count_h(pn)
                 lg, lh, lc = (st.best_left_g[pair_old],
                               st.best_left_h[pair_old],
                               st.best_left_c[pair_old])
@@ -1012,7 +1042,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 split_gain=st.split_gain.at[node_idx].set(gain.astype(f32), mode="drop"),
                 internal_value=st.internal_value.at[node_idx].set(out.astype(f32), mode="drop"),
                 internal_weight=st.internal_weight.at[node_idx].set(ph.astype(f32), mode="drop"),
-                internal_count=st.internal_count.at[node_idx].set(pc.astype(f32), mode="drop"),
+                internal_count=st.internal_count.at[node_idx].set(
+                    pn if int_counts else pc.astype(f32), mode="drop"),
                 cat_bitset=st.cat_bitset.at[node_idx].set(bitset, mode="drop"),
                 left_child=st.left_child.at[node_idx].set(~pair_old, mode="drop"),
                 right_child=st.right_child.at[node_idx].set(~pair_new, mode="drop"),
@@ -1143,8 +1174,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
             # exact child counts from the routed partition (reference:
             # serial_tree_learner.cpp:798 overwrites the estimated SplitInfo
             # counts with DataPartition::leaf_count after the split)
-            lc_x = jnp.where(smaller_is_left, slot_cnt, pc - slot_cnt)
-            rc_x = pc - lc_x
+            lc_x = jnp.where(smaller_is_left, slot_cnt, pn - slot_cnt)
+            rc_x = pn - lc_x
 
             # ---- per-leaf stats for the children ----
             st2 = st2._replace(
@@ -1568,11 +1599,11 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                         # full scan)
                         res = (rs_split if use_rs else fp_split)(
                             hist2, st2.sum_g[ids2], st2.sum_h[ids2],
-                            st2.cnt[ids2], st.col_mask)
+                            count_h(st2.cnt[ids2]), st.col_mask)
                     else:
                         res = find_splits(
                             hist2, st2.sum_g[ids2], st2.sum_h[ids2],
-                            st2.cnt[ids2],
+                            count_h(st2.cnt[ids2]),
                             col_mask=cmask2,
                             adv_bounds=((st2.adv_vmin[ids2],
                                          st2.adv_vmax[ids2])
@@ -1588,7 +1619,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                                 key, 100000 + st.round_idx)
                                        if use_extra else None),
                             cegb_penalty=(cegb_pen(
-                                st2.cnt[ids2], st2.cegb_used,
+                                count_h(st2.cnt[ids2]), st2.cegb_used,
                                 lazy_unused_counts(
                                     st2.cegb_lazy,
                                     jnp.full(L, -1, i32).at[
@@ -1729,7 +1760,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         internal_value=final.internal_value, internal_weight=final.internal_weight,
         internal_count=final.internal_count, cat_bitset=final.cat_bitset,
         leaf_value=leaf_value.astype(f32), leaf_weight=final.sum_h.astype(f32),
-        leaf_count=final.cnt.astype(f32),
+        leaf_count=final.cnt if int_counts else final.cnt.astype(f32),
         leaf_parent=final.leaf_parent, num_leaves=final.num_leaves_cur,
         leaf_depth=final.depth,
     )
@@ -1876,8 +1907,9 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             "data x feature mesh with a contraction/segsum backend; use "
             "the per-class grow_tree scan for tree_learner=feature")
     G_h = G
+    plan = None
     if use_rs:
-        from ..parallel.comms import make_rs_context, reduce_hist
+        from ..parallel.comms import make_rs_context
         plan, rs_split, rs_bitset = make_rs_context(
             mesh, row_axis, layout, routing, G, Bmax, params)
         G_h = plan.g_pad
@@ -1951,6 +1983,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
         if mesh is not None:
             from jax.sharding import PartitionSpec as P
+            from ..parallel.comms import reduce_hist_rows
             from ..parallel.mesh import shard_map_rows
 
             def _rh(bT, lid, wT, tb, bi, num_slots, with_hist=True):
@@ -1962,17 +1995,15 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                         with_hist=with_hist, bin_buckets=params.bin_buckets,
                         num_class=K)
                     if with_hist:
-                        if use_rs:
-                            h = reduce_hist(h, row_axis, 2, plan,
-                                            params.hist_comms_dtype,
-                                            chunks=params.hist_comms_chunks)
-                        else:
-                            with jax.named_scope("hist_psum"):
-                                h = jax.lax.psum(h, row_axis)
+                        h = reduce_hist_rows(
+                            h, row_axis, 2, plan, params.hist_comms_dtype,
+                            params.hist_comms_chunks,
+                            params.hist_reduce_limbs)
                     elif use_rs:
                         h = jnp.zeros(h.shape[:2] + (plan.gs,) + h.shape[3:],
                                       h.dtype)
-                    return nl, h, jax.lax.psum(c, row_axis)
+                    with jax.named_scope("slot_count_psum"):
+                        return nl, h, jax.lax.psum(c.astype(i32), row_axis)
 
                 hspec = (P(None, None, row_axis, None, None) if use_rs
                          else P(None, None, None, None, None))
@@ -2025,7 +2056,17 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 backend=params.hist_backend, acc_dtype=hdt)[..., :2]
     root_g = jnp.sum(grad, axis=1, dtype=hdt)                # (K,)
     root_h = jnp.sum(hess, axis=1, dtype=hdt)
-    root_c = jnp.broadcast_to(jnp.sum(cnt_w, dtype=hdt), (K,))
+    int_counts = _int_counts(use_stream, mesh)
+    if int_counts:
+        root_n = jnp.broadcast_to(
+            jnp.sum(cnt_w.astype(i32), dtype=i32), (K,))
+        root_c = root_n.astype(hdt)
+    else:
+        root_n = root_c = jnp.broadcast_to(jnp.sum(cnt_w, dtype=hdt), (K,))
+
+    def count_h(c):
+        """A count of the state's in the histogram dtype (as grow_tree)."""
+        return c.astype(hdt) if int_counts else c
     cm_root = jnp.broadcast_to(col_mask[None, :], (K, F))
     if use_rs or use_fp:
         root_split = (rs_split if use_rs else fp_split)(
@@ -2056,11 +2097,12 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         split_gain=jnp.zeros((K, L), f32),
         internal_value=jnp.zeros((K, L), f32),
         internal_weight=jnp.zeros((K, L), f32),
-        internal_count=jnp.zeros((K, L), f32),
+        internal_count=jnp.zeros((K, L), i32 if int_counts else f32),
         cat_bitset=jnp.zeros((K, L, Bmax), bool),
         sum_g=jnp.zeros((K, L), hdt).at[:, 0].set(root_g),
         sum_h=jnp.zeros((K, L), hdt).at[:, 0].set(root_h),
-        cnt=jnp.zeros((K, L), hdt).at[:, 0].set(root_c),
+        cnt=jnp.zeros((K, L), i32 if int_counts else hdt)
+        .at[:, 0].set(root_n),
         depth=jnp.zeros((K, L), i32),
         leaf_parent=jnp.full((K, L), -1, i32),
         best_gain=jnp.full((K, L), NEG_INF, hdt).at[:, 0].set(
@@ -2136,8 +2178,9 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             thr = ta(st.best_thr, pair_old)
             dirf = ta(st.best_dir, pair_old)
             gain = ta(st.best_gain, pair_old)
-            pg, ph, pc = (ta(st.sum_g, pair_old), ta(st.sum_h, pair_old),
+            pg, ph, pn = (ta(st.sum_g, pair_old), ta(st.sum_h, pair_old),
                           ta(st.cnt, pair_old))
+            pc = count_h(pn)
             lg, lh, lc = (ta(st.best_left_g, pair_old),
                           ta(st.best_left_h, pair_old),
                           ta(st.best_left_c, pair_old))
@@ -2183,7 +2226,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 internal_weight=st.internal_weight.at[k2, node_idx].set(
                     ph.astype(f32), mode="drop"),
                 internal_count=st.internal_count.at[k2, node_idx].set(
-                    pc.astype(f32), mode="drop"),
+                    pn if int_counts else pc.astype(f32), mode="drop"),
                 cat_bitset=st.cat_bitset.at[k2, node_idx].set(
                     bitset, mode="drop"),
                 left_child=st.left_child.at[k2, node_idx].set(
@@ -2311,8 +2354,8 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                         backend=params.hist_backend, acc_dtype=hdt)
                 hist_small = hist3[..., :2]
                 slot_cnt = hist3[:, :, 0, :, 2].sum(axis=-1)
-            lc_x = jnp.where(smaller_is_left, slot_cnt, pc - slot_cnt)
-            rc_x = pc - lc_x
+            lc_x = jnp.where(smaller_is_left, slot_cnt, pn - slot_cnt)
+            rc_x = pn - lc_x
 
             # ---- per-leaf stats for the children ----
             st2 = st2._replace(
@@ -2357,12 +2400,12 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                         hist2.reshape(K * 2 * S, G_h, Bmax, 2),
                         ta(st2.sum_g, ids2).reshape(-1),
                         ta(st2.sum_h, ids2).reshape(-1),
-                        ta(st2.cnt, ids2).reshape(-1), col_mask)
+                        count_h(ta(st2.cnt, ids2)).reshape(-1), col_mask)
                 else:
                     res = find_splits(hist2.reshape(K * 2 * S, G_h, Bmax, 2),
                                       ta(st2.sum_g, ids2).reshape(-1),
                                       ta(st2.sum_h, ids2).reshape(-1),
-                                      ta(st2.cnt, ids2).reshape(-1),
+                                      count_h(ta(st2.cnt, ids2)).reshape(-1),
                                       col_mask=cm2)
             ids2_m = jnp.where(valid2, ids2, drop)
 
@@ -2425,7 +2468,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         internal_count=final.internal_count, cat_bitset=final.cat_bitset,
         leaf_value=leaf_value.astype(f32),
         leaf_weight=final.sum_h.astype(f32),
-        leaf_count=final.cnt.astype(f32),
+        leaf_count=final.cnt if int_counts else final.cnt.astype(f32),
         leaf_parent=final.leaf_parent, num_leaves=final.num_leaves_cur,
         leaf_depth=final.depth,
     )

@@ -199,6 +199,44 @@ def reduce_hist(h: jax.Array, axis: str, g_dim: int, plan: ShardPlan,
     return jnp.sum(contrib, axis=g_dim)
 
 
+def split_limbs(h: jax.Array) -> jax.Array:
+    """int32 partial sums -> their high and low 16 bits stacked along axis 0
+    (`h == hi * 65536 + lo`, lo in [0, 65535], hi signed), for a collective
+    whose TOTAL may pass 2^31 though no device's part does: each limb sums
+    over up to 2^15 devices without overflow.  Twice the wire bytes;
+    `join_limbs` puts the total together as float32."""
+    with jax.named_scope("hist_limbs_split"):
+        return jnp.concatenate([h >> 16, h & 0xFFFF], axis=0)
+
+
+def join_limbs(s: jax.Array) -> jax.Array:
+    """The reduced limbs of `split_limbs` -> the totals as float32, rounded
+    once (each limb converts exactly), i.e. what `.astype(float32)` of the
+    exact integer total gives."""
+    n = s.shape[0] // 2
+    with jax.named_scope("hist_limbs_join"):
+        return (s[:n].astype(jnp.float32) * 65536.0
+                + s[n:].astype(jnp.float32))
+
+
+def reduce_hist_rows(h: jax.Array, axis: str, g_dim: int, plan=None,
+                     dtype: str = "f32", chunks: int = 1,
+                     limbs: int = 1) -> jax.Array:
+    """The histogram collective of a row-sharded mesh, called INSIDE
+    shard_map by both growers: `psum` of the whole block (``plan`` None) or
+    `reduce_hist` to the device's group slice; with ``limbs`` == 2 an int32
+    block crosses in two 16-bit limbs and comes back float32."""
+    wide = limbs == 2 and jnp.issubdtype(h.dtype, jnp.integer)
+    if wide:
+        h = split_limbs(h)
+    if plan is not None:
+        h = reduce_hist(h, axis, g_dim, plan, dtype, chunks=chunks)
+    else:
+        with jax.named_scope("hist_psum"):
+            h = jax.lax.psum(h, axis)
+    return join_limbs(h) if wide else h
+
+
 def pack_gh_wire(h: jax.Array, axis: str, width: int, d: int):
     """Quantize-and-pack an int32 (…, 2) grad/hess histogram block into ONE
     integer lane per pair for the cross-device collective (hist_packed_width;

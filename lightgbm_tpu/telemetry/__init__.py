@@ -35,7 +35,8 @@ from .prometheus import registry_text, render_parts, render_prometheus
 from .quality import (QualityMonitor, QualityProfile, js_divergence,
                       psi, quality_sidecar_path)
 from .tracer import SpanRecord, SpanTracer, global_tracer
-from .watchdog import (WatchEntry, get_recompile_threshold, hist_pass_count,
+from .watchdog import (WatchEntry, get_recompile_threshold,
+                       hist_comm_counts, hist_pass_count,
                        hist_pass_iteration, hist_small_pass_count,
                        host_sync_count, launch_count,
                        note_hist_passes, note_host_sync, note_launch,
@@ -54,7 +55,7 @@ __all__ = [
     "set_recompile_threshold", "get_recompile_threshold", "reset_watchdog",
     "launch_count", "host_sync_count", "note_host_sync", "note_launch",
     "hist_pass_count", "hist_pass_iteration", "hist_small_pass_count",
-    "note_hist_passes", "scan_slot_count",
+    "note_hist_passes", "scan_slot_count", "hist_comm_counts",
     "reset_counters", "costmodel", "cost_summary", "machine_balance",
     "memory_snapshot", "device_memory_gb", "host_rss_gb",
     "TraceContext", "TailRing", "AccessLog", "TRACE_HEADER",

@@ -58,6 +58,8 @@ _host_syncs = 0
 _hist_passes = 0
 _hist_small_passes = 0      # those of them that took the small-slot pass
 _scan_slots = 0             # pairs the rounds' split scans ran over
+_hist_comm_rounds = 0       # passes that reduced their histogram over a mesh
+_hist_comm_bytes = 0        # payload a device materialised in them
 _hist_pass_iteration = 0
 
 
@@ -91,6 +93,15 @@ def scan_slot_count() -> int:
     return _scan_slots
 
 
+def hist_comm_counts() -> tuple:
+    """(rounds, bytes): the histogram passes that reduced across a mesh
+    and the reduced payload one device materialised in them
+    (parallel/comms.py ``hist_comms_bytes_per_round`` a pass, a limb), as
+    of the same poll: the engine's arithmetic on :func:`hist_pass_count`,
+    not a count of the device's own; (0, 0) on one device."""
+    return _hist_comm_rounds, _hist_comm_bytes
+
+
 def hist_pass_iteration() -> int:
     """The boosting iteration at which :func:`hist_pass_count` was last
     read off the device."""
@@ -98,16 +109,20 @@ def hist_pass_iteration() -> int:
 
 
 def note_hist_passes(n: int, iteration: int, small: int = 0,
-                     scan_slots: int = 0) -> None:
+                     scan_slots: int = 0, comm_rounds: int = 0,
+                     comm_bytes: int = 0) -> None:
     """Add ``n`` passes, ``small`` of them small-slot ones, and the
     ``scan_slots`` their rounds scanned, read off the device at
     ``iteration`` (the engine's flag poll calls this with the deltas since
-    its last poll)."""
+    its last poll), and the ``comm_rounds`` of them that reduced across a
+    mesh with the ``comm_bytes`` that delivered to a device."""
     global _hist_passes, _hist_small_passes, _scan_slots, \
-        _hist_pass_iteration
+        _hist_comm_rounds, _hist_comm_bytes, _hist_pass_iteration
     _hist_passes += n
     _hist_small_passes += small
     _scan_slots += scan_slots
+    _hist_comm_rounds += comm_rounds
+    _hist_comm_bytes += comm_bytes
     _hist_pass_iteration = iteration
 
 
@@ -135,12 +150,15 @@ def reset_counters() -> None:
     the start of each timed arm so launches/iter and host_syncs/iter are
     attributable to THAT arm, not contaminated by the previous one."""
     global _launches, _host_syncs, _hist_passes, _hist_small_passes, \
-        _scan_slots, _hist_pass_iteration
+        _scan_slots, _hist_comm_rounds, _hist_comm_bytes, \
+        _hist_pass_iteration
     _launches = 0
     _host_syncs = 0
     _hist_passes = 0
     _hist_small_passes = 0
     _scan_slots = 0
+    _hist_comm_rounds = 0
+    _hist_comm_bytes = 0
     _hist_pass_iteration = 0
 
 

@@ -1,0 +1,83 @@
+#!/usr/bin/env python3
+"""sha256 of the lowered v5e text of the one-chip fused iteration of the
+benchmark's three training configurations, outside debug locations: the
+identity criterion of a PR that must leave the one-chip program alone
+(PERF.md section 6, PRs 32 and 35).  No chip: the topology is described
+(tests/test_tpu_aot_compile.py `_lower_iteration`).
+
+    JAX_PLATFORMS=cpu python scripts/lowered_iteration_digest.py [out_dir]
+
+Run it from the root of each checkout to compare (a `git archive` of the
+parent and the tree); equal lines mean equal programs.  Debug locations are
+in two places and both go: the text's own `loc(...)`, and the Python call
+stack serialized inside every Mosaic kernel body (`tpu_custom_call`'s
+`backend_config`), which is decoded and printed again without it.
+"""
+import base64
+import hashlib
+import json
+import os
+import re
+import sys
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+ROOT = os.getcwd()
+sys.path[:0] = [ROOT, os.path.join(ROOT, "tests")]
+
+
+def kernel_body(b64):
+    """A serialized Mosaic module -> its assembly without debug info."""
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib import tpu
+    from jax._src.lib.mlir import ir
+    ctx = jmlir.make_ir_context()
+    tpu.register_dialect(ctx)
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(b64))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+def canonical(text):
+    out = []
+    for line in text.splitlines():
+        if line.startswith("#loc"):
+            continue
+        line = re.sub(r"\s*loc\(.*?\)$", "", line)
+        m = re.search(r'backend_config = "(.*?)"(, |})', line)
+        if m and "tpu_custom_call" in line:
+            cfg = json.loads(re.sub(
+                r"\\([0-9A-Fa-f]{2})", lambda k: chr(int(k.group(1), 16)),
+                m.group(1)))
+            body = cfg["custom_call_config"].pop("body")
+            line = line.replace(m.group(1), json.dumps(cfg, sort_keys=True)
+                                + "\n" + kernel_body(body))
+        out.append(line)
+    return "\n".join(out)
+
+
+def main():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    from lightgbm_tpu import runtime
+    from lightgbm_tpu.robustness.checkpoint import atomic_write_text
+    import test_tpu_aot_compile as aot
+    out_dir = sys.argv[1] if len(sys.argv) > 1 else None
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    with runtime.lowering_for("tpu"):
+        for name, make in (("higgs_like", aot._higgs_like),
+                           ("mslr_like", aot._mslr_like),
+                           ("epsilon_like", aot._epsilon_like)):
+            _, lowered = aot._lower_iteration(chip, *make())
+            text = canonical(lowered.as_text())
+            if out_dir:
+                os.makedirs(out_dir, exist_ok=True)
+                atomic_write_text(os.path.join(out_dir, name + ".txt"), text)
+            print(name, hashlib.sha256(text.encode()).hexdigest(),
+                  flush=True)
+
+
+if __name__ == "__main__":
+    main()

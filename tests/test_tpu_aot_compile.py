@@ -12,6 +12,8 @@ right ANSWERS is chip_smoke.py's job, on the chip.
 A topology that cannot be built is an error, not a skip: without it this
 file proves nothing and must not pass quietly.
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -27,9 +29,13 @@ from lightgbm_tpu.utils.log import LightGBMError
 
 
 @pytest.fixture(scope="module")
-def tpu():
-    topo = topologies.get_topology_desc(platform="tpu",
+def topo():
+    return topologies.get_topology_desc(platform="tpu",
                                         topology_name="v5e:2x2")
+
+
+@pytest.fixture(scope="module")
+def tpu(topo):
     dev = topo.devices[0]
     assert dev.platform == "tpu" and "v5" in dev.device_kind
     with runtime.lowering_for("tpu"):
@@ -90,6 +96,15 @@ def _epsilon_like():
             X, (rs.rand(len(X)) < 0.5).astype(np.float64), {})
 
 
+def _criteo_like():
+    """benchmark configuration criteo_dp_like on one chip: 67 dense columns,
+    63 bins, 255 leaves, quantized — G = 67 on one M-tile."""
+    X, rs = _rows(8192, 67, 4)
+    return ({"objective": "binary", "num_leaves": 255, "max_bin": 63,
+             "use_quantized_grad": True, "num_grad_quant_bins": 64},
+            X, (rs.rand(len(X)) < 0.03).astype(np.float64), {})
+
+
 def _lower_iteration(sharding, params, X, y, ds_kw):
     """Build the Booster as on the chip and lower — for the topology's TPU —
     the program its first ``update()`` would launch (the fused iteration),
@@ -131,7 +146,8 @@ def iterations(tpu):
                for name, make in (("higgs", _higgs_like),
                                   ("mslr", _mslr_like),
                                   ("multiclass", _multiclass_k10),
-                                  ("epsilon", _epsilon_like))}
+                                  ("epsilon", _epsilon_like),
+                                  ("criteo", _criteo_like))}
     with ThreadPoolExecutor(len(lowered)) as pool:
         texts = {name: pool.submit(lambda lo=lo: lo.compile().as_text())
                  for name, (_, lo) in lowered.items()}
@@ -173,6 +189,94 @@ def test_epsilon_like_iteration_compiles(iterations):
         # a tree's last round routes and counts only
         f"(s32[1,{n}], f32[1,128])",
     } | SMALL_KINDS["epsilon"](n)
+
+
+def test_criteo_like_iteration_compiles(iterations):
+    """G = 67 on one chip: between `higgs_like` (28) and `mslr_like` (136),
+    one M-tile, the uniform one-hot axis; the kernel calls the four-chip cell
+    runs a shard at a time."""
+    eng, text = iterations["criteo"]
+    assert "tpu_custom_call" in text
+    gp = eng._grow_params
+    assert gp.int_hist and gp.hist_reduce_limbs == 1 and eng.mesh is None
+    assert (eng.dd.num_groups, eng.dd.max_bins) == (67, 63)
+    assert eng._pack_block == 2048 and eng._stream_tiling.num_tiles == 1
+    assert eng._root_pass == "factored"
+    n, m_rows = eng._packed.shape[1], eng._stream_tiling.tile_m_rows
+    assert {f"(s32[1,{n}], s32[{m_rows},128], f32[1,64])",
+            f"(s32[1,{n}], f32[1,128])"} <= _route_and_hist_kinds(text)
+
+
+def test_four_chip_pieces_compile_at_the_cells_real_shard(topo, tpu):
+    """`criteo_train_dp4`'s programs for the v5e 2x2 mesh at the cell's real
+    shard (105.25M rows trained, 26,312,704 a chip): the table packed a
+    shard at a time, the grower's mesh branch - the stream kernel inside
+    shard_map, the histogram crossing in two limbs, the int32 slot-count
+    psum - and the score update's gather kernel a shard.  The engine is a
+    small one on four of this process's CPU devices (its layouts and grow
+    parameters are what the chip's would be); shapes stand for the table."""
+    import re
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from lightgbm_tpu.ops.grow import grow_tree
+    from lightgbm_tpu.parallel.mesh import shard_map_rows
+    if len(jax.devices()) < 4:
+        pytest.skip("needs four devices for the small meshed engine")
+    params, X, y, _ = _criteo_like()
+    eng = lgb.Booster(dict(params, tree_learner="data", mesh_shape="data:4",
+                           verbosity=-1), lgb.Dataset(X, label=y)).engine
+    assert eng._mesh_stream and eng._use_leaf_gather_kernel
+    rows = 105_250_816
+    assert rows % (4 * eng._pack_block) == 0
+    eng.dd = eng.dd._replace(bins=jax.ShapeDtypeStruct((rows, 67),
+                                                       jnp.uint8))
+    assert eng._resolved_int_hist() and eng._resolved_reduce_limbs() == 2
+    gp = eng._grow_params._replace(hist_reduce_limbs=2)
+    mesh = Mesh(np.array(topo.devices), ("data",))
+
+    def sds(shape, dtype, spec):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    def replicated(tree):
+        return jax.tree.map(lambda a: sds(a.shape, a.dtype, P()), tree)
+
+    bins = sds((rows, 67), jnp.uint8, P("data", None))
+    per_row = sds((rows,), jnp.float32, P("data"))
+    packed = sds((eng._packed.shape[0], rows), eng._packed.dtype,
+                 P(None, "data"))
+    grown = jax.jit(functools.partial(
+        grow_tree, params=gp, mesh=mesh, row_axis="data",
+        with_passes=True)).lower(
+            bins, per_row, per_row, per_row, sds((67,), jnp.bool_, P()),
+            layout=replicated(eng.dd.layout),
+            routing=replicated(eng.dd.routing), packed=packed,
+            gh_scales=sds((2,), jnp.float32, P())).compile()
+    text = grown.as_text()
+    assert "tpu_custom_call" in text and "all-reduce" in text
+    shard = rows // 4
+    assert f"(s32[1,{shard}], " in text          # the kernel runs a shard
+    # two limbs of the 64-slot block and of the root's cross the mesh
+    assert re.search(r"s32\[128,67,63,2\][^\n]* all-reduce", text)
+    assert re.search(r"s32\[2,67,63,2\][^\n]* all-reduce", text)
+    tree, leaf_id, passes = grown.out_info
+    assert tree.leaf_count.dtype == jnp.int32
+    assert tree.internal_count.dtype == jnp.int32
+    assert passes.shape == (3,)
+    # what one chip holds while a tree grows, beside its arguments
+    mem = grown.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 8 * 2 ** 30
+
+    pack = jax.jit(shard_map_rows(
+        lambda b: stream_kernel.pack_bins_T(
+            b, eng._pack_block, max_bins=63).bins_T,
+        mesh, (P("data"),), P(None, "data"))).lower(bins).compile()
+    assert pack.out_info.shape == packed.shape
+    gather = jax.jit(shard_map_rows(
+        lambda lid, values: stream_kernel.leaf_gather(lid, values), mesh,
+        (P("data"), P()), P("data"))).lower(
+            sds((rows,), jnp.int32, P("data")),
+            sds((255,), jnp.float32, P())).compile()
+    assert "tpu_custom_call" in gather.as_text()
 
 
 def _route_and_hist_kinds(text):
