@@ -321,10 +321,15 @@ class GBDT:
         # (static per compiled program; published at every flag poll)
         self._root_pass = None
         if self._grow_params.hist_backend == "stream":
-            from ..pallas.stream_kernel import root_pass_kind
+            from ..pallas.stream_kernel import (onehot_build_kind,
+                                                root_pass_kind)
             self._root_pass = root_pass_kind(
                 packed.dtype, self._grow_params.int_hist,
                 self.num_tree_per_iteration)
+            # ... and how its 64-slot passes build their bin one-hot
+            self._poll_tiling["onehot_build"] = onehot_build_kind(
+                packed.dtype, self._grow_params.int_hist,
+                self._grow_params.bin_buckets)
         self._grow_partial = functools.partial(
             grow_tree, layout=dd.layout, routing=dd.routing,
             params=self._grow_params,

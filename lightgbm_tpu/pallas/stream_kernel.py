@@ -49,6 +49,8 @@ from ..binning import bucket_group_pad, bucket_run_rows
 from ..runtime import on_tpu, pallas_interpret
 from ..utils.log import LightGBMError
 
+_BYTES = 0x01010101   # one per byte of a word
+_TOPS = -0x7F7F7F80   # 0x80 in every byte, as an int32
 NUM_TAB = 24          # per-leaf table rows (padded to a sublane multiple)
 # a u8 block of more groups than this is not widened to int32 whole for the
 # route's group select (a (G_pad, T) int32 temporary): the select goes this
@@ -283,23 +285,42 @@ def _wsplit(w):
     return hi, lo
 
 
-def _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
-                     slot_ohs, *, T, G, B, S, two_pass, int_weights, f32_dots,
-                     u8_layout, bin_buckets, m_rows, K):
-    """hist_ref += the one-hot contraction of this block's G groups (the
-    first G rows of `bins_ref`, widened as `bins32` in the u8 layout)
-    against the rows' slot-folded grad/hess."""
-    i32, f32 = jnp.int32, jnp.float32
-    bf16 = f32 if f32_dots else jnp.bfloat16
-    w2 = w_ref[0:2 * K, :]                                   # (2K, T) f32
-    w_hi, w_lo = _wsplit(w2)
+def onehot_build_kind(bins_dtype, int_weights: bool, bin_buckets=None) -> str:
+    """How the 64-slot and tiled passes of such a program build their bin
+    one-hot: "words" — int8, four rows a 32-bit word, byte-parallel
+    (_onehot_words) — where the bins are in the u8 layout, the weights are
+    integer-valued and the M-axis is uniform; "compare" (an int32 key
+    against an iota, then a convert: _onehot_compare) otherwise: the
+    bucketed axis (its runs are not word-aligned in the table), float
+    weights, the packed-word layout."""
+    return ("words" if int_weights and bins_dtype == jnp.int8
+            and bin_buckets is None else "compare")
 
-    # build the bin-match one-hot shared by the int and float contraction
-    # paths. The one-hot is built B-MAJOR — row r = b * G + g — via
-    # key = bin * G + g tiled B times against a flat 2-D iota: measured
-    # ~40% of kernel time used to go into the (G, B, T) 3-D
-    # broadcast-compare layout this replaces.
+
+def onehot_rows(num_groups: int, B: int, words: bool) -> int:
+    """Rows of the uniform M-axis over `num_groups` groups of B bins: the
+    word form's cover whole words of four groups."""
+    return (-(-num_groups // 4) * 4 if words else num_groups) * B
+
+
+def onehot_word_major(axis_groups: int) -> bool:
+    """The order _onehot_words gives the rows of an M-axis over
+    `axis_groups` groups (onehot_rows' whole words of four: a table's, or a
+    tile's): word-major unless they fill whole 32-group word arrays, where
+    they keep the compare-built b-major order."""
+    return axis_groups % 32 != 0
+
+
+def _onehot_compare(bins_ref, bins32, *, T, G, B, u8_layout, bin_buckets,
+                    m_rows):
+    """The (m_rows, T) boolean bin one-hot of the block's first G groups,
+    B-MAJOR — row r = b * G + g — via key = bin * G + g tiled B times
+    against a flat 2-D iota (~40% of kernel time used to go into the
+    (G, B, T) 3-D broadcast-compare layout this replaced)."""
+    i32 = jnp.int32
     if u8_layout:
+        if bins32 is None:
+            bins32 = bins_ref[...].astype(i32)
         bins_G = bins32[:G, :]                               # (G, T) no unpack
     else:
         # unpack the 4-per-word packed group bins
@@ -308,9 +329,10 @@ def _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
             word_g = bins_ref[g // 4:g // 4 + 1, :]
             rows.append(jax.lax.shift_right_logical(word_g, (g % 4) * 8) & 0xFF)
         bins_G = jnp.concatenate(rows, axis=0)               # (G, T)
-    # (a per-bin compare-block construct — B int8 compares of (G, T)
-    # concatenated — measured 14% SLOWER than this key form: the 64-block
-    # concat relayout costs more than the (B*G, T) key/iota compare)
+    # (tried and left: a per-bin compare-block construct — B int8 compares
+    # of (G, T) concatenated — measured 14% SLOWER than this key form on the
+    # earlier rig: the 64-block concat relayout costs more than the
+    # (B*G, T) key/iota compare)
     if bin_buckets is None:
         g_iota = jax.lax.broadcasted_iota(i32, (G, T), 0)
         key = bins_G * G + g_iota                            # (G, T)
@@ -320,39 +342,115 @@ def _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
         if _ABLATE == "dblcon":  # additive probe: one extra (never-hit) construct
             key_t2 = jnp.concatenate([key + B * G] * B, axis=0)
             oh_match = oh_match | (key_t2 == r_iota)
-    else:
-        # BUCKETED M-axis: groups are laid out in runs of equal bin-bucket
-        # size (binning.device_group_order), and each run contributes
-        # Bk * Gk8 one-hot rows — M = sum of rounded per-group bin counts
-        # instead of G * Bmax, which is where low-cardinality features'
-        # histogram cost actually goes (the reference's scatter never paid
-        # per-bin; this is the matmul formulation's equivalent).  Row
-        # r = roff_k + b * Gk8 + g_local; the key trick is per run.  Gk
-        # pads to a sublane multiple (8) with never-matching keys so the
-        # Bk tiled concat pieces stay aligned.
-        parts = []
-        goff = roff = 0
-        for Bk, Gk in bin_buckets:
-            Gk8 = bucket_group_pad(Gk)
-            sub = bins_G[goff:goff + Gk, :]                  # (Gk, T)
-            # real keys first, then pad rows pinned to -1 (below every
-            # r_iota value). Padding the BIN value instead (1 << 24) only
-            # worked while (1 << 24) * Gk8 stayed inside int32 — at
-            # Gk8 >= 128 that product wraps and a pad row could alias a
-            # real histogram row.
-            gi_k = jax.lax.broadcasted_iota(i32, (Gk, T), 0)
-            key_k = sub * Gk8 + gi_k + roff
-            if Gk8 > Gk:
-                key_k = jnp.concatenate(
-                    [key_k, jnp.full((Gk8 - Gk, T), -1, i32)], axis=0)
-            parts.extend([key_k] * Bk)
-            goff += Gk
-            roff += Bk * Gk8
-        if m_rows > roff:
-            parts.append(jnp.full((m_rows - roff, T), -1, i32))
-        key_t = jnp.concatenate(parts, axis=0)               # (m_rows, T)
-        r_iota = jax.lax.broadcasted_iota(i32, (m_rows, T), 0)
-        oh_match = key_t == r_iota
+        return oh_match
+    # BUCKETED M-axis: groups are laid out in runs of equal bin-bucket
+    # size (binning.device_group_order), and each run contributes
+    # Bk * Gk8 one-hot rows — M = sum of rounded per-group bin counts
+    # instead of G * Bmax, which is where low-cardinality features'
+    # histogram cost actually goes (the reference's scatter never paid
+    # per-bin; this is the matmul formulation's equivalent).  Row
+    # r = roff_k + b * Gk8 + g_local; the key trick is per run.  Gk
+    # pads to a sublane multiple (8) with never-matching keys so the
+    # Bk tiled concat pieces stay aligned.
+    parts = []
+    goff = roff = 0
+    for Bk, Gk in bin_buckets:
+        Gk8 = bucket_group_pad(Gk)
+        sub = bins_G[goff:goff + Gk, :]                      # (Gk, T)
+        # real keys first, then pad rows pinned to -1 (below every
+        # r_iota value). Padding the BIN value instead (1 << 24) only
+        # worked while (1 << 24) * Gk8 stayed inside int32 — at
+        # Gk8 >= 128 that product wraps and a pad row could alias a
+        # real histogram row.
+        gi_k = jax.lax.broadcasted_iota(i32, (Gk, T), 0)
+        key_k = sub * Gk8 + gi_k + roff
+        if Gk8 > Gk:
+            key_k = jnp.concatenate(
+                [key_k, jnp.full((Gk8 - Gk, T), -1, i32)], axis=0)
+        parts.extend([key_k] * Bk)
+        goff += Gk
+        roff += Bk * Gk8
+    if m_rows > roff:
+        parts.append(jnp.full((m_rows - roff, T), -1, i32))
+    key_t = jnp.concatenate(parts, axis=0)                   # (m_rows, T)
+    r_iota = jax.lax.broadcasted_iota(i32, (m_rows, T), 0)
+    return key_t == r_iota
+
+
+def _onehot_words(bins_ref, *, T, G, B):
+    """The int8 bin one-hot of the block's first G groups, built four rows
+    at a time in the 32-bit words the u8 layout stores the bins in (word r
+    of a 32-group block holds groups 4r..4r+3 of one row, as
+    _factored_accumulate reads them): no int32 key, no (M, T) int32 compare
+    and no int32 -> int8 pack.  Bins are under 128 in this layout, so is a
+    byte's XOR with a bin, and 0x80 - x has its top bit set in exactly the
+    bytes of x that are 0 with no borrow between bytes: four word operations
+    for 32 rows.  Returned as pieces of about 256 rows, a dot each, every
+    piece a whole number of (8, T) word arrays (nothing is concatenated at a
+    7- or 17-row pitch), in the order onehot_word_major() says:
+
+    - WORD-MAJOR, r = (g // 4) * 4B + 4b + g % 4: one word row broadcast
+      over 8 sublanes against eight bins a word array, a (4B, T) piece a
+      word row of four groups (the last word's padding groups included:
+      4 * ceil(G / 4) * B rows);
+    - where the axis' groups fill whole 32-group word arrays (the M-tiles)
+      B-MAJOR, r = b * G + g, the compare-built order: every word array
+      against one bin in all its bytes, so the caller's unflatten of the
+      tiles' large block is the one it always was (the word-major transpose
+      of six (16, 8192, 128) blocks a tree measured 3.7 ms a tree dearer
+      in XLA, PERF.md section 6, PR 36)."""
+    i32 = jnp.int32
+
+    def match(x):
+        """0x01 in every byte of x (bytes under 128) that is 0, else 0x00;
+        under the dblcon probe one extra construct besides (never hit while
+        B <= 64: no bin has bit 6 set)."""
+        oh = jax.lax.shift_right_logical(_TOPS - x, 7) & _BYTES
+        if _ABLATE == "dblcon":
+            oh = oh | (jax.lax.shift_right_logical(
+                _TOPS - (x ^ (0x40 * _BYTES)), 7) & _BYTES)
+        return oh
+
+    W = -(-G // 4)
+    blocks = [pltpu.bitcast(bins_ref[a * 32:(a + 1) * 32, :], i32)  # (8, T)
+              for a in range(-(-W // 8))]   # static unroll: 32 groups each
+    if not onehot_word_major(4 * W):
+        per = max(1, 64 // W)                                # bins a piece
+        return [pltpu.bitcast(jnp.concatenate(
+            [match(words ^ (b * _BYTES))
+             for b in range(p * per, (p + 1) * per) for words in blocks],
+            axis=0), jnp.int8) for p in range(B // per)]     # (per * 4W, T)
+    sub = jax.lax.broadcasted_iota(i32, (8, T), 0)
+    bins_b = [(sub + 8 * j) * _BYTES for j in range(B // 8)]  # bins 8j..8j+7
+    pieces = []
+    for w in range(W):
+        row = jnp.broadcast_to(blocks[w // 8][w % 8:w % 8 + 1, :], (8, T))
+        pieces.append(pltpu.bitcast(
+            jnp.concatenate([match(row ^ b) for b in bins_b], axis=0),
+            jnp.int8))                                       # (4B, T)
+    return pieces
+
+
+def _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
+                     slot_ohs, *, T, G, B, S, two_pass, int_weights, f32_dots,
+                     u8_layout, bin_buckets, m_rows, K):
+    """hist_ref += the one-hot contraction of this block's G groups (the
+    first G rows of `bins_ref`; `bins32` their widening in the u8 layout
+    where a caller has it) against the rows' slot-folded grad/hess."""
+    i32, f32 = jnp.int32, jnp.float32
+    bf16 = f32 if f32_dots else jnp.bfloat16
+    w2 = w_ref[0:2 * K, :]                                   # (2K, T) f32
+    w_hi, w_lo = _wsplit(w2)
+
+    # the bin-match one-hot: the int path's own word form where
+    # onehot_build_kind() has it, the boolean shared by the int and float
+    # contraction paths otherwise
+    words = onehot_build_kind(bins_ref.dtype, int_weights,
+                              bin_buckets) == "words"
+    if not words:
+        oh_match = _onehot_compare(bins_ref, bins32, T=T, G=G, B=B,
+                                   u8_layout=u8_layout,
+                                   bin_buckets=bin_buckets, m_rows=m_rows)
 
     if int_weights:
         # Quantized-gradient histograms (reference: gradient_discretizer.cpp
@@ -371,32 +469,55 @@ def _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
         if _ABLATE == "nohist":      # int-path probe: no one-hot, no dot
             hist_ref[...] += jnp.sum(A_i, axis=1)[None, :]
             return
-        if f32_dots:
-            # CPU interpret: f32 products of |v| <= 127 ints are exact and
-            # per-block sums stay below 2^24, so rounding back is lossless
-            d = jax.lax.dot_general(
-                oh_match.astype(f32), A_i.astype(f32),
-                (((1,), (1,)), ((), ())), preferred_element_type=f32)
-            hist_ref[...] += d.astype(i32)
-        else:
-            if _ABLATE == "constoh":     # int-path probe: constant operand
-                oh_i = jnp.full((B * G, T), 1, jnp.int8)
-            else:
-                oh_i = oh_match.astype(jnp.int8)
-            if _ABLATE == "noA":         # int-path probe: constant A operand
-                A_8 = jnp.full((2 * S, T), 1, jnp.int8)
-            else:
-                A_8 = A_i.astype(jnp.int8)
-            hist_ref[...] += jax.lax.dot_general(
+
+        def dot_i(oh_i, A_8):
+            if f32_dots:
+                # CPU interpret: f32 products of |v| <= 127 ints are exact
+                # and per-block sums stay below 2^24, so rounding back is
+                # lossless
+                return jax.lax.dot_general(
+                    oh_i.astype(f32), A_i.astype(f32),
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=f32).astype(i32)
+            return jax.lax.dot_general(
                 oh_i, A_8, (((1,), (1,)), ((), ())),
                 preferred_element_type=i32)
-            if _ABLATE == "dbldot_i8":   # additive probe: one extra int8 dot
-                d2 = jax.lax.dot_general(
-                    oh_i, jnp.flip(A_8, 1), (((1,), (1,)), ((), ())),
-                    preferred_element_type=i32)
-                # |d2| < 2^30 so this adds exactly 0, but the compiler
-                # cannot prove it — the extra dot survives DCE
-                hist_ref[...] += jnp.abs(d2) // jnp.int32(2 ** 30)
+
+        def A_int8():
+            if _ABLATE == "noA":         # int-path probe: constant A operand
+                return jnp.full((2 * S, T), 1, jnp.int8)
+            return A_i.astype(jnp.int8)
+
+        if words:
+            # a piece of about 256 rows and its dot at a time: one piece's
+            # word arithmetic runs under another's dot (measured: a second
+            # construct a piece adds nothing to a pass, PERF.md section 6,
+            # PR 36), and no (M, T) operand is held
+            A_8 = A_int8()
+            if _ABLATE == "constoh":     # int-path probe: constant operands
+                # (one value a piece, or the compiler keeps one dot of all)
+                pieces = [jnp.full((4 * B, T), w + 1, jnp.int8)
+                          for w in range(m_rows // (4 * B))]
+            else:
+                pieces = _onehot_words(bins_ref, T=T, G=G, B=B)
+            off = 0
+            for oh_i in pieces:
+                hist_ref[off:off + oh_i.shape[0], :] += dot_i(oh_i, A_8)
+                off += oh_i.shape[0]
+            return
+        if _ABLATE == "constoh":         # int-path probe: constant operand
+            oh_i = jnp.full((B * G, T), 1, jnp.int8)
+        else:
+            oh_i = oh_match if f32_dots else oh_match.astype(jnp.int8)
+        A_8 = A_int8()
+        hist_ref[...] += dot_i(oh_i, A_8)
+        if _ABLATE == "dbldot_i8":       # additive probe: one extra int8 dot
+            d2 = jax.lax.dot_general(
+                oh_i, jnp.flip(A_8, 1), (((1,), (1,)), ((), ())),
+                preferred_element_type=i32)
+            # |d2| < 2^30 so this adds exactly 0, but the compiler
+            # cannot prove it — the extra dot survives DCE
+            hist_ref[...] += jnp.abs(d2) // jnp.int32(2 ** 30)
         return
 
     # (histograms carry only grad/hess — per-bin counts are estimated from
@@ -486,8 +607,6 @@ def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
         # contraction — and the whole VMEM-resident histogram block — is
         # dropped)
         return
-    if u8_layout and bins32 is None:
-        bins32 = bins_ref[...].astype(jnp.int32)
     _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
                      slot_ohs, T=T, G=G, B=B, S=S, two_pass=two_pass,
                      int_weights=int_weights, f32_dots=f32_dots,
@@ -512,8 +631,7 @@ def _hist_tiles_kernel(bins_ref, slot_ref, w_ref, hist_ref, *, T, Gt, B, S,
     slots = [slot_ref[k:k + 1, :] for k in range(K)]
     s_iota = jax.lax.broadcasted_iota(i32, (S, T), 0)
     slot_ohs = [(s_iota == slot).astype(bf16) for slot in slots]
-    bins32 = bins_ref[...].astype(i32) if u8_layout else None    # (Gt, T)
-    _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
+    _accumulate_hist(hist_ref, bins_ref, None, w_ref, slots, s_iota,
                      slot_ohs, T=T, G=Gt, B=B, S=S, two_pass=two_pass,
                      int_weights=int_weights, f32_dots=f32_dots,
                      u8_layout=u8_layout, bin_buckets=None, m_rows=Gt * B,
@@ -545,7 +663,6 @@ def _hist_tiles_kernel(bins_ref, slot_ref, w_ref, hist_ref, *, T, Gt, B, S,
 # kernel's sublane arithmetic is written for it), and 16 x 8 low digits make
 # the RHS one 128-row MXU tile
 ROOT_GF = 16
-_BYTES = 0x01010101   # one per byte of a word
 
 
 def root_pass_kind(bins_dtype, int_weights: bool, num_class: int = 1) -> str:
@@ -850,12 +967,21 @@ def stream_vmem_estimate(m_rows: int, block_rows: int, int_hist: bool,
       G=28  T=2048 S=64    8.59 ->  9.4    G=136 T=512  S=64   12.80 -> 13.3
       G=28  T=4096 S=64   16.40 -> 17.6 x  G=136 T=512  S=128  17.36 -> 17.6 x
       G=136 T=256  S=128  12.65 -> 13.2    G=136 T=1024 S=64   21.84 -> 22.1 x
-      int8: G=28 T=4096    4.09 -> 10.6    int8: G=136 T=1024   9.19 -> 13.6
+      int8, compare-built (the bucketed axis, packed words):
+            G=28 T=4096    4.09 -> 10.6          G=136 T=1024   9.19 -> 13.6
+      int8, word-built (onehot_build_kind; PR 36: the largest allocation a
+      compile under a lowered limit names):
+            G=28 T=4096    1.31 -> 10.6          G=67  T=2048   0.95 -> 12.1
+            G=136 T=1024   0.45 -> 13.6   (the 128-group tile's 4.13 is the
+                                           pipeline's second histogram block)
 
     (x = over the limit, on both sides).  No false accept over G in
     {28, 136} x T in 256..4096 x S in {64, 128} x int8/bf16 and K in
-    {1, 3, 10}; conservative for int8, where Mosaic tiles the one-hot
-    instead of materialising it."""
+    {1, 3, 10}; conservative for int8, where Mosaic tiles the compare-built
+    one-hot instead of materialising it, and by an order of magnitude for
+    the word-built one, which is never held whole (a (4B, T) piece a dot).
+    The block sizes were chosen by measurement under this estimate and stay
+    as they were; what the word form's room is worth is not measured."""
     oh_bytes = 1 if int_hist else 2
     return (m_rows * block_rows * oh_bytes
             + m_rows * (hist_channels or 128) * 4
@@ -926,7 +1052,8 @@ def stream_tiling(bmax: int, num_groups: int = 28, int_hist: bool = False,
         m_rows = -(-sum(bucket_run_rows(bk, gk)
                         for bk, gk in bin_buckets) // 128) * 128
     else:
-        m_rows = num_groups * B
+        m_rows = onehot_rows(num_groups, B, onehot_build_kind(
+            _bins_dtype(bmax), int_hist) == "words")
     tiers = (4096, 2048, 1024, 512, 256) if int_hist \
         else (2048, 1024, 512, 256)
     whole = next((T for T in tiers
@@ -986,6 +1113,12 @@ class StreamLayout(NamedTuple):
     num_groups: int
 
 
+def _bins_dtype(max_bins: int):
+    """The layout pack_bins_T gives a table: the u8 layout (int8, one group
+    a row) where the bins fit int8, packed words (int32) otherwise."""
+    return jnp.int8 if max_bins <= 127 else jnp.int32
+
+
 def pack_bins_T(bins: jax.Array, block_rows: int = 1024,
                 max_bins: int = 256, tile_groups: int = 0) -> StreamLayout:
     """(N, G) uint8 -> transposed (GW_pad, N_pad) i32 packed layout, or the
@@ -1000,7 +1133,7 @@ def pack_bins_T(bins: jax.Array, block_rows: int = 1024,
     n, g = bins.shape
     n_pad = -(-n // block_rows) * block_rows
     per = tile_groups or 32            # i8 tiling: 32-sublane multiples
-    if max_bins <= 127:
+    if _bins_dtype(max_bins) == jnp.int8:
         g_pad = -(-g // per) * per
         w = xp.pad(bins, ((0, n_pad - n), (0, g_pad - g))).astype(xp.int8)
         return StreamLayout(bins_T=w.T, n_pad=n_pad, num_groups=g)
@@ -1009,6 +1142,22 @@ def pack_bins_T(bins: jax.Array, block_rows: int = 1024,
     w = w.reshape(n_pad, gw_pad, 4)
     packed = (w[..., 0] | (w[..., 1] << 8) | (w[..., 2] << 16) | (w[..., 3] << 24))
     return StreamLayout(bins_T=packed.T, n_pad=n_pad, num_groups=g)
+
+
+def _unflatten_hist(hist, G: int, B: int, S: int, K: int, words: bool):
+    """The kernels' (tiles, m_rows, 2*S*K) histogram blocks over G groups a
+    tile of B bins -> (K, S, tiles * G, B, 2), by the order the builder
+    gave a block's rows: word-major r = (g // 4) * 4B + 4b + g % 4
+    (_onehot_words where onehot_word_major(G) says so; G a whole number of
+    words, the groups that fill the last included) or b-major r = b * G + g
+    (_onehot_compare, and _onehot_words over whole word arrays)."""
+    tiles = hist.shape[0]
+    if words and onehot_word_major(G):
+        hist7 = hist.reshape(tiles, G // 4, B, 4, K, 2, S)
+        return hist7.transpose(4, 6, 0, 1, 3, 2, 5).reshape(
+            K, S, tiles * G, B, 2)
+    return hist.reshape(tiles, B, G, K, 2, S).transpose(
+        3, 5, 0, 2, 1, 4).reshape(K, S, tiles * G, B, 2)
 
 
 def _route_and_hist_tiled(bins_T, slot, w_T, num_slots, bmax, num_groups,
@@ -1048,10 +1197,12 @@ def _route_and_hist_tiled(bins_T, slot, w_T, num_slots, bmax, num_groups,
             vmem_limit_bytes=TILES_VMEM_LIMIT),
         interpret=pallas_interpret(),
     )(bins_T, slot, w_T)
-    # (tile, b, g in tile) rows -> (K, S, G, Bmax, 2); int histograms are
-    # unscaled by the caller
-    hist4 = hist.reshape(tiles, B, Gt, K, 2, S).transpose(3, 5, 0, 2, 1, 4)
-    hist4 = hist4.reshape(K, S, tiles * Gt, B, 2)[:, :, :G, :bmax, :]
+    # a tile's rows -> (K, S, G, Bmax, 2); int histograms are unscaled by
+    # the caller
+    hist4 = _unflatten_hist(
+        hist, Gt, B, S, K,
+        onehot_build_kind(bins_T.dtype, int_weights) == "words"
+    )[:, :, :G, :bmax, :]
     return hist4[0] if K == 1 else hist4
 
 
@@ -1183,6 +1334,8 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     if K > 1 and _ABLATE:
         raise ValueError("LGBTPU_KABLATE probes require num_class == 1")
     B = -(-bmax // 8) * 8
+    words = onehot_build_kind(bins_T.dtype, int_weights,
+                              bin_buckets) == "words"
     if bin_buckets is not None:
         if _ABLATE:
             raise ValueError("LGBTPU_KABLATE probes require the uniform "
@@ -1193,7 +1346,7 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
         m_tot = sum(bucket_run_rows(bk, gk) for bk, gk in bin_buckets)
         m_rows = -(-m_tot // 128) * 128
     else:
-        m_rows = G * B
+        m_rows = onehot_rows(G, B, words)
 
     hist_dtype = jnp.int32 if int_weights else jnp.float32
     outs = _route_hist_call(
@@ -1234,10 +1387,10 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
         if K == 1:
             hist4 = hist4[0]
         return new_leaf, hist4, _cnt_out(cnt)
-    # (B*G, 2*S*K) b-major rows -> (K, S, G, Bmax, 2); int histograms are
+    # (m_rows, 2*S*K) rows -> (K, S, G, Bmax, 2); int histograms are
     # unscaled by the caller
-    hist4 = hist.reshape(B, G, K, 2, S).transpose(2, 4, 1, 0, 3)[
-        :, :, :, :bmax, :]
+    hist4 = _unflatten_hist(hist[None], m_rows // B, B, S, K,
+                            words)[:, :, :G, :bmax, :]
     if K == 1:
         hist4 = hist4[0]
     return new_leaf, hist4, _cnt_out(cnt)

@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
 """sha256 of the lowered v5e text of the one-chip fused iteration of the
-benchmark's three training configurations, outside debug locations: the
+benchmark's three training configurations (and, since PR 36, of `mslr_like`
+over a table that takes the bucketed one-hot M-axis, as the cell's does),
+outside debug locations: the
 identity criterion of a PR that must leave the one-chip program alone
 (PERF.md section 6, PRs 32 and 35).  No chip: the topology is described
 (tests/test_tpu_aot_compile.py `_lower_iteration`).
@@ -56,6 +58,21 @@ def canonical(text):
     return "\n".join(out)
 
 
+def mslr_like_bucketed(aot):
+    """`mslr_like` with columns of the cell's kind: the AOT file's table is
+    all continuous, so its one-hot M-axis is uniform, where `mslr_train`'s
+    is bucketed (small integer counts beside the scores).  Ninety of the 136
+    columns become counts of 5, 12 and 25 values; the program then runs the
+    bucketed axis, which main() asserts."""
+    import numpy as np
+    params, X, y, kw = aot._mslr_like()
+    rs = np.random.RandomState(11)
+    X = X.copy()
+    for lo, hi, card in ((0, 40, 5), (40, 70, 12), (70, 90, 25)):
+        X[:, lo:hi] = rs.randint(0, card, (len(X), hi - lo))
+    return params, X, y, kw
+
+
 def main():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
@@ -69,8 +86,12 @@ def main():
     with runtime.lowering_for("tpu"):
         for name, make in (("higgs_like", aot._higgs_like),
                            ("mslr_like", aot._mslr_like),
-                           ("epsilon_like", aot._epsilon_like)):
-            _, lowered = aot._lower_iteration(chip, *make())
+                           ("epsilon_like", aot._epsilon_like),
+                           ("mslr_like_bucketed",
+                            lambda: mslr_like_bucketed(aot))):
+            eng, lowered = aot._lower_iteration(chip, *make())
+            assert (eng._grow_params.bin_buckets is not None) == (
+                name == "mslr_like_bucketed"), name
             text = canonical(lowered.as_text())
             if out_dir:
                 os.makedirs(out_dir, exist_ok=True)

@@ -616,3 +616,149 @@ def test_bucketed_m_axis_exact():
     np.testing.assert_array_equal(np.asarray(hist_u), np.asarray(hist_b))
     np.testing.assert_allclose(np.asarray(cnt_u), np.asarray(cnt_b),
                                atol=1e-6)
+
+
+# ---- the 64-slot pass's bin one-hot built in words (onehot_build_kind) ----
+
+def _word_case(G, bmax, K, tile_groups, n=1300, L=8, S=4, block=1024):
+    """Operands of a 64-slot-style pass over a random (n, G) table of `bmax`
+    bins for K classes, with rows in no slot (leaf 3 keeps none, leaf 1's
+    left child has none) and rows past the data (n is no multiple of the
+    block; they carry zero weights), and the np.add.at histograms."""
+    from lightgbm_tpu.ops.grow import RoutingLayout
+    rs = np.random.RandomState(1000 * G + 10 * bmax + K)
+    bins = rs.randint(0, bmax, size=(n, G)).astype(np.uint8)
+    bins[:, G - 1] = bmax - 1          # the last group, the last bin: the
+    bins[: n // 2, 0] = 0              # corners of the M-axis are hit
+    routing = RoutingLayout(
+        feat_group=jnp.arange(G, dtype=jnp.int32),
+        span_start=jnp.zeros(G, jnp.int32),
+        default_bin=jnp.zeros(G, jnp.int32), bundled=jnp.zeros(G, bool),
+        nan_bin=jnp.full(G, -1, jnp.int32),
+        num_bins=jnp.full(G, bmax, jnp.int32))
+    i32 = jnp.int32
+    n_pad = -(-n // block) * block
+    leaf = np.zeros((K, n_pad), np.int32)
+    w = np.zeros((2 * K + 6, n_pad), np.float32)
+    w[2 * K, :n] = 1.0
+    tabs, want = [], np.zeros((K, S, G, bmax, 2), np.int64)
+    counts = np.zeros((K, S), np.int64)
+    for k in range(K):
+        leaf[k, :n] = rs.randint(0, 4, n)
+        w[2 * k, :n] = rs.randint(-32, 33, n)
+        w[2 * k + 1, :n] = rs.randint(0, 33, n)
+        feat = rs.randint(0, G, L).astype(np.int32)
+        thr = rs.randint(0, bmax - 1, L).astype(np.int32)
+        chosen = np.array([1, 1, 0, 0] + [0] * (L - 4), np.int32)
+        # slot + 1 of the left child, the right child, an unsplit leaf
+        sl1 = np.array([1, 0, 0, 0] + [0] * (L - 4), np.int32)
+        sr1 = np.array([2, 3, 0, 0] + [0] * (L - 4), np.int32)
+        sk1 = np.array([0, 0, 4, 0] + [0] * (L - 4), np.int32)
+        newid = np.array([4, 5, 0, 0] + [0] * (L - 4), np.int32)
+        tabs.append(build_route_tables(
+            *(jnp.asarray(a, i32) for a in (chosen, feat, thr, np.zeros(L),
+                                            newid, sl1, sr1, sk1)),
+            routing, L))
+        lk = leaf[k, :n]
+        left = bins[np.arange(n), feat[lk]] <= thr[lk]
+        slot = np.where(chosen[lk] > 0, np.where(left, sl1[lk], sr1[lk]),
+                        sk1[lk]) - 1
+        keep = slot >= 0
+        assert (~keep).sum() > n // 8
+        counts[k] = np.bincount(slot[keep], minlength=S)
+        for g in range(G):
+            for c in range(2):
+                np.add.at(want[k, :, g, :, c], (slot[keep], bins[keep, g]),
+                          w[2 * k + c, :n][keep].astype(np.int64))
+    B = -(-bmax // 8) * 8
+    static = dict(num_slots=S, bmax=bmax, num_groups=G, num_leaves=L,
+                  block_rows=block, has_cat=False, int_weights=True,
+                  num_class=K, tile_groups=tile_groups)
+    operands = (jnp.asarray(leaf), jnp.asarray(w),
+                jnp.concatenate(tabs, axis=1),
+                jnp.zeros((B, K * L), jnp.bfloat16))
+    return bins, operands, static, (want if K > 1 else want[0]), counts
+
+
+WORD_CASES = [
+    pytest.param(5, 15, 1, 0, id="g5_b15"),
+    pytest.param(28, 63, 1, 0, id="g28_b63"),
+    pytest.param(67, 63, 1, 0, id="g67_b63_last_word_part_filled"),
+    pytest.param(136, 127, 1, 0, id="g136_b127_uniform"),
+    pytest.param(28, 63, 3, 0, id="g28_b63_k3"),
+    pytest.param(5, 127, 3, 0, id="g5_b127_k3"),
+    pytest.param(32, 63, 1, 0, id="g32_whole_word_array_b_major"),
+    pytest.param(30, 15, 1, 0, id="g30_axis_of_32_b_major"),
+    pytest.param(136, 63, 1, 128, id="g136_two_tiles_of_128"),
+    pytest.param(136, 15, 3, 128, id="g136_b15_k3_two_tiles_of_128"),
+]
+
+
+@pytest.mark.parametrize("G, bmax, K, tile_groups", WORD_CASES)
+def test_word_built_pass_exact(G, bmax, K, tile_groups):
+    """The pass whose bin one-hot is built in words (u8 bins, integer
+    weights, the uniform axis) against the compare-built one over the same
+    table in the packed-word layout and against np.add.at: int32, bit for
+    bit, leaf ids and counts too."""
+    bins, operands, static, want, counts = _word_case(G, bmax, K,
+                                                      tile_groups)
+    out = {}
+    for kind, max_bins in (("words", bmax), ("compare", 255)):
+        bins_T = pack_bins_T(jnp.asarray(bins), static["block_rows"],
+                             max_bins=max_bins,
+                             tile_groups=tile_groups).bins_T
+        assert sk.onehot_build_kind(bins_T.dtype, True) == kind
+        out[kind] = route_and_hist(bins_T, *operands, **static)
+    new_leaf, hist, cnt = out["words"]
+    assert hist.dtype == jnp.int32 and hist.shape == want.shape
+    np.testing.assert_array_equal(np.asarray(hist), want)
+    for a, b in zip(out["words"], out["compare"]):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(cnt).reshape(K, -1), counts)
+
+
+@pytest.mark.parametrize("dtype, int_weights, buckets, want", [
+    pytest.param(jnp.int8, True, None, "words", id="u8_int_uniform"),
+    pytest.param(jnp.int8, False, None, "compare", id="u8_float"),
+    pytest.param(jnp.int32, True, None, "compare", id="packed_words_int"),
+    pytest.param(jnp.int8, True, ((64, 3), (16, 2)), "compare",
+                 id="u8_int_bucketed"),
+    pytest.param(jnp.int32, False, None, "compare", id="packed_words_float"),
+])
+def test_onehot_build_kind_rule(dtype, int_weights, buckets, want):
+    """Which programs build the one-hot in words, and that the M-axis and the
+    tiling follow the same rule: whole words of four groups there."""
+    assert sk.onehot_build_kind(dtype, int_weights, buckets) == want
+    assert sk.onehot_rows(67, 64, want == "words") == (
+        4352 if want == "words" else 4288)
+    # the word form's row order: word-major unless the axis' groups fill
+    # whole 32-group word arrays (the M-tiles), which keep the b-major one
+    assert [sk.onehot_word_major(g) for g in (28, 68, 32, 128, 136)] == [
+        True, True, False, False, True]
+    if buckets is None:
+        bmax = 63 if dtype == jnp.int8 else 255
+        plan = sk.stream_tiling(bmax, 19, int_weights)
+        assert plan.num_tiles == 1 and plan.tile_m_rows == (
+            (20 if want == "words" else 19) * (bmax + 1))
+
+
+@pytest.mark.parametrize("params, kind", [
+    pytest.param({"use_quantized_grad": True}, "words", id="quantized_u8"),
+    pytest.param({}, "compare", id="float"),
+    pytest.param({"use_quantized_grad": True, "max_bin": 255}, "compare",
+                 id="quantized_packed_words"),
+])
+def test_flag_poll_record_carries_the_onehot_build(params, kind):
+    """The engine's static poll fields say how the program's 64-slot passes
+    build their one-hot, beside root_pass / hist_tiles."""
+    rs = np.random.RandomState(5)
+    X = rs.randn(600, 6)
+    ds = lgb.Dataset(X, label=(X[:, 0] > 0).astype(float),
+                     params={"max_bin": params.get("max_bin", 63),
+                             "verbosity": -1})
+    bst = lgb.Booster({"objective": "binary", "num_leaves": 7,
+                       "hist_backend": "stream", "verbosity": -1, **params},
+                      ds)
+    eng = bst.engine
+    assert eng._poll_tiling["onehot_build"] == kind
+    assert eng._poll_tiling["hist_m_rows"] == eng._stream_tiling.tile_m_rows

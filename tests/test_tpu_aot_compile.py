@@ -207,6 +207,61 @@ def test_criteo_like_iteration_compiles(iterations):
             f"(s32[1,{n}], f32[1,128])"} <= _route_and_hist_kinds(text)
 
 
+@pytest.mark.parametrize("name, kind, m_rows", [
+    pytest.param("higgs", "words", 28 * 64, id="higgs_g28_t4096"),
+    pytest.param("mslr", "words", 136 * 64, id="mslr_uniform_g136_t1024"),
+    pytest.param("epsilon", "words", 128 * 64, id="epsilon_128_group_tile"),
+    pytest.param("criteo", "words", 68 * 64, id="criteo_g67_t2048"),
+])
+def test_onehot_build_of_the_cells_programs(iterations, name, kind, m_rows):
+    """The 64-slot passes of the three uniform-axis cells (and of this
+    file's all-continuous `mslr_like` table, whose axis is uniform too)
+    build their bin one-hot in words and compile for the v5e under
+    SCOPED_VMEM_LIMIT (the one-tile kernels raise no limit) /
+    TILES_VMEM_LIMIT (the sweeps), at the cells' block sizes and with whole
+    words of four groups on the M-axis (4,352 rows for 67 groups)."""
+    eng, text = iterations[name]
+    assert eng._poll_tiling["onehot_build"] == kind
+    assert eng._grow_params.bin_buckets is None
+    plan = eng._stream_tiling
+    assert plan.tile_m_rows == m_rows
+    # the record's rows are the table's own groups' (the tiles' pads left out)
+    assert eng._poll_tiling["hist_m_rows"] == (
+        eng.dd.num_groups * 64 if plan.tile_groups else m_rows)
+    n = eng._packed.shape[1]
+    want = ("s32[16,8192,128]" if plan.tile_groups
+            else f"(s32[1,{n}], s32[{m_rows},128], f32[1,64])")
+    assert want in _route_and_hist_kinds(text)
+    est = stream_kernel.stream_vmem_estimate(m_rows, plan.block_rows, True)
+    assert est <= stream_kernel.SCOPED_VMEM_LIMIT
+
+
+def test_the_bucketed_program_is_the_parents(tpu):
+    """Rule 0 for the cell that must not move: the lowered v5e iteration of
+    `mslr_like` over a table that takes the bucketed one-hot M-axis, as
+    `mslr_train`'s does, keeps the compare-built one-hot and hashes, outside
+    debug locations, to what PR 36's parent lowers it to
+    (scripts/lowered_iteration_digest.py run from a `git archive` of
+    5355464; PERF.md section 6).  A PR that means to change the bucketed
+    program re-pins this line and says so."""
+    import hashlib
+    import importlib.util
+    import sys
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "lowered_iteration_digest", Path(__file__).resolve().parents[1]
+        / "scripts" / "lowered_iteration_digest.py")
+    digest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(digest)
+    eng, lowered = _lower_iteration(
+        tpu, *digest.mslr_like_bucketed(sys.modules[__name__]))
+    assert eng._grow_params.bin_buckets is not None
+    assert eng._poll_tiling["onehot_build"] == "compare"
+    text = digest.canonical(lowered.as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "a1d992a2e47ae07858daf84608bb405355c68ef9d1b19414e58203424e801f48")
+
+
 def test_four_chip_pieces_compile_at_the_cells_real_shard(topo, tpu):
     """`criteo_train_dp4`'s programs for the v5e 2x2 mesh at the cell's real
     shard (105.25M rows trained, 26,312,704 a chip): the table packed a

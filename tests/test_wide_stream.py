@@ -141,6 +141,28 @@ def test_tiled_slot_pass_exact(wide, tile_groups):
 
 
 @pytest.mark.parametrize("tile_groups", TILES)
+def test_tiled_word_built_pass_is_the_compare_built_one(wide, tile_groups):
+    """The sweeps build their bin one-hot in words over u8 bins and integer
+    weights (onehot_build_kind), a tile's rows b-major as the compare-built
+    one's (whole 32-group word arrays: onehot_word_major); the same table
+    in the packed-word layout takes the compare-built one: the same int32
+    sums bit for bit, the ragged last tile's included."""
+    from lightgbm_tpu.pallas.stream_kernel import onehot_build_kind
+    bins_T, leaf, w_T, tabs, bits = _operands(wide, tile_groups)
+    packed = pack_bins_T(jnp.asarray(wide["bins"]), 1024, max_bins=255,
+                         tile_groups=tile_groups).bins_T
+    assert onehot_build_kind(bins_T.dtype, True) == "words"
+    assert onehot_build_kind(packed.dtype, True) == "compare"
+    assert onehot_build_kind(bins_T.dtype, False) == "compare"
+    static = dict(has_cat=False, int_weights=True, tile_groups=tile_groups)
+    got = route_and_hist(bins_T, leaf, w_T, tabs, bits, S, 63, F, L, **static)
+    ref = route_and_hist(packed, leaf, w_T, tabs, bits, S, 63, F, L, **static)
+    for a, b in zip(got, ref):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert np.asarray(got[1]).any()
+
+
+@pytest.mark.parametrize("tile_groups", TILES)
 def test_tiled_route_only_pass_exact(wide, tile_groups):
     """The route-only pass has no M-axis: one sweep, the same leaf ids and
     counts as the 64-slot pass gives, an all-zero histogram."""
