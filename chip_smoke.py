@@ -196,6 +196,20 @@ def kernel_exactness(dd, params):
                          [(0, 3, 24, 4, True), (2, 17, 40, 5, False)])
 
 
+def round_tables(splits, routing, L):
+    """(leaf, feature, threshold bin, new leaf, smaller child is the left
+    one) in slot order -> the route tables of a round that makes those
+    splits, slot s + 1 for the smaller child of split s."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.pallas.stream_kernel import build_route_tables
+    cols = np.zeros((7, L), np.int32)       # chosen feat thr dir new sl1 sr1
+    for s, (at, feat, thr, new, left) in enumerate(splits):
+        cols[:, at] = (1, feat, thr, 0, new, (s + 1) * left,
+                       (s + 1) * (not left))
+    return build_route_tables(*(jnp.asarray(c) for c in cols),
+                              jnp.zeros(L, jnp.int32), routing, L)
+
+
 def small_pass_exactness(tag, bins, bins_T, routing, gi, hi, kw, G, Bmax, L,
                          splits):
     """The small-slot pass (a round that splits one or two leaves) on the
@@ -205,8 +219,7 @@ def small_pass_exactness(tag, bins, bins_T, routing, gi, hi, kw, G, Bmax, L,
     child is the left one) in slot order; rows sit in leaves 0..3, so some
     are in no slot."""
     import jax.numpy as jnp
-    from lightgbm_tpu.pallas.stream_kernel import (build_route_tables,
-                                                   route_and_hist,
+    from lightgbm_tpu.pallas.stream_kernel import (route_and_hist,
                                                    route_and_hist_live)
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
         __file__)), "benchmark"))
@@ -221,16 +234,12 @@ def small_pass_exactness(tag, bins, bins_T, routing, gi, hi, kw, G, Bmax, L,
            .at[2].set(1.0))
     bits = jnp.zeros((-(-Bmax // 8) * 8, L), jnp.bfloat16)
     for k in (1, 2):
-        cols = np.zeros((7, L), np.int32)   # chosen feat thr dir new sl1 sr1
         want_leaf, slot = lid.copy(), np.full(N, -1, np.int64)
         for s, (at, feat, thr, new, left) in enumerate(splits[:k]):
-            cols[:, at] = (1, feat, thr, 0, new, (s + 1) * left,
-                           (s + 1) * (not left))
             right = (lid == at) & (bins_np[:, group_of[feat]] > thr)
             want_leaf[right] = new
             slot[(lid == at) & (right != left)] = s
-        tabs = build_route_tables(*(jnp.asarray(c) for c in cols),
-                                  jnp.zeros(L, jnp.int32), routing, L)
+        tabs = round_tables(splits[:k], routing, L)
         full = route_and_hist(bins_T, leaf, w_T, tabs, bits, 64, Bmax, G, L,
                               **kw)
         live = route_and_hist_live(jnp.int32(k), bins_T, leaf, w_T, tabs,
@@ -252,6 +261,108 @@ def small_pass_exactness(tag, bins, bins_T, routing, gi, hi, kw, G, Bmax, L,
               and np.array_equal(np.asarray(live[2][:k]),
                                  plain[:, 0, :, 2].sum(1)),
               f"{int((slot >= 0).sum())} of {N} rows in a slot")
+
+
+def sampled_kernel_exactness(dd, params):
+    """What a sampled tree (GOSS / bagging with row compaction) adds to the
+    kernels' work, on the chip at G = 28 and the cell's block: (1) a 64-slot
+    histogram pass over the COMPACT view (ops/compact.py: the in-bag rows
+    partitioned to the front of a fixed capacity) against NumPy alone over
+    the in-bag rows (reference_hist: np.add.at, int64), tolerance 0; (2)
+    `route_replay`'s leaf ids over every row against the chain of per-round
+    route-only passes it fuses, and against NumPy's routing."""
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops.compact import (compact_transposed_view,
+                                          plan_sample_rows)
+    from lightgbm_tpu.pallas.stream_kernel import (NUM_TAB, pack_bins_T,
+                                                   route_and_hist,
+                                                   route_replay,
+                                                   stream_block_rows)
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "benchmark"))
+    import reference_hist
+    G, Bmax, L = dd.num_groups, dd.max_bins, params["num_leaves"]
+    T = stream_block_rows(Bmax, G, True)
+    N, capacity = 16 * T, 6 * T
+    bins = dd.bins[:N]
+    bins_np = np.asarray(bins)
+    group_of = np.asarray(dd.routing.feat_group)
+    rs = np.random.RandomState(4)
+    mask = rs.rand(N) < 0.30                     # goss 0.2 / 0.1 keeps 30%
+    gi = rs.randint(-32, 33, N) * mask
+    hi = rs.randint(0, 33, N) * mask
+    bins_T = pack_bins_T(bins, T, max_bins=Bmax).bins_T
+    w_T = (jnp.zeros((8, N), jnp.float32)
+           .at[0].set(jnp.asarray(gi, jnp.float32))
+           .at[1].set(jnp.asarray(hi, jnp.float32))
+           .at[2].set(jnp.asarray(mask, jnp.float32)))
+    bits = jnp.zeros((-(-Bmax // 8) * 8, L), jnp.bfloat16)
+    kw = dict(block_rows=T, has_cat=False, int_weights=True)
+
+    # (1) one round over the compact view: rows sit in leaves 0..3, three
+    # of which split; the histogram slots hold the smaller children
+    splits = [(0, 3, 24, 4, True), (2, 17, 40, 5, False), (3, 9, 12, 6, True)]
+    lid = rs.randint(0, 4, N).astype(np.int32)
+    plan = plan_sample_rows(w_T[2], capacity)
+    check("sampled: the partition keeps every in-bag row, in order",
+          int(plan.nc) == int(mask.sum()) <= capacity
+          and np.array_equal(np.asarray(plan.perm[:int(plan.nc)]),
+                             np.nonzero(mask)[0]),
+          f"{int(plan.nc)} in-bag of {N}, capacity {capacity}")
+    bins_T_c, w_T_c = compact_transposed_view(bins_T, w_T, 2, capacity, T)
+    lid_c = jnp.asarray(lid)[plan.perm].reshape(1, -1)
+    new_c, hist, scnt = route_and_hist(bins_T_c, lid_c, w_T_c,
+                                       round_tables(splits, dd.routing, L),
+                                       bits, 64, Bmax, G, L, **kw)
+    want_leaf = reference_hist.route(
+        bins_np[:, group_of], lid,
+        {at: (feat, thr, new) for at, feat, thr, new, _ in splits})
+    slot = np.full(N, -1, np.int64)
+    for s, (at, feat, thr, new, left) in enumerate(splits):
+        right = (lid == at) & (bins_np[:, group_of[feat]] > thr)
+        slot[(lid == at) & (right != left)] = s
+    slot[~mask] = -1                              # the in-bag rows alone
+    plain = reference_hist.histograms(bins_np, slot, gi.astype(np.int64),
+                                      hi.astype(np.int64), len(splits), Bmax)
+    k = len(splits)
+    check("sampled: 64-slot pass over the compact view == NumPy reference "
+          "over the in-bag rows exactly (histogram, slot counts, leaf ids)",
+          hist.dtype == jnp.int32
+          and np.array_equal(np.asarray(hist[:k], np.int64), plain[..., :2])
+          and not np.asarray(hist[k:]).any()
+          and np.array_equal(np.asarray(scnt[:k]), plain[:, 0, :, 2].sum(1))
+          and np.array_equal(np.asarray(new_c[0]),
+                             want_leaf[np.asarray(plan.perm)]),
+          f"{int((slot >= 0).sum())} of {int(mask.sum())} in-bag rows in a "
+          "slot")
+
+    # (2) three rounds of tables replayed over every row in one launch
+    rounds = [[(0, 3, 24, 1, True)],
+              [(0, 17, 40, 2, True), (1, 9, 12, 3, False)],
+              [(2, 5, 30, 4, True), (3, 21, 8, 5, True), (1, 0, 33, 6, False)]]
+    R = L + 10                                    # the grower's buffer
+    tabs_buf = jnp.zeros((R * NUM_TAB, L), jnp.float32)
+    chain = jnp.zeros((1, N), jnp.int32)
+    want = np.zeros(N, np.int64)
+    for r, sp in enumerate(rounds):
+        tb = round_tables(sp, dd.routing, L)
+        tabs_buf = tabs_buf.at[r * NUM_TAB:(r + 1) * NUM_TAB].set(tb)
+        chain, _, _ = route_and_hist(bins_T, chain, w_T, tb, bits, 64, Bmax,
+                                     G, L, with_hist=False, **kw)
+        want = reference_hist.route(
+            bins_np[:, group_of], want,
+            {at: (feat, thr, new) for at, feat, thr, new, _ in sp})
+    lowered = route_replay.lower(bins_T, tabs_buf, jnp.int32(len(rounds)), L,
+                                 block_rows=T, rounds_buf=R).as_text()
+    check("sampled: route_replay lowers to a Mosaic tpu_custom_call",
+          "tpu_custom_call" in lowered)
+    replayed = route_replay(bins_T, tabs_buf, jnp.int32(len(rounds)), L,
+                            block_rows=T, rounds_buf=R)
+    check("sampled: route_replay's leaf ids == the per-round route-only "
+          "chain's == NumPy's routing, every row",
+          np.array_equal(np.asarray(replayed), np.asarray(chain[0]))
+          and np.array_equal(np.asarray(replayed), want),
+          f"leaves reached {sorted(set(want.tolist()))}")
 
 
 def wide_kernel_exactness():
@@ -581,6 +692,9 @@ def run(device, devs):
         with phase("kernel: route_and_hist vs _hist_segsum at the cell's "
                    "block shape"):
             kernel_exactness(bst.engine.dd, params)
+        with phase("kernel: a sampled tree's compact pass and route replay "
+                   "(G = 28)"):
+            sampled_kernel_exactness(bst.engine.dd, params)
         with phase("kernel: the same over sixteen M-tiles (G = 2,000)"):
             wide_kernel_exactness()
 

@@ -351,6 +351,7 @@ class GBDT:
         # sticky capacity choice (see _row_compaction_capacity)
         self._last_sampled_rows: Optional[int] = None
         self._last_compact_rows = 0
+        self._last_sample_mode = "none"
         self._compact_cap = 0
         self._sample_count_cache: Optional[Tuple[int, np.ndarray]] = None
         self._grow_fn_k = None
@@ -623,7 +624,10 @@ class GBDT:
         if cap_min <= self._compact_cap < local:
             return self._compact_cap
         cap = cap_min + q if cap_min + q < local else cap_min
-        self._compact_cap = cap
+        with _tel_tracer.boundary("GBDT::SamplePlan", rows=local,
+                                  expected_fraction=nc_max / local,
+                                  capacity=cap):
+            self._compact_cap = cap
         return cap
 
     # ------------------------------------------------------------------
@@ -777,18 +781,43 @@ class GBDT:
         gp = self._grow_params
         if gp.hist_backend != "stream" or self._last_compact_rows <= 0:
             return 0
+        if self._route_replay_fused():
+            return 1
+        L = gp.num_leaves
+        S = min(gp.max_splits_per_round, max(L - 1, 1))
+        return -(-(L - 1) // max(S, 1)) + 1
+
+    def _route_replay_fused(self) -> bool:
+        """Whether a compacted tree's full-data routing is ONE replay launch
+        after growth (the grower's fusion gate, ops/grow.py) and not a
+        route-only pass a round."""
+        gp = self._grow_params
         L = gp.num_leaves
         S = min(gp.max_splits_per_round, max(L - 1, 1))
         batched_mc = (self.num_tree_per_iteration > 1
                       and self._use_batched_multiclass())
-        fused = (gp.route_fusion and S >= 64 and gp.max_depth <= 0
-                 and gp.plain_growth and not gp.has_categorical
-                 and L <= 256 and not batched_mc
-                 and self._parse_forced_splits() is None
-                 and self._cegb_lazy is None)
-        if fused:
-            return 1
-        return -(-(L - 1) // max(S, 1)) + 1
+        return bool(gp.route_fusion and S >= 64 and gp.max_depth <= 0
+                    and gp.plain_growth and not gp.has_categorical
+                    and L <= 256 and not batched_mc
+                    and self._parse_forced_splits() is None
+                    and self._cegb_lazy is None)
+
+    def _poll_sampling_fields(self, sampled, overflow) -> Dict[str, Any]:
+        """What a flag poll publishes of the sampler: host statics of the
+        newest iteration, the in-bag count and the overflow count (words the
+        poll fetched anyway; None where it fetched none)."""
+        compact = self._last_compact_rows
+        out = {"sample_mode": self._last_sample_mode,
+               "compact_rows": compact,
+               "route_only_passes": self._route_only_passes_per_tree()}
+        if sampled is not None:
+            out["sampled_rows"] = sampled
+        if overflow is not None:
+            out["compact_overflow"] = overflow
+        if compact > 0 and self._grow_params.hist_backend == "stream":
+            out["route_replay"] = ("fused" if self._route_replay_fused()
+                                   else "per_round")
+        return out
 
     # ------------------------------------------------------------------
     def _mesh_shards_rows_only(self) -> bool:
@@ -1839,8 +1868,25 @@ class GBDT:
         if cap * 4 >= local * 3 or cap >= local:
             return 0   # <25% savings: the partition + route pass would eat it
         if not (self._compact_cap and cap <= self._compact_cap < local):
-            self._compact_cap = cap
+            with _tel_tracer.boundary("GBDT::SamplePlan", rows=local,
+                                      expected_fraction=frac, capacity=cap):
+                self._compact_cap = cap
         return self._compact_cap
+
+    def _sample_mode(self) -> str:
+        """This iteration's sampler as the program variants name it:
+        "bagging" takes the epoch mask as an argument, "goss" derives its
+        mask in-trace from the gradients, "none" samples nothing (no
+        strategy, or GOSS's warm-up)."""
+        strategy = self.sample_strategy
+        mode = ("none" if not strategy.is_active()
+                else strategy.fused_mode(self.iter_))
+        if mode not in ("none", "mask_arg", "traced"):
+            raise LightGBMError(
+                f"unknown fused sample mode {mode!r} from "
+                f"{type(strategy).__name__}")
+        return {"none": "none", "mask_arg": "bagging",
+                "traced": "goss"}[mode]
 
     def _iter_fused(self):
         """Gradients + sampling + tree growth + train-score update as ONE
@@ -1851,16 +1897,7 @@ class GBDT:
         state and the stacked TreeArrays."""
         k = self.num_tree_per_iteration
         strategy = self.sample_strategy
-        mode = ("none" if not strategy.is_active()
-                else strategy.fused_mode(self.iter_))
-        if mode not in ("none", "mask_arg", "traced"):
-            raise LightGBMError(
-                f"unknown fused sample mode {mode!r} from "
-                f"{type(strategy).__name__}")
-        # static program variants: "bagging" takes the epoch mask as an
-        # argument, "goss" derives its mask in-trace from the gradients
-        sample_mode = {"mask_arg": "bagging", "traced": "goss"}[mode] \
-            if mode != "none" else "none"
+        sample_mode = self._sample_mode()
         mask_arg = self._pad_mask
         if sample_mode == "bagging":
             mask_arg = self._shard_row_array(
@@ -2009,6 +2046,7 @@ class GBDT:
             setattr(self.objective, a, v)
         self._train_state = new_state
         self._last_compact_rows = compact
+        self._last_sample_mode = sample_mode
         self._fused_last = True
         return new_state, arrays
 
@@ -2058,7 +2096,8 @@ class GBDT:
                          scan_slots=scan_slot_count(),
                          **({"root_pass": self._root_pass}
                             if self._root_pass else {}),
-                         **self._poll_tiling, **comm)
+                         **self._poll_tiling, **comm,
+                         **self._poll_sampling_fields(sampled, overflow))
         note_host_sync()
         self._nan_guard.resolve(pending, got[1:1 + len(pending)])
         if st is not None:
@@ -2333,6 +2372,7 @@ class GBDT:
         self._last_sampled_rows = None
         compact = self._row_compaction_capacity(mask)
         self._last_compact_rows = compact
+        self._last_sample_mode = self._sample_mode()
         compact_kw = {"compact_rows": compact} if compact else {}
         if quant_done:
             grad_raw, hess_raw, gh_scales = graw, hraw, q_scales
@@ -2539,8 +2579,10 @@ class GBDT:
         if self.iter_ % self._finished_check_every == 0:
             from ..telemetry import note_host_sync
             note_host_sync()
-            with _tel_tracer.boundary("GBDT::FlagPoll",
-                                      iteration=self.iter_):
+            with _tel_tracer.boundary(
+                    "GBDT::FlagPoll", iteration=self.iter_,
+                    **self._poll_sampling_fields(self._last_sampled_rows,
+                                                 None)):
                 self._nan_guard.poll()
                 finished = bool(self._finished_dev)
             if finished:

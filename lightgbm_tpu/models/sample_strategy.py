@@ -162,8 +162,15 @@ class BaggingSampleStrategy(SampleStrategy):
 
 
 class GOSSStrategy(SampleStrategy):
-    """Gradient-based one-side sampling (reference: goss.hpp:19): keep top_rate by
-    |grad*hess|, sample other_rate of the rest with gradient amplification."""
+    """Gradient-based one-side sampling (reference: goss.hpp:19; Ke et al.
+    2017, Algorithm 2): keep the top_rate * N rows of largest |grad*hess|,
+    sample other_rate * N of the REST (both rates are shares of all N rows,
+    which is why Config holds top_rate + other_rate <= 1) and amplify the
+    sampled rest by (1 - top_rate) / other_rate, so that the rest's gradient
+    sum is unbiased.  The reference draws exactly other_rate * N rows; here a
+    row of the rest is kept with probability other_rate / (1 - top_rate), the
+    same number in expectation: an exact count would take a second
+    device-wide selection a tree."""
 
     def __init__(self, config: Config, num_data: int, query_boundaries=None,
                  label=None):
@@ -205,7 +212,7 @@ class GOSSStrategy(SampleStrategy):
         if self._is_warmup(iteration):
             return 1.0
         c = self.config
-        return min(1.0, c.top_rate + (1.0 - c.top_rate) * c.other_rate)
+        return min(1.0, c.top_rate + c.other_rate)
 
     def sample_traced(self, key, grad, hess):
         """Pure jit-safe GOSS draw — shared by the eager path and the
@@ -215,16 +222,19 @@ class GOSSStrategy(SampleStrategy):
         g2 = grad * hess if grad.ndim == 1 else jnp.sum(jnp.abs(grad * hess), axis=1)
         mag = jnp.abs(g2) if g2.ndim == 1 else g2
         k_top = max(1, int(c.top_rate * n))
-        # k-th largest |grad*hess| via ONE device sort (measured 230M rows/s,
-        # docs/PERF.md) — jax.lax.top_k over millions of rows is the slow
-        # path on TPU.  Under a row-sharded mesh the sort is a GLOBAL
-        # collective, so the threshold is a global statistic across row
+        # k-th largest |grad*hess| via ONE device sort (97.7 ms at 31.4M rows
+        # on the v5e: PERF.md section 6, PR 37); jax.lax.top_k over millions
+        # of rows has not been timed.  Under a row-sharded mesh the sort is a
+        # GLOBAL collective, so the threshold is a global statistic across row
         # shards and data-parallel GOSS trees are well-defined: every shard
         # keeps its rows against the same cut (docs/DISTRIBUTED.md).
         thresh = jnp.sort(mag)[n - k_top]
         is_top = mag >= thresh
         u = jax.random.uniform(key, (n,))
-        keep_rest = (~is_top) & (u < c.other_rate)
+        # other_rate is a share of ALL rows: other_rate / (1 - top_rate) of
+        # the rest, which the amplification below makes unbiased
+        keep_rest = (~is_top) & (
+            u < min(1.0, c.other_rate / max(1.0 - c.top_rate, 1e-12)))
         amp = (1.0 - c.top_rate) / max(c.other_rate, 1e-12)
         mask = (is_top | keep_rest).astype(jnp.float32)
         scale = jnp.where(keep_rest, amp, 1.0) * mask

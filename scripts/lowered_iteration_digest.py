@@ -1,7 +1,9 @@
 #!/usr/bin/env python3
 """sha256 of the lowered v5e text of the one-chip fused iteration of the
 benchmark's three training configurations (and, since PR 36, of `mslr_like`
-over a table that takes the bucketed one-hot M-axis, as the cell's does),
+over a table that takes the bucketed one-hot M-axis, as the cell's does;
+since PR 37 a fifth line, `higgs_goss_like`: the SAMPLED iteration of
+`higgs_like` under goss 0.2 / 0.1, past the sampler's warm-up),
 outside debug locations: the
 identity criterion of a PR that must leave the one-chip program alone
 (PERF.md section 6, PRs 32 and 35).  No chip: the topology is described
@@ -73,11 +75,33 @@ def mslr_like_bucketed(aot):
     return params, X, y, kw
 
 
+def higgs_goss_like(aot):
+    """`higgs_like` under upstream's documented one-side sampling (the cell
+    `higgs_goss_train`): `data_sample_strategy=goss`, `top_rate` 0.2,
+    `other_rate` 0.1, `learning_rate` 0.1."""
+    params, X, y, kw = aot._higgs_like()
+    return (dict(params, learning_rate=0.1, data_sample_strategy="goss",
+                 top_rate=0.2, other_rate=0.1), X, y, kw)
+
+
+def lower_sampled(aot, chip, params, X, y, kw):
+    """aot._lower_iteration past the sampler's warm-up: the first
+    `update()` lowers the program tree 10 would launch (`sample_mode=goss`,
+    the analytic compaction capacity).  The warm-up's predicate is patched
+    here, in the script, so that the same lines come from a parent
+    checkout's AOT file; the iteration number itself enters the program
+    only through key VALUES, which are arguments."""
+    from unittest import mock
+    from lightgbm_tpu.models.sample_strategy import GOSSStrategy
+    with mock.patch.object(GOSSStrategy, "_is_warmup",
+                           lambda self, iteration: False):
+        return aot._lower_iteration(chip, params, X, y, kw)
+
+
 def main():
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
     from lightgbm_tpu import runtime
-    from lightgbm_tpu.robustness.checkpoint import atomic_write_text
     import test_tpu_aot_compile as aot
     out_dir = sys.argv[1] if len(sys.argv) > 1 else None
     topo = topologies.get_topology_desc(platform="tpu",
@@ -92,12 +116,19 @@ def main():
             eng, lowered = aot._lower_iteration(chip, *make())
             assert (eng._grow_params.bin_buckets is not None) == (
                 name == "mslr_like_bucketed"), name
-            text = canonical(lowered.as_text())
-            if out_dir:
-                os.makedirs(out_dir, exist_ok=True)
-                atomic_write_text(os.path.join(out_dir, name + ".txt"), text)
-            print(name, hashlib.sha256(text.encode()).hexdigest(),
-                  flush=True)
+            emit(name, lowered, out_dir)
+        eng, lowered = lower_sampled(aot, chip, *higgs_goss_like(aot))
+        assert eng._compact_cap > 0, "the sampled line lowered no compaction"
+        emit("higgs_goss_like", lowered, out_dir)
+
+
+def emit(name, lowered, out_dir):
+    from lightgbm_tpu.robustness.checkpoint import atomic_write_text
+    text = canonical(lowered.as_text())
+    if out_dir:
+        os.makedirs(out_dir, exist_ok=True)
+        atomic_write_text(os.path.join(out_dir, name + ".txt"), text)
+    print(name, hashlib.sha256(text.encode()).hexdigest(), flush=True)
 
 
 if __name__ == "__main__":

@@ -136,11 +136,25 @@ def _lower_iteration(sharding, params, X, y, ds_kw):
     return eng, got[0]
 
 
+def _digest_module():
+    """scripts/lowered_iteration_digest.py as a module: the tables and the
+    lowering of the lines it prints beyond this file's own."""
+    import importlib.util
+    from pathlib import Path
+    spec = importlib.util.spec_from_file_location(
+        "lowered_iteration_digest", Path(__file__).resolve().parents[1]
+        / "scripts" / "lowered_iteration_digest.py")
+    digest = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(digest)
+    return digest
+
+
 @pytest.fixture(scope="module")
 def iterations(tpu):
     """The three iteration programs ``python bench.py`` runs and the wide
     benchmark cell's, at their full widths and small N: lowered one after
     another (tracing holds the GIL), compiled side by side (XLA does not)."""
+    import sys
     from concurrent.futures import ThreadPoolExecutor
     lowered = {name: _lower_iteration(tpu, *make())
                for name, make in (("higgs", _higgs_like),
@@ -148,6 +162,10 @@ def iterations(tpu):
                                   ("multiclass", _multiclass_k10),
                                   ("epsilon", _epsilon_like),
                                   ("criteo", _criteo_like))}
+    # the cell higgs_goss_train's SAMPLED iteration, past the warm-up
+    digest, me = _digest_module(), sys.modules[__name__]
+    lowered["higgs_goss"] = digest.lower_sampled(
+        me, tpu, *digest.higgs_goss_like(me))
     with ThreadPoolExecutor(len(lowered)) as pool:
         texts = {name: pool.submit(lambda lo=lo: lo.compile().as_text())
                  for name, (_, lo) in lowered.items()}
@@ -236,6 +254,52 @@ def test_onehot_build_of_the_cells_programs(iterations, name, kind, m_rows):
     assert est <= stream_kernel.SCOPED_VMEM_LIMIT
 
 
+def test_higgs_goss_like_sampled_iteration_compiles(iterations):
+    """`_higgs_like` plus `data_sample_strategy=goss`, `top_rate` 0.2,
+    `other_rate` 0.1, past the sampler's warm-up: the program the cell
+    `higgs_goss_train` times.  The v5e compiler takes the histogram passes
+    over the compact view (the analytic capacity, whole kernel blocks), the
+    small-slot and factored-root calls at that length, and `route_replay`
+    over every row - its call under a name and a result type no
+    `route_and_hist` pattern of the benchmark's readers matches."""
+    import re
+    eng, text = iterations["higgs_goss"]
+    assert "tpu_custom_call" in text
+    assert eng._grow_params.int_hist and eng._pack_block == 4096
+    assert eng._route_replay_fused()
+    cap, n = eng._compact_cap, eng._packed.shape[1]
+    assert 0 < cap < n and cap % eng._pack_block == 0
+    kinds = _route_and_hist_kinds(text)
+    assert {f"(s32[1,{cap}], s32[1792,128], f32[1,64])",
+            f"(s32[1,{cap}], f32[1,128])", "s32[512,128]"} <= kinds
+    # no pass of the sampled tree streams the whole table
+    assert not any(f"[1,{n}]" in k for k in kinds)
+    replay = re.findall(r"^\s*%route_replay[.\d]* = (.*?) custom-call\(",
+                        text, re.M)
+    assert [re.sub(r"\{[^}]*\}", "", r) for r in replay] == [f"s32[1,{n}]"]
+    assert len(re.findall(r" sort\(", text)) >= 2
+
+
+def test_the_sampled_program_is_pinned(tpu):
+    """scripts/lowered_iteration_digest.py's fifth line, `higgs_goss_like`:
+    the lowered v5e sampled iteration hashes, outside debug locations, to
+    what PR 37 left.  Its parent's root (1fc5688) reads ee0b8942... here:
+    the one thing PR 37 changed in the program is the sampler's keep rate
+    over the rest, `other_rate / (1 - top_rate)` where the parent compared
+    the draw with `other_rate` (the source's rule: other_rate is a share of
+    ALL rows) - one constant of this program; the four dense lines are the
+    parent's.  A PR that means to change the sampled program re-pins this
+    line and says so."""
+    import hashlib
+    import sys
+    digest, me = _digest_module(), sys.modules[__name__]
+    eng, lowered = digest.lower_sampled(me, tpu, *digest.higgs_goss_like(me))
+    assert eng._compact_cap == 4096
+    text = digest.canonical(lowered.as_text())
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "786bb408091b2330beab0bf2bdeb041bf3bfb43e0897b9e336a4f0624c5e8c18")
+
+
 def test_the_bucketed_program_is_the_parents(tpu):
     """Rule 0 for the cell that must not move: the lowered v5e iteration of
     `mslr_like` over a table that takes the bucketed one-hot M-axis, as
@@ -245,14 +309,8 @@ def test_the_bucketed_program_is_the_parents(tpu):
     5355464; PERF.md section 6).  A PR that means to change the bucketed
     program re-pins this line and says so."""
     import hashlib
-    import importlib.util
     import sys
-    from pathlib import Path
-    spec = importlib.util.spec_from_file_location(
-        "lowered_iteration_digest", Path(__file__).resolve().parents[1]
-        / "scripts" / "lowered_iteration_digest.py")
-    digest = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(digest)
+    digest = _digest_module()
     eng, lowered = _lower_iteration(
         tpu, *digest.mslr_like_bucketed(sys.modules[__name__]))
     assert eng._grow_params.bin_buckets is not None
