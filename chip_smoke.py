@@ -267,13 +267,16 @@ def sampled_kernel_exactness(dd, params):
     """What a sampled tree (GOSS / bagging with row compaction) adds to the
     kernels' work, on the chip at G = 28 and the cell's block: (1) a 64-slot
     histogram pass over the COMPACT view (ops/compact.py: the in-bag rows
-    partitioned to the front of a fixed capacity) against NumPy alone over
-    the in-bag rows (reference_hist: np.add.at, int64), tolerance 0; (2)
+    streamed to the front of a fixed capacity by pallas/compact_kernel.py,
+    itself held to `jnp.take` by the stable permutation bit for bit) against
+    NumPy alone over the in-bag rows (reference_hist: np.add.at, int64),
+    tolerance 0; (2)
     `route_replay`'s leaf ids over every row against the chain of per-round
     route-only passes it fuses, and against NumPy's routing."""
     import jax.numpy as jnp
     from lightgbm_tpu.ops.compact import (compact_transposed_view,
                                           plan_sample_rows)
+    from lightgbm_tpu.pallas.compact_kernel import compact_rows
     from lightgbm_tpu.pallas.stream_kernel import (NUM_TAB, pack_bins_T,
                                                    route_and_hist,
                                                    route_replay,
@@ -310,6 +313,29 @@ def sampled_kernel_exactness(dd, params):
                              np.nonzero(mask)[0]),
           f"{int(plan.nc)} in-bag of {N}, capacity {capacity}")
     bins_T_c, w_T_c = compact_transposed_view(bins_T, w_T, 2, capacity, T)
+    nc, perm = int(plan.nc), np.asarray(plan.perm)
+    check("sampled: compact_rows lowers to a Mosaic tpu_custom_call",
+          "tpu_custom_call" in compact_rows.lower(
+              bins_T, w_T, mask_row=2, capacity=capacity,
+              block_rows=T).as_text())
+    # float weights no sum could vouch for, through the same kernel: the
+    # view is the in-bag columns' own bits, and zero behind them
+    def raw(a):
+        return np.ascontiguousarray(a).view(np.uint8)
+
+    scale = np.array([[1e-40], [1e30]] * 4)     # subnormal and huge rows
+    w_f = jnp.asarray((rs.standard_normal((8, N)) * scale * mask).astype(
+        np.float32)).at[2].set(w_T[2])
+    bins_T_f, w_f_c = compact_transposed_view(bins_T, w_f, 2, capacity, T)
+    check("sampled: the streamed compact view == jnp.take by the stable "
+          "permutation bit for bit (int8 bins, integer and float32 weight "
+          "rows), zero past the in-bag rows",
+          all(np.array_equal(raw(np.asarray(got)[:, :nc]),
+                             raw(np.asarray(src)[:, perm[:nc]]))
+              and not raw(np.asarray(got)[:, nc:]).any()
+              for got, src in ((bins_T_c, bins_T), (w_T_c, w_T),
+                               (bins_T_f, bins_T), (w_f_c, w_f))),
+          f"{nc} columns of {capacity}")
     lid_c = jnp.asarray(lid)[plan.perm].reshape(1, -1)
     new_c, hist, scnt = route_and_hist(bins_T_c, lid_c, w_T_c,
                                        round_tables(splits, dd.routing, L),
@@ -331,8 +357,7 @@ def sampled_kernel_exactness(dd, params):
           and np.array_equal(np.asarray(hist[:k], np.int64), plain[..., :2])
           and not np.asarray(hist[k:]).any()
           and np.array_equal(np.asarray(scnt[:k]), plain[:, 0, :, 2].sum(1))
-          and np.array_equal(np.asarray(new_c[0]),
-                             want_leaf[np.asarray(plan.perm)]),
+          and np.array_equal(np.asarray(new_c[0])[:nc], want_leaf[perm[:nc]]),
           f"{int((slot >= 0).sum())} of {int(mask.sum())} in-bag rows in a "
           "slot")
 

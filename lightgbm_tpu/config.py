@@ -626,7 +626,10 @@ class Config:
     # GOSS/bagging row compaction (docs/PERF.md "sample-strategy
     # speedups"): auto = when a sampling mask is sparse enough, one
     # stable partition per tree compacts the in-bag rows so histogram
-    # MACs scale with the SAMPLED row count; off = legacy dense masking
+    # MACs scale with the SAMPLED row count (on the stream backend one
+    # streaming kernel places every in-bag row by the count of those
+    # before it: no sort, no gather; the other backends sort once per
+    # tree); off = legacy dense masking
     # (masked rows still stream through the kernel); pad = partition but
     # keep the full row count (A/B reference — byte-identical trees to
     # auto, proving compaction drops only exact-zero work).

@@ -815,8 +815,13 @@ class GBDT:
         if overflow is not None:
             out["compact_overflow"] = overflow
         if compact > 0 and self._grow_params.hist_backend == "stream":
+            from ..pallas.compact_kernel import compact_kind
             out["route_replay"] = ("fused" if self._route_replay_fused()
                                    else "per_round")
+            # which route ops/compact.py takes to the compact view: static
+            # per compiled program, as the tiling it is decided on
+            out["compact_kind"] = compact_kind(
+                self._stream_tiling.tile_groups)
         return out
 
     # ------------------------------------------------------------------

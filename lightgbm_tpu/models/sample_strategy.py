@@ -6,8 +6,9 @@ gradients), which feeds the histogram count channel directly.  Making tree
 cost actually SCALE with the sampled row count is the grower's job: when the
 mask is sparse enough, the engine hands ops/grow a static row capacity and
 one stable partition per tree compacts the in-bag rows into the view every
-histogram pass streams (ops/compact.plan_sample_rows — the reference's
-bag_data_indices_ prefix, device-side).
+histogram pass streams (ops/compact — the reference's bag_data_indices_
+prefix, device-side: prefix counts through pallas/compact_kernel.py on the
+stream engine, a sorted permutation on the others).
 """
 from __future__ import annotations
 

@@ -5,10 +5,16 @@ histogram pass of that tree scans the SAMPLED row count.
 Reference analog: src/treelearner/data_partition.hpp (LightGBM keeps rows of one leaf
 contiguous via a parallel stable partition so per-leaf histograms scan a contiguous
 range), src/boosting/bagging.hpp (the in-bag prefix) and
-src/treelearner/cuda/cuda_data_partition.cu (prefix-sum compaction on device). The
-TPU re-design reaches the same contiguity with a device-wide key sort.
+src/treelearner/cuda/cuda_data_partition.cu (prefix-sum compaction on device).
 
-Everything here is an O(N log N) sort + gathers — no (N, S) intermediates.
+Two routes to the same contiguity.  The stream engine's operands
+(`compact_transposed_view`) go through pallas/compact_kernel.py: prefix
+counts of the in-bag flags are every kept row's destination, and one kernel
+streams the table once — no sort, no gather.  The contraction / segsum
+engines (`compact_row_views`), and a table the stream kernel tiles, take a
+device-wide stable key sort (`plan_sample_rows`) and XLA's gathers by its
+permutation, which those engines reuse for their per-round slot gathers.
+Neither makes an (N, S) intermediate.
 """
 from __future__ import annotations
 
@@ -23,21 +29,25 @@ class SamplePlan(NamedTuple):
 
     Reference analog: bagging_.cc / data_partition.hpp keep the in-bag rows
     in a contiguous ``bag_data_indices_`` prefix so every histogram pass
-    scans only ``bag_data_cnt_`` rows.  The TPU equivalent is ONE stable
-    key/index sort per tree (76.8 ms at 31.4M rows on the v5e; the two row
-    gathers that follow it took 559 ms there: PERF.md section 6, PR 37)
-    whose permutation gathers the sampled rows to the front of a fixed-capacity
-    view; the streaming kernel then runs ``capacity / T`` grid blocks
-    instead of ``N / T``, so the dominant one-hot MAC cost scales with the
-    SAMPLED row count.  Positions past ``nc`` hold out-of-bag rows whose
-    grad/hess/count weights are already exactly 0 (the mask multiplied
-    them), so no in-kernel masking is needed.
+    scans only ``bag_data_cnt_`` rows.  This plan is the SORTED route to
+    it: ONE stable key/index sort per tree whose permutation gathers the
+    sampled rows to the front of a fixed-capacity view — what the
+    contraction / segsum engines and a tiled stream table take.  (The
+    stream engine's one-tile tables stopped sorting in PR 38: at 31.4M rows
+    on the v5e the sort took 76.8 ms and the two row gathers behind it
+    559.8 ms a tree, PERF.md section 6; pallas/compact_kernel.py gives the
+    same columns from prefix counts.)  The histogram pass then runs over
+    ``capacity`` rows instead of N, so the dominant one-hot MAC cost scales
+    with the SAMPLED row count.  Positions past ``nc`` hold out-of-bag rows
+    whose grad/hess/count weights are already exactly 0 (the mask
+    multiplied them), so no masking is needed.
 
-    Bit-exactness contract: the stable partition keeps sampled rows in
-    original relative order, and truncating the all-zero-weight tail
-    changes every f32 histogram accumulation by exact-zero terms only —
-    the compacted pass is byte-identical to streaming the full sorted
-    layout (tests/test_sample_compact.py proves it model-string-equal).
+    Bit-exactness contract (both routes): the stable partition keeps
+    sampled rows in original relative order, and truncating the
+    all-zero-weight tail changes every f32 histogram accumulation by
+    exact-zero terms only — the compacted pass is byte-identical to
+    streaming the full layout (tests/test_sample_compact.py proves it
+    model-string-equal).
     """
     perm: jax.Array     # (capacity,) i32 — source row per compacted position
     nc: jax.Array       # () i32 — number of sampled rows (caller guarantees
@@ -78,7 +88,7 @@ def compact_row_views(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
 def compact_transposed_view(bins_T: jax.Array, w_T: jax.Array,
                             mask_row: int, capacity: int, block: int,
-                            mesh=None, row_axis=None):
+                            mesh=None, row_axis=None, tile_groups: int = 0):
     """Compacted (rows-last) streaming-kernel operands for one sampled tree.
 
     Shared by grow_tree and grow_tree_k (whose only difference is which
@@ -87,14 +97,26 @@ def compact_transposed_view(bins_T: jax.Array, w_T: jax.Array,
     (G, N) / ``w_T`` (C, N) to the front and truncates to ``capacity``
     columns; under ``mesh`` every device partitions its OWN row shard
     inside shard_map (no cross-device row movement — the caller sizes
-    ``capacity`` to cover the fullest shard).  Returns (bins_T_h, w_T_h).
+    ``capacity`` to cover the fullest shard).  Returns (bins_T_h, w_T_h):
+    the in-bag columns in the table's order, bit for bit.
+
+    A table of one M-tile streams through pallas/compact_kernel.py
+    (`compact_kind`: "stream"; columns past the in-bag count are zero); one
+    the stream kernel cuts into ``tile_groups``-group tiles keeps the sort
+    and XLA's gathers ("take"; those columns hold out-of-bag rows under
+    zero weights) — a chunk's dot over its thousands of byte rows was
+    neither built nor run, and no deployment measured compacts one.
     """
+    from ..pallas.compact_kernel import compact_kind, compact_rows
     if capacity % block:
         raise ValueError(
             f"compact_rows={capacity} must be a multiple of the "
             f"stream kernel block ({block})")
 
     def _local(bT, wT):
+        if compact_kind(tile_groups) == "stream":
+            return compact_rows(bT, wT, mask_row=mask_row, capacity=capacity,
+                                block_rows=block)
         plan = plan_sample_rows(wT[mask_row], capacity)
         return (jnp.take(bT, plan.perm, axis=1),
                 jnp.take(wT, plan.perm, axis=1))

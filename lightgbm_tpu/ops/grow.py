@@ -462,10 +462,11 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
     records all_gather over both axes with the exact tie-break.  Per-row
     arrays stay sharded over rows only (replicated over feature).
     compact_rows: static PER-SHARD row capacity for GOSS/bagging row
-    compaction (0 = off).  One stable partition per tree (ops/compact.
-    plan_sample_rows) gathers the in-bag rows to the front and every
-    histogram pass runs over `compact_rows` rows instead of N — the
-    dominant MAC cost scales with the sampled row count (reference analog:
+    compaction (0 = off).  One stable partition per tree (ops/compact:
+    a streaming kernel on the stream engine, a sort's permutation on the
+    others) moves the in-bag rows to the front and every histogram pass
+    runs over `compact_rows` rows instead of N — the dominant MAC cost
+    scales with the sampled row count (reference analog:
     bag_data_indices_ prefix scans).  A per-round full-data ROUTE-ONLY
     kernel pass keeps leaf_id current for all N rows (score update, renew
     paths).  The caller guarantees compact_rows covers the in-bag count,
@@ -671,16 +672,18 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                   .at[2, :N].set(cnt_w))
 
         # ---- GOSS/bagging row compaction: one stable partition per tree
-        # (never a per-round gather) builds the compact view every histogram
-        # pass of this tree streams; padded/out-of-bag columns carry exact
-        # zero weights, so truncating them changes no f32 sum (the
-        # sorted-full vs compacted bit-identity the A/B suite asserts)
+        # (never a per-round gather; one streaming kernel, the in-bag rows
+        # placed by their prefix counts — pallas/compact_kernel.py) builds
+        # the compact view every histogram pass of this tree streams; the
+        # columns past the in-bag rows carry exact zero weights, so
+        # truncating them changes no f32 sum (the full vs compacted
+        # bit-identity the A/B suite asserts)
         bins_T_h, w_T_h = bins_T, w_T
         if use_compact:
             from .compact import compact_transposed_view
             bins_T_h, w_T_h = compact_transposed_view(
                 bins_T, w_T, 2, compact_rows, T_rows,
-                mesh=mesh, row_axis=row_axis)
+                mesh=mesh, row_axis=row_axis, tile_groups=tile_groups)
         n_pad_h = bins_T_h.shape[1]
 
         # ---- GOSS+stream fusion (docs/PERF.md "histogram-formulation

@@ -259,9 +259,10 @@ def test_higgs_goss_like_sampled_iteration_compiles(iterations):
     `other_rate` 0.1, past the sampler's warm-up: the program the cell
     `higgs_goss_train` times.  The v5e compiler takes the histogram passes
     over the compact view (the analytic capacity, whole kernel blocks), the
-    small-slot and factored-root calls at that length, and `route_replay`
+    small-slot and factored-root calls at that length, `route_replay`
     over every row - its call under a name and a result type no
-    `route_and_hist` pattern of the benchmark's readers matches."""
+    `route_and_hist` pattern of the benchmark's readers matches - and the
+    streaming compaction (`compact_rows`) that builds the view."""
     import re
     eng, text = iterations["higgs_goss"]
     assert "tpu_custom_call" in text
@@ -277,17 +278,48 @@ def test_higgs_goss_like_sampled_iteration_compiles(iterations):
     replay = re.findall(r"^\s*%route_replay[.\d]* = (.*?) custom-call\(",
                         text, re.M)
     assert [re.sub(r"\{[^}]*\}", "", r) for r in replay] == [f"s32[1,{n}]"]
-    assert len(re.findall(r" sort\(", text)) >= 2
+    # the compact view is one Mosaic call under its own name (PR 38) ...
+    compact = re.findall(r"^\s*%compact_rows[.\d]* = (.*?) custom-call\(",
+                         text, re.M)
+    assert [re.sub(r"\{[^}]*\}", "", c) for c in compact] == [
+        f"(s8[32,{cap}], f32[8,{cap}])"]
+    assert eng._stream_tiling.tile_groups == 0        # compact_kind: stream
+    # ... and the one sort over the table's rows left is the sampler's
+    # threshold: the partition sorts nothing
+    assert len(re.findall(rf"\[{n}\]\S*(?:, \S+)*\) sort\(", text)) == 1
+
+
+@pytest.mark.parametrize("groups, dtype, block, blocks, cap_blocks", [
+    # the cell higgs_goss_train: 31.5M rows -> the analytic capacity
+    pytest.param(32, jnp.int8, 4096, 7690, 2880, id="higgs_goss_cell"),
+    pytest.param(160, jnp.int8, 1024, 2048, 2048, id="g136_t1024_pad"),
+    pytest.param(64, jnp.int32, 512, 4096, 1024, id="packed_words_t512"),
+    pytest.param(32, jnp.int8, 256, 4096, 1536, id="t256_mask_block_padded"),
+])
+def test_compact_rows_compiles_at_real_shapes(tpu, groups, dtype, block,
+                                              blocks, cap_blocks):
+    """pallas/compact_kernel.py through Mosaic for the v5e: the per-chunk
+    prefix in SMEM blocks, the byte views of the 32-bit rows, the aligned
+    dynamic windows of the accumulator, the aliased zero results."""
+    from lightgbm_tpu.pallas.compact_kernel import compact_rows
+    n, cap = blocks * block, cap_blocks * block
+    compiled = compact_rows.lower(
+        jax.ShapeDtypeStruct((groups, n), dtype, sharding=tpu),
+        jax.ShapeDtypeStruct((8, n), jnp.float32, sharding=tpu),
+        mask_row=2, capacity=cap, block_rows=block).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    bins_c, w_c = compiled.out_info
+    assert (bins_c.shape, bins_c.dtype) == ((groups, cap), dtype)
+    assert (w_c.shape, w_c.dtype) == ((8, cap), jnp.float32)
 
 
 def test_the_sampled_program_is_pinned(tpu):
     """scripts/lowered_iteration_digest.py's fifth line, `higgs_goss_like`:
     the lowered v5e sampled iteration hashes, outside debug locations, to
-    what PR 37 left.  Its parent's root (1fc5688) reads ee0b8942... here:
-    the one thing PR 37 changed in the program is the sampler's keep rate
-    over the rest, `other_rate / (1 - top_rate)` where the parent compared
-    the draw with `other_rate` (the source's rule: other_rate is a share of
-    ALL rows) - one constant of this program; the four dense lines are the
+    what PR 38 left.  PR 37's (a2ab3ec) reads 786bb408... here: what PR 38
+    changed in the program is the compact view's making - the partition's
+    `sort_key_val` and the two row gathers out, pallas/compact_kernel.py's
+    prefix counts and its one Mosaic call in; the four dense lines are the
     parent's.  A PR that means to change the sampled program re-pins this
     line and says so."""
     import hashlib
@@ -297,7 +329,7 @@ def test_the_sampled_program_is_pinned(tpu):
     assert eng._compact_cap == 4096
     text = digest.canonical(lowered.as_text())
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "786bb408091b2330beab0bf2bdeb041bf3bfb43e0897b9e336a4f0624c5e8c18")
+        "a5238574cd9e7477cbd099c7b8059cb5e946546dfc110e9fb322b93249b00823")
 
 
 def test_the_bucketed_program_is_the_parents(tpu):
