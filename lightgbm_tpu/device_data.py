@@ -200,7 +200,7 @@ def to_device(binned: BinnedData, pad_rows_to: int = 256,
     shard to a device from the host (``_ship_shards``), rows padded to
     ``pad_rows_to`` and groups to ``pad_groups_to``; without, whole onto
     the default device."""
-    from .telemetry import boundary
+    from .telemetry import boundary, device_hbm_bytes
     view = view or device_view(binned)
     bins = binned.bins
     n, g = bins.shape
@@ -230,4 +230,5 @@ def to_device(binned: BinnedData, pad_rows_to: int = 256,
             shards = arr.addressable_shards
             span.set(shards=len({s.device for s in shards}),
                      bytes_per_shard=int(shards[0].data.nbytes))
+        span.set(**device_hbm_bytes())
     return view._replace(bins=arr)

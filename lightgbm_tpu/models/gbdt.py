@@ -32,7 +32,7 @@ from ..robustness import chaos as _chaos
 from ..runtime import check_device_type, on_tpu, platform_name
 from ..robustness.guards import (NanGuard, check_finite_init,
                                  check_model_trees)
-from ..telemetry import (costmodel as _tel_cost,
+from ..telemetry import (costmodel as _tel_cost, device_hbm_bytes,
                          global_registry as _tel_registry,
                          global_tracer as _tel_tracer, memory_snapshot,
                          watched_jit)
@@ -1521,13 +1521,14 @@ class GBDT:
             return {}
         bound = {}
         with _tel_tracer.boundary("GBDT::ShardBind", rows=n,
-                                  arrays=len(held)):
+                                  arrays=len(held)) as bind:
             for a, v in held.items():
                 host = np.asarray(v)
                 setattr(obj, a, host)
                 pad = [(0, n - host.shape[0])] + [(0, 0)] * (host.ndim - 1)
                 bound[a] = self._shard_row_array(np.pad(host, pad))
             jax.block_until_ready(bound)
+            bind.set(**device_hbm_bytes())
         return bound
 
     def _bound_objective(self):
@@ -2075,6 +2076,10 @@ class GBDT:
         comm = self._poll_comm_fields()
         with _tel_tracer.boundary("GBDT::FlagPoll",
                                   iteration=self.iter_) as poll:
+            # read BEFORE the blocking fetch, while the device still works
+            # through its backlog: what training holds with its launches in
+            # flight, at no cost to the loop (the host would wait anyway)
+            poll.set(**device_hbm_bytes())
             got = jax.device_get(fetch)
             if st is not None:
                 # the device's count of histogram passes rides the fetch:

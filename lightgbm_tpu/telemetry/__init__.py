@@ -10,7 +10,8 @@ or :func:`configure` directly):
     emits, streamed to a JSONL sink (param ``telemetry_out``);
   * **recompile watchdog** (:mod:`.watchdog`) — always-on compile
     counting per jitted entry point with threshold warnings (param
-    ``telemetry_recompile_threshold``);
+    ``telemetry_recompile_threshold``), and one ``Runtime::Compile`` ring
+    record for every program the process compiles or fetches;
   * **multi-host straggler detection** lives in
     :mod:`lightgbm_tpu.parallel.straggler` (it needs the process mesh,
     which is the parallel layer's concern) and reports through the
@@ -29,8 +30,8 @@ from .context import (TRACE_HEADER, AccessLog, TailRing, TraceContext,
                       new_trace_id, request_complete, request_instant,
                       request_span)
 from .costmodel import cost_summary, machine_balance
-from .metrics import (MetricsRegistry, device_memory_gb, global_registry,
-                      host_rss_gb, memory_snapshot)
+from .metrics import (MetricsRegistry, device_hbm_bytes, device_memory_gb,
+                      global_registry, host_rss_gb, memory_snapshot)
 from .prometheus import registry_text, render_parts, render_prometheus
 from .quality import (QualityMonitor, QualityProfile, js_divergence,
                       psi, quality_sidecar_path)
@@ -49,7 +50,7 @@ __all__ = [
     "SpanTracer", "SpanRecord", "MetricsRegistry", "WatchEntry",
     "global_tracer", "global_registry",
     "configure", "enabled", "enabled_source", "enable", "disable", "reset",
-    "span", "boundary", "recent_spans", "instant", "counter_sample", "inc", "gauge", "observe",
+    "span", "boundary", "recent_spans", "instant", "inc", "gauge", "observe",
     "quantiles", "record", "export_trace", "flush", "summary",
     "watched_jit", "recompile_counts", "watchdog_summary",
     "set_recompile_threshold", "get_recompile_threshold", "reset_watchdog",
@@ -57,7 +58,7 @@ __all__ = [
     "hist_pass_count", "hist_pass_iteration", "hist_small_pass_count",
     "note_hist_passes", "scan_slot_count", "hist_comm_counts",
     "reset_counters", "costmodel", "cost_summary", "machine_balance",
-    "memory_snapshot", "device_memory_gb", "host_rss_gb",
+    "memory_snapshot", "device_hbm_bytes", "device_memory_gb", "host_rss_gb",
     "TraceContext", "TailRing", "AccessLog", "TRACE_HEADER",
     "new_trace_id", "request_span", "request_complete", "request_instant",
     "render_prometheus", "render_parts", "registry_text",
@@ -133,7 +134,6 @@ span = global_tracer.span
 boundary = global_tracer.boundary
 recent_spans = global_tracer.recent_spans
 instant = global_tracer.instant
-counter_sample = global_tracer.counter
 inc = global_registry.inc
 gauge = global_registry.gauge
 observe = global_registry.observe

@@ -210,21 +210,40 @@ def host_rss_gb() -> float:
         return 0.0
 
 
-def device_memory_gb() -> Dict[str, float]:
-    """Peak device HBM from the allocator (``peak_hbm_gb``).  XLA:CPU keeps
-    no allocator stats, so the field is simply absent there; a TPU that
-    reports none is an error, never a differently named estimate."""
+def device_hbm_bytes() -> Dict[str, int]:
+    """The allocator's ``bytes_in_use`` and ``peak_bytes_in_use`` as
+    ``{"hbm_in_use_bytes", "hbm_peak_bytes"}``, each the LARGEST over this
+    process's devices (under a row mesh the fullest chip is not always the
+    first), in bytes.  ``{}`` where the platform keeps no allocator
+    statistics (XLA:CPU).  The one HBM reading of the package: the
+    boundary records of a ship, a bind, a compile and a flag poll carry
+    these two fields, and every GB figure below is a view of them."""
     import jax
-    dev = jax.local_devices()[0]
-    stats = dev.memory_stats() or {}
-    peak = stats.get("peak_bytes_in_use")
+    out: Dict[str, int] = {}
+    for dev in jax.local_devices():
+        stats = dev.memory_stats() or {}
+        for field, key in (("hbm_in_use_bytes", "bytes_in_use"),
+                           ("hbm_peak_bytes", "peak_bytes_in_use")):
+            if key in stats:
+                out[field] = max(out.get(field, 0), int(stats[key]))
+    return out
+
+
+def device_memory_gb() -> Dict[str, float]:
+    """Peak device HBM (``peak_hbm_gb``) in GB of 1e9, the benchmark's
+    unit, over the fullest local device.  XLA:CPU keeps no allocator
+    stats, so the field is simply absent there; a TPU that reports none is
+    an error, never a differently named estimate."""
+    peak = device_hbm_bytes().get("hbm_peak_bytes")
     if peak is None:
+        import jax
+        dev = jax.local_devices()[0]
         if dev.platform == "tpu":
             raise RuntimeError(
                 f"{dev.device_kind} reported no peak_bytes_in_use "
-                f"(memory_stats keys: {sorted(stats)})")
+                f"(memory_stats keys: {sorted(dev.memory_stats() or {})})")
         return {}
-    return {"peak_hbm_gb": round(peak / 2 ** 30, 4)}
+    return {"peak_hbm_gb": round(peak / 1e9, 4)}
 
 
 def memory_snapshot() -> Dict[str, float]:

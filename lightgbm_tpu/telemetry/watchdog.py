@@ -16,18 +16,27 @@ keeps counting against the same entry, while a fresh model's first
 compile does not inherit another model's count. Module-level kernel jits
 that legitimately re-specialize per shape (pallas kernels, ranking
 buckets) pass ``warn_after=0`` to count without ever warning.
+
+Every program the process compiles, or fetches from the persistent cache,
+also leaves one ``Runtime::Compile`` record in the boundary-span ring
+(:func:`_on_compile_event`): which watched entry asked for it (none: the
+eager op-by-op set-up), which trace of that entry, whether the cache had
+it, and how long tracing, lowering and the backend took.
 """
 from __future__ import annotations
 
 import functools
 import threading
+import time
 import weakref
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
 from ..utils.log import log_warning
 from . import costmodel as _costmodel
+from .metrics import device_hbm_bytes
+from .tracer import global_tracer
 
 _lock = threading.Lock()
 # weak enumeration for summaries: an entry stays alive exactly as long as
@@ -162,6 +171,128 @@ def reset_counters() -> None:
     _hist_pass_iteration = 0
 
 
+# ---- Runtime::Compile: one ring record a compiled or fetched program ----
+# What jax 0.9.0 reports round a compile, on the compiling thread, through
+# `dispatch.log_elapsed_time` (jax/_src/pjit.py, interpreters/pxla.py,
+# compiler.py): a scalar (the start stamp) when tracing, lowering to MLIR
+# or the backend compile BEGINS and a duration when it ends.  Tracing nests
+# (a jit called inside a traced body is traced inside it and ends first),
+# helpers are traced DURING lowering (Pallas bodies, lowering rules), and
+# the backend compile encloses the persistent cache's read: on a cache hit
+# the retrieval's duration fires inside it, so the backend's duration is
+# the last event on a hit and on a miss alike and closes the record.  A
+# program found in jit's in-memory caches fires none.
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+COMPILE_SPAN = "Runtime::Compile"
+
+
+class _Compiling(threading.local):
+    """One thread's compile in progress, between jax's events."""
+    open_traces = 0      # jit tracings begun and not ended (they nest)
+    lowering = False     # inside a lowering to MLIR
+    tracing = 0          # watched entries whose Python body is being traced
+    entry: Optional["WatchEntry"] = None   # who asked for the next program
+    armed = False        # `entry` was set since the last outermost trace
+    trace_ns = 0
+    lower_ns = 0
+    retrieval_ns: Optional[int] = None     # set by a persistent-cache hit
+
+    def clear(self) -> None:
+        self.entry, self.armed = None, False
+        self.trace_ns, self.lower_ns, self.retrieval_ns = 0, 0, None
+
+    def traced(self, entry: Optional["WatchEntry"]) -> None:
+        """A watched entry's body has been traced (``None``: it raised).
+        The program is the outermost jit's: an entry traced inside another
+        entry, or inside an unwatched jit (more than its own tracing is
+        open), is inlined there, and a program compiled while a body is
+        still being traced is an eager one of the trace's own."""
+        self.tracing -= 1
+        if not self.tracing:
+            owns = entry is not None and self.open_traces == 1
+            self.entry, self.armed = (entry, True) if owns else (None, False)
+
+    def expect(self, entry: "WatchEntry", timing: Tuple[int, int]) -> None:
+        """The next backend compile on this thread is ``entry``'s (the AOT
+        path: its ``.lower()`` may be long past)."""
+        self.clear()
+        self.entry = entry
+        self.trace_ns, self.lower_ns = timing
+
+    def take_timing(self, traced: bool) -> Tuple[int, int]:
+        """(trace_ns, lower_ns) of the ``.lower()`` that just returned."""
+        timing = (self.trace_ns if traced else 0, self.lower_ns)
+        self.clear()
+        return timing
+
+
+_compiling = _Compiling()
+
+
+def _on_compile_start(event: str, value: float, **_: Any) -> None:
+    """Scalar listener: the start stamps, which alone tell what nests."""
+    if event == _TRACE_EVENT:
+        _compiling.open_traces += 1
+    elif event == _LOWER_EVENT:
+        _compiling.lowering = True
+
+
+def _on_compile_event(event: str, duration: float, **_: Any) -> None:
+    """Duration listener: the ends; the backend's closes the record."""
+    st = _compiling
+    if event == _TRACE_EVENT:
+        st.open_traces = max(st.open_traces - 1, 0)
+        if st.open_traces or st.lowering:
+            return           # a jit inside the one traced, or a helper
+        # an entry survives its own outermost trace and no other: a second
+        # one is another program's (the first was traced and not compiled)
+        if not st.armed:
+            st.entry = None
+        st.armed = False
+        st.trace_ns, st.lower_ns = int(duration * 1e9), 0
+        st.retrieval_ns = None
+    elif event == _LOWER_EVENT:
+        st.lowering = False
+        st.lower_ns = int(duration * 1e9)
+    elif event == _CACHE_HIT_EVENT:
+        st.retrieval_ns = int(duration * 1e9)
+    elif event == _BACKEND_EVENT:
+        entry = None if st.tracing else st.entry
+        hit = st.retrieval_ns is not None
+        took = st.retrieval_ns if hit else int(duration * 1e9)
+        args: Dict[str, Any] = {
+            "entry": entry.name if entry else None,
+            "trace": entry.count if entry else None,
+            "cache": "hit" if hit else "miss",
+            "trace_ns": st.trace_ns, "lower_ns": st.lower_ns,
+            **device_hbm_bytes()}
+        if entry is not None and entry.count > 1 and entry.signatures:
+            args["signature"] = entry.signatures[-1]
+        st.clear()
+        stack = global_tracer._stack()
+        # retroactive, so no TraceAnnotation: the ring record alone
+        global_tracer._record(COMPILE_SPAN, stack[-1] if stack else None,
+                              time.time_ns() - took, took, args)
+
+
+def _listen_for_compiles() -> None:
+    """Register the two listeners once: when the package is imported, so
+    that the eager programs of a Dataset's set-up are on record before the
+    first entry exists, and again at a ``watched_jit`` if something cleared
+    jax's listeners since."""
+    from jax._src import monitoring
+    if _on_compile_event not in monitoring.get_event_duration_listeners():
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
+        jax.monitoring.register_scalar_listener(_on_compile_start)
+
+
+_listen_for_compiles()
+
+
 class WatchEntry:
     """Compile counter for one watched entry point."""
 
@@ -197,7 +328,6 @@ class WatchEntry:
             if prev is not None and prev != sig:
                 msg += f"; previous signature {prev}"
             log_warning(msg)
-            from .tracer import global_tracer
             global_tracer.instant(f"recompile:{self.name}", count=count,
                                   signature=sig)
         from .metrics import global_registry
@@ -255,12 +385,20 @@ def watched_jit(fun=None, *, name: Optional[str] = None, owner: Any = None,
                 watches[wname] = entry
         with _lock:
             _entries.add(entry)
+        _listen_for_compiles()
 
         @functools.wraps(f)
         def traced(*args, **kwargs):
             # runs ONLY while jax traces (i.e. on a compilation-cache miss)
             entry.note_trace(args, kwargs)
-            return f(*args, **kwargs)
+            _compiling.tracing += 1
+            try:
+                out = f(*args, **kwargs)
+            except BaseException:
+                _compiling.traced(None)
+                raise
+            _compiling.traced(entry)
+            return out
 
         jitted = jax.jit(traced, **jit_kwargs)
 
@@ -289,8 +427,10 @@ def watched_jit(fun=None, *, name: Optional[str] = None, owner: Any = None,
             lowered = jitted.lower(*args, **kwargs)
             # a jaxpr-cache miss runs `traced` during lower and already
             # counted; the wrapper must then NOT count the .compile() too
+            counted = entry.count > c0
             return _WatchedLowered(lowered, entry, args, kwargs,
-                                   counted=entry.count > c0)
+                                   counted=counted,
+                                   timing=_compiling.take_timing(counted))
 
         dispatched.lower = lower
         for attr in ("trace", "eval_shape", "clear_cache"):
@@ -309,24 +449,34 @@ class _WatchedLowered:
     the cost model — the full analysis for free, since the caller paid
     for the compile anyway."""
 
-    __slots__ = ("_lowered", "_entry", "_args", "_kwargs", "_counted")
+    __slots__ = ("_lowered", "_entry", "_args", "_kwargs", "_counted",
+                 "_timing")
 
     def __init__(self, lowered, entry: WatchEntry, args: tuple,
-                 kwargs: dict, counted: bool = False) -> None:
+                 kwargs: dict, counted: bool = False,
+                 timing: Tuple[int, int] = (0, 0)) -> None:
         self._lowered = lowered
         self._entry = entry
         self._args = args
         self._kwargs = kwargs
         self._counted = counted
+        self._timing = timing      # (trace_ns, lower_ns) of the .lower()
 
     def compile(self, *args, **kwargs):
-        compiled = self._lowered.compile(*args, **kwargs)
         if not self._counted:
             # lower() hit the jaxpr cache, so nothing counted this entry
             # compile yet — an AOT compile of an already-traced signature
-            # is still a real XLA compile
+            # is still a real XLA compile (counted before it, so that its
+            # Runtime::Compile record holds this trace's number)
             self._entry.note_trace(self._args, self._kwargs)
         self._counted = False   # a second .compile() of this Lowered counts
+        _compiling.expect(self._entry, self._timing)
+        try:
+            compiled = self._lowered.compile(*args, **kwargs)
+        finally:
+            # jax found the executable in memory, or the compile raised:
+            # no event came, and the next program is not this entry's
+            _compiling.clear()
         _costmodel.note_compiled(self._entry, compiled)
         return _WatchedCompiled(compiled, self._entry)
 

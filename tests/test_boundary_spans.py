@@ -1,10 +1,14 @@
 """Layer-boundary spans (telemetry/tracer.py SpanTracer.boundary): always
 live, written to the profiler's trace and to a bounded in-process ring at
-once; the device-side histogram-pass counter; and what went with the second
-accumulator (utils/timer.py)."""
+once; the device-side histogram-pass counter; what went with the second
+accumulator (utils/timer.py); and the records the host writes where it
+already stands still: `Runtime::Compile` for every program compiled or
+fetched, and the one HBM reading on the ship, the bind, the compile and the
+flag poll."""
 import glob
 import os
 import threading
+import time
 from pathlib import Path
 
 import jax
@@ -45,6 +49,12 @@ def _booster(params, X, y):
 
 def _names(records):
     return [r.name for r in records]
+
+
+def _layer_spans():
+    """The ring without the `Runtime::Compile` records: whether a call
+    compiles depends on what the process has run before it."""
+    return [r for r in tel.recent_spans() if r.name != "Runtime::Compile"]
 
 
 # ------------------------------------------------------------------ the tracer
@@ -211,7 +221,7 @@ def test_eager_iteration_polls_under_the_same_span(monkeypatch):
     for _ in range(3):
         bst.update()
     assert not bst.engine._fused_last
-    names = _names(tel.recent_spans())
+    names = _names(_layer_spans())
     assert names.count("GBDT::Iteration") == 3
     assert names.count("GBDT::FlagPoll") == 3
     assert names.count("GBDT::TrainTree") == 3
@@ -372,12 +382,12 @@ def test_dataset_construct_and_ship_leave_their_three_records():
     X, y = make_synthetic_binary(n=900, f=5)
     ds = lgb.Dataset(X, label=y)
     ds.construct()
-    find, fill = tel.recent_spans()
+    find, fill = _layer_spans()
     assert (find.name, fill.name) == ("Dataset::FindBins", "Dataset::Bin")
     assert find.args == {"rows": 900} and find.parent is None
     assert find.start_unix_ns + find.duration_ns <= fill.start_unix_ns + 10**6
     ds.construct()                                  # built: nothing more
-    assert len(tel.recent_spans()) == 2
+    assert len(_layer_spans()) == 2
     dd = ds.device_data()
     ship = tel.recent_spans(name="Dataset::Ship")
     assert len(ship) == 1 and ship[0].args == {"rows": 900, "groups": 5}
@@ -385,7 +395,7 @@ def test_dataset_construct_and_ship_leave_their_three_records():
     # a validation set binned with the training mappers: Bin alone
     tel.reset()
     lgb.Dataset(X[:100], label=y[:100], reference=ds).construct()
-    assert _names(tel.recent_spans()) == ["Dataset::Bin"]
+    assert _names(_layer_spans()) == ["Dataset::Bin"]
 
 
 @pytest.fixture
@@ -401,7 +411,7 @@ def trained():
 def test_predict_on_the_host_says_why(trained):
     bst, X = trained
     want = bst.predict(X, raw_score=True)
-    walk, call = tel.recent_spans()
+    walk, call = _layer_spans()
     assert (walk.name, walk.parent) == ("Predict::HostWalk", "Predict")
     assert call.name == "Predict" and call.parent is None
     assert call.args == {"rows": 1500, "trees": 4, "path": "host",
@@ -417,7 +427,7 @@ def test_predict_on_the_host_says_why(trained):
     # the single-row fast path is a per-row loop's body: no span
     tel.reset()
     bst.predict(X[:1])
-    assert tel.recent_spans() == []
+    assert _layer_spans() == []
 
 
 def test_predict_on_the_device_leaves_all_six_children(trained, monkeypatch):
@@ -429,7 +439,7 @@ def test_predict_on_the_device_leaves_all_six_children(trained, monkeypatch):
     got = bst.predict(X, raw_score=True)
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-6)
     assert bst.last_predict_path == "device"
-    ring = tel.recent_spans()
+    ring = _layer_spans()
     assert _names(ring) == [
         "Predict::RoutingTables", "Predict::Rebin", "Predict::PackShip",
         "Predict::NodeTables", "Predict::Walk", "Predict::Readback",
@@ -438,6 +448,225 @@ def test_predict_on_the_device_leaves_all_six_children(trained, monkeypatch):
     assert ring[-1].args == {"rows": 1500, "trees": 4, "path": "device",
                              "reason": ""}
     assert sum(r.duration_ns for r in ring[:-1]) <= ring[-1].duration_ns
+
+
+# ------------------------------------------------- Runtime::Compile records
+def _compiles(entry="any"):
+    return [r for r in tel.recent_spans(name="Runtime::Compile")
+            if entry == "any" or r.args["entry"] == entry]
+
+
+def test_a_watched_entry_leaves_one_compile_record_a_trace():
+    import jax.numpy as jnp
+    f = tel.watched_jit(lambda x: x * 2 + 1, name="doubler")
+    x4, x5 = jnp.ones(4), jnp.ones(5)       # their own eager programs first
+    tel.reset()
+    f(x4)
+    (first,) = _compiles()
+    assert first.args["entry"] == "doubler" and first.args["trace"] == 1
+    assert first.args["cache"] in ("miss", "hit") and first.parent is None
+    assert first.duration_ns > 0 and first.args["trace_ns"] > 0 \
+        and first.args["lower_ns"] > 0
+    assert "signature" not in first.args
+    # the record is retroactive: it starts its own duration ago
+    assert first.start_unix_ns + first.duration_ns <= time.time_ns()
+    f(x4)                                    # the same shapes: no compile
+    assert len(_compiles()) == 1
+    f(x5)                                    # another shape: trace 2, and why
+    second = _compiles()[-1]
+    assert len(_compiles()) == 2 and second.args["trace"] == 2
+    assert second.args["signature"] == "(float32[5])"
+    # XLA:CPU keeps no allocator statistics: no HBM field, nothing raised
+    assert not {"hbm_in_use_bytes", "hbm_peak_bytes"} & set(second.args)
+    # nothing but the ring: no Chrome event, but a phase total like any span
+    assert tel.global_tracer.events == []
+    assert tel.global_tracer.phase_counts()["Runtime::Compile"] == 2
+
+
+def test_a_program_no_entry_asked_for_has_no_entry():
+    import jax.numpy as jnp
+    x = jnp.ones(7)
+    tel.reset()
+    with tel.boundary("Layer::SetUp"):
+        jax.jit(lambda v: v - 3)(x)          # a plain jit
+        jnp.cumsum(x)                        # an eager op: its own program
+    plain, eager = _compiles()
+    assert plain.args["entry"] is None and plain.args["trace"] is None
+    assert eager.args["entry"] is None
+    # the parent is the boundary open on the compiling thread
+    assert plain.parent == eager.parent == "Layer::SetUp"
+
+
+def test_the_outermost_entry_owns_the_program():
+    import jax.numpy as jnp
+    inner = tel.watched_jit(lambda x: x + 1, name="inner_entry")
+
+    def body(x):
+        # an eager program in the middle of a trace is nobody's entry
+        return inner(x) * jnp.asarray(np.arange(6.0)).sum()
+    outer = tel.watched_jit(body, name="outer_entry")
+    x6, x9 = jnp.ones(6), jnp.ones(9)
+    tel.reset()
+    outer(x6)
+    assert [r.args["entry"] for r in _compiles()][-1] == "outer_entry"
+    assert _compiles("inner_entry") == []    # inlined: no program of its own
+    assert all(r.args["entry"] is None for r in _compiles()[:-1])
+    # an unwatched jit that inlines an entry compiles its own program
+    tel.reset()
+    jax.jit(lambda x: inner(x) * 3)(x9)
+    (rec,) = _compiles()
+    assert rec.args["entry"] is None
+    # a trace that raises leaves no entry behind for the next program
+    bad = tel.watched_jit(lambda x: x.nope, name="bad_entry")
+    with pytest.raises(AttributeError):
+        bad(x6)
+    tel.reset()
+    jax.jit(lambda x: x * 5)(x6)
+    assert [r.args["entry"] for r in _compiles()] == [None]
+
+
+def test_the_aot_path_leaves_the_same_record():
+    import jax.numpy as jnp
+    f = tel.watched_jit(lambda x: jnp.sin(x), name="aot_entry")
+    x = jnp.ones(3)
+    tel.reset()
+    lowered = f.lower(x)
+    assert _compiles() == []                 # lowered, nothing compiled yet
+    jax.jit(lambda v: v * 7)(x)              # another program in between
+    compiled = lowered.compile()
+    other, rec = _compiles()
+    assert other.args["entry"] is None
+    assert rec.args["entry"] == "aot_entry" and rec.args["trace"] == 1
+    assert rec.args["trace_ns"] > 0 and rec.args["lower_ns"] > 0
+    np.testing.assert_allclose(compiled(x), np.sin(np.ones(3)), rtol=1e-6)
+    # the same signature again: the watchdog counts it, jax finds the
+    # executable in memory and compiles nothing - no record, and the entry
+    # does not wait for the next program of the thread
+    f.lower(x).compile()
+    assert tel.recompile_counts()["aot_entry"] == 2
+    assert len(_compiles("aot_entry")) == 1
+    jax.jit(lambda v: v * 9)(x)
+    assert _compiles()[-1].args["entry"] is None
+
+
+def test_compile_records_are_kept_per_thread():
+    import jax.numpy as jnp
+    f = tel.watched_jit(lambda x: x * 11, name="threaded_entry")
+    x = jnp.ones(13)
+    tel.reset()
+
+    def worker():
+        with tel.boundary("T::Worker"):
+            f(x)
+
+    with tel.boundary("T::Main"):
+        t = threading.Thread(target=worker)
+        t.start()
+        t.join(timeout=60)
+        jax.jit(lambda v: v * 13)(x)
+    assert not t.is_alive()
+    theirs, mine = _compiles()
+    assert (theirs.args["entry"], theirs.parent) == ("threaded_entry",
+                                                     "T::Worker")
+    assert (mine.args["entry"], mine.parent) == (None, "T::Main")
+
+
+def test_the_fused_iteration_names_its_compile(fused):
+    X, y = make_synthetic_binary(n=1500, f=6)
+    bst = _booster(dict(STREAM, objective="binary", eval_fetch_freq=2), X, y)
+    tel.reset()
+    bst.update()
+    iters = _compiles("fused_iter")
+    assert len(iters) == 1 and iters[0].args["trace"] == 1
+    assert iters[0].parent == "GBDT::FusedIter"
+    # the kernels it inlines are entries too and compile nothing themselves
+    assert _compiles("route_and_hist") == []
+    n = len(_compiles())
+    bst.update()                             # steady state: no record more
+    assert len(_compiles()) == n
+
+
+# ----------------------------------------------------------- the HBM reading
+@pytest.fixture
+def two_devices_report(monkeypatch):
+    """`memory_stats` stubbed: device 0 holds less and peaked higher,
+    device 1 holds more, the others keep no statistics."""
+    stats = {0: {"bytes_in_use": 5_000_000_000,
+                 "peak_bytes_in_use": 8_439_998_464},
+             1: {"bytes_in_use": 5_100_000_000,
+                 "peak_bytes_in_use": 8_439_344_640}}
+    monkeypatch.setattr(type(jax.local_devices()[0]), "memory_stats",
+                        lambda self: stats.get(self.id))
+    return {"hbm_in_use_bytes": 5_100_000_000,
+            "hbm_peak_bytes": 8_439_998_464}
+
+
+def test_the_hbm_reading_is_the_fullest_device_in_bytes(two_devices_report):
+    from lightgbm_tpu.telemetry import metrics
+    assert tel.device_hbm_bytes() == two_devices_report
+    # GB of 1e9, the benchmark's unit - 7.8604 under 2**30
+    assert tel.device_memory_gb() == {"peak_hbm_gb": 8.44}
+    assert tel.memory_snapshot()["peak_hbm_gb"] == 8.44
+    assert tel.summary()["memory"]["peak_hbm_gb"] == 8.44
+    assert metrics.device_memory_gb() == {"peak_hbm_gb": 8.44}
+
+
+def test_without_allocator_statistics_the_fields_are_absent():
+    assert jax.local_devices()[0].memory_stats() is None     # XLA:CPU
+    assert tel.device_hbm_bytes() == {} and tel.device_memory_gb() == {}
+    assert "peak_hbm_gb" not in tel.memory_snapshot()
+    X, y = make_synthetic_binary(n=900, f=5)
+    lgb.Dataset(X, label=y).construct().device_data()
+    (ship,) = tel.recent_spans(name="Dataset::Ship")
+    assert ship.args == {"rows": 900, "groups": 5}
+
+
+def test_ship_poll_and_compile_carry_the_reading(fused, two_devices_report):
+    X, y = make_synthetic_binary(n=1500, f=6)
+    bst = _booster(dict(STREAM, objective="binary", eval_fetch_freq=2), X, y)
+    for _ in range(4):
+        bst.update()
+    (ship,) = tel.recent_spans(name="Dataset::Ship")
+    polls = tel.recent_spans(name="GBDT::FlagPoll")
+    assert [p.args["iteration"] for p in polls] == [2, 4]
+    for rec in (ship, *polls, *_compiles()):
+        assert {k: rec.args[k] for k in two_devices_report} \
+            == two_devices_report, rec.name
+    assert _compiles("fused_iter")
+    # per iteration and round a dispatch: nothing
+    for name in ("GBDT::Iteration", "GBDT::FusedIter"):
+        assert all(not {"hbm_in_use_bytes", "hbm_peak_bytes"}
+                   & set(r.args or {}) for r in tel.recent_spans(name=name))
+
+
+def test_the_poll_reads_memory_before_it_blocks(fused, monkeypatch):
+    """The reading is taken while the device still works: before the
+    poll's `device_get`, never after it."""
+    from lightgbm_tpu.models import gbdt
+    order = []
+    real_get = jax.device_get
+    monkeypatch.setattr(gbdt, "device_hbm_bytes",
+                        lambda: order.append("hbm") or {})
+    monkeypatch.setattr(
+        gbdt.jax, "device_get",
+        lambda x: order.append("fetch") or real_get(x))
+    X, y = make_synthetic_binary(n=900, f=5)
+    bst = _booster(dict(STREAM, objective="binary", eval_fetch_freq=2), X, y)
+    bst.update()
+    order.clear()
+    bst.update()
+    assert order == ["hbm", "fetch"]
+
+
+def test_shard_bind_carries_the_reading(two_devices_report):
+    X, y = make_synthetic_binary(n=2048, f=6)
+    bst = _booster(dict(STREAM, objective="binary", tree_learner="data",
+                        num_machines=2), X, y)
+    bst.update()
+    binds = tel.recent_spans(name="GBDT::ShardBind")
+    assert binds, "the row mesh binds the objective's rows once"
+    assert {k: binds[0].args[k] for k in two_devices_report} \
+        == two_devices_report
 
 
 # ------------------------------------------------------ the second accumulator

@@ -89,7 +89,7 @@ def test_span_nesting_and_events(telemetry):
 def test_trace_export_roundtrip(telemetry, tmp_path):
     with tel.span("region"):
         tel.instant("marker", detail=1)
-        tel.counter_sample("track", value=3.5)
+        tel.global_tracer.counter("track", value=3.5)
     path = str(tmp_path / "trace.json")
     tel.export_trace(path)
     blob = json.loads(open(path).read())
@@ -125,7 +125,7 @@ def test_zero_overhead_when_disabled():
     with tel.span("a"):
         pass
     tel.instant("x")
-    tel.counter_sample("x", v=1)
+    tel.global_tracer.counter("x", v=1)
     tel.inc("c")
     tel.gauge("g", 1.0)
     tel.observe("h", 0.1)
