@@ -814,6 +814,12 @@ class GBDT:
             out["sampled_rows"] = sampled
         if overflow is not None:
             out["compact_overflow"] = overflow
+        if self._last_sample_mode == "goss":
+            from .sample_strategy import THRESHOLD_PASSES
+            # how the top_rate cut is found: an exact select of count
+            # passes over the magnitudes' bit patterns (kth_largest)
+            out["goss_threshold"] = "select"
+            out["threshold_passes"] = THRESHOLD_PASSES
         if compact > 0 and self._grow_params.hist_backend == "stream":
             from ..pallas.compact_kernel import compact_kind
             out["route_replay"] = ("fused" if self._route_replay_fused()

@@ -22,6 +22,41 @@ import numpy as np
 from ..config import Config
 
 
+# bits of the key a pass of kth_largest decides: 8 passes of 15 compares an
+# element over float32 keys; 2 bits (16 passes) and 1 bit (32) measured
+# slower on the v5e (PERF.md section 6, PR 40)
+SELECT_BITS = 4
+THRESHOLD_PASSES = 32 // SELECT_BITS
+
+
+def kth_largest(mag: jax.Array, k: int) -> jax.Array:
+    """The k-th largest element of the non-negative float array ``mag``
+    (1-based, ties counted), exactly: the value ``jnp.sort(mag)[n - k]``
+    holds, found without sorting.  A non-negative float's bit pattern, read
+    as an unsigned integer, orders as the float does (+inf and positive NaN
+    at the top), so the answer is the largest key ``t`` with
+    ``count(key >= t) >= k``; it is built SELECT_BITS bits a pass from the
+    top, each pass counting the keys at or above every candidate digit in
+    one multi-output reduction (no (N, digits) array) and keeping the
+    largest digit whose count still reaches k."""
+    nbits = mag.dtype.itemsize * 8
+    udt = jnp.dtype(f"uint{nbits}")
+    keys = jax.lax.bitcast_convert_type(mag, udt)
+
+    def one_pass(i, prefix):
+        shift = (nbits - SELECT_BITS * (i + 1)).astype(udt)
+        counts = jnp.stack([
+            jnp.sum(keys >= (prefix | (udt.type(d) << shift)),
+                    dtype=jnp.int32)
+            for d in range(1, 1 << SELECT_BITS)])
+        # counts fall as the digit rises: the digit is how many reach k
+        digit = jnp.sum(counts >= k, dtype=udt)
+        return prefix | (digit << shift)
+
+    top = jax.lax.fori_loop(0, nbits // SELECT_BITS, one_pass, udt.type(0))
+    return jax.lax.bitcast_convert_type(top, mag.dtype)
+
+
 class SampleStrategy:
     """Returns (mask, grad, hess) per iteration; mask==1 means in-bag."""
 
@@ -223,13 +258,14 @@ class GOSSStrategy(SampleStrategy):
         g2 = grad * hess if grad.ndim == 1 else jnp.sum(jnp.abs(grad * hess), axis=1)
         mag = jnp.abs(g2) if g2.ndim == 1 else g2
         k_top = max(1, int(c.top_rate * n))
-        # k-th largest |grad*hess| via ONE device sort (97.7 ms at 31.4M rows
-        # on the v5e: PERF.md section 6, PR 37); jax.lax.top_k over millions
-        # of rows has not been timed.  Under a row-sharded mesh the sort is a
-        # GLOBAL collective, so the threshold is a global statistic across row
-        # shards and data-parallel GOSS trees are well-defined: every shard
-        # keeps its rows against the same cut (docs/DISTRIBUTED.md).
-        thresh = jnp.sort(mag)[n - k_top]
+        # the k_top-th largest |grad*hess| by an exact select of count passes
+        # (kth_largest; 3.7 ms at 31.4M rows on the v5e where a sort took
+        # 97.7: PERF.md section 6, PR 40).  Under a row-sharded mesh each
+        # pass's counts are a GLOBAL psum, so the threshold is a global
+        # statistic across row shards and data-parallel GOSS trees are
+        # well-defined: every shard keeps its rows against the same cut
+        # (docs/DISTRIBUTED.md).
+        thresh = kth_largest(mag, k_top)
         is_top = mag >= thresh
         u = jax.random.uniform(key, (n,))
         # other_rate is a share of ALL rows: other_rate / (1 - top_rate) of

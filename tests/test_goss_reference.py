@@ -237,6 +237,9 @@ def test_flag_poll_and_sample_plan_records_carry_the_sampler(table):
     assert poll["route_replay"] == "fused" and poll["route_only_passes"] == 1
     # the compact view came from the streaming kernel, not sort + take
     assert poll["compact_kind"] == "stream" and "compact_kind" not in dense
+    # the top_rate cut came from the select's count passes, not a sort
+    assert poll["goss_threshold"] == "select"
+    assert poll["threshold_passes"] == 8 and "goss_threshold" not in dense
     assert poll["sampled_rows"] == int(eng.models[2].internal_count[0])
     assert {"hist_passes", "scan_slots", "onehot_build"} <= set(poll)
     plans = tel.recent_spans(name="GBDT::SamplePlan", since_unix_ns=t0)

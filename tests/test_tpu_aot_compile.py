@@ -284,9 +284,15 @@ def test_higgs_goss_like_sampled_iteration_compiles(iterations):
     assert [re.sub(r"\{[^}]*\}", "", c) for c in compact] == [
         f"(s8[32,{cap}], f32[8,{cap}])"]
     assert eng._stream_tiling.tile_groups == 0        # compact_kind: stream
-    # ... and the one sort over the table's rows left is the sampler's
-    # threshold: the partition sorts nothing
-    assert len(re.findall(rf"\[{n}\]\S*(?:, \S+)*\) sort\(", text)) == 1
+    # ... and nothing sorts the table's rows: the partition never did since
+    # PR 38, and since PR 40 the sampler's threshold is a select of count
+    # passes (models/sample_strategy.py kth_largest)
+    assert not re.findall(rf"\[{n}\]\S*(?:, \S+)*\) sort\(", text)
+    # a pass counts its 15 candidates in ONE multi-output reduction and
+    # makes no (N, digits) compare array, in a fusion's body or out of it
+    assert re.search(r"= \((?:s32\[\]\S*, (?:/\*index=\d+\*/)?){14}s32\[\]"
+                     r"\S*\) fusion\(", text)
+    assert not re.findall(rf"\[(?:{n},(?:3|15)|(?:3|15),{n})\]", text)
 
 
 @pytest.mark.parametrize("groups, dtype, block, blocks, cap_blocks", [
@@ -316,12 +322,13 @@ def test_compact_rows_compiles_at_real_shapes(tpu, groups, dtype, block,
 def test_the_sampled_program_is_pinned(tpu):
     """scripts/lowered_iteration_digest.py's fifth line, `higgs_goss_like`:
     the lowered v5e sampled iteration hashes, outside debug locations, to
-    what PR 38 left.  PR 37's (a2ab3ec) reads 786bb408... here: what PR 38
-    changed in the program is the compact view's making - the partition's
-    `sort_key_val` and the two row gathers out, pallas/compact_kernel.py's
-    prefix counts and its one Mosaic call in; the four dense lines are the
-    parent's.  A PR that means to change the sampled program re-pins this
-    line and says so."""
+    what PR 40 left.  PR 37's (a2ab3ec) reads 786bb408... here, PR 38's and
+    PR 39's a5238574...: PR 38 took the partition's `sort_key_val` and the
+    two row gathers out for pallas/compact_kernel.py's one Mosaic call, and
+    PR 40 the sampler's threshold sort for an exact select of eight count
+    passes (models/sample_strategy.py kth_largest); the four dense lines
+    are the parent's.  A PR that means to change the sampled program
+    re-pins this line and says so."""
     import hashlib
     import sys
     digest, me = _digest_module(), sys.modules[__name__]
@@ -329,7 +336,7 @@ def test_the_sampled_program_is_pinned(tpu):
     assert eng._compact_cap == 4096
     text = digest.canonical(lowered.as_text())
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "a5238574cd9e7477cbd099c7b8059cb5e946546dfc110e9fb322b93249b00823")
+        "32c82febfb5f3634333f7ffdac2a330d72fa853c72d63ab740ca8c97265229ff")
 
 
 def test_the_bucketed_program_is_the_parents(tpu):
