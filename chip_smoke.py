@@ -275,9 +275,10 @@ def sampled_kernel_exactness(dd, params):
     route-only passes it fuses, and against NumPy's routing."""
     import jax.numpy as jnp
     from lightgbm_tpu.ops.compact import (compact_transposed_view,
-                                          plan_sample_rows)
+                                          live_row_blocks, plan_sample_rows)
     from lightgbm_tpu.pallas.compact_kernel import compact_rows
-    from lightgbm_tpu.pallas.stream_kernel import (NUM_TAB, pack_bins_T,
+    from lightgbm_tpu.pallas.stream_kernel import (NUM_TAB, build_route_tables,
+                                                   pack_bins_T,
                                                    route_and_hist,
                                                    route_replay,
                                                    stream_block_rows)
@@ -312,7 +313,8 @@ def sampled_kernel_exactness(dd, params):
           and np.array_equal(np.asarray(plan.perm[:int(plan.nc)]),
                              np.nonzero(mask)[0]),
           f"{int(plan.nc)} in-bag of {N}, capacity {capacity}")
-    bins_T_c, w_T_c = compact_transposed_view(bins_T, w_T, 2, capacity, T)
+    bins_T_c, w_T_c, placed = compact_transposed_view(bins_T, w_T, 2,
+                                                      capacity, T)
     nc, perm = int(plan.nc), np.asarray(plan.perm)
     check("sampled: compact_rows lowers to a Mosaic tpu_custom_call",
           "tpu_custom_call" in compact_rows.lower(
@@ -326,7 +328,7 @@ def sampled_kernel_exactness(dd, params):
     scale = np.array([[1e-40], [1e30]] * 4)     # subnormal and huge rows
     w_f = jnp.asarray((rs.standard_normal((8, N)) * scale * mask).astype(
         np.float32)).at[2].set(w_T[2])
-    bins_T_f, w_f_c = compact_transposed_view(bins_T, w_f, 2, capacity, T)
+    bins_T_f, w_f_c, _ = compact_transposed_view(bins_T, w_f, 2, capacity, T)
     check("sampled: the streamed compact view == jnp.take by the stable "
           "permutation bit for bit (int8 bins, integer and float32 weight "
           "rows), zero past the in-bag rows",
@@ -360,6 +362,44 @@ def sampled_kernel_exactness(dd, params):
           and np.array_equal(np.asarray(new_c[0])[:nc], want_leaf[perm[:nc]]),
           f"{int((slot >= 0).sum())} of {int(mask.sum())} in-bag rows in a "
           "slot")
+    # the grower's passes over the view stop at the row blocks that hold
+    # in-bag rows: each pass so, against the same pass over the whole view
+    rb = live_row_blocks(placed, T)
+    streamed = int(rb[0]) * T
+    zL = jnp.zeros(L, jnp.int32)
+    root_tabs = build_route_tables(zL, zL, zL, zL, zL, zL, zL,
+                                   zL.at[0].set(1), dd.routing, L)
+    zeros = jnp.zeros_like(lid_c)
+    passes = {
+        "root": ((bins_T_c, zeros, w_T_c, root_tabs, bits, 1, Bmax, G, L),
+                 dict(root=True)),
+        "64-slot": ((bins_T_c, lid_c, w_T_c, round_tables(splits, dd.routing,
+                                                          L), bits, 64, Bmax,
+                     G, L), {}),
+        "small-slot": ((bins_T_c, lid_c, w_T_c, round_tables(
+            splits[:2], dd.routing, L), bits, 64, Bmax, G, L),
+            dict(small_slots=2)),
+        "route-only": ((bins_T_c, lid_c, w_T_c, round_tables(
+            splits, dd.routing, L), bits, 64, Bmax, G, L),
+            dict(with_hist=False)),
+    }
+    same = {}
+    for name, (args, extra) in passes.items():
+        whole = route_and_hist(*args, **extra, **kw)
+        live = route_and_hist(*args, row_blocks=rb, **extra, **kw)
+        same[name] = (
+            all(np.array_equal(np.asarray(a), np.asarray(b))
+                for a, b in zip(whole[1:], live[1:]))
+            and np.array_equal(np.asarray(live[0])[:, :streamed],
+                               np.asarray(whole[0])[:, :streamed])
+            and np.array_equal(np.asarray(live[0])[:, streamed:],
+                               np.asarray(args[1])[:, streamed:]))
+    check("sampled: root, 64-slot, small-slot and route-only passes over the "
+          "live row blocks == over the whole view (histograms, counts, leaf "
+          "ids; the ids past them as they came in)",
+          streamed < capacity and int(placed[0]) == nc
+          and all(same.values()),
+          f"{int(rb[0])} of {capacity // T} blocks; {same}")
 
     # (2) three rounds of tables replayed over every row in one launch
     rounds = [[(0, 3, 24, 1, True)],

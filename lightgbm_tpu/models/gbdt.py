@@ -828,7 +828,24 @@ class GBDT:
             # per compiled program, as the tiling it is decided on
             out["compact_kind"] = compact_kind(
                 self._stream_tiling.tile_groups)
+            if sampled is not None:
+                out["pass_rows"] = self._pass_rows(sampled)
         return out
+
+    def _pass_rows(self, sampled: int) -> int:
+        """Rows a histogram pass over a compacted tree's view streamed: the
+        kernel blocks that hold the in-bag rows, at most the capacity (the
+        whole capacity where the view is tiled and streams every block).
+        Host arithmetic on the in-bag count; under a row mesh, of a shard
+        holding the mean share."""
+        compact = self._last_compact_rows
+        if self._stream_tiling.tile_groups:
+            return compact
+        D = 1
+        if self.mesh is not None and self._row_axis is not None:
+            D = int(self.mesh.shape[self._row_axis])
+        T = self._pack_block
+        return min(-(-sampled // (D * T)) * T, compact)
 
     # ------------------------------------------------------------------
     def _mesh_shards_rows_only(self) -> bool:
@@ -2201,6 +2218,9 @@ class GBDT:
             # programs ran at (0 = dense masking)
             rec["sampled_rows"] = self._last_sampled_rows
             rec["compact_rows"] = self._last_compact_rows
+            if (self._last_compact_rows > 0
+                    and self._grow_params.hist_backend == "stream"):
+                rec["pass_rows"] = self._pass_rows(self._last_sampled_rows)
             _tel_registry.gauge("train/sampled_rows",
                                 self._last_sampled_rows)
         # ---- histogram formulation (docs/PERF.md floor A/B) ----

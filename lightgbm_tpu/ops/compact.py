@@ -97,8 +97,11 @@ def compact_transposed_view(bins_T: jax.Array, w_T: jax.Array,
     (G, N) / ``w_T`` (C, N) to the front and truncates to ``capacity``
     columns; under ``mesh`` every device partitions its OWN row shard
     inside shard_map (no cross-device row movement — the caller sizes
-    ``capacity`` to cover the fullest shard).  Returns (bins_T_h, w_T_h):
-    the in-bag columns in the table's order, bit for bit.
+    ``capacity`` to cover the fullest shard).  Returns (bins_T_h, w_T_h,
+    placed): the in-bag columns in the table's order, bit for bit, and how
+    many there are, min(in-bag count, capacity), as (1,) int32 - a device's
+    own count under ``mesh`` ((D,) over the row axis), which the grower
+    turns into the row blocks its passes over the view stream.
 
     A table of one M-tile streams through pallas/compact_kernel.py
     (`compact_kind`: "stream"; columns past the in-bag count are zero); one
@@ -119,7 +122,8 @@ def compact_transposed_view(bins_T: jax.Array, w_T: jax.Array,
                                 block_rows=block)
         plan = plan_sample_rows(wT[mask_row], capacity)
         return (jnp.take(bT, plan.perm, axis=1),
-                jnp.take(wT, plan.perm, axis=1))
+                jnp.take(wT, plan.perm, axis=1),
+                jnp.minimum(plan.nc, capacity).reshape(1))
 
     with jax.named_scope("compact_rows"):
         if mesh is not None:
@@ -128,5 +132,13 @@ def compact_transposed_view(bins_T: jax.Array, w_T: jax.Array,
             return shard_map_rows(
                 _local, mesh,
                 (P(None, row_axis), P(None, row_axis)),
-                (P(None, row_axis), P(None, row_axis)))(bins_T, w_T)
+                (P(None, row_axis), P(None, row_axis), P(row_axis)))(
+                    bins_T, w_T)
         return _local(bins_T, w_T)
+
+
+def live_row_blocks(placed: jax.Array, block: int) -> jax.Array:
+    """The row blocks a pass over a compact view streams: the blocks that
+    hold its ``placed`` in-bag columns, at least one.  The view's capacity
+    keeps its headroom for the next tree's count; the passes stop here."""
+    return jnp.maximum(-(-placed // block), 1).astype(jnp.int32)

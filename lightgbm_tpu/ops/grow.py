@@ -677,13 +677,15 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         # the compact view every histogram pass of this tree streams; the
         # columns past the in-bag rows carry exact zero weights, so
         # truncating them changes no f32 sum (the full vs compacted
-        # bit-identity the A/B suite asserts)
-        bins_T_h, w_T_h = bins_T, w_T
+        # bit-identity the A/B suite asserts); every pass over the view
+        # streams only the row blocks that hold in-bag rows (`rb_h`)
+        bins_T_h, w_T_h, rb_h = bins_T, w_T, None
         if use_compact:
-            from .compact import compact_transposed_view
-            bins_T_h, w_T_h = compact_transposed_view(
+            from .compact import compact_transposed_view, live_row_blocks
+            bins_T_h, w_T_h, placed = compact_transposed_view(
                 bins_T, w_T, 2, compact_rows, T_rows,
                 mesh=mesh, row_axis=row_axis, tile_groups=tile_groups)
+            rb_h = live_row_blocks(placed, T_rows)
         n_pad_h = bins_T_h.shape[1]
 
         # ---- GOSS+stream fusion (docs/PERF.md "histogram-formulation
@@ -727,15 +729,17 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 D_rows = mesh.shape[row_axis]
 
             def _rh(bT, lid_row, wT, tb, bi, num_slots, with_hist=True,
-                    root=False, live_slots=None):
-                def _local(bT, lid_row, wT, tb, bi, live=None):
+                    root=False, live_slots=None, row_blocks=None):
+                def _local(bT, lid_row, wT, tb, bi, *extra):
+                    live = extra[0] if live_slots is not None else None
+                    rb = extra[-1] if row_blocks is not None else None
                     nl, h, c = route_and_hist_live(
                         live, bT, lid_row, wT, tb, bi, num_slots, Bmax, G, L,
                         block_rows=T_rows, has_cat=params.has_categorical,
                         two_pass=params.hist_two_pass, int_weights=use_int,
                         with_hist=with_hist,
                         bin_buckets=params.bin_buckets, root=root,
-                        tile_groups=tile_groups)
+                        tile_groups=tile_groups, row_blocks=rb)
                     if with_hist:
                         if use_packed:
                             pw, pscales = pack_gh_wire(h, row_axis, packed_w,
@@ -767,24 +771,26 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 hspec = (P(None, row_axis, None, None) if use_rs
                          else P(None, None, None, None))
                 # the round's split count is replicated: every device takes
-                # the same branch, and the psum follows as for any pass
+                # the same branch, and the psum follows as for any pass; a
+                # device streams its own view's live row blocks
                 live = () if live_slots is None else (live_slots,)
+                rbs = () if row_blocks is None else (row_blocks,)
                 wrapped = shard_map_rows(
                     _local, mesh,
                     (P(None, row_axis), P(None, row_axis),
                      P(None, row_axis), P(None, None), P(None, None))
-                    + (P(),) * len(live),
+                    + (P(),) * len(live) + (P(row_axis),) * len(rbs),
                     (P(None, row_axis), hspec, P(None)))
-                return wrapped(bT, lid_row, wT, tb, bi, *live)
+                return wrapped(bT, lid_row, wT, tb, bi, *live, *rbs)
         else:
             def _rh(bT, lid_row, wT, tb, bi, num_slots, with_hist=True,
-                    root=False, live_slots=None):
+                    root=False, live_slots=None, row_blocks=None):
                 return route_and_hist_live(
                     live_slots, bT, lid_row, wT, tb, bi, num_slots, Bmax, G, L,
                     block_rows=T_rows, has_cat=params.has_categorical,
                     two_pass=params.hist_two_pass, int_weights=use_int,
                     with_hist=with_hist, bin_buckets=params.bin_buckets,
-                    root=root, tile_groups=tile_groups)
+                    root=root, tile_groups=tile_groups, row_blocks=row_blocks)
 
         zL = jnp.zeros(L, i32)
         tabs0 = build_route_tables(zL, zL, zL, zL, zL, zL, zL,
@@ -796,7 +802,7 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
         # every row in leaf 0, tabs0 splits nothing: route_and_hist takes
         # the factored root contraction where root_pass_kind() allows it
         _, root_hist, _ = _rh(bins_T_h, lid0.reshape(1, -1), w_T_h, tabs0,
-                              bits0, 1, root=True)
+                              bits0, 1, root=True, row_blocks=rb_h)
         if use_int:
             root_hist = root_hist.astype(f32) * hscale
     else:
@@ -1099,7 +1105,8 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array, cnt_w: jax.Arra
                 with jax.named_scope("route_and_hist"):
                     new_leaf_row, hist_small, slot_cnt = _rh(
                         bins_T_h, lid_h.reshape(1, -1), w_T_h, tabs,
-                        bits_l.T, S, with_hist=with_hist, live_slots=live)
+                        bits_l.T, S, with_hist=with_hist, live_slots=live,
+                        row_blocks=rb_h)
                 if use_compact and fuse:
                     # GOSS+stream fusion: stash this round's tables — the
                     # full-data route-only pass is REPLAYED in one fused
@@ -1976,12 +1983,13 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # ---- GOSS/bagging row compaction (see grow_tree): one stable
         # partition per iteration serves all K lockstep class trees — the
         # mask row (2K) is shared across classes
-        bins_T_h, w_T_h = bins_T, w_T
+        bins_T_h, w_T_h, rb_h = bins_T, w_T, None
         if use_compact:
-            from .compact import compact_transposed_view
-            bins_T_h, w_T_h = compact_transposed_view(
+            from .compact import compact_transposed_view, live_row_blocks
+            bins_T_h, w_T_h, placed = compact_transposed_view(
                 bins_T, w_T, 2 * K, compact_rows, T_rows,
                 mesh=mesh, row_axis=row_axis)
+            rb_h = live_row_blocks(placed, T_rows)
         n_pad_h = bins_T_h.shape[1]
 
         if mesh is not None:
@@ -1989,14 +1997,15 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             from ..parallel.comms import reduce_hist_rows
             from ..parallel.mesh import shard_map_rows
 
-            def _rh(bT, lid, wT, tb, bi, num_slots, with_hist=True):
-                def _local(bT, lid, wT, tb, bi):
+            def _rh(bT, lid, wT, tb, bi, num_slots, with_hist=True,
+                    row_blocks=None):
+                def _local(bT, lid, wT, tb, bi, *rbs):
                     nl, h, c = route_and_hist(
                         bT, lid, wT, tb, bi, num_slots, Bmax, G, L,
                         block_rows=T_rows, has_cat=params.has_categorical,
                         two_pass=params.hist_two_pass, int_weights=use_int,
                         with_hist=with_hist, bin_buckets=params.bin_buckets,
-                        num_class=K)
+                        num_class=K, row_blocks=rbs[0] if rbs else None)
                     if with_hist:
                         h = reduce_hist_rows(
                             h, row_axis, 2, plan, params.hist_comms_dtype,
@@ -2010,20 +2019,23 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
                 hspec = (P(None, None, row_axis, None, None) if use_rs
                          else P(None, None, None, None, None))
+                rbs = () if row_blocks is None else (row_blocks,)
                 wrapped = shard_map_rows(
                     _local, mesh,
                     (P(None, row_axis), P(None, row_axis),
-                     P(None, row_axis), P(None, None), P(None, None)),
+                     P(None, row_axis), P(None, None), P(None, None))
+                    + (P(row_axis),) * len(rbs),
                     (P(None, row_axis), hspec, P(None, None)))
-                return wrapped(bT, lid, wT, tb, bi)
+                return wrapped(bT, lid, wT, tb, bi, *rbs)
         else:
-            def _rh(bT, lid, wT, tb, bi, num_slots, with_hist=True):
+            def _rh(bT, lid, wT, tb, bi, num_slots, with_hist=True,
+                    row_blocks=None):
                 return route_and_hist(
                     bT, lid, wT, tb, bi, num_slots, Bmax, G, L,
                     block_rows=T_rows, has_cat=params.has_categorical,
                     two_pass=params.hist_two_pass, int_weights=use_int,
                     with_hist=with_hist, bin_buckets=params.bin_buckets,
-                    num_class=K)
+                    num_class=K, row_blocks=row_blocks)
 
         zKL = jnp.zeros(K * L, i32)
         tabs0 = build_route_tables(zKL, zKL, zKL, zKL, zKL, zKL, zKL,
@@ -2033,7 +2045,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         leaf_id_c = jnp.zeros((K, n_pad_h) if use_compact else (1, 1), i32)
         _, root_hist, _ = _rh(bins_T_h,
                               leaf_id_c if use_compact else leaf_id,
-                              w_T_h, tabs0, bits0, 1)
+                              w_T_h, tabs0, bits0, 1, row_blocks=rb_h)
         if use_int:
             root_hist = root_hist.astype(f32) \
                 * hscale[:, None, None, None, :]
@@ -2289,7 +2301,7 @@ def grow_tree_k(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     new_leaf_h, hist_small, slot_cnt = _rh(
                         bins_T_h, lid_h, w_T_h, tabs,
                         bits_l.reshape(K * L, Bpad).T, S,
-                        with_hist=with_hist)
+                        with_hist=with_hist, row_blocks=rb_h)
                 if use_int and with_hist:
                     hist_small = hist_small.astype(f32) \
                         * hscale[:, None, None, None, :]

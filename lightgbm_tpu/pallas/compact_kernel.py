@@ -179,7 +179,8 @@ def compact_rows(bins_T: jax.Array, w_T: jax.Array, mask_row: int,
     Returns (bins_T_c, w_T_c), (rows, capacity) each: column j < min(nc,
     capacity) is the j-th in-bag column of the input, bit for bit and in the
     input's order (nc the in-bag count; an in-bag row of rank >= capacity is
-    dropped); every column from nc on is zero."""
+    dropped); every column from nc on is zero - and, third, min(nc,
+    capacity) as a (1,) int32, the last prefix."""
     T = block_rows
     n_pad = bins_T.shape[1]
     if n_pad % T or capacity % T or T % CHUNK:
@@ -209,7 +210,7 @@ def compact_rows(bins_T: jax.Array, w_T: jax.Array, mask_row: int,
     def target(b, p):        # the output block the rows before block b reach
         return jnp.minimum(p[b] // T, capacity // T - 1)
 
-    return pl.pallas_call(
+    placed = pl.pallas_call(
         functools.partial(_compact_kernel, T=T, capacity=capacity,
                           n_ops=len(ops), f32_dots=pallas_interpret()),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -241,3 +242,4 @@ def compact_rows(bins_T: jax.Array, w_T: jax.Array, mask_row: int,
         interpret=pallas_interpret(),
     )(prefix, in_block.reshape(nb, 1, chunks), mask, *ops,
       *[jnp.zeros(o.shape, o.dtype) for o in outs])
+    return (*placed, prefix[-1:])

@@ -565,17 +565,41 @@ def _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
         hist_ref[...] += dot(oh, A_hi)
 
 
-def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
-                       newleaf_ref, *outs, T, G, B, S, L, GW,
+def _split_live(refs, live_rows: bool):
+    """A one-tile pass kernel's refs -> (the scalar-prefetched count of live
+    row blocks, or None where the pass streams every block; the rest)."""
+    return (refs[0], refs[1:]) if live_rows else (None, refs)
+
+
+def _pass_step(rb_ref, zero, work, route=lambda: ()):
+    """One grid step of a one-tile pass: the accumulators zeroed at step 0
+    (`zero`), the block routed (`route`, where the pass routes) and `work`
+    done on what the route returns.  Where the pass streams every block
+    (rb_ref None) the route comes first; over a compact view's live blocks
+    the zeroing stands outside the guard that skips the blocks past them."""
+    if rb_ref is None:
+        routed = route()
+        zero()
+        work(*routed)
+        return
+    zero()
+    pl.when(pl.program_id(0) < rb_ref[0])(lambda: work(*route()))
+
+
+def _route_hist_kernel(*refs, T, G, B, S, L, GW,
                        has_cat: bool, two_pass: bool = True,
                        int_weights: bool = False, f32_dots: bool = False,
                        u8_layout: bool = False, with_hist: bool = True,
                        bin_buckets=None, m_rows: int = 0, K: int = 1,
-                       slot_out: bool = False):
+                       slot_out: bool = False, live_rows: bool = False):
     """The pass over a table of one M-tile: route, count, contract, one grid
     axis over the row blocks.  Without with_hist it only routes and counts;
     with slot_out it then also writes each row's histogram slot (-1: none)
-    to a last (K, T) output, for _hist_tiles_kernel's sweeps."""
+    to a last (K, T) output, for _hist_tiles_kernel's sweeps.  With
+    live_rows (a compact view's pass) only the first `rb_ref[0]` blocks are
+    routed, counted and contracted."""
+    rb_ref, refs = _split_live(refs, live_rows)
+    bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref, newleaf_ref, *outs = refs
     if slot_out:
         *outs, slot_ref = outs
     if with_hist:
@@ -585,33 +609,40 @@ def _route_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
         # (G*B, 2*S*K) VMEM-resident block is never allocated
         hist_ref, (cnt_ref,) = None, outs
     b = pl.program_id(0)
-    slots, bins32 = _route_rows(
-        bins_ref, leaf_ref, tabs_ref, bits_ref, newleaf_ref, T=T, B=B, L=L,
-        GW=GW, has_cat=has_cat, f32_dots=f32_dots, u8_layout=u8_layout, K=K)
+
+    def route():
+        return _route_rows(
+            bins_ref, leaf_ref, tabs_ref, bits_ref, newleaf_ref, T=T, B=B,
+            L=L, GW=GW, has_cat=has_cat, f32_dots=f32_dots,
+            u8_layout=u8_layout, K=K)
 
     # ---------------- histogram ----------------
-    @pl.when(b == 0)
-    def _():
-        if with_hist:
-            hist_ref[...] = jnp.zeros_like(hist_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+    def zero():
+        @pl.when(b == 0)
+        def _():
+            if with_hist:
+                hist_ref[...] = jnp.zeros_like(hist_ref)
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
 
-    s_iota, slot_ohs = _count_slots(cnt_ref, w_ref, slots, T=T, S=S, K=K,
-                                    f32_dots=f32_dots)
-    if slot_out:
-        for k in range(K):
-            slot_ref[k:k + 1, :] = slots[k]
-    if not with_hist:
-        # route-only round (a tree's LAST split round: the children's
-        # histograms would never be scanned, so the dominant one-hot
-        # contraction — and the whole VMEM-resident histogram block — is
-        # dropped)
-        return
-    _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
-                     slot_ohs, T=T, G=G, B=B, S=S, two_pass=two_pass,
-                     int_weights=int_weights, f32_dots=f32_dots,
-                     u8_layout=u8_layout, bin_buckets=bin_buckets,
-                     m_rows=m_rows, K=K)
+    def count_and_contract(slots, bins32):
+        s_iota, slot_ohs = _count_slots(cnt_ref, w_ref, slots, T=T, S=S, K=K,
+                                        f32_dots=f32_dots)
+        if slot_out:
+            for k in range(K):
+                slot_ref[k:k + 1, :] = slots[k]
+        if not with_hist:
+            # route-only round (a tree's LAST split round: the children's
+            # histograms would never be scanned, so the dominant one-hot
+            # contraction — and the whole VMEM-resident histogram block —
+            # is dropped)
+            return
+        _accumulate_hist(hist_ref, bins_ref, bins32, w_ref, slots, s_iota,
+                         slot_ohs, T=T, G=G, B=B, S=S, two_pass=two_pass,
+                         int_weights=int_weights, f32_dots=f32_dots,
+                         u8_layout=u8_layout, bin_buckets=bin_buckets,
+                         m_rows=m_rows, K=K)
+
+    _pass_step(rb_ref, zero, count_and_contract, route)
 
 
 def _hist_tiles_kernel(bins_ref, slot_ref, w_ref, hist_ref, *, T, Gt, B, S,
@@ -737,14 +768,20 @@ def _factored_accumulate(hist_ref, bins_ref, w_rows, *, T, NG, HP, f32_dots):
             hist_ref[g * R:(g + 1) * R, :] += d
 
 
-def _root_hist_kernel(bins_ref, w_ref, hist_ref, *, T, NG, HP, f32_dots,
-                      row_axis=0):
-    @pl.when(pl.program_id(row_axis) == 0)
-    def _():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
+def _root_hist_kernel(*refs, T, NG, HP, f32_dots, row_axis=0,
+                      live_rows: bool = False):
+    rb_ref, (bins_ref, w_ref, hist_ref) = _split_live(refs, live_rows)
 
-    _factored_accumulate(hist_ref, bins_ref, _weight_bytes(w_ref), T=T,
-                         NG=NG, HP=HP, f32_dots=f32_dots)
+    def zero():
+        @pl.when(pl.program_id(row_axis) == 0)
+        def _():
+            hist_ref[...] = jnp.zeros_like(hist_ref)
+
+    def contract():
+        _factored_accumulate(hist_ref, bins_ref, _weight_bytes(w_ref), T=T,
+                             NG=NG, HP=HP, f32_dots=f32_dots)
+
+    _pass_step(rb_ref, zero, contract)
 
 
 # A round that splits one or two leaves has one or two live slots, and the
@@ -773,25 +810,38 @@ def small_pass_index(live_slots, bins_dtype, int_weights: bool,
         live_slots, 0).astype(jnp.int32)
 
 
-def _route_small_hist_kernel(bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref,
-                             hist_ref, newleaf_ref, cnt_ref, *, T, B, S, L,
-                             NG, HP, has_cat, f32_dots):
+def _route_small_hist_kernel(*refs, T, B, S, L, NG, HP, has_cat, f32_dots,
+                             live_rows: bool = False):
     """The small-slot pass over a table of one M-tile: route, count, and the
     factored contraction of the rows' slot-masked grad/hess, the bins block
-    read once and no slot leaving the kernel."""
-    (slot,), _ = _route_rows(
-        bins_ref, leaf_ref, tabs_ref, bits_ref, newleaf_ref, T=T, B=B, L=L,
-        GW=bins_ref.shape[0], has_cat=has_cat, f32_dots=f32_dots,
-        u8_layout=True, K=1)
+    read once and no slot leaving the kernel.  live_rows as
+    _route_hist_kernel's."""
+    rb_ref, refs = _split_live(refs, live_rows)
+    (bins_ref, leaf_ref, w_ref, tabs_ref, bits_ref, hist_ref, newleaf_ref,
+     cnt_ref) = refs
+    b = pl.program_id(0)
 
-    @pl.when(pl.program_id(0) == 0)
-    def _():
-        hist_ref[...] = jnp.zeros_like(hist_ref)
-        cnt_ref[...] = jnp.zeros_like(cnt_ref)
+    def route():
+        (slot,), _ = _route_rows(
+            bins_ref, leaf_ref, tabs_ref, bits_ref, newleaf_ref, T=T, B=B,
+            L=L, GW=bins_ref.shape[0], has_cat=has_cat, f32_dots=f32_dots,
+            u8_layout=True, K=1)
+        return (slot,)
 
-    _count_slots(cnt_ref, w_ref, [slot], T=T, S=S, K=1, f32_dots=f32_dots)
-    _factored_accumulate(hist_ref, bins_ref, _weight_bytes(w_ref, slot, S),
-                         T=T, NG=NG, HP=HP, f32_dots=f32_dots)
+    def zero():
+        @pl.when(b == 0)
+        def _():
+            hist_ref[...] = jnp.zeros_like(hist_ref)
+            cnt_ref[...] = jnp.zeros_like(cnt_ref)
+
+    def count_and_contract(slot):
+        _count_slots(cnt_ref, w_ref, [slot], T=T, S=S, K=1,
+                     f32_dots=f32_dots)
+        _factored_accumulate(hist_ref, bins_ref,
+                             _weight_bytes(w_ref, slot, S), T=T, NG=NG,
+                             HP=HP, f32_dots=f32_dots)
+
+    _pass_step(rb_ref, zero, count_and_contract, route)
 
 
 def _small_hist_tiles_kernel(bins_ref, slot_ref, w_ref, hist_ref, *, T, S,
@@ -806,6 +856,50 @@ def _small_hist_tiles_kernel(bins_ref, slot_ref, w_ref, hist_ref, *, T, S,
     _factored_accumulate(hist_ref, bins_ref,
                          _weight_bytes(w_ref, slot_ref[0:1, :], S), T=T,
                          NG=NG, HP=HP, f32_dots=f32_dots)
+
+
+def _one_tile_call(kernel, operands, *, T, ins, outs, out_shape,
+                   row_blocks=None, leaf=None):
+    """The pallas_call of a pass over a table of one M-tile, grid (row
+    block,).  `ins` / `outs` give each block as (shape, walks): a block that
+    walks the row axis is block (0, b) at step b, any other the whole array.
+
+    `row_blocks` ((1,) int32: how many row blocks of a compact view hold
+    in-bag rows, scalar-prefetched) stops a walking block at the last of
+    them: a step past it fetches nothing new, and `kernel` (called with
+    live_rows=True) does no work there, so the pass costs the in-bag rows
+    and not the view's capacity.  `leaf` = (operand, result) indices of the
+    leaf ids, aliased, so the columns past the live blocks keep the ids
+    they came in with.  Without row_blocks the call is the plain grid of
+    every block."""
+    n_pad = operands[0].shape[1]
+    if row_blocks is None:
+        def at(walks):
+            return (lambda b: (0, b)) if walks else (lambda b: (0, 0))
+    else:
+        def at(walks):
+            return ((lambda b, rb: (0, jnp.minimum(b, rb[0] - 1))) if walks
+                    else (lambda b, rb: (0, 0)))
+    in_specs = [pl.BlockSpec(shape, at(walks)) for shape, walks in ins]
+    out_specs = ([pl.BlockSpec(shape, at(walks)) for shape, walks in outs]
+                 if isinstance(outs, list) else pl.BlockSpec(outs[0],
+                                                             at(outs[1])))
+    compiler_params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    if row_blocks is None:
+        return pl.pallas_call(
+            kernel, grid=(n_pad // T,), in_specs=in_specs,
+            out_specs=out_specs, out_shape=out_shape,
+            compiler_params=compiler_params, interpret=pallas_interpret(),
+        )(*operands)
+    return pl.pallas_call(
+        functools.partial(kernel, live_rows=True),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_pad // T,), in_specs=in_specs,
+            out_specs=out_specs),
+        out_shape=out_shape,
+        input_output_aliases={} if leaf is None else {1 + leaf[0]: leaf[1]},
+        compiler_params=compiler_params, interpret=pallas_interpret(),
+    )(row_blocks, *operands)
 
 
 def _factored_digits(bmax: int):
@@ -827,7 +921,8 @@ def _factored_unpack(out, NG: int, S: int, HP: int, G: int, bmax: int):
 
 
 def _root_hist_factored(bins_T, w_T, bmax: int, num_groups: int,
-                        block_rows: int, tile_groups: int = 0):
+                        block_rows: int, tile_groups: int = 0,
+                        row_blocks=None):
     """(1, G, bmax, 2) int32 root histogram of integer-valued grad/hess rows
     over u8-layout bins: what route_and_hist(..., num_slots=1) returns for
     rows that all sit in one leaf, by the factored contraction.  Rows
@@ -843,29 +938,36 @@ def _root_hist_factored(bins_T, w_T, bmax: int, num_groups: int,
     tiles = GW // tile_groups if tile_groups else 1
     NGt = (tile_groups or -(-G // GF) * GF) // GF       # 16-groups a tile
     NG = tiles * NGt
-    at = (lambda f: lambda j, b: f(j, b)) if tile_groups \
-        else (lambda f: lambda b: f(0, b))
-    out = pl.pallas_call(
-        functools.partial(_root_hist_kernel, T=T, NG=NGt, HP=HP,
-                          f32_dots=pallas_interpret(),
-                          row_axis=1 if tile_groups else 0),
-        grid=(tiles, n_pad // T) if tile_groups else (n_pad // T,),
-        in_specs=[
-            pl.BlockSpec((tile_groups or GW, T), at(lambda j, b: (j, b))),
-            pl.BlockSpec((w_T.shape[0], T), at(lambda j, b: (0, b))),
-        ],
-        out_specs=pl.BlockSpec((NGt * R, 8 * GF), at(lambda j, b: (j, 0))),
-        out_shape=jax.ShapeDtypeStruct((NG * R, 8 * GF), jnp.int32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * (2 if tile_groups else 1)),
-        interpret=pallas_interpret(),
-    )(bins_T, w_T)
+    kernel = functools.partial(_root_hist_kernel, T=T, NG=NGt, HP=HP,
+                               f32_dots=pallas_interpret(),
+                               row_axis=1 if tile_groups else 0)
+    out_shape = jax.ShapeDtypeStruct((NG * R, 8 * GF), jnp.int32)
+    if tile_groups:
+        out = pl.pallas_call(
+            kernel,
+            grid=(tiles, n_pad // T),
+            in_specs=[
+                pl.BlockSpec((tile_groups, T), lambda j, b: (j, b)),
+                pl.BlockSpec((w_T.shape[0], T), lambda j, b: (0, b)),
+            ],
+            out_specs=pl.BlockSpec((NGt * R, 8 * GF), lambda j, b: (j, 0)),
+            out_shape=out_shape,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * 2),
+            interpret=pallas_interpret(),
+        )(bins_T, w_T)
+    else:
+        out = _one_tile_call(
+            kernel, (bins_T, w_T), T=T,
+            ins=[((GW, T), True), ((w_T.shape[0], T), True)],
+            outs=((NGt * R, 8 * GF), False), out_shape=out_shape,
+            row_blocks=row_blocks)
     return _factored_unpack(out, NG, 1, HP, G, bmax)
 
 
 def _route_small_hist(bins_T, leaf_id, w_T, tabs, bits, S: int, bmax: int,
                       num_groups: int, num_leaves: int, block_rows: int,
-                      has_cat: bool, tile_groups: int = 0):
+                      has_cat: bool, tile_groups: int = 0, row_blocks=None):
     """The small-slot pass (S <= SMALL_PASS_SLOTS live slots, integer-valued
     grad/hess, u8-layout bins): (new leaf ids (1, N_pad), (S, G, bmax, 2)
     int32 histograms of the routed rows' slots, (S,) exact slot counts), the
@@ -878,8 +980,6 @@ def _route_small_hist(bins_T, leaf_id, w_T, tabs, bits, S: int, bmax: int,
     B = -(-bmax // 8) * 8
     HP = _factored_digits(bmax)
     R = 2 * S * HP * GF
-    params = pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",) * (2 if tile_groups else 1))
     if tile_groups:
         # the counts a sublane wide whatever S: a 2-column second result
         # is the one-hot root's to the trace's readers
@@ -905,38 +1005,29 @@ def _route_small_hist(bins_T, leaf_id, w_T, tabs, bits, S: int, bmax: int,
                                    lambda j, b: (j, 0, 0, 0)),
             out_shape=jax.ShapeDtypeStruct((tiles, 1, NGt * R, 8 * GF),
                                            jnp.int32),
-            compiler_params=params,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",) * 2),
             interpret=pallas_interpret(),
         )(bins_T, slot, w_T)
     else:
         NG = -(-G // GF)
         # the histogram first: a result tuple that opens with the leaf ids
         # is the 64-slot pass's to the trace's readers
-        out, new_leaf, cnt = pl.pallas_call(
+        out, new_leaf, cnt = _one_tile_call(
             functools.partial(_route_small_hist_kernel, T=T, B=B, S=S, L=L,
                               NG=NG, HP=HP, has_cat=has_cat,
                               f32_dots=pallas_interpret()),
-            grid=(n_pad // T,),
-            in_specs=[
-                pl.BlockSpec((GW, T), lambda b: (0, b)),
-                pl.BlockSpec((1, T), lambda b: (0, b)),
-                pl.BlockSpec((w_T.shape[0], T), lambda b: (0, b)),
-                pl.BlockSpec((NUM_TAB, L), lambda b: (0, 0)),
-                pl.BlockSpec((B, L), lambda b: (0, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((NG * R, 8 * GF), lambda b: (0, 0)),
-                pl.BlockSpec((1, T), lambda b: (0, b)),
-                pl.BlockSpec((1, S), lambda b: (0, 0)),
-            ],
+            (bins_T, leaf_id, w_T, tabs, bits), T=T,
+            ins=[((GW, T), True), ((1, T), True), ((w_T.shape[0], T), True),
+                 ((NUM_TAB, L), False), ((B, L), False)],
+            outs=[((NG * R, 8 * GF), False), ((1, T), True),
+                  ((1, S), False)],
             out_shape=[
                 jax.ShapeDtypeStruct((NG * R, 8 * GF), jnp.int32),
                 jax.ShapeDtypeStruct((1, n_pad), jnp.int32),
                 jax.ShapeDtypeStruct((1, S), jnp.float32),
             ],
-            compiler_params=params,
-            interpret=pallas_interpret(),
-        )(bins_T, leaf_id, w_T, tabs, bits)
+            row_blocks=row_blocks, leaf=(1, 1))
     return (new_leaf, _factored_unpack(out, NG, S, HP, G, bmax),
             cnt.reshape(-1))
 
@@ -1208,47 +1299,33 @@ def _route_and_hist_tiled(bins_T, slot, w_T, num_slots, bmax, num_groups,
 
 def _route_hist_call(bins_T, leaf_id, w_T, tabs, bits, *, S, G, B, L, T, K,
                      has_cat, two_pass, int_weights, with_hist, bin_buckets,
-                     m_rows, slot_out):
+                     m_rows, slot_out, row_blocks=None):
     """The one pallas_call of _route_hist_kernel: (new leaf ids, [histogram
     block with with_hist], slot counts, [slots with slot_out])."""
     GW, n_pad = bins_T.shape
     hist_dtype = jnp.int32 if int_weights else jnp.float32
-    out_specs = [
-        pl.BlockSpec((K, T), lambda b: (0, b)),
-        pl.BlockSpec((m_rows, 2 * S * K), lambda b: (0, 0)),
-        pl.BlockSpec((1, S * K), lambda b: (0, 0)),
-    ]
+    outs = [((K, T), True), ((m_rows, 2 * S * K), False), ((1, S * K), False)]
     out_shape = [
         jax.ShapeDtypeStruct((K, n_pad), jnp.int32),
         jax.ShapeDtypeStruct((m_rows, 2 * S * K), hist_dtype),
         jax.ShapeDtypeStruct((1, S * K), jnp.float32),
     ]
     if not with_hist:
-        del out_specs[1], out_shape[1]
+        del outs[1], out_shape[1]
     if slot_out:
-        out_specs.append(out_specs[0])
+        outs.append(outs[0])
         out_shape.append(out_shape[0])
-    return pl.pallas_call(
+    return _one_tile_call(
         functools.partial(_route_hist_kernel, T=T, G=G, B=B, S=S, L=L, GW=GW,
                           has_cat=has_cat, two_pass=two_pass,
                           int_weights=int_weights, f32_dots=pallas_interpret(),
                           u8_layout=bins_T.dtype == jnp.int8,
                           with_hist=with_hist, bin_buckets=bin_buckets,
                           m_rows=m_rows, K=K, slot_out=slot_out),
-        grid=(n_pad // T,),
-        in_specs=[
-            pl.BlockSpec((GW, T), lambda b: (0, b)),
-            pl.BlockSpec((K, T), lambda b: (0, b)),
-            pl.BlockSpec((w_T.shape[0], T), lambda b: (0, b)),
-            pl.BlockSpec((NUM_TAB, K * L), lambda b: (0, 0)),
-            pl.BlockSpec((B, K * L), lambda b: (0, 0)),
-        ],
-        out_specs=out_specs,
-        out_shape=out_shape,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=pallas_interpret(),
-    )(bins_T, leaf_id, w_T, tabs, bits)
+        (bins_T, leaf_id, w_T, tabs, bits), T=T,
+        ins=[((GW, T), True), ((K, T), True), ((w_T.shape[0], T), True),
+             ((NUM_TAB, K * L), False), ((B, K * L), False)],
+        outs=outs, out_shape=out_shape, row_blocks=row_blocks, leaf=(1, 0))
 
 
 @functools.partial(watched_jit, name="route_and_hist", warn_after=0,
@@ -1263,7 +1340,8 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
                    has_cat: bool = True, two_pass: bool = True,
                    int_weights: bool = False, with_hist: bool = True,
                    bin_buckets=None, num_class: int = 1, root: bool = False,
-                   tile_groups: int = 0, small_slots: int = 0):
+                   tile_groups: int = 0, small_slots: int = 0,
+                   row_blocks=None):
     """One fused streaming pass: route rows through this round's splits and
     build grad/hess histograms and exact data counts of the rows' NEW slots.
 
@@ -1299,11 +1377,20 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
     contracts: a tile's histogram block stays in VMEM over its sweep of the
     rows, reads only its own groups' rows of bins_T, and every sweep sees the
     same slots.  0 is one tile: the one fused kernel.
+
+    row_blocks ((1,) int32, traced) is the caller's statement that the
+    columns from row_blocks * block_rows on hold no in-bag row (a compact
+    view, ops/compact.py): the pass streams the first row_blocks blocks
+    alone, and the leaf ids past them come back as they went in.  A tiled
+    table streams every block (its view's tail holds out-of-bag rows under
+    zero weights, which add nothing).
     """
+    if tile_groups:
+        row_blocks = None
     if (root and num_slots == 1 and with_hist and root_pass_kind(
             bins_T.dtype, int_weights, num_class) == "factored"):
         hist = _root_hist_factored(bins_T, w_T, bmax, num_groups, block_rows,
-                                   tile_groups)
+                                   tile_groups, row_blocks)
         # the slot's count is the caller's own row count; no grower reads it
         return leaf_id, hist, jnp.sum(w_T[2]).reshape(1)
     if tile_groups and bin_buckets is not None:
@@ -1319,7 +1406,7 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
                              "program (small_pass_index)")
         new_leaf, hist, cnt = _route_small_hist(
             bins_T, leaf_id, w_T, tabs, bits, S, bmax, num_groups,
-            num_leaves, block_rows, has_cat, tile_groups)
+            num_leaves, block_rows, has_cat, tile_groups, row_blocks)
         return (new_leaf,
                 jnp.pad(hist, ((0, num_slots - S),) + ((0, 0),) * 3),
                 jnp.pad(cnt, (0, num_slots - S)))
@@ -1353,7 +1440,7 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
         bins_T, leaf_id, w_T, tabs, bits, S=S, G=G, B=B, L=L, T=T, K=K,
         has_cat=has_cat, two_pass=two_pass, int_weights=int_weights,
         with_hist=with_hist, bin_buckets=bin_buckets, m_rows=m_rows,
-        slot_out=tiled)
+        slot_out=tiled, row_blocks=row_blocks)
 
     def _cnt_out(cnt):
         return cnt.reshape(-1) if K == 1 else cnt.reshape(K, S)
@@ -1399,7 +1486,7 @@ def route_and_hist(bins_T: jax.Array, leaf_id: jax.Array, w_T: jax.Array,
 def route_and_hist_live(live_slots, bins_T, leaf_id, w_T, tabs, bits,
                         num_slots: int, bmax: int, num_groups: int,
                         num_leaves: int, *, int_weights: bool = False,
-                        with_hist: bool = True, **static):
+                        with_hist: bool = True, row_blocks=None, **static):
     """route_and_hist for a round that knows how many leaves it splits
     (`live_slots`, traced; `tabs` gives rows the slots 0..live_slots-1 and no
     other): a round of one or two takes the small-slot pass, every other
@@ -1410,7 +1497,8 @@ def route_and_hist_live(live_slots, bins_T, leaf_id, w_T, tabs, bits,
     call = functools.partial(
         route_and_hist, num_slots=num_slots, bmax=bmax,
         num_groups=num_groups, num_leaves=num_leaves,
-        int_weights=int_weights, with_hist=with_hist, **static)
+        int_weights=int_weights, with_hist=with_hist, row_blocks=row_blocks,
+        **static)
     which = small_pass_index(live_slots if with_hist else None, bins_T.dtype,
                              int_weights, num_slots,
                              static.get("num_class", 1))

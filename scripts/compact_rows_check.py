@@ -74,15 +74,17 @@ def main():
     seed = int(sys.argv[1]) if len(sys.argv) > 1 else 0
     bins, w = operands(jax.random.PRNGKey(seed % (2 ** 31)))
     (want_bins, want_w, nc), take_ms = timed_ms(by_take, bins, w, reps=2)
-    (got_bins, got_w), stream_ms = timed_ms(
+    (got_bins, got_w, placed), stream_ms = timed_ms(
         lambda b, x: compact_rows(b, x, mask_row=MASK_ROW, capacity=CAPACITY,
                                   block_rows=BLOCK), bins, w, reps=5)
     bad = [int(n) for n in differing(got_bins, got_w, want_bins, want_w, nc)]
-    print(json.dumps({"ok": not any(bad), "differing_bytes": bad,
+    ok = not any(bad) and int(placed[0]) == min(int(nc), CAPACITY)
+    print(json.dumps({"ok": ok, "differing_bytes": bad,
                       "rows": ROWS, "capacity": CAPACITY, "in_bag": int(nc),
+                      "placed": int(placed[0]),
                       "sort_and_take_ms": take_ms, "compact_rows_ms": stream_ms,
                       "device": runtime.device_record()}))
-    return 0 if not any(bad) else 1
+    return 0 if ok else 1
 
 
 if __name__ == "__main__":

@@ -40,10 +40,12 @@ def _operands(mask, rs, bins_dtype=np.int8, groups=32, channels=8,
 def _assert_is_the_stable_take(bins_T, w_T, mask_row, capacity, got):
     """`got` == take by the stable permutation on the live columns (the
     in-bag rows of rank >= capacity dropped as perm[:capacity] drops them),
-    zero bytes from the in-bag count on."""
+    zero bytes from the in-bag count on, and the count of live columns."""
     plan = plan_sample_rows(w_T[mask_row], capacity)
     live = min(int(plan.nc), capacity)
     perm = np.asarray(plan.perm)[:live]
+    *got, placed = got
+    assert np.asarray(placed).tolist() == [live]
     for out, src in zip(got, (bins_T, w_T)):
         assert out.shape == (src.shape[0], capacity) and out.dtype == src.dtype
         assert np.array_equal(_bits(out[:, :live]),
@@ -169,10 +171,11 @@ def test_the_route_is_chosen_on_the_tiling(tile_groups, kind):
     n = 4 * T
     bins_T, w_T = _operands((rs.rand(n) < 0.3).astype(np.float32), rs)
     assert compact_kind(tile_groups) == kind
-    b_c, w_c = compact_transposed_view(bins_T, w_T, 2, 2 * T, T,
-                                       tile_groups=tile_groups)
+    b_c, w_c, placed = compact_transposed_view(bins_T, w_T, 2, 2 * T, T,
+                                               tile_groups=tile_groups)
     plan = plan_sample_rows(w_T[2], 2 * T)
     nc, perm = int(plan.nc), np.asarray(plan.perm)
+    assert np.asarray(placed).tolist() == [nc]
     assert np.array_equal(np.asarray(b_c)[:, :nc],
                           np.asarray(bins_T)[:, perm[:nc]])
     assert np.array_equal(_bits(w_c[:, :nc]), _bits(np.asarray(w_T)[:, perm[:nc]]))
@@ -196,13 +199,15 @@ def test_every_shard_compacts_its_own_rows(shares):
     bins_T, w_T = _operands(mask, rs)
     mesh = Mesh(np.array(jax.devices()[:4]), ("data",))
     rows = NamedSharding(mesh, P(None, "data"))
-    b_c, w_c = jax.jit(lambda b, w: compact_transposed_view(
+    b_c, w_c, placed = jax.jit(lambda b, w: compact_transposed_view(
         b, w, 2, capacity, T, mesh=mesh, row_axis="data"))(
             jax.device_put(bins_T, rows), jax.device_put(w_T, rows))
     assert b_c.shape == (32, 4 * capacity) and w_c.shape == (8, 4 * capacity)
+    assert placed.shape == (4,)
     for d in range(4):
         mine = slice(d * shard, (d + 1) * shard)
         _assert_is_the_stable_take(
             bins_T[:, mine], w_T[:, mine], 2, capacity,
             (np.asarray(b_c)[:, d * capacity:(d + 1) * capacity],
-             np.asarray(w_c)[:, d * capacity:(d + 1) * capacity]))
+             np.asarray(w_c)[:, d * capacity:(d + 1) * capacity],
+             np.asarray(placed)[d:d + 1]))

@@ -250,6 +250,32 @@ def test_flag_poll_and_sample_plan_records_carry_the_sampler(table):
                              poll["compact_rows"]}
 
 
+def test_flag_poll_says_the_rows_a_compacted_pass_streamed(table):
+    """A compacted tree's passes stop at the row blocks that hold its
+    in-bag rows: its `GBDT::FlagPoll` record (and the iteration's log
+    record) says how many rows a pass streamed, `pass_rows` - whole kernel
+    blocks, no fewer than the in-bag rows and no more than the capacity.
+    A dense tree's record has none."""
+    import time
+    from lightgbm_tpu import telemetry as tel
+    t0 = time.time_ns()
+    bst = _grow(table, dict(_FUSED_STREAM), rounds=1)
+    eng = bst.engine
+    eng._poll_device_flags()
+    dense = tel.recent_spans(name="GBDT::FlagPoll", since_unix_ns=t0)[-1].args
+    assert "pass_rows" not in dense
+    for _ in range(2):
+        bst.update()
+    eng._poll_device_flags()
+    poll = tel.recent_spans(name="GBDT::FlagPoll", since_unix_ns=t0)[-1].args
+    T = eng._pack_block
+    assert poll["compact_rows"] > 0 and poll["compact_rows"] % T == 0
+    assert poll["pass_rows"] % T == 0
+    assert poll["sampled_rows"] <= poll["pass_rows"] <= poll["compact_rows"]
+    assert poll["pass_rows"] < poll["sampled_rows"] + T
+    assert eng._pass_rows(poll["sampled_rows"]) == poll["pass_rows"]
+
+
 # ---------------------------------------------------------------------------
 # (c) the loop's own check on a sampled tree, and its controls
 # ---------------------------------------------------------------------------

@@ -153,6 +153,56 @@ def test_goss_compacted_bit_identical_mesh_4dev(comms, monkeypatch):
 
 
 @needs_mesh
+def test_goss_compacted_bit_identical_uneven_shards_mesh_4dev(monkeypatch):
+    """4-way mesh, GOSS, shards whose in-bag counts differ by more than a
+    kernel block: a third of shard 0's rows weigh three times as much (so
+    they dominate the global top_rate cut), none of shard 3's.  Every
+    device's passes stop at its OWN view's live row blocks, so the devices
+    stream different row counts of one histogram; the model must equal
+    the same views streamed whole (the passes before row_blocks) and the
+    pad reference."""
+    import lightgbm_tpu.ops.compact as compact
+    T = 256
+    monkeypatch.setenv("LGBTPU_BLOCK_ROWS", str(T))
+    X, y = make_synthetic_binary(n=4000)
+    rs = np.random.RandomState(0)
+    heavy = np.concatenate([rs.rand(1000) < share
+                            for share in (0.33, 0.2, 0.08, 0.0)])
+    w = np.where(heavy, 3.0, 1.0)
+    p = dict(GOSS, objective="binary", hist_backend="stream",
+             tree_learner="data", mesh_shape="data:4")
+    placed, live_row_blocks = [], compact.live_row_blocks
+
+    def spy(count, block):
+        jax.debug.callback(lambda c: placed.append(np.asarray(c)), count)
+        return live_row_blocks(count, block)
+
+    def whole(count, block):
+        return jnp.full_like(count, capacity // block)
+
+    monkeypatch.setattr(compact, "live_row_blocks", spy)
+    jax.clear_caches()
+    a = _train(p, X, y, "auto", weight=w)
+    capacity = a.engine._last_compact_rows
+    assert capacity == 2 * T and not a.engine._compact_overflow
+    counts = np.array(placed)
+    assert counts.shape[1] == 4 and counts.max() <= capacity
+    assert (counts.max(1) - counts.min(1) > T).any()
+    assert (-(-counts // T) == 1).any() and (-(-counts // T) == 2).any()
+    monkeypatch.setattr(compact, "live_row_blocks", whole)
+    jax.clear_caches()
+    full = _train(p, X, y, "auto", weight=w)
+    monkeypatch.setattr(compact, "live_row_blocks", live_row_blocks)
+    jax.clear_caches()
+    pad = _train(p, X, y, "pad", weight=w)
+    assert full.engine._last_compact_rows == capacity
+    assert pad.engine._last_compact_rows > capacity
+    assert _strip_params(a.model_to_string()) == \
+        _strip_params(full.model_to_string()) == \
+        _strip_params(pad.model_to_string())
+
+
+@needs_mesh
 @pytest.mark.slow
 def test_bagging_compacted_bit_identical_mesh_4dev(monkeypatch):
     monkeypatch.setenv("LGBTPU_BLOCK_ROWS", "256")

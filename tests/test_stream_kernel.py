@@ -236,7 +236,9 @@ def test_factored_root_exact(case):
         in_bag = np.random.RandomState(3).rand(N) < 0.4
         keep = jnp.asarray(in_bag, jnp.float32)
         w_T = w_T.at[:3, :N].multiply(keep[None, :])
-        bins_T, w_T = compact_transposed_view(bins_T, w_T, 2, block, block)
+        bins_T, w_T, placed = compact_transposed_view(bins_T, w_T, 2, block,
+                                                      block)
+        assert int(placed[0]) == in_bag.sum()
         lid = lid[:, :block]
         assert bins_T.shape[1] == block and in_bag.sum() <= block
     args = (bins_T, lid, w_T, tabs, bits, 1, Bmax, G, L)

@@ -314,21 +314,25 @@ def test_compact_rows_compiles_at_real_shapes(tpu, groups, dtype, block,
         jax.ShapeDtypeStruct((8, n), jnp.float32, sharding=tpu),
         mask_row=2, capacity=cap, block_rows=block).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    bins_c, w_c = compiled.out_info
+    bins_c, w_c, placed = compiled.out_info
     assert (bins_c.shape, bins_c.dtype) == ((groups, cap), dtype)
     assert (w_c.shape, w_c.dtype) == ((8, cap), jnp.float32)
+    assert (placed.shape, placed.dtype) == ((1,), jnp.int32)
 
 
 def test_the_sampled_program_is_pinned(tpu):
     """scripts/lowered_iteration_digest.py's fifth line, `higgs_goss_like`:
     the lowered v5e sampled iteration hashes, outside debug locations, to
-    what PR 40 left.  PR 37's (a2ab3ec) reads 786bb408... here, PR 38's and
-    PR 39's a5238574...: PR 38 took the partition's `sort_key_val` and the
-    two row gathers out for pallas/compact_kernel.py's one Mosaic call, and
-    PR 40 the sampler's threshold sort for an exact select of eight count
-    passes (models/sample_strategy.py kth_largest); the four dense lines
-    are the parent's.  A PR that means to change the sampled program
-    re-pins this line and says so."""
+    the value pinned here.  It read 786bb408... with the partition's sort
+    and gathers (a2ab3ec), a5238574... once they were
+    pallas/compact_kernel.py's one Mosaic call, 32c82feb... once the
+    sampler's threshold sort was an exact select of eight count passes
+    (models/sample_strategy.py kth_largest), and reads bc782b49... since the
+    passes over the compact view stop at its live row blocks (the one-tile
+    pass calls take `row_blocks` as a scalar prefetch and alias the leaf
+    ids); the four dense lines did not move with any of these.  A change
+    that means to change the sampled program re-pins this line and says
+    so."""
     import hashlib
     import sys
     digest, me = _digest_module(), sys.modules[__name__]
@@ -336,7 +340,7 @@ def test_the_sampled_program_is_pinned(tpu):
     assert eng._compact_cap == 4096
     text = digest.canonical(lowered.as_text())
     assert hashlib.sha256(text.encode()).hexdigest() == (
-        "32c82febfb5f3634333f7ffdac2a330d72fa853c72d63ab740ca8c97265229ff")
+        "bc782b492071adfa446ad44d38efb0fd41f962f4dbb22f7766e7f5aeeece5f42")
 
 
 def test_the_bucketed_program_is_the_parents(tpu):
